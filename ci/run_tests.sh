@@ -2,7 +2,10 @@
 # CI entry point: run the whole suite on the CPU backend (the conftest pins
 # JAX to CPU and forces an 8-device virtual mesh so every multi-chip
 # sharding path compiles and executes without TPU hardware), then the
-# multi-chip dry run and a bench smoke on CPU.
+# multi-chip dry run and an explicit CPU-backend bench run (a plumbing
+# check: `python bench.py` without BENCH_PLATFORM=cpu exits non-zero when
+# JAX finds no TPU).  The chip itself is checked by `python chip_smoke.py`
+# on a machine that has one.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -91,15 +94,17 @@ python -m pytest tests/test_staging.py tests/test_observability.py \
     tests/test_calibration.py -q -m 'not slow'
 python -m pytest tests/ -q -m 'not slow'
 python __graft_entry__.py 8
+# (pipefail above: a bench section that raises fails this line)
 BENCH_PLATFORM=cpu BENCH_E2E_TUPLES=131072 python bench.py | tee bench_ci_out.txt
 # the e2e decomposition keys (ratio_vs_kernel, staging_share_of_staged_run)
 # are the staging plane's evidence trail — fail if a bench refactor drops them
 python tools/check_bench_keys.py bench_ci_out.txt
 rm -f bench_ci_out.txt
-# run-over-run perf tripwire on the guarded bench_history.json scalars:
+# run-over-run perf tripwire on the guarded bench_history.json scalars
+# (a local, git-ignored record: a fresh checkout has only the row the
+# bench leg above just appended, and nothing to compare it with):
 # >10% regression vs the previous same-methodology run fails under CI=1
-# (warns locally); the bench leg above just appended the run under
-# judgment
+# (warns locally)
 CI="${CI:-1}" python tools/check_bench_regress.py
 # calibration gate: probe the CI backend, then verify the written store
 # is fresh + valid for THIS device kind (exit 1 = stale/corrupt/missing,
@@ -113,8 +118,9 @@ rm -f /tmp/wf_ci_calibration.json
 BENCH_HOST_TUPLES=4000 BENCH_HOST_VEC=2048 BENCH_HOST_REPS=1 python bench_host.py
 # nightly leg (CI_NIGHTLY=1): the slow-marked tail — the RSS soaks, the
 # two-OS-process DCN validation, the 100k ordering-perf pair, the
-# heaviest fuzz seeds and spec-sweep cells, the grouping/bench-chain/
-# sketch-overhead heavies (wfverify-round headroom pass), the chaos
+# heaviest fuzz seeds and spec-sweep cells, the grouping/sketch-overhead
+# heavies (wfverify-round headroom pass), the v5e AOT compile of the CB
+# step inside lax.scan, the chaos
 # soak matrix, and the xplane-serialize profile capture — runs here so
 # deselecting `slow` above never leaves them uncovered
 if [ "${CI_NIGHTLY:-0}" != "0" ]; then

@@ -55,7 +55,7 @@ def _store_doc(recorded_at=None, device_kind=None, constants=None,
         "jax_version": jax_version,
         "constants": constants or {
             "ici_bytes_per_sec": 42e9,
-            "h2d_tunnel_bytes_per_sec": 1e9,
+            "h2d_bytes_per_sec": 1e9,
             "hbm_bytes_per_sec": 5e9,
             "dispatch_overhead_usec": 8.0,
             "sampled_sync_usec": 2.0,
@@ -176,9 +176,9 @@ def test_corrupt_file_degrades_graph_build_with_warning(tmp_path):
             break
     g.wait_end()
     # the process stays on its modeled defaults
-    v, prov = cal.constant("hbm_bytes_per_sec")
+    v, prov = cal.constant("ici_bytes_per_sec")
     assert prov == "modeled"
-    assert v == cal.MODELED_DEFAULTS["hbm_bytes_per_sec"]
+    assert v == cal.MODELED_DEFAULTS["ici_bytes_per_sec"]
 
 
 # ---------------------------------------------------------------------------
@@ -193,13 +193,28 @@ def test_constant_round_trip_flips_value_and_tag():
     v, prov = cal.constant("ici_bytes_per_sec")
     assert v == 42e9
     assert cal.is_calibrated(prov)
-    v, prov = cal.constant("h2d_tunnel_bytes_per_sec")
+    # no modeled default exists for the H2D rate: measured or absent
+    v, prov = cal.constant("h2d_bytes_per_sec")
     assert (v, cal.is_calibrated(prov)) == (1e9, True)
     # clearing the store restores the modeled default
     cal.set_default_store(None)
     v, prov = cal.constant("ici_bytes_per_sec")
     assert prov == "modeled"
     assert v == cal.MODELED_DEFAULTS["ici_bytes_per_sec"]
+    assert cal.constant("h2d_bytes_per_sec") == (None, None)
+
+
+def test_hbm_peak_is_keyed_by_device_kind(monkeypatch):
+    """The roofline ceiling is the PUBLISHED peak of the live device
+    kind; a kind nobody published a figure for has no ceiling at all —
+    never another chip's number."""
+    assert cal.live_device_kind() not in cal.HBM_PEAK_BYTES_PER_SEC
+    assert cal.constant("hbm_bytes_per_sec") == (None, None)
+    assert "hbm_bytes_per_sec" not in cal.provenance_summary()["constants"]
+    monkeypatch.setattr(cal, "_device_kind_cache", "TPU v5 lite")
+    assert cal.constant("hbm_bytes_per_sec") == (819e9, "modeled")
+    monkeypatch.setattr(cal, "_device_kind_cache", "TPU v99")
+    assert cal.constant("hbm_bytes_per_sec") == (None, None)
 
 
 def test_constant_missing_key_stays_modeled():
@@ -213,10 +228,10 @@ def test_device_kind_mismatch_degrades_with_one_warning():
     _install(device_kind="TPU v99")
     with warnings.catch_warnings(record=True) as wlog:
         warnings.simplefilter("always")
-        v, prov = cal.constant("hbm_bytes_per_sec")
+        v, prov = cal.constant("dispatch_overhead_usec")
         v2, prov2 = cal.constant("ici_bytes_per_sec")
     assert prov == prov2 == "modeled"
-    assert v == cal.MODELED_DEFAULTS["hbm_bytes_per_sec"]
+    assert v == cal.MODELED_DEFAULTS["dispatch_overhead_usec"]
     kind_warns = [w for w in wlog if "device kind" in str(w.message)]
     assert len(kind_warns) == 1, "the mismatch warning must fire ONCE"
 
@@ -225,17 +240,17 @@ def test_ttl_staleness_degrades_with_one_warning():
     _install(recorded_at=time.time() - cal.TTL_S - 3600)
     with warnings.catch_warnings(record=True) as wlog:
         warnings.simplefilter("always")
-        v, prov = cal.constant("hbm_bytes_per_sec")
-        v2, _ = cal.constant("hbm_bytes_per_sec")
+        v, prov = cal.constant("ici_bytes_per_sec")
+        v2, _ = cal.constant("ici_bytes_per_sec")
     assert prov == "modeled"
-    assert v == v2 == cal.MODELED_DEFAULTS["hbm_bytes_per_sec"]
+    assert v == v2 == cal.MODELED_DEFAULTS["ici_bytes_per_sec"]
     stale = [w for w in wlog if "days old" in str(w.message)]
     assert len(stale) == 1, "the staleness warning must fire ONCE"
     # freshness is judged at read time: the SAME store read with a
     # clock inside the TTL serves the calibrated value
-    v, prov = cal.constant("hbm_bytes_per_sec",
+    v, prov = cal.constant("ici_bytes_per_sec",
                            now=time.time() - cal.TTL_S - 3000)
-    assert (v, cal.is_calibrated(prov)) == (5e9, True)
+    assert (v, cal.is_calibrated(prov)) == (42e9, True)
 
 
 def test_kill_switch_blocks_config_load(tmp_path, monkeypatch):
@@ -245,7 +260,7 @@ def test_kill_switch_blocks_config_load(tmp_path, monkeypatch):
     assert cal.killed()
     g, _ = _graph(_cfg(calibration=str(path)), n=512, name="cal_kill_app")
     assert cal.default_store() is None
-    _, prov = cal.constant("hbm_bytes_per_sec")
+    _, prov = cal.constant("ici_bytes_per_sec")
     assert prov == "modeled"
 
 
@@ -468,18 +483,21 @@ def test_roofline_section_on_real_graph(monkeypatch):
     assert sec["enabled"]
     assert sec["per_hop"], "no hop ever sampled a rate"
     assert sec["dominant_op"] in sec["per_hop"]
-    assert sec["bandwidth_provenance"] == "modeled"
+    # the CPU backend has no published HBM peak and nothing calibrated
+    # one: the section carries rates but no ceiling, no ratio
+    assert sec["bandwidth_bytes_per_sec"] is None
+    assert sec["bandwidth_provenance"] is None
     for name, hop in sec["per_hop"].items():
         assert hop["achieved_tuples_per_sec"] > 0, name
         assert hop["tuples_per_sec_provenance"] == "measured"
+        assert "ratio_vs_roofline" not in hop
         if "bytes_per_tuple" in hop:       # sweep-ledger join
             assert hop["bytes_per_tuple_provenance"] == "modeled"
-            assert hop["ratio_vs_roofline"] >= 0
             assert hop["achieved_bytes_per_sec"] == pytest.approx(
                 hop["achieved_tuples_per_sec"] * hop["bytes_per_tuple"],
                 rel=0.01)
     assert set(sec["calibration"]["constants"]) \
-        == set(cal.MODELED_DEFAULTS)
+        == {k for k, v in cal.MODELED_DEFAULTS.items() if v is not None}
     assert sec["verdict"] is None
 
 

@@ -604,3 +604,98 @@ def test_aligned_mesh_reduce_drops_out_of_range_keys():
     n_oor = int(np.sum((keys < 0) | (keys >= K)))
     assert red.num_dropped_tuples() == n_oor
     assert outs and all(0 <= k < K for k in outs)
+
+
+# ---------------------------------------------------------------------------
+# TPU cross-lowering (no chip): the Pallas -> Mosaic lowering and the
+# XLA:TPU compile run on the CPU backend, so a kernel that cannot lower
+# for a TPU fails HERE, not at a graph's first batch on the chip
+# ---------------------------------------------------------------------------
+
+# bench.CONFIGS["tpu"] — the shape chip_smoke.py drives
+_CAP, _K, _P, _R, _D = 262144, 1024, 128, 8, 1
+_S = jax.ShapeDtypeStruct
+
+
+def _lowers_for_tpu(fn, *shapes) -> str:
+    assert jax.config.jax_enable_x64, "the package's x64 state is the point"
+    return jax.export.export(jax.jit(fn), platforms=["tpu"])(*shapes) \
+        .mlir_module()
+
+
+def _ffat_step_shapes(monoid, pallas=pk.PallasMode(False)):
+    step = fk.make_ffat_step(_CAP, _K, _P, _R, _D, lambda x: x["v"],
+                             lambda a, b: a + b, lambda x: x["k"],
+                             monoid=monoid, pallas=pallas)
+    state = jax.eval_shape(
+        lambda: fk.make_ffat_state(jnp.zeros((), jnp.float32), _K, _R))
+    batch = ({"k": _S((_CAP,), jnp.int32), "v": _S((_CAP,), jnp.float32)},
+             _S((_CAP,), jnp.int64), _S((_CAP,), jnp.bool_))
+    return step, state, batch
+
+
+def test_grouping_kernel_lowers_for_tpu():
+    assert pk.grouping_supported(_CAP, _K + 1)
+    text = _lowers_for_tpu(
+        lambda ids: pk.grouping_rank_hist(ids, _K + 1, False),
+        _S((_CAP,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("monoid,dt", [("sum", jnp.float32),
+                                       ("max", jnp.float32),
+                                       ("sum", jnp.int32)])
+def test_sliding_fold_kernel_lowers_for_tpu(monoid, dt):
+    npp = _R - 1 + _CAP // _P + 2
+    vals, valid = _S((_K, npp), dt), _S((_K, npp), jnp.bool_)
+    assert pk.fold_supported(vals, _R, monoid, False)
+    text = _lowers_for_tpu(
+        lambda v, m: pk.sliding_fold(v, m, _R, monoid, False), vals, valid)
+    assert "tpu_custom_call" in text
+
+
+def test_dense_table_kernel_lowers_for_tpu():
+    text = _lowers_for_tpu(
+        lambda r, v, c: pk.dense_monoid_table(
+            r, [v, c], ["sum", "max"], [0.0, 0], _K, False),
+        _S((_CAP,), jnp.int32), _S((_CAP,), jnp.float32),
+        _S((_CAP,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("monoid", [None, "sum"])
+def test_ffat_step_with_kernels_lowers_for_tpu(monoid):
+    """The CB step a default TPU graph builds — generic combiner
+    (grouping kernel) and declared sum (grouping + pane fold)."""
+    step, state, batch = _ffat_step_shapes(monoid)
+    assert "tpu_custom_call" in _lowers_for_tpu(step, state, *batch)
+
+
+@pytest.mark.slow   # ~40 s: two real XLA:TPU compiles
+@pytest.mark.parametrize("with_kernels", [False, True])
+def test_cb_step_compiles_inside_scan_for_v5e(monkeypatch, with_kernels):
+    """What the megastep executor builds (``lax.scan`` around the CB
+    step, K=8) compiles for a v5e chip.  It did not while the K-long
+    fired-count running sum was int64: XLA:TPU emulates that cumsum as
+    a u32-pair reduce-window it cannot place in scoped VMEM inside a
+    while body (windows/ffat_kernels.py).  AOT against a described
+    topology — the real XLA:TPU and Mosaic compilers, no chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    sh = SingleDeviceSharding(
+        topologies.get_topology_desc("v5e:2x2", "tpu").devices[0])
+    step, state, batch = _ffat_step_shapes(
+        None, pk.PallasMode(False) if with_kernels else None)
+
+    def mega(st, payload, ts, valid):
+        def body(carry, x):
+            st2, out, fired, out_ts = step(carry, *x)
+            return st2, (out, fired, out_ts)
+        return jax.lax.scan(body, st, (payload, ts, valid))
+
+    stacked = jax.tree.map(lambda s: _S((8,) + s.shape, s.dtype), batch)
+    compiled = jax.jit(mega, in_shardings=sh, out_shardings=sh) \
+        .lower(state, *stacked).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == with_kernels

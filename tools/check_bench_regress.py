@@ -292,6 +292,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     threshold = float(os.environ.get("WF_BENCH_REGRESS_PCT", "10")) / 100.0
     strict = os.environ.get("CI") not in (None, "", "0")
+    if not os.path.exists(args.history):
+        # the history is a local, git-ignored record: a fresh checkout
+        # has none, and with nothing to compare nothing regressed
+        print(f"check_bench_regress: OK — no history at {args.history}")
+        return 0
     try:
         with open(args.history) as f:
             hist = json.load(f)

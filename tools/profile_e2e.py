@@ -43,10 +43,11 @@ def main():
     import numpy as np
 
     import bench as B
+    from windflow_tpu.compile_cache import setup_compile_cache
 
-    B._setup_compile_cache(jax)   # the bench's own methodology: fresh
-    # graph objects re-trace/lower every program; the persistent cache is
-    # what keeps the timed run measuring the framework, not the compiler
+    setup_compile_cache()   # the bench's own methodology: fresh graph
+    # objects re-trace/lower every program; the persistent cache is what
+    # keeps the timed run measuring the framework, not the compiler
     dev = jax.devices()[0]
     platform = dev.platform
     cfg = B.CONFIGS[platform]

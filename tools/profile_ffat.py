@@ -236,6 +236,8 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from windflow_tpu.compile_cache import setup_compile_cache
+    setup_compile_cache()
     dev = jax.devices()[0]
     platform = dev.platform
     # bench.py TPU config shapes (kept identical so the shares transfer)

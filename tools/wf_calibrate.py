@@ -2,9 +2,9 @@
 """wf_calibrate: probe the live backend, write calibration.json.
 
 The shard ledger's ICI model, the tenant ledger's modeled ICI share,
-the roofline ceiling, and ``bench.py``'s gap diagnosis all compute
-from constants (``calibration.MODELED_DEFAULTS``) that were, until
-this tool, hardcoded guesses.  ``wf_calibrate`` measures them — a
+and the roofline ceiling all compute from constants
+(``calibration.MODELED_DEFAULTS``) that were, until this tool,
+hardcoded guesses.  ``wf_calibrate`` measures them — a
 short seeded probe suite on the backend this process actually has —
 and writes a versioned ``calibration.json`` keyed by device kind +
 jax version.  Point ``Config.calibration`` / ``WF_TPU_CALIBRATION``
@@ -15,10 +15,10 @@ changes (docs/OBSERVABILITY.md "Calibration plane").
 
 Probes (all seeded, a few seconds total):
 
-* ``h2d_tunnel_bytes_per_sec`` — median host→device transfer rate of
-  a packed staging buffer (the SAME ``PackedBatchBuilder`` path the
-  runtime stages batches through, so the number is the tunnel the
-  staged e2e leg actually pays).
+* ``h2d_bytes_per_sec`` — median host→device transfer rate of a
+  packed staging buffer (the SAME ``PackedBatchBuilder`` path the
+  runtime stages batches through, so the number is the link the
+  staged e2e leg actually pays).  No modeled default exists for it.
 * ``dispatch_overhead_usec`` — wall cost of dispatching one cached
   trivial jitted program (the per-dispatch floor the megastep fold
   amortizes).
@@ -26,7 +26,7 @@ Probes (all seeded, a few seconds total):
   each ``trace_device_sync_every``-sampled batch pays).
 * ``hbm_bytes_per_sec`` — effective memory bandwidth of a large
   compiled elementwise copy (the roofline ceiling; on the CPU
-  fallback this measures host memory, honestly).
+  backend this measures host memory, honestly).
 * ``kernel_step_usec`` — one fused FFAT window step at the bench
   shape (the per-device-kind step timing the roofline cross-checks).
 * ``ici_bytes_per_sec`` — psum ring bandwidth across the mesh; only
@@ -207,7 +207,7 @@ def probe_ici(jax, np, reps: int = 7):
 
 
 PROBES = (
-    ("h2d_tunnel_bytes_per_sec", probe_h2d),
+    ("h2d_bytes_per_sec", probe_h2d),
     ("dispatch_overhead_usec", probe_dispatch),
     ("sampled_sync_usec", probe_sync),
     ("hbm_bytes_per_sec", probe_hbm),
@@ -224,6 +224,9 @@ def calibrate(out_path: str) -> int:
         return 2
     import jax
     import numpy as np
+
+    from windflow_tpu.compile_cache import setup_compile_cache
+    setup_compile_cache()
     dev = jax.devices()[0]
     kind = str(getattr(dev, "device_kind", None) or dev.platform)
     constants, probes = {}, {}

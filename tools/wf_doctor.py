@@ -428,7 +428,11 @@ def validate(bundle: dict) -> None:
                 raise BundleError(
                     f"roofline.json: hop {op!r} bytes provenance "
                     f"{prov!r} is not a legal tag")
-        if not _legal_provenance(rfl.get("bandwidth_provenance")):
+        # a device with neither a published peak nor a calibrated
+        # bandwidth carries no ceiling: both fields absent together
+        if (rfl.get("bandwidth_bytes_per_sec") is not None
+                or rfl.get("bandwidth_provenance") is not None) \
+                and not _legal_provenance(rfl.get("bandwidth_provenance")):
             raise BundleError(
                 f"roofline.json: bandwidth_provenance "
                 f"{rfl.get('bandwidth_provenance')!r} is not a legal "

@@ -190,8 +190,12 @@ CODES = {
                        "make) collective-free"),
     "WF902": ("error", "host callback / infeed-outfeed custom call "
                        "inside a hot-path program"),
-    "WF903": ("error", "f64/i64 values survived into a TPU-targeted "
-                       "program past the compiled-dtype gates"),
+    # a warning (as docs/ANALYSIS.md has always listed it): the
+    # framework's own timestamp lanes are int64, so on a TPU every
+    # ts-carrying program trips it — as an error it failed the preflight
+    # of a process's second mesh graph on the first real 4-chip run
+    "WF903": ("warning", "f64/i64 values survived into a TPU-targeted "
+                         "program past the compiled-dtype gates"),
     "WF904": ("warning", "dynamic-shape op in the lowered module (IR "
                          "twin of the WF812 recompile hazard)"),
     "WF905": ("error", "donation miss at IR level: donated operands "

@@ -495,10 +495,10 @@ class Config:
     # Calibration store (monitoring/calibration.py, tools/wf_calibrate.py,
     # docs/OBSERVABILITY.md "Calibration plane"): path of a versioned
     # calibration.json (probe-measured values for the modeled constants:
-    # ICI B/s, H2D tunnel B/s, HBM B/s, dispatch overhead, sampled-sync
+    # ICI B/s, H2D B/s, HBM B/s, dispatch overhead, sampled-sync
     # cost, kernel step time) keyed by device kind + jax version.  When
-    # set, the shard ledger's ICI model, the tenant ledger, the live
-    # roofline, and bench's gap_diagnosis compute from the calibrated
+    # set, the shard ledger's ICI model, the tenant ledger and the live
+    # roofline compute from the calibrated
     # constants and their provenance tags flip `modeled` →
     # `calibrated(<age>)`; stale past WF_TPU_CALIBRATION_TTL_S (default
     # 7 days) or a device-kind mismatch degrades back to `modeled` with

@@ -396,16 +396,17 @@ def _stage_soa(soa, tss, n: int, capacity: int, watermark: int,
                             watermark=watermark, device=device,
                             frontier=frontier, ts_max=ts_max,
                             ts_min=ts_min, pool=pool, trace=trace)
+    # host buffers go STRAIGHT to their placement: a sharding splits on
+    # the host and each chip receives only its own shard (staging through
+    # jnp.asarray first would land the whole batch on device 0 and
+    # re-shard from there)
+    def put(a):
+        return jnp.asarray(a) if device is None \
+            else jax.device_put(a, device)
     payload = jax.tree.map(
-        lambda a: jnp.asarray(_pad_leading(np.ascontiguousarray(a),
-                                           capacity)), soa)
-    ts = jnp.asarray(_pad_leading(np.asarray(tss, dtype=np.int64), capacity),
-                     dtype=TS_DTYPE)
-    valid = jnp.asarray(np.arange(capacity) < n)
-    if device is not None:
-        payload = jax.device_put(payload, device)
-        ts = jax.device_put(ts, device)
-        valid = jax.device_put(valid, device)
+        lambda a: put(_pad_leading(np.ascontiguousarray(a), capacity)), soa)
+    ts = put(_pad_leading(np.asarray(tss, dtype=np.int64), capacity))
+    valid = put(np.arange(capacity) < n)
     out = DeviceBatch(payload, ts, valid, watermark=watermark, size=n,
                       frontier=frontier, ts_max=ts_max, ts_min=ts_min,
                       trace=trace)

@@ -42,8 +42,15 @@ banded matmul accumulates f32 sums in contraction order where the lax
 fold uses a doubling tree — exact whenever the summands are integers
 below 2**24 (every record-for-record A/B family), reassociation-grade
 otherwise, exactly the tolerance the declared-"sum" contract already
-implies for psum.  max/min and integer sums are bit-identical
-unconditionally.
+implies for psum.  That holds only at ``Precision.HIGHEST``: the MXU's
+default single bf16 pass rounds the values to 8 mantissa bits (1.8e-3
+relative error per window on a v5e — chip_smoke.py leg A_sum).  max/min
+and integer sums are bit-identical unconditionally.
+
+The compiled kernels trace with x64 off (:func:`_pallas_call`) and
+shift along lanes with ``pltpu.roll`` + an iota mask
+(:func:`_shift_cols`): Mosaic has no 64-bit vector types and refuses
+the unaligned ``tpu.concatenate`` that ``jnp.pad`` lowers to.
 """
 
 from __future__ import annotations
@@ -54,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: lane tile of the grouping / table kernels (second-to-last dim of the
 #: one-hot blocks; 256 keeps the [TILE, buckets] mask under ~4 MB VMEM
@@ -165,14 +173,38 @@ def _iota2(dtype, shape, dim):
     return jax.lax.broadcasted_iota(dtype, shape, dim)
 
 
+def _pallas_call(kernel, *args, interpret: bool, **kw):
+    """``pl.pallas_call(kernel, **kw)(*args)``; the compiled (Mosaic)
+    form traces with x64 OFF.
+
+    The package enables ``jax_enable_x64`` process-wide (int64
+    timestamp lanes), under which every Python scalar, index-map result
+    and ``program_id`` product in a kernel traces 64-bit — and Mosaic
+    has no 64-bit vector types (the TPU lowering dies in
+    ``convert_element_type`` / "failed to legalize func.return").  The
+    compiled dtype gates admit 32-bit-or-narrower lanes only, so the
+    whole call traces in a scoped 32-bit world instead of pinning each
+    constant by hand.  The interpreter keeps the ambient x64: its gates
+    admit int64/f64 lanes, which a 32-bit trace would truncate."""
+    if interpret:
+        return pl.pallas_call(kernel, interpret=True, **kw)(*args)
+    with jax.enable_x64(False):
+        return pl.pallas_call(kernel, **kw)(*args)
+
+
 def _shift_cols(x, k: int, fill):
-    """Shift a [..., N] VALUE right along the last axis by ``k``,
-    filling the vacated low columns with ``fill`` (the in-kernel form
-    of ``ffat_kernels._shift_leaf``)."""
+    """Shift a 2-D ``[rows, N]`` VALUE right along the lane axis by
+    ``k``, filling the vacated low columns with ``fill`` (the in-kernel
+    form of ``ffat_kernels._shift_leaf``).  A lane rotate + iota mask:
+    ``jnp.pad`` lowers to an unaligned ``tpu.concatenate`` Mosaic
+    refuses ("Input offsets outside of the first tile")."""
     if k == 0:
         return x
-    widths = [(0, 0)] * (x.ndim - 1) + [(k, 0)]
-    return jnp.pad(x, widths, constant_values=fill)[..., :x.shape[-1]]
+    n = x.shape[-1]
+    if k >= n:
+        return jnp.full(x.shape, fill, x.dtype)
+    return jnp.where(_iota2(jnp.int32, x.shape, x.ndim - 1) >= k,
+                     pltpu.roll(x, k, x.ndim - 1), fill)
 
 
 def _monoid_op(kind: str):
@@ -267,13 +299,13 @@ def grouping_rank_hist(ids, nbuckets: int, interpret: bool):
         def _phase1():
             @pl.when(t == 0)
             def _():
-                tot = run[0, :]
+                tot = run[...]
                 inc = tot
                 s = 1
                 while s < NBp:
                     inc = inc + _shift_cols(inc, s, 0)
                     s *= 2
-                bstart[...] = (inc - tot)[None, :]
+                bstart[...] = inc - tot
                 run[...] = jnp.zeros_like(run)
 
             onef = onehot.astype(jnp.float32)
@@ -296,9 +328,8 @@ def grouping_rank_hist(ids, nbuckets: int, interpret: bool):
             dest_ref[0, :] = (within + cross + start).astype(jnp.int32)
             run[...] += colsum
 
-    from jax.experimental.pallas import tpu as pltpu
-    dest, rank, hist = pl.pallas_call(
-        kernel,
+    dest, rank, hist = _pallas_call(
+        kernel, ids2,
         grid=(2, T),
         in_specs=[pl.BlockSpec((1, LANE_TILE), lambda p, t: (0, t))],
         out_specs=(pl.BlockSpec((1, LANE_TILE), lambda p, t: (0, t)),
@@ -310,7 +341,7 @@ def grouping_rank_hist(ids, nbuckets: int, interpret: bool):
         scratch_shapes=[pltpu.VMEM((1, NBp), jnp.int32),
                         pltpu.VMEM((1, NBp), jnp.int32)],
         interpret=interpret,
-    )(ids2)
+    )
     return dest[0, :B], rank[0, :B], hist[0, :NB]
 
 
@@ -380,8 +411,12 @@ def _fold_leaf(x, valid, R: int, monoid: str):
             mi = _iota2(jnp.int32, (ch + R - 1, ch), 1)
             band = ((li >= mi) & (li <= mi + (R - 1))) \
                 .astype(jnp.float32)
+            # HIGHEST: the MXU's default single bf16 pass keeps 8
+            # mantissa bits of the VALUES (the 0/1 band is exact) —
+            # measured 1.8e-3 relative error per window sum on a v5e
             chunks.append(jnp.dot(sub, band,
-                                  preferred_element_type=jnp.float32))
+                                  preferred_element_type=jnp.float32,
+                                  precision=jax.lax.Precision.HIGHEST))
         return jnp.concatenate(chunks, axis=1)
     # VPU path: EXACTLY ffat_kernels._sliding_reduce_plain's schedule
     # (pow2 doubling + binary stitching) so float results are
@@ -431,15 +466,15 @@ def sliding_fold(values, valid, R: int, monoid: str, interpret: bool):
             o_ref[...] = _fold_leaf(i_ref[...], v, R, monoid)
 
     spec = pl.BlockSpec((ROW_TILE, NPPp), lambda k: (k, 0))
-    folded = pl.pallas_call(
-        kernel,
+    folded = _pallas_call(
+        kernel, vpad, *lpad,
         grid=(Kp // ROW_TILE,),
         in_specs=[spec] * (1 + len(leaves)),
         out_specs=tuple([spec] * len(leaves)),
         out_shape=tuple(jax.ShapeDtypeStruct((Kp, NPPp), l.dtype)
                         for l in leaves),
         interpret=interpret,
-    )(vpad, *lpad)
+    )
     if not isinstance(folded, (list, tuple)):
         folded = (folded,)
     return jax.tree_util.tree_unflatten(
@@ -596,8 +631,8 @@ def dense_monoid_table(row, leaves: Sequence, ops: Sequence[str],
 
     out_specs = tuple(pl.BlockSpec((w, Sp), lambda t: (0, 0))
                       for w in widths)
-    outs = pl.pallas_call(
-        kernel,
+    outs = _pallas_call(
+        kernel, row2, *ins,
         grid=(Bp // LANE_TILE,),
         in_specs=[pl.BlockSpec((1, LANE_TILE), lambda t: (0, t))]
         + [pl.BlockSpec((w, LANE_TILE), lambda t: (0, t))
@@ -606,7 +641,7 @@ def dense_monoid_table(row, leaves: Sequence, ops: Sequence[str],
         out_shape=tuple(jax.ShapeDtypeStruct((w, Sp), l.dtype)
                         for w, l in zip(widths, leaves)),
         interpret=interpret,
-    )(row2, *ins)
+    )
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
     tables = []

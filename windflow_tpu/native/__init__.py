@@ -3,16 +3,23 @@
 The native layer mirrors the reference's C++ runtime surface (SURVEY.md
 §2.2 keyby hashing, §5.8 watermark plumbing): bulk ingest parsing, key
 partitioning, and the watermark fold, plus the log-structured KV
-(``wf_kv.cpp``).  The library is built on demand with make from the
-package-data sources and loaded via ctypes; every entry point has a numpy
-fallback so the framework works (slower) without a C++ toolchain.
+(``wf_kv.cpp``).  The library is compiled on first use from the sources
+shipped next to this module and loaded via ctypes.  Every entry point
+keeps a numpy twin for installs without a C++ toolchain, but selecting
+it is never silent: a failed build warns with the compiler's message,
+and ``WF_TPU_NO_NATIVE=1`` is the explicit opt-out.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import shutil
 import subprocess
+import tempfile
+import warnings
 from typing import Optional
 
 import numpy as np
@@ -20,78 +27,85 @@ import numpy as np
 # native sources ship as package data next to this module, so wheels and
 # editable checkouts build identically
 _NATIVE_DIR = os.path.dirname(os.path.abspath(__file__))
-_SO_PATH = os.path.join(_NATIVE_DIR, "libwfhost.so")
+_SOURCES = ("wf_host.cpp", "wf_kv.cpp")
 
 _lib = None
 _load_attempted = False
 
 
-_SOURCES = ("wf_host.cpp", "wf_kv.cpp")
+class NativeBuildError(RuntimeError):
+    """The native library could not be compiled from its sources."""
 
 
-_CACHE_DIR = os.path.join(os.path.expanduser("~"), ".cache",
-                          "windflow_tpu", "native")
+def build_dir() -> str:
+    """Where the compiled library lives: a fixed git-ignored directory
+    next to the sources, or the user cache for a read-only install
+    (site-packages)."""
+    if os.access(_NATIVE_DIR, os.W_OK):
+        return os.path.join(_NATIVE_DIR, "_build")
+    return os.path.join(os.path.expanduser("~"), ".cache", "windflow_tpu",
+                        "native")
 
 
-def _build() -> bool:
-    global _SO_PATH
-    if not all(os.path.exists(os.path.join(_NATIVE_DIR, s))
-               for s in _SOURCES):
-        return False
+def _source_digest() -> str:
+    """Content hash of everything the build reads.  It names the
+    artifact, so a library built from other sources is never loaded —
+    file times say nothing after a copy or a checkout."""
+    h = hashlib.sha256()
+    for name in _SOURCES + ("Makefile",):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:16]
+
+
+def build(force: bool = False) -> str:
+    """Compile the library for the current sources unless it is already
+    there (``force`` recompiles regardless); returns its path.  Built in
+    a private temp dir and published with an atomic rename, so a
+    concurrent reader never dlopens a half-written file.  Raises
+    :class:`NativeBuildError` carrying make's output."""
+    out_dir = build_dir()
+    final = os.path.join(out_dir, f"libwfhost-{_source_digest()}.so")
+    if os.path.exists(final) and not force:
+        return final
     try:
-        if os.access(_NATIVE_DIR, os.W_OK):
-            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                           capture_output=True, timeout=120)
-            return os.path.exists(_SO_PATH)
-        # Read-only install (site-packages): build in a private temp dir
-        # and atomically publish the .so into the user cache — concurrent
-        # processes each build their own copy and the rename is atomic, so
-        # a reader never dlopens a half-written library.
-        import shutil
-        import tempfile
-        os.makedirs(_CACHE_DIR, exist_ok=True)
-        with tempfile.TemporaryDirectory(dir=_CACHE_DIR) as tmp:
+        os.makedirs(out_dir, exist_ok=True)
+        with tempfile.TemporaryDirectory(dir=out_dir) as tmp:
             for src in _SOURCES + ("Makefile",):
                 shutil.copy(os.path.join(_NATIVE_DIR, src), tmp)
             subprocess.run(["make", "-C", tmp], check=True,
-                           capture_output=True, timeout=120)
-            built = os.path.join(tmp, "libwfhost.so")
-            if not os.path.exists(built):
-                return False
-            final = os.path.join(_CACHE_DIR, "libwfhost.so")
-            os.replace(built, final)
-            _SO_PATH = final
-            return True
-    except (OSError, subprocess.SubprocessError):
-        # no toolchain / read-only everything / make failure: callers fall
-        # back to the numpy implementations
-        return False
+                           capture_output=True, text=True, timeout=300)
+            os.replace(os.path.join(tmp, "libwfhost.so"), final)
+    except subprocess.CalledProcessError as e:
+        raise NativeBuildError(
+            f"make failed ({e.returncode}): {e.stderr.strip()[-2000:]}") \
+            from e
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"{type(e).__name__}: {e}") from e
+    for old in glob.glob(os.path.join(out_dir, "libwfhost-*.so")):
+        if old != final:
+            os.remove(old)
+    return final
 
 
 def lib() -> Optional[ctypes.CDLL]:
-    """The loaded native library, building it first if needed; None when the
-    toolchain or sources are unavailable (callers fall back to numpy)."""
-    global _lib, _load_attempted, _SO_PATH
+    """The loaded native library, building it first if needed.  ``None``
+    selects the numpy twins: under ``WF_TPU_NO_NATIVE``, or — with a
+    warning naming the failure — when the build or the load failed."""
+    global _lib, _load_attempted
     if _lib is not None or _load_attempted:
         return _lib
     _load_attempted = True
     if os.environ.get("WF_TPU_NO_NATIVE"):
         return None
-    srcs = [os.path.join(_NATIVE_DIR, s) for s in _SOURCES]
-    if not os.access(_NATIVE_DIR, os.W_OK):
-        # read-only install: the artifact lives in the user cache
-        cached = os.path.join(_CACHE_DIR, "libwfhost.so")
-        if os.path.exists(cached):
-            _SO_PATH = cached
-    stale = (not os.path.exists(_SO_PATH)
-             or any(os.path.exists(s)
-                    and os.path.getmtime(s) > os.path.getmtime(_SO_PATH)
-                    for s in srcs))
-    if stale and not _build():
-        return None
     try:
-        L = ctypes.CDLL(_SO_PATH)
-    except OSError:
+        L = ctypes.CDLL(build())
+    except (NativeBuildError, OSError) as e:
+        warnings.warn(
+            f"windflow_tpu native runtime unavailable ({e}); using the "
+            "numpy parsers and the pure-Python KV store — set "
+            "WF_TPU_NO_NATIVE=1 to choose them on purpose",
+            RuntimeWarning, stacklevel=2)
         return None
     i8, i4, u8 = ctypes.c_int64, ctypes.c_int32, ctypes.c_uint64
     p = ctypes.c_void_p

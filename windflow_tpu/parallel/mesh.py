@@ -37,20 +37,8 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# jax.shard_map is the >= 0.6 spelling (replication check kwarg
-# `check_vma`); the 0.4.x floor ships it under jax.experimental with the
-# check named `check_rep` — resolve once so every collective below works
-# on both
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
 
 from windflow_tpu.basic import WindFlowError
 from windflow_tpu.batch import DeviceBatch, HostBatch, host_to_device
@@ -107,12 +95,8 @@ def state_sharding(mesh: Mesh) -> NamedSharding:
 def stage_batch(hb: HostBatch, capacity: int, mesh: Mesh) -> DeviceBatch:
     """Host→mesh staging: pad to ``capacity`` and lay tuples out data-sharded
     (the multi-chip form of the reference's pinned-staging H2D path)."""
-    db = host_to_device(hb, capacity=capacity)
-    sh = batch_sharding(mesh)
-    return DeviceBatch(
-        jax.tree.map(lambda a: jax.device_put(a, sh), db.payload),
-        jax.device_put(db.ts, sh), jax.device_put(db.valid, sh),
-        watermark=db.watermark, size=db.known_size)
+    return host_to_device(hb, capacity=capacity,
+                          device=batch_sharding(mesh))
 
 
 def _aligned_slot_bound(op) -> Optional[int]:

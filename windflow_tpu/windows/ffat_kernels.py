@@ -490,13 +490,22 @@ def make_ffat_step(capacity: int, K: int, P: int, R: int, D: int,
         # output batch (see docstring): compacted slot i belongs to the key
         # whose fired-count running sum first exceeds i; everything else is
         # per-slot arithmetic + one gather from the sliding fold.
-        offs = jnp.cumsum(n_fired)                             # [K]
+        # The running sum is int32 and widened after: a key fires at
+        # most capacity/(P*D) + 1 windows per batch and the total is
+        # bounded by MAXO.  XLA:TPU emulates an int64 cumsum as a
+        # variadic u32-pair reduce-window, which it cannot place in
+        # scoped VMEM once the step sits in a lax.scan body (megastep):
+        # "RESOURCE_EXHAUSTED ... reduce-window (u32[8,128], u32[8,128])
+        # ... Scoped allocation 19.07M, limit 16.00M" at every capacity.
+        fired32 = n_fired.astype(jnp.int32)
+        offs = jnp.cumsum(fired32)                             # [K]
         n_out = offs[K - 1]
-        i_slot = jnp.arange(MAXO, dtype=jnp.int64)
+        i_slot = jnp.arange(MAXO, dtype=jnp.int32)
         k_out = jnp.searchsorted(offs, i_slot, side="right") \
             .astype(jnp.int32)                                 # [MAXO]
         k_c = jnp.minimum(k_out, K - 1)
-        j_out = i_slot - (offs[k_c] - n_fired[k_c])            # rank in key
+        j_out = (i_slot - (offs[k_c] - fired32[k_c])) \
+            .astype(jnp.int64)                                 # rank in key
         e_out = state["win_next"][k_c] + j_out * D
         # window value: sliding-fold cell at the window's end pane
         widx_out = jnp.clip(
