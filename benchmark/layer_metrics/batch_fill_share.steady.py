@@ -1,0 +1,4 @@
+"""As ``batch_fill_share.sat``, for the cells that report latency."""
+from benchmark.harness import load_module
+
+read = load_module("layer_metrics", "batch_fill_share.sat").read

@@ -1,0 +1,4 @@
+"""As ``h2d_host_ms_per_batch.sat``, for the cells that report latency."""
+from benchmark.harness import load_module
+
+read = load_module("layer_metrics", "h2d_host_ms_per_batch.sat").read

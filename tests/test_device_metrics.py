@@ -335,15 +335,24 @@ def test_dump_trace_carries_profiler_cross_reference(tmp_path):
     with open(path) as f:
         trace = json.load(f)
     other = trace["otherData"]
-    assert "trace:<trace_id>" in other["profiler_annotation_format"]
-    assert other["profiler_dir"]
+    # the layer spans are inside a profiler capture itself: this file
+    # carries their table, on its own (wall) clock, and points at no
+    # second file to line up by eye
+    assert "profiler_annotation_format" not in other
+    assert "profiler_dir" not in other
+    assert other["clock"] == "wall_usec"
+    assert other["layers"] == g.stats()["Layers"]
+    assert other["layers"]["wf.dispatch"]["count"] > 0
 
 
 class _CountingAnnotation:
     count = 0
+    dispatches = 0
 
-    def __init__(self, *a, **k):
+    def __init__(self, name, **counts):
         _CountingAnnotation.count += 1
+        _CountingAnnotation.dispatches += name == "wf.dispatch" \
+            and counts.get("op", "").endswith("_map")
 
     def __enter__(self):
         return self
@@ -351,20 +360,28 @@ class _CountingAnnotation:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **counts):
+        pass
+
 
 def test_annotation_off_path_is_one_attribute_check(monkeypatch):
-    """Recorder off => no trace lane => the dispatch path must never even
-    construct a TraceAnnotation (the documented off-path budget: one
-    `is not None` check per batch)."""
+    """Recorder off => no root span => no site ever constructs a
+    TraceAnnotation or writes a table (the documented off-path budget:
+    one `is None` check per site)."""
     monkeypatch.setattr(jax.profiler, "TraceAnnotation",
                         _CountingAnnotation)
     _CountingAnnotation.count = 0
     g, _ = _graph("dm_annot_off", flight_recorder=False)
     g.run()
     assert _CountingAnnotation.count == 0
-    # and with sampling on, the sampled batches ARE annotated
-    _CountingAnnotation.count = 0
+    assert g.stats()["Layers"] == {}
+    # and with it on EVERY dispatch is annotated, whatever the sampling
+    _CountingAnnotation.count = _CountingAnnotation.dispatches = 0
     g, _ = _graph("dm_annot_on", flight_recorder=True,
-                  trace_sample_every=2)
+                  trace_sample_every=64)
     g.run()
-    assert _CountingAnnotation.count > 0
+    launched = sum(r["Device_programs_launched"]
+                   for op in g.stats()["Operators"]
+                   if op["Operator_name"] == "dm_annot_on_map"
+                   for r in op["Replicas"])
+    assert _CountingAnnotation.dispatches == launched > 1

@@ -1,0 +1,325 @@
+"""Layer spans (docs/OBSERVABILITY.md "Span tracing"): the one span
+primitive of the flight recorder, at every layer boundary of a sweep.
+Nothing here times anything: a fake ``TraceAnnotation`` records what a
+profiler capture would hold, and the recorder's own table is compared with
+itself."""
+
+import dataclasses
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+import windflow_tpu as wf
+from windflow_tpu import staging
+from windflow_tpu.basic import default_config
+from windflow_tpu.io import FrameSource
+from windflow_tpu.monitoring import recorder as flightrec
+from windflow_tpu.monitoring.openmetrics import (parse_exposition,
+                                                 render_openmetrics)
+
+#: every span name the FrameSource -> FFAT -> columnar sink graph reaches
+#: per batch (``wf.pool.wait``, ``wf.megastep.drain`` have tests of their own)
+REACHED = {"wf.sweep", "wf.source.tick", "wf.parse", "wf.pack",
+           "wf.wire.encode", "wf.h2d", "wf.dispatch", "wf.compile",
+           "wf.drain", "wf.sink.d2h", "wf.sink.deliver"}
+
+
+class _Annotation:
+    """Stands where ``jax.profiler.TraceAnnotation`` does and keeps what a
+    capture would: name, counts, the thread, and the open/close order."""
+
+    made = []
+
+    def __init__(self, name, **counts):
+        self.name, self.counts = name, dict(counts)
+        self.thread = threading.get_ident()
+        self.opened = self.closed = None
+        _Annotation.made.append(self)
+
+    def __enter__(self):
+        self.opened = len(_Annotation.made)
+        return self
+
+    def __exit__(self, *exc):
+        self.closed = len(_Annotation.made)
+        return False
+
+    def set_metadata(self, **counts):
+        self.counts.update(counts)
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    _Annotation.made = []
+    return _Annotation.made
+
+
+def _frame_graph(name, n=5000, cap=1024, **cfg_kw):
+    """FrameSource -> keyed sliding count-window sum -> columnar sink,
+    in 700-record chunks that never end on a batch."""
+    rec = np.zeros(n, dtype=[("k", "<i8"), ("t", "<i8"), ("v0", "<f8")])
+    rec["k"] = np.arange(n) % 8
+    rec["t"] = np.arange(n)
+    rec["v0"] = 1.0
+    blob = rec.tobytes()
+    step = rec.dtype.itemsize * 700
+
+    def chunks():
+        for i in range(0, len(blob), step):
+            yield blob[i:i + step]
+
+    src = FrameSource(chunks, nv=1, fmt="frames", output_batch_size=cap)
+    src.record_spec = {"key": np.int32(0), "v0": np.float32(0.0)}
+    win = (wf.Ffat_WindowsTPU_Builder(lambda t: t["v0"], lambda a, b: a + b)
+           .withName("ffat").withCBWindows(16, 4)
+           .withKeyBy(lambda t: t["key"]).withMaxKeys(8).build())
+    got = []
+    snk = wf.Sink_Builder(got.append).withColumnarSink().build()
+    cfg = dataclasses.replace(default_config, **cfg_kw)
+    g = wf.PipeGraph(name, wf.ExecutionMode.DEFAULT, wf.TimePolicy.EVENT,
+                     config=cfg)
+    g.add_source(src).add(win).add_sink(snk)
+    return g, got
+
+
+@pytest.fixture
+def ran(annotations):
+    g, got = _frame_graph("spans_on", trace_sample_every=2)
+    g.run()
+    assert sum(len(c) for c in got if c is not None) > 0
+    return g, annotations
+
+
+def test_every_reachable_span_is_recorded(ran):
+    g, made = ran
+    layers = g.stats()["Layers"]
+    assert REACHED <= set(layers)
+    assert REACHED <= {a.name for a in made}
+    for name, row in layers.items():
+        assert row["count"] == sum(a.name == name for a in made)
+        assert 0 <= row["self_ns"] <= row["total_ns"]
+
+
+def test_self_times_telescope_to_the_sweeps(ran):
+    """A span's self time is its duration minus its children's, so on the
+    driver thread the self times of all names add up to ``wf.sweep``'s
+    total, to the nanosecond."""
+    g, _ = ran
+    table = g._recorder.layers(thread=threading.get_ident())
+    assert sum(r["self_ns"] for r in table.values()) \
+        == table["wf.sweep"]["total_ns"]
+    # one thread recorded: the graph-wide table is the driver's
+    assert g.stats()["Layers"] == table
+
+
+def test_spans_nest_as_the_layers_do(ran):
+    """parse and pack are siblings under the source's tick; the wire
+    encode, the H2D and the unpack dispatch happen inside a pack; a
+    compile inside the dispatch that met a new signature."""
+    _, made = ran
+
+    def parent(a):
+        inside = [b for b in made if b.opened < a.opened
+                  and b.closed >= a.closed and b is not a]
+        return max(inside, key=lambda b: b.opened).name if inside else None
+
+    by_name = {}
+    for a in made:
+        by_name.setdefault(a.name, set()).add(parent(a))
+    assert by_name["wf.sweep"] == {None}
+    assert by_name["wf.source.tick"] == {"wf.sweep"}
+    assert by_name["wf.parse"] == {"wf.source.tick"}
+    assert by_name["wf.pack"] == {"wf.source.tick"}
+    assert by_name["wf.drain"] == {"wf.sweep"}
+    # (the window's flush at end of stream compiles under the drain, the
+    # sink's egress pack under its transfer)
+    assert "wf.dispatch" in by_name["wf.compile"] \
+        <= {"wf.dispatch", "wf.drain", "wf.sink.d2h"}
+    assert "wf.pack" in by_name["wf.wire.encode"]
+    assert "wf.pack" in by_name["wf.h2d"]
+    assert by_name["wf.sink.d2h"] <= {"wf.drain"}
+    assert by_name["wf.sink.deliver"] <= {"wf.drain"}
+    sweeps = [a.counts["sweep"] for a in made if a.name == "wf.sweep"]
+    assert sweeps == list(range(1, len(sweeps) + 1))
+
+
+def test_batch_number_is_shared_from_encode_to_sink(ran):
+    g, made = ran
+    staged = [a.counts["batch"] for a in made if a.name == "wf.wire.encode"]
+    assert staged == sorted(set(staged)) and staged[0] >= 1
+    for name, op in (("wf.h2d", None), ("wf.dispatch", "staging.unpack"),
+                     ("wf.dispatch", "ffat")):
+        seen = [a.counts["batch"] for a in made if a.name == name
+                and (op is None or a.counts["op"] == op)]
+        assert seen == staged, (name, op)
+    # the sink pulls its deferred queue in one transfer: the span carries
+    # the first batch's number and how many ride with it
+    d2h = [a.counts for a in made if a.name == "wf.sink.d2h"]
+    # (the window's flush at the end of the stream is born on the device:
+    # it has no number)
+    arrived = staged + [0]
+    assert sum(c["batches"] for c in d2h) == len(arrived)
+    firsts, i = [], 0
+    for c in d2h:
+        firsts.append(arrived[i])
+        i += c["batches"]
+    assert [c["batch"] for c in d2h] == firsts
+    assert all(c["bytes"] > 0 for c in d2h)
+    delivered = [a.counts["batch"] for a in made
+                 if a.name == "wf.sink.deliver"]
+    assert set(delivered) <= set(arrived)
+    # a sampled batch's trace id is that number
+    traced = {e["trace"] for e in g._recorder.events()}
+    assert traced and traced <= set(staged)
+
+
+def test_every_dispatch_has_a_span(ran):
+    """Not one batch in 64: each staged batch shows its unpack and its
+    operator step by ``op=`` and ``batch=``."""
+    g, made = ran
+    st = g.stats()
+    disp = [a.counts for a in made if a.name == "wf.dispatch"]
+    assert all("op" in c and "batch" in c for c in disp)
+    n = st["Staging"]["batches"]
+    assert sum(c["op"] == "staging.unpack" for c in disp) == n
+    assert sum(c["op"] == "ffat" for c in disp) == n
+    # (the unpack program is the process's: an earlier graph compiled it)
+    compiled = {a.counts["op"] for a in made if a.name == "wf.compile"}
+    assert "ffat" in compiled
+
+
+def test_fill_is_counted_where_the_batch_is_cut(ran):
+    """Chunks of 700 records into batches of 1024: what the punctuation
+    and the end of stream flush short shows as ``n < cap`` on the wire
+    encode and in the staging counters."""
+    g, made = ran
+    enc = [a.counts for a in made if a.name == "wf.wire.encode"]
+    stg = g.stats()["Staging"]
+    assert stg["batches"] == len(enc)
+    assert stg["tuples"] == sum(c["n"] for c in enc) == 5000
+    assert stg["capacity"] == sum(c["cap"] for c in enc) == 1024 * len(enc)
+    assert stg["partial_batches"] == sum(c["n"] < c["cap"] for c in enc) >= 1
+    assert all(c["bytes"] > 0 and c["logical"] >= c["n"] for c in enc)
+    parsed = [a.counts for a in made if a.name == "wf.parse"]
+    assert sum(c["n"] for c in parsed) == 5000
+    assert sum(c["bytes"] for c in parsed) >= 5000 * 24
+    assert sum(a.counts["n"] for a in made if a.name == "wf.pack") == 5000
+
+
+def test_recorder_off_constructs_nothing(annotations):
+    g, got = _frame_graph("spans_off", flight_recorder=False)
+    g.run()
+    assert sum(len(c) for c in got if c is not None) > 0
+    assert annotations == []
+    assert g._recorder is None
+    st = g.stats()
+    assert st["Layers"] == {}
+    # the counters are the program's, not the recorder's
+    assert st["Staging"]["tuples"] == 5000
+
+
+def test_span_outside_a_sweep_is_inert(annotations):
+    with flightrec.span("wf.h2d", batch=1, bytes=4) as sp:
+        sp.note(bytes=8)
+    assert annotations == []
+    assert getattr(flightrec._open, "top", None) is None
+
+
+def test_span_restores_the_stack_when_the_body_raises(annotations):
+    rec = flightrec.FlightRecorder()
+    with pytest.raises(ValueError):
+        with rec.span("wf.sweep", sweep=1):
+            with flightrec.span("wf.source.tick"):
+                raise ValueError("user code")
+    assert getattr(flightrec._open, "top", None) is None
+    table = rec.layers()
+    assert table["wf.sweep"]["count"] == table["wf.source.tick"]["count"] == 1
+    assert table["wf.sweep"]["self_ns"] + table["wf.source.tick"]["self_ns"] \
+        == table["wf.sweep"]["total_ns"]
+
+
+def test_pool_wait_is_a_span_only_when_it_blocks(annotations):
+    class Gate:
+        def __init__(self, ready):
+            self.ready = ready
+
+        def is_ready(self):
+            return self.ready
+
+    rec = flightrec.FlightRecorder()
+    pool = staging.StagingPool()
+    with rec.span("wf.sweep", sweep=1):
+        for ready in (True, False):
+            pool.release(np.empty(256, np.uint32), gate=Gate(ready))
+            pool.acquire(256)
+    assert [a.name for a in annotations] == ["wf.sweep", "wf.pool.wait"]
+    assert pool.gate_waits == 1
+
+
+def test_megastep_group_is_spanned(annotations):
+    """A K-group ships in one transfer, one dispatch and one blocking
+    drain, each carrying the group's first batch and ``k``."""
+    g, got = _frame_graph("spans_mega", n=16 * 1024, megastep_sweeps=2,
+                          punctuation_interval_usec=10 ** 12,
+                          wire_compression=False)
+    g.run()
+    edge = g.stats()["Megastep"]["edges"][0]
+    assert edge["megasteps"] >= 1
+    drains = [a.counts for a in annotations
+              if a.name == "wf.megastep.drain"]
+    mega = [a.counts for a in annotations if a.name == "wf.dispatch"
+            and a.counts["op"].startswith("megastep.")]
+    assert len(drains) == len(mega) == edge["megasteps"]
+    assert all(c["k"] == 2 and c["batch"] >= 1 for c in drains + mega)
+    assert sum(c["bytes"] > 2 * 1024 * 4 for c in
+               (a.counts for a in annotations if a.name == "wf.h2d")) \
+        >= edge["megasteps"]
+
+
+def test_pool_threads_record_tables_of_their_own(annotations):
+    """A host operator drained on the worker pool: its ``wf.drain`` is a
+    root on the pool thread, and that thread's self times telescope to it
+    as the driver's do to ``wf.sweep``."""
+    n = 3000
+    src = (wf.Source_Builder(
+        lambda: iter({"key": i % 8, "v": float(i)} for i in range(n)))
+        .withName("src").withOutputBatchSize(256).build())
+    m = wf.Map_Builder(lambda t: {"key": t["key"], "v": t["v"] + 1.0}) \
+        .withName("hostmap").build()
+    seen = []
+    snk = wf.Sink_Builder(lambda t, ctx=None: seen.append(t)) \
+        .withName("snk").build()
+    cfg = dataclasses.replace(default_config, host_worker_threads=2)
+    g = wf.PipeGraph("spans_pool", wf.ExecutionMode.DEFAULT, config=cfg)
+    g.add_source(src).add(m).add_sink(snk)
+    g.run()
+    assert len([t for t in seen if t is not None]) == n
+    me = threading.get_ident()
+    pool_threads = [t for t in g._recorder._layers if t != me]
+    assert pool_threads
+    for t in pool_threads:
+        table = g._recorder.layers(thread=t)
+        assert "wf.sweep" not in table
+        assert sum(r["self_ns"] for r in table.values()) \
+            == table["wf.drain"]["total_ns"]
+    assert {a.thread for a in annotations if a.name == "wf.drain"} \
+        >= set(pool_threads)
+    total = g.stats()["Layers"]["wf.drain"]["count"]
+    assert total == sum(a.name == "wf.drain" for a in annotations)
+
+
+def test_layers_are_one_openmetrics_family(ran):
+    g, _ = ran
+    st = g.stats()
+    fams = parse_exposition(render_openmetrics(st))
+    fam = fams["wf_layer_span_total"]
+    assert fam["type"] == "counter"
+    got = {(lab["span"], lab["stat"]): v for _, lab, v in fam["samples"]}
+    for name, row in st["Layers"].items():
+        for stat in ("count", "total_ns", "self_ns"):
+            assert got[(name, stat)] == row[stat]
+    assert not any(n.startswith("wf_layer") and n != "wf_layer_span_total"
+                   for n in fams)

@@ -187,10 +187,9 @@ class Config:
                                         "rank_scatter")
     # Profiler bridge (monitoring/device_metrics, docs/OBSERVABILITY.md):
     # directory PipeGraph.profile(duration_ms) writes its jax.profiler
-    # capture into ("" = "{log_dir}/{name}_xprof").  The capture lines up
-    # with dump_trace()'s Chrome trace through the per-batch
-    # "op:<name> trace:<id>" TraceAnnotations the dispatch path puts on
-    # sampled (trace-lane) batches.
+    # capture into ("" = "{log_dir}/{name}_xprof").  With the flight
+    # recorder on the capture holds the layer spans (wf.sweep, wf.parse,
+    # wf.dispatch with op= and batch=, ...) on the device lines' clock.
     profiler_dir: str = os.environ.get("WF_TPU_PROFILER_DIR", "")
     # Pre-flight static analysis (windflow_tpu/analysis): PipeGraph.start()
     # runs PipeGraph.check() — abstract evaluation of the whole graph, zero

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from windflow_tpu import staging
+from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.monitoring.jit_registry import wf_jit
 
 TS_DTYPE = jnp.int64
@@ -130,13 +131,13 @@ class DeviceBatch:
     """
 
     __slots__ = ("payload", "ts", "valid", "keys", "watermark", "_frontier",
-                 "_size", "ts_max", "ts_min", "trace")
+                 "_size", "ts_max", "ts_min", "trace", "seq")
 
     def __init__(self, payload, ts, valid, keys=None, watermark: int = WM_NONE,
                  size: Optional[int] = None, frontier: Optional[int] = None,
                  ts_max: Optional[int] = None,
                  ts_min: Optional[int] = None,
-                 trace: Optional[tuple] = None):
+                 trace: Optional[tuple] = None, seq: int = 0):
         self.payload = payload
         self.ts = ts
         self.valid = valid
@@ -150,6 +151,11 @@ class DeviceBatch:
         #: ``(trace_id, t_origin_usec)`` when this batch is the 1-in-N
         #: sampled one, else None.  Host metadata only — never transferred.
         self.trace = trace
+        #: the recorder's sequence number of the staged batch this one
+        #: descends from: the ``batch=`` its layer spans share from wire
+        #: encode to the sink (0 when the recorder is off or the batch
+        #: was born on the device).  Relayed wherever ``trace`` is.
+        self.seq = seq
 
     @property
     def frontier(self) -> int:
@@ -296,8 +302,8 @@ def stage_packed(buf: np.ndarray, treedef, dtypes, capacity: int, n: int,
                  frontier: Optional[int] = None,
                  ts_max: Optional[int] = None, ts_min: Optional[int] = None,
                  pool=None, trace: Optional[tuple] = None,
-                 wire=None, logical_nbytes: Optional[int] = None
-                 ) -> DeviceBatch:
+                 wire=None, logical_nbytes: Optional[int] = None,
+                 seq: int = 0) -> DeviceBatch:
     """ONE host→device transfer of a packed staging buffer (built by
     ``staging.PackedBatchBuilder`` or the inline pack in ``_stage_soa``)
     into a DeviceBatch.  When ``pool`` is given, ``buf`` is recycled with
@@ -309,13 +315,15 @@ def stage_packed(buf: np.ndarray, treedef, dtypes, capacity: int, n: int,
     ``logical_nbytes`` keeps the byte accounting honest (wire bytes =
     the transfer, logical bytes = the decoded lanes)."""
     unpack = _get_unpack(treedef, dtypes, capacity, wire=wire)
-    dbuf = jnp.asarray(buf) if device is None \
-        else jax.device_put(buf, device)
+    with flightrec.span("wf.h2d", batch=seq, bytes=buf.nbytes):
+        dbuf = jnp.asarray(buf) if device is None \
+            else jax.device_put(buf, device)
     # device-plane accounting (monitoring/device_metrics): every fused
     # staging transfer credits the process-wide staged-byte gauge —
     # wire bytes as shipped, logical bytes as decoded
     staging.device_bytes.note(buf.nbytes, logical_nbytes)
-    cols, ts, valid, gate = unpack(dbuf)
+    with flightrec.span("wf.dispatch", op="staging.unpack", batch=seq):
+        cols, ts, valid, gate = unpack(dbuf)
     if pool is not None:
         # gate on the unpack's private scalar output, NOT a lane the
         # consumer sees: a donated lane's deletion happens at the host's
@@ -324,7 +332,7 @@ def stage_packed(buf: np.ndarray, treedef, dtypes, capacity: int, n: int,
         pool.release(buf, gate=gate)
     return DeviceBatch(jax.tree.unflatten(treedef, cols), ts, valid,
                        watermark=watermark, size=n, frontier=frontier,
-                       ts_max=ts_max, ts_min=ts_min, trace=trace)
+                       ts_max=ts_max, ts_min=ts_min, trace=trace, seq=seq)
 
 
 def _stage_soa(soa, tss, n: int, capacity: int, watermark: int,

@@ -39,6 +39,7 @@ from windflow_tpu.basic import (Config, ExecutionMode, RoutingMode,
                                 TimePolicy, WindFlowError,
                                 current_time_usecs, default_config)
 from windflow_tpu.graph.multipipe import MultiPipe
+from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.ops.base import Operator
 from windflow_tpu.ops.source import Source, SourceReplica
 from windflow_tpu.parallel.collectors import create_collector
@@ -191,10 +192,6 @@ class PipeGraph:
         # WF9xx findings over the lowered StableHLO of this graph's
         # programs (check() stores it; stats()/postmortem re-audit live)
         self._ir_audit_report = None
-        # profiler bridge: directory the last profile() capture actually
-        # landed in, so dump_trace()'s cross-reference points at a real
-        # capture even when profile(log_dir=...) overrode the config
-        self._last_profile_dir = None
 
     # -- construction --------------------------------------------------------
     def add_source(self, source: Source) -> MultiPipe:
@@ -792,6 +789,28 @@ class PipeGraph:
         """One scheduler sweep: pull a chunk from each live source (unless
         backpressured), then drain every replica in topological order.
         Returns True on any progress."""
+        rec = self._recorder
+        if rec is None:
+            return self._sweep()
+        # the root of this thread's layer spans (monitoring/recorder.py):
+        # every site below finds the recorder through it
+        with rec.span("wf.sweep", sweep=next(rec.sweeps)):
+            return self._sweep()
+
+    def _tick(self, sr) -> bool:
+        with flightrec.span("wf.source.tick"):
+            return sr.tick(self._tick_chunk(sr))
+
+    def _drain(self, rep, limit: int) -> bool:
+        """``rep.drain`` under a ``wf.drain`` span: below ``wf.sweep`` on
+        the driver thread, a root of its own on a pool thread."""
+        rec = self._recorder
+        if rec is None:
+            return rep.drain(limit)
+        with rec.span("wf.drain", op=rep.op.name):
+            return rep.drain(limit)
+
+    def _sweep(self) -> bool:
         progress = False
         throttled = self._backpressured()
         if throttled:
@@ -802,7 +821,7 @@ class PipeGraph:
             self._throttle_events += 1
         for sr in self._source_replicas:
             if not sr.exhausted and not throttled:
-                if sr.tick(self._tick_chunk(sr)):
+                if self._tick(sr):
                     progress = True
                 # Cadence punctuation keeps watermarks advancing on idle
                 # streams.  Skipped while throttled: a punctuation flushes
@@ -817,10 +836,10 @@ class PipeGraph:
             # serial (single consumer per inbox), cross-replica it runs on
             # the pool; the sweep barrier below keeps the topological
             # drain of the driver-thread replicas race-free
-            futures = [self._pool.submit(rep.drain, limit)
+            futures = [self._pool.submit(self._drain, rep, limit)
                        for rep in self._pool_replicas if rep.inbox]
         for rep in self._main_replicas:
-            if rep.drain(limit):
+            if rep.inbox and self._drain(rep, limit):
                 progress = True
         if self._pool is not None:
             for f in futures:
@@ -839,7 +858,7 @@ class PipeGraph:
                 break
             ticked = False
             for sr in self._source_replicas:
-                if not sr.exhausted and sr.tick(self._tick_chunk(sr)):
+                if not sr.exhausted and self._tick(sr):
                     ticked = True
             if not ticked:
                 break
@@ -850,7 +869,7 @@ class PipeGraph:
             # cases): force one tick so the graph cannot deadlock on its own
             # throttle.
             for sr in self._source_replicas:
-                if not sr.exhausted and sr.tick(self._tick_chunk(sr)):
+                if not sr.exhausted and self._tick(sr):
                     progress = True
         if self._durability is not None:
             # epoch cadence (windflow_tpu/durability): counts sweeps and,
@@ -1234,11 +1253,11 @@ class PipeGraph:
         driving the started graph for ``duration_ms`` (or until it
         finishes).  The capture lands in ``log_dir`` /
         ``Config.profiler_dir`` (default ``{log_dir}/{name}_xprof``) as a
-        TensorBoard/Perfetto ``plugins/profile`` directory; because the
-        dispatch path wraps every sampled trace-lane batch in a
-        ``TraceAnnotation("op:<name> trace:<id>")`` (ops/tpu.py), the XLA
-        device spans in that capture line up with :meth:`dump_trace`'s
-        flight-recorder spans by trace id.  Returns the capture
+        TensorBoard/Perfetto ``plugins/profile`` directory.  With the
+        flight recorder on, the layer spans (``wf.sweep``, ``wf.parse``,
+        ``wf.dispatch`` with ``op=`` and ``batch=``, ...:
+        monitoring/recorder.py) are inside that capture, on the host
+        plane and on the device lines' clock.  Returns the capture
         directory."""
         if not self._started:
             raise WindFlowError("profile() needs a started graph — call "
@@ -1248,7 +1267,6 @@ class PipeGraph:
         d = log_dir or self.config.profiler_dir \
             or os.path.join(self.config.log_dir, f"{self.name}_xprof")
         os.makedirs(d, exist_ok=True)
-        self._last_profile_dir = d
         jax.profiler.start_trace(d)
         try:
             deadline = time.monotonic() + duration_ms / 1e3
@@ -1262,9 +1280,8 @@ class PipeGraph:
     def dump_trace(self, path: Optional[str] = None) -> str:
         """Write the flight recorder's span events as Chrome-trace JSON
         (``{name}_trace.json`` under ``Config.log_dir``), loadable in
-        ``chrome://tracing`` / Perfetto next to a ``jax.profiler`` capture
-        (``otherData`` carries the annotation format + capture directory
-        that cross-reference the two); the raw events ride along as
+        ``chrome://tracing`` / Perfetto (``otherData`` carries the ledger
+        sections and the per-layer span table); the raw events ride along as
         ``{name}_events.json`` for offline re-export through
         ``tools/trace_export.py``.  Returns the trace path."""
         if self._recorder is None:
@@ -1277,12 +1294,9 @@ class PipeGraph:
         path = path or os.path.join(d, f"{self.name}_trace.json")
         events = self._recorder.events()
         write_chrome_trace(events, path, metadata={
-            # profiler-bridge cross-reference: the jax.profiler capture's
-            # device spans carry these annotations for the same trace ids
-            "profiler_annotation_format": "op:<operator> trace:<trace_id>",
-            "profiler_dir": self._last_profile_dir
-            or self.config.profiler_dir
-            or os.path.join(self.config.log_dir, f"{self.name}_xprof"),
+            # what the host did at each layer boundary over the run
+            # (count, total and self time per span name)
+            "layers": self._recorder.layers(),
             # sweep-ledger cross-reference: per-hop dispatch counts and
             # attributed HBM bytes for the spans in this trace
             "sweep": self._sweep_section(),
@@ -1335,7 +1349,8 @@ class PipeGraph:
             # wire plane (windflow_tpu/wire.py): per-lane codec table +
             # wire-vs-logical byte counters of this graph's staging
             # emitters (docs/OBSERVABILITY.md "Wire plane")
-            "Staging": {"Wire": self._wire_section()},
+            "Staging": {"Wire": self._wire_section(),
+                        **self._staged_counts()},
             "Stage_prefetch_depth": self.config.stage_prefetch_depth,
             "Stage_prefetch_ticks": self._prefetch_ticks,
             "Dropped_tuples": self.get_num_dropped_tuples(),
@@ -1362,6 +1377,11 @@ class PipeGraph:
             "Flight_recorder": (self._recorder.summary()
                                 if self._recorder is not None
                                 else {"enabled": False}),
+            # host time per layer span (count, total_ns, self_ns by span
+            # name): what a profiler capture shows per event, summed
+            # over the run; empty with the recorder off
+            "Layers": (self._recorder.layers()
+                       if self._recorder is not None else {}),
             # pre-flight analysis (windflow_tpu/analysis): check() cost +
             # finding counts, so preflight stays visible in every dump
             "Preflight": {
@@ -1428,6 +1448,19 @@ class PipeGraph:
             "Reshard": self._reshard_section(),
             "Operators": [op.dump_stats() for op in self._operators],
         }
+
+    def _staged_counts(self) -> dict:
+        """What the staging edges shipped, counted where each batch is
+        cut: ``tuples / capacity`` is the fill share, ``partial_batches``
+        the ones a punctuation, a lane change or the end of stream
+        flushed short."""
+        from windflow_tpu.wire import iter_stage_emitters
+        ems = [em for _src, _route, em in iter_stage_emitters(self)]
+        return {"batches": sum(e.staged_batches for e in ems),
+                "partial_batches": sum(e.partial_batches for e in ems),
+                "tuples": sum(e.staged_tuples for e in ems),
+                "capacity": sum(e.staged_batches * e._local_cap
+                                for e in ems)}
 
     def _wire_section(self) -> dict:
         """Guarded like every other plane section; with

@@ -109,10 +109,11 @@ class DeviceSourceReplica(BaseSourceReplica):
         self.stats.device_programs_launched += 1
         # device-born batches join the flight recorder's trace lane at
         # birth ("emitted" — nothing was staged over the host link)
+        seq = self.emitter._new_seq()
         self.emitter.emit_device_batch(
             DeviceBatch(payload, ts, valid, watermark=self.current_wm,
                         size=self.op.capacity, ts_min=ts_lo, ts_max=ts_hi,
-                        trace=self.emitter._new_trace()))
+                        trace=self.emitter._trace_of(seq), seq=seq))
         self._i += self.op.parallelism
         self._count_toward_punctuation(self.op.capacity)
         return True

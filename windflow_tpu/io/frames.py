@@ -23,6 +23,7 @@ from windflow_tpu import native
 from windflow_tpu.basic import RoutingMode, TimePolicy, WindFlowError, \
     current_time_usecs
 from windflow_tpu.meta import adapt
+from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.ops.base import Operator
 from windflow_tpu.ops.source import BaseSourceReplica, Source
 
@@ -46,7 +47,9 @@ class FrameSourceReplica(BaseSourceReplica):
             self._exhausted = True
             self._terminate()
             return True  # termination (EOS cascade) is progress
-        self._ingest(self._carry + chunk)
+        buf = self._carry + chunk
+        if buf:     # a paced iterator yields b"" while nothing is due
+            self._ingest(buf)
         return True
 
     def _flush_carry(self) -> None:
@@ -58,6 +61,19 @@ class FrameSourceReplica(BaseSourceReplica):
             self._ingest(self._carry, final=True)
 
     def _ingest(self, buf: bytes, final: bool = False) -> None:
+        with flightrec.span("wf.parse", bytes=len(buf)) as sp:
+            parsed = self._parse(buf, final)
+            sp.note(n=0 if parsed is None else len(parsed[1]))
+        if parsed is None:
+            return
+        cols, tss, row_wms = parsed
+        self.emitter.emit_columns(cols, tss, self.current_wm,
+                                  row_wms=row_wms)
+        self._count_toward_punctuation(len(tss))
+
+    def _parse(self, buf: bytes, final: bool):
+        """Bytes to the columns the emitter takes: the native parse and
+        the column shaping.  None when ``buf`` holds no whole record."""
         nv = self.op.nv
         if self.op.fmt == "frames":
             keys, tss, vals, consumed = native.parse_frames(buf, nv)
@@ -66,7 +82,7 @@ class FrameSourceReplica(BaseSourceReplica):
         self._carry = b"" if final else buf[consumed:]
         n = len(keys)
         if n == 0:
-            return
+            return None
         if self.time_policy == TimePolicy.INGRESS:
             # every record of the chunk arrived with the chunk: one arrival
             # stamp (monotone vs earlier chunks), not a synthetic +arange
@@ -96,9 +112,7 @@ class FrameSourceReplica(BaseSourceReplica):
         for i, name in enumerate(self.op.fields):
             cols[name] = np.ascontiguousarray(vals[:, i].astype(vd,
                                                                 copy=False))
-        self.emitter.emit_columns(cols, tss, self.current_wm,
-                                  row_wms=row_wms)
-        self._count_toward_punctuation(n)
+        return cols, tss, row_wms
 
 
 class FrameSource(Source):

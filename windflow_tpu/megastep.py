@@ -399,15 +399,18 @@ class MegastepEdge:
         # super-batch staging: ONE pooled K*L host buffer, ONE H2D
         nwords = group[0].buf.shape[0]
         pool = group[0].pool
-        sup = pool.acquire(self.k * nwords)
-        for i, p in enumerate(group):
-            sup[i * nwords:(i + 1) * nwords] = p.buf
-            p.pool.release(p.buf, None)     # host copy done, no gate
-        xs = {"buf": jax.device_put(sup.reshape(self.k, nwords))}
-        if self.kind == "ffat_tb":
+        seq0 = group[0].seq     # the group's spans carry its first batch
+        with flightrec.span("wf.h2d", batch=seq0,
+                            bytes=self.k * nwords * 4):
+            sup = pool.acquire(self.k * nwords)
             for i, p in enumerate(group):
-                self._wm_buf[i] = p.wm_pane
-            xs["wm"] = jax.device_put(self._wm_buf)
+                sup[i * nwords:(i + 1) * nwords] = p.buf
+                p.pool.release(p.buf, None)     # host copy done, no gate
+            xs = {"buf": jax.device_put(sup.reshape(self.k, nwords))}
+            if self.kind == "ffat_tb":
+                for i, p in enumerate(group):
+                    self._wm_buf[i] = p.wm_pane
+                xs["wm"] = jax.device_put(self._wm_buf)
 
         # trace lane, per batch at GROUP times: collected+dispatched when
         # the scan actually launches (so emitted->dispatched measures each
@@ -432,10 +435,13 @@ class MegastepEdge:
                             shared=self.k)
                 ring.record(tr[0], flightrec.DISPATCHED, t_disp,
                             shared=self.k)
-        carry, ys = mega(self._carry_init(), xs)
+        with flightrec.span("wf.dispatch", op=f"megastep.{self.kind}",
+                            batch=seq0, k=self.k):
+            carry, ys = mega(self._carry_init(), xs)
         # the ONE blocking D2H per megastep: materialize the stacked
         # outputs; per-batch slices below are zero-copy numpy views
-        host = jax.tree.map(np.asarray, ys)
+        with flightrec.span("wf.megastep.drain", batch=seq0, k=self.k):
+            host = jax.tree.map(np.asarray, ys)
         if n_traced:
             t_done = current_time_usecs()
             for idx in range(n_traced):
@@ -491,6 +497,7 @@ class MegastepEdge:
                 out = DeviceBatch(pay, ts_i, valid_i, watermark=p.wm,
                                   size=size, frontier=front)
             out.trace = tr
+            out.seq = p.seq
             # one LOGICAL batch served: the ledger divides the single
             # megastep dispatch by these to report 1/K honestly
             rep.stats.device_programs_launched += 1

@@ -58,6 +58,7 @@ from typing import Callable, Dict, Optional
 import jax
 
 from windflow_tpu.analysis.hotpath import hot_path
+from windflow_tpu.monitoring import recorder as flightrec
 
 #: cost-analysis capture mode on an op name's first compile (see module
 #: docstring): "lowered" | "compiled" | "off"
@@ -284,7 +285,9 @@ class WfJit:
 
     # -- cold path: a compile is happening -----------------------------------
     def _compile_call(self, sig, args, kwargs):
-        with self._lock:
+        # the whole cold path holds the calling thread: the wait for a
+        # sibling's compile, the cost capture's lowering, trace + compile
+        with flightrec.span("wf.compile", op=self.op_name), self._lock:
             return self._compile_call_locked(sig, args, kwargs)
 
     def _compile_call_locked(self, sig, args, kwargs):

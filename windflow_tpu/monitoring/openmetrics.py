@@ -411,6 +411,19 @@ def _families(stats: dict,
             "verdict") \
             .add(rsh.get("recovery_ms") or 0, base)
 
+    # -- layer spans (monitoring/recorder.py) --------------------------------
+    layers = stats.get("Layers") or {}
+    if layers:
+        f_layer = fam("wf_layer_span_total", "counter",
+                      "Host time per layer span of the sweep: stat=count "
+                      "spans closed, total_ns their durations, self_ns "
+                      "those minus their child spans (docs/OBSERVABILITY.md "
+                      "span table)")
+        for name, row in layers.items():
+            for stat in ("count", "total_ns", "self_ns"):
+                f_layer.add(row.get(stat, 0), dict(base, span=name,
+                                                   stat=stat))
+
     # -- latency histograms --------------------------------------------------
     lat = stats.get("Latency") or {}
     f_svc = fam("wf_service_latency_usec", "histogram",

@@ -34,20 +34,40 @@ time.  This module adds the missing batch-granular layer:
 
 * **Export.**  :func:`chrome_trace_from_events` renders the merged rings as
   Chrome-trace JSON (the ``traceEvents`` array format) loadable in
-  ``chrome://tracing`` or Perfetto next to a ``jax.profiler`` capture;
-  ``PipeGraph.dump_trace()`` and ``tools/trace_export.py`` wrap it.
+  ``chrome://tracing`` or Perfetto; ``PipeGraph.dump_trace()`` and
+  ``tools/trace_export.py`` wrap it.
+
+* **Layer spans.**  :func:`span` brackets the host's work at every layer
+  boundary of a sweep (``wf.sweep``, ``wf.parse``, ``wf.pack``,
+  ``wf.wire.encode``, ``wf.h2d``, ``wf.dispatch``, ... — the table in
+  docs/OBSERVABILITY.md "Span tracing").  A span is a
+  ``jax.profiler.TraceAnnotation``, so under a profiler capture it lands
+  on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the device's
+  ``XLA Modules`` line, on one clock, with its counts as event stats; an
+  inactive profiler formats nothing.  Every span also adds ``count``,
+  ``total_ns`` and ``self_ns`` (its duration minus its children's, by a
+  per-thread stack) to the recorder's per-thread table
+  (``stats()["Layers"]``), the operator's view of the same numbers when
+  no profiler runs.  Spans open per sweep, per chunk and per batch,
+  never per tuple; the spans of one staged batch share ``batch=``, the
+  recorder's batch sequence number (a sampled batch's trace id is that
+  number).
 
 When ``Config.flight_recorder`` is off, ``PipeGraph`` binds no recorder at
-all: replicas hold ``ring = None`` and emitters ``flight = None``, so the
-hot path's only residue is a ``is not None`` check per batch.
+all: replicas hold ``ring = None`` and emitters ``flight = None``, no root
+span is ever opened, and the hot path's only residue is an ``is None``
+check per site.
 """
 
 from __future__ import annotations
 
 import itertools
 import json
-from typing import List, Optional
+import threading
+import time
+from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from windflow_tpu.analysis import debug_concurrency as _dbg
@@ -227,6 +247,83 @@ class ReplicaRing:
         return out
 
 
+#: the innermost open span of each thread (``.top``); a thread that has
+#: none is outside every recorded sweep, and :func:`span` is inert there
+_open = threading.local()
+
+
+class _NoSpan:
+    """What :func:`span` returns outside a recorded sweep."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **counts) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """One open layer span: the profiler annotation plus the bookkeeping
+    that turns durations into per-name totals and self times."""
+
+    __slots__ = ("table", "name", "parent", "ann", "t0", "child_ns")
+
+    def __init__(self, table: dict, name: str, counts: dict,
+                 parent: Optional["_Span"]) -> None:
+        self.table = table
+        self.name = name
+        self.parent = parent
+        self.ann = jax.profiler.TraceAnnotation(name, **counts)
+        self.child_ns = 0
+
+    def __enter__(self):
+        _open.top = self
+        self.ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter_ns() - self.t0
+        self.ann.__exit__(*exc)
+        parent = self.parent
+        _open.top = parent
+        if parent is not None:
+            parent.child_ns += dur
+        row = self.table.get(self.name)
+        if row is None:
+            row = self.table[self.name] = [0, 0, 0]
+        row[0] += 1
+        row[1] += dur
+        row[2] += dur - self.child_ns
+        return False
+
+    def note(self, **counts) -> None:
+        """Counts known only once the work is done (encoded bytes, rows
+        parsed): appended to the open profiler event, nowhere else."""
+        self.ann.set_metadata(**counts)
+
+
+def span(name: str, **counts):
+    """Layer span under the innermost span this thread has open, in the
+    table of the recorder that opened the outermost one
+    (:meth:`FlightRecorder.span`: ``wf.sweep`` on the driver thread, the
+    pool's ``wf.drain`` on a worker).  Outside any — the recorder is off,
+    or the call is not part of a sweep — it is inert: no annotation is
+    constructed, no table written."""
+    top = getattr(_open, "top", None)
+    if top is None:
+        return _NO_SPAN
+    return _Span(top.table, name, counts, top)
+
+
 class FlightRecorder:
     """Graph-scoped recorder: owns the per-replica rings, the trace-id
     counter and the sampling decision.  Built by ``PipeGraph._build`` when
@@ -247,17 +344,55 @@ class FlightRecorder:
         # spans in the Chrome export)
         self._seq = itertools.count(1)
         self.traces_started = 0
+        #: sweep numbers for ``wf.sweep`` (PipeGraph.step)
+        self.sweeps = itertools.count(1)
+        #: layer-span tables, one per thread that opened a span:
+        #: ``{thread ident: {name: [count, total_ns, self_ns]}}``.  Per
+        #: thread so that no add races another and a thread's self times
+        #: telescope exactly to its outermost spans' total.
+        self._layers: Dict[int, dict] = {}
 
     # -- trace assignment (batch-birth sites: emitters, staging plane) ------
-    def maybe_trace(self) -> Optional[tuple]:
-        """Sampling decision for one new batch: ``(trace_id, t_origin)``
-        for the 1-in-N sampled batch, None otherwise.  One counter tick +
-        one modulo when not sampled."""
-        seq = next(self._seq)
+    def next_batch(self) -> int:
+        """Sequence number of one new batch: the ``batch=`` its layer
+        spans share, and its trace id if it is sampled."""
+        return next(self._seq)
+
+    def trace_of(self, seq: int) -> Optional[tuple]:
+        """Sampling decision for batch ``seq``: ``(trace_id, t_origin)``
+        for the 1-in-N sampled batch, None otherwise."""
         if seq % self.sample_every:
             return None
         self.traces_started += 1
         return (seq, current_time_usecs())
+
+    def maybe_trace(self) -> Optional[tuple]:
+        """One counter tick + one modulo when not sampled."""
+        return self.trace_of(next(self._seq))
+
+    # -- layer spans ---------------------------------------------------------
+    def span(self, name: str, **counts) -> _Span:
+        """Open ``name`` in this recorder's table of the calling thread,
+        under whatever span the thread has open (none: a root).  The
+        module-level :func:`span` serves every site below a root."""
+        table = self._layers.setdefault(threading.get_ident(), {})
+        return _Span(table, name, counts, getattr(_open, "top", None))
+
+    def layers(self, thread: Optional[int] = None) -> dict:
+        """``{name: {"count", "total_ns", "self_ns"}}`` summed over the
+        threads that recorded (``stats()["Layers"]``), or of one thread
+        (``threading.get_ident()`` of the driver, say)."""
+        tables = list(self._layers.values()) if thread is None \
+            else [self._layers.get(thread, {})]
+        out: Dict[str, dict] = {}
+        for table in tables:
+            for name, (count, total, self_ns) in list(table.items()):
+                row = out.setdefault(name, {"count": 0, "total_ns": 0,
+                                            "self_ns": 0})
+                row["count"] += count
+                row["total_ns"] += total
+                row["self_ns"] += self_ns
+        return out
 
     # -- ring registry -------------------------------------------------------
     def ring_for(self, op_name: str, replica_index: int) -> ReplicaRing:
@@ -294,18 +429,17 @@ def chrome_trace_from_events(events: List[dict],
                              metadata: Optional[dict] = None) -> dict:
     """Render raw span events as Chrome-trace JSON (``traceEvents`` array
     format), loadable in ``chrome://tracing`` and Perfetto.
-    ``metadata`` entries are merged into ``otherData`` — the profiler
-    bridge (graph/pipegraph.py ``profile()``) records the annotation
-    format and capture directory there so this file and a
-    ``jax.profiler`` capture cross-reference in one Perfetto session.
+    ``metadata`` entries are merged into ``otherData``
+    (``PipeGraph.dump_trace`` puts the ledger sections there).
 
     Layout: one *thread* track per ``(op, replica)`` carrying instant
     events for every record, plus one *async* span per traced batch and
     stage pair (``b``/``e`` events keyed by the trace id) so a batch's
     staged→...→sunk journey reads as a nested bar across the pipeline.
-    Timestamps are the recorder's wall-clock microseconds — the same
-    domain as a ``jax.profiler`` capture, so the two files line up when
-    opened side by side."""
+    Timestamps are the recorder's wall-clock microseconds
+    (``time.time_ns``), NOT the profiler's clock: what the host did
+    between two device programs is read from the layer spans inside a
+    ``jax.profiler`` capture (:func:`span`), not from this file."""
     trace_events: List[dict] = []
     tids = {}
     for e in events:

@@ -16,6 +16,7 @@ from typing import Any, Callable, Optional
 
 from windflow_tpu.basic import RoutingMode
 from windflow_tpu.meta import adapt
+from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.ops.base import Operator, Replica
 
 
@@ -37,6 +38,7 @@ class SinkReplica(Replica):
         super().__init__(op, index)
         self._fn = adapt(op.fn, 1)
         self._pending = []          # deferred device batches (columnar)
+        self._pending_bytes = 0     # their transfer size (wf.sink.d2h)
 
     def process_single(self, item, ts, wm):
         self._fn(item, self.context)
@@ -48,7 +50,8 @@ class SinkReplica(Replica):
         # moves the timestamp and validity lanes too, so the D2H counter
         # uses the shared whole-batch definition (batch.transfer_nbytes).
         from windflow_tpu.batch import transfer_nbytes
-        self.stats.d2h_bytes += transfer_nbytes(batch)
+        nbytes = transfer_nbytes(batch)
+        self.stats.d2h_bytes += nbytes
         if self.op.columnar:
             # Deferred conversion: hold the last ``defer`` batches and pull
             # the oldest — JAX dispatch is asynchronous, so the device→host
@@ -57,24 +60,37 @@ class SinkReplica(Replica):
             # reference hides D2H behind per-batch CUDA streams the same
             # way).  EOS drains the queue.
             self._pending.append(batch)
+            self._pending_bytes += nbytes
             if len(self._pending) > self.op.columnar_defer:
                 # drain the whole queue in ONE device->host transfer
                 pend, self._pending = self._pending, []
                 self._deliver_columns(pend)
             return
         from windflow_tpu.batch import device_to_host
-        hb = device_to_host(batch)
-        for item, ts in zip(hb.items, hb.tss):
-            self.context._set_context(ts, batch.watermark)
-            self._fn(item, self.context)
+        with flightrec.span("wf.sink.d2h", batch=batch.seq, batches=1,
+                            bytes=nbytes):
+            hb = device_to_host(batch)
+        with flightrec.span("wf.sink.deliver", batch=batch.seq,
+                            rows=len(hb.items)):
+            for item, ts in zip(hb.items, hb.tss):
+                self.context._set_context(ts, batch.watermark)
+                self._fn(item, self.context)
 
     def _deliver_columns(self, batches):
         from windflow_tpu.batch import device_to_columns_multi
-        for b, (cols, tss) in zip(batches,
-                                  device_to_columns_multi(batches)):
+        nbytes, self._pending_bytes = self._pending_bytes, 0
+        # one transfer for the whole queue: the span carries its first
+        # batch's number and how many ride with it
+        with flightrec.span("wf.sink.d2h", batch=batches[0].seq,
+                            batches=len(batches), bytes=nbytes):
+            columns = device_to_columns_multi(batches)
+        for b, (cols, tss) in zip(batches, columns):
             if len(tss):
                 self.context._set_context(int(tss[-1]), b.watermark)
-                self._fn(SinkColumns(cols, tss, b.watermark), self.context)
+                with flightrec.span("wf.sink.deliver", batch=b.seq,
+                                    rows=len(tss)):
+                    self._fn(SinkColumns(cols, tss, b.watermark),
+                             self.context)
 
     def on_eos(self):
         if self._pending:

@@ -58,18 +58,10 @@ class _TPUReplica(Replica):
         return self.op._step(batch)
 
     def process_device_batch(self, batch: DeviceBatch) -> None:
-        if batch.trace is not None:
-            # profiler bridge: the sampled (1-in-N trace-lane) batch's
-            # device dispatch is wrapped in a TraceAnnotation carrying the
-            # flight-recorder trace id, so a jax.profiler capture
-            # (PipeGraph.profile) and dump_trace()'s Chrome trace line up
-            # span-for-span in one Perfetto session.  Untraced batches pay
-            # exactly this one attribute check (budget asserted by
-            # tests/test_device_metrics.py).
-            with jax.profiler.TraceAnnotation(
-                    f"op:{self.op.name} trace:{batch.trace[0]}"):
-                out = self._op_step(batch)
-        else:
+        # the stable name of this dispatch in a profiler capture is the
+        # host span's op= (the XLA module is jit_step for every operator)
+        with flightrec.span("wf.dispatch", op=self.op.name,
+                            batch=batch.seq):
             out = self._op_step(batch)
         self.stats.device_programs_launched += 1
         if self.ring is not None and batch.trace is not None:
@@ -97,11 +89,13 @@ class _TPUReplica(Replica):
                     self.latency.note_window_fire(self.op.name, out.ts,
                                                   out.valid, now)
         if out is not None:
+            # operator steps build fresh DeviceBatches; the trace lane
+            # and the batch number are host metadata, relayed here so one
+            # hook covers every device operator (map/filter/reduce/
+            # stateful/windows)
             if out.trace is None:
-                # operator steps build fresh DeviceBatches; the trace lane
-                # is host metadata, relayed here so one hook covers every
-                # device operator (map/filter/reduce/stateful/windows)
                 out.trace = batch.trace
+            out.seq = batch.seq
             self.stats.outputs_sent += out.known_size or 0
             self.emitter.emit_device_batch(out)
 

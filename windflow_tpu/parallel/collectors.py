@@ -104,7 +104,7 @@ class WatermarkCollector(Collector):
                                   keys=msg.keys, watermark=f,
                                   size=msg.known_size, frontier=ff,
                                   ts_max=msg.ts_max, ts_min=msg.ts_min,
-                                  trace=msg.trace)
+                                  trace=msg.trace, seq=msg.seq)
         elif f != msg.watermark:
             if isinstance(msg, HostBatch):
                 msg = dataclasses.replace(msg, watermark=f)

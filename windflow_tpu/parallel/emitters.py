@@ -135,16 +135,26 @@ class Emitter:
         self.ring = ring
         self.flight = flight
 
-    def _new_trace(self, stage: int = flightrec.EMITTED):
-        """Trace lane for a batch BORN at this emitter: the 1-in-N sampling
-        decision plus the birth span event; None (and no work beyond one
-        check) when the recorder is off or the batch is not sampled."""
+    def _new_seq(self) -> int:
+        """Sequence number of a batch BORN at this emitter (the
+        ``batch=`` of its layer spans); 0 when the recorder is off."""
+        return 0 if self.flight is None else self.flight.next_batch()
+
+    def _trace_of(self, seq: int, stage: int = flightrec.EMITTED):
+        """Trace lane of batch ``seq``: the 1-in-N sampling decision plus
+        the birth span event; None (and no work beyond one check) when
+        the recorder is off or the batch is not sampled."""
         if self.flight is None:
             return None
-        tr = self.flight.maybe_trace()
+        tr = self.flight.trace_of(seq)
         if tr is not None and self.ring is not None:
             self.ring.record(tr[0], stage, tr[1])
         return tr
+
+    def _new_trace(self, stage: int = flightrec.EMITTED):
+        """Trace lane of a new batch that carries no sequence number
+        (host batches)."""
+        return self._trace_of(self._new_seq(), stage)
 
     # -- host-tuple interface ----------------------------------------------
     def emit(self, item: Any, ts: int, wm: int,
@@ -420,11 +430,11 @@ class _StagedPacket:
     filled in by the megastep edge for time-based window tails."""
 
     __slots__ = ("buf", "fmt", "wm", "frontier", "ts_min", "ts_max",
-                 "n", "trace", "nbytes", "logical_nbytes", "pool",
+                 "n", "seq", "trace", "nbytes", "logical_nbytes", "pool",
                  "treedef", "dtypes", "capacity", "wm_pane")
 
     def __init__(self, buf, fmt, wm, frontier, ts_min, ts_max, n,
-                 trace, logical_nbytes, pool, treedef, dtypes,
+                 seq, trace, logical_nbytes, pool, treedef, dtypes,
                  capacity):
         self.buf = buf
         self.fmt = fmt
@@ -433,6 +443,7 @@ class _StagedPacket:
         self.ts_min = ts_min
         self.ts_max = ts_max
         self.n = n
+        self.seq = seq
         self.trace = trace
         self.nbytes = buf.nbytes
         self.logical_nbytes = logical_nbytes
@@ -497,6 +508,12 @@ class DeviceStageEmitter(Emitter):
         self._b_wm = WM_NONE            # running row-frontier max
         self._b_ts_min = None           # data-ts extrema of the OPEN batch
         self._b_ts_max = None
+        # what this edge staged, counted where each batch is cut
+        # (stats()["Staging"]): a batch shipped short of its capacity is
+        # one the punctuation, a lane change or the end of stream flushed
+        self.staged_batches = 0
+        self.partial_batches = 0
+        self.staged_tuples = 0
         # shard-plane key probe (monitoring/shard_ledger.HostKeyProbe):
         # attached by the ledger when this non-keyed staging edge feeds
         # a keyed device consumer whose key extraction runs in-program
@@ -552,6 +569,11 @@ class DeviceStageEmitter(Emitter):
     def _advance_frontier(self, wm):
         if wm != WM_NONE and wm > self._frontier:
             self._frontier = wm
+
+    def _count_staged(self, n: int) -> None:
+        self.staged_batches += 1
+        self.partial_batches += n < self._local_cap
+        self.staged_tuples += n
 
     def _local_share(self, nbytes: int) -> int:
         """This PROCESS's share of a staged batch's bytes: on a
@@ -612,7 +634,9 @@ class DeviceStageEmitter(Emitter):
                 {nm: np.asarray(a) for nm, a in cols.items()})
             if all(l.ndim == 1 and staging.packable_dtype(l.dtype)
                    for l in leaves):
-                self._emit_columns_packed(leaves, treedef, tss, wm, row_wms)
+                with flightrec.span("wf.pack", n=len(tss)):
+                    self._emit_columns_packed(leaves, treedef, tss, wm,
+                                              row_wms)
                 return
         if self._builder is not None:
             # falling back mid-stream: ship the open packed rows first so
@@ -672,15 +696,21 @@ class DeviceStageEmitter(Emitter):
             return
         wm = self._b_wm if self._b_wm != WM_NONE else fallback_wm
         self._advance_frontier(wm)
-        buf = b.finish()
-        logical_nbytes = buf.nbytes
-        fmt = None
-        if self._wire_on:
-            # wire plane (windflow_tpu/wire.py): lane-wise re-encode of
-            # the finished logical buffer; a batch compression cannot
-            # shrink ships the logical buffer unchanged (fmt None)
-            enc = self._wire_encoder(self._b_dtypes, b.capacity)
-            buf, fmt = enc.encode(buf, pool=b.pool)
+        self._count_staged(b.n)
+        seq = self._new_seq()
+        with flightrec.span("wf.wire.encode", batch=seq, n=b.n,
+                            cap=b.capacity) as sp:
+            buf = b.finish()
+            logical_nbytes = buf.nbytes
+            fmt = None
+            if self._wire_on:
+                # wire plane (windflow_tpu/wire.py): lane-wise re-encode
+                # of the finished logical buffer; a batch compression
+                # cannot shrink ships the logical buffer unchanged (fmt
+                # None)
+                enc = self._wire_encoder(self._b_dtypes, b.capacity)
+                buf, fmt = enc.encode(buf, pool=b.pool)
+            sp.note(bytes=buf.nbytes, logical=logical_nbytes)
         if self.stats is not None:
             # the packed path's H2D transfer is exactly this buffer;
             # the logical counter keeps compression from silently
@@ -688,8 +718,8 @@ class DeviceStageEmitter(Emitter):
             self.stats.h2d_bytes += buf.nbytes
             self.stats.h2d_logical_bytes += logical_nbytes
         pkt = _StagedPacket(buf, fmt, wm, self._frontier,
-                            self._b_ts_min, self._b_ts_max, b.n,
-                            self._new_trace(flightrec.STAGED),
+                            self._b_ts_min, self._b_ts_max, b.n, seq,
+                            self._trace_of(seq, flightrec.STAGED),
                             logical_nbytes, b.pool, self._b_treedef,
                             self._b_dtypes, b.capacity)
         ms = self._megastep
@@ -708,7 +738,7 @@ class DeviceStageEmitter(Emitter):
                           pkt.capacity, pkt.n, watermark=pkt.wm,
                           device=None, frontier=pkt.frontier,
                           ts_max=pkt.ts_max, ts_min=pkt.ts_min,
-                          pool=pkt.pool, trace=pkt.trace,
+                          pool=pkt.pool, trace=pkt.trace, seq=pkt.seq,
                           wire=pkt.fmt,
                           logical_nbytes=pkt.logical_nbytes)
         d = self._next
@@ -750,10 +780,13 @@ class DeviceStageEmitter(Emitter):
         self._col_rows = rem
 
     def _stage_columns(self, cols, tss, wm):
+        self._count_staged(len(tss))
+        seq = self._new_seq()
         db = columns_to_device(cols, tss, self.output_batch_size,
                                watermark=wm, device=self._stage_target,
                                frontier=self._frontier,
-                               trace=self._new_trace(flightrec.STAGED))
+                               trace=self._trace_of(seq, flightrec.STAGED))
+        db.seq = seq
         if self.stats is not None:
             self.stats.h2d_bytes += self._local_share(_db_nbytes(db))
             self.stats.h2d_logical_bytes += \
@@ -827,10 +860,13 @@ class DeviceStageEmitter(Emitter):
                 self._b_wm = max(prev_wm, ob.wm)
                 return
         hb = HostBatch(self._ob.items, self._ob.tss, self._ob.wm)
+        self._count_staged(len(hb))
+        seq = self._new_seq()
         db = host_to_device(hb, capacity=self.output_batch_size,
                             device=self._stage_target,
                             frontier=self._frontier,
-                            trace=self._new_trace(flightrec.STAGED))
+                            trace=self._trace_of(seq, flightrec.STAGED))
+        db.seq = seq
         if self.stats is not None:
             self.stats.h2d_bytes += self._local_share(_db_nbytes(db))
             self.stats.h2d_logical_bytes += \
@@ -1338,13 +1374,15 @@ class AlignedMeshStageEmitter(Emitter):
             wm = min(wm, pend)
         on = ts[valid]
         ts_lo, ts_hi = int(on.min()), int(on.max())
+        seq = self._new_seq()
         payload = {n: jax.device_put(a, self._sharding)
                    for n, a in lanes.items()}
         db = DeviceBatch(payload, jax.device_put(ts, self._sharding),
                          jax.device_put(valid, self._sharding),
                          watermark=wm, size=total, frontier=wm,
                          ts_max=ts_hi, ts_min=ts_lo,
-                         trace=self._new_trace(flightrec.STAGED))
+                         trace=self._trace_of(seq, flightrec.STAGED),
+                         seq=seq)
         if self.stats is not None:
             nb = _db_nbytes(db)
             self.stats.h2d_bytes += nb
@@ -1487,7 +1525,8 @@ class DeviceKeyByEmitter(Emitter):
                                       frontier=batch.frontier,
                                       ts_max=batch.ts_max,
                                       ts_min=batch.ts_min,
-                                      trace=batch.trace))
+                                      trace=batch.trace,
+                                      seq=batch.seq))
 
 
 class DevicePassEmitter(Emitter):
@@ -1700,7 +1739,7 @@ class SplittingEmitter(Emitter):
                                 size=None, frontier=batch.frontier,
                                 ts_max=batch.ts_max,
                                 ts_min=batch.ts_min,
-                                trace=batch.trace))
+                                trace=batch.trace, seq=batch.seq))
             return
         # Fallback: host-side per-tuple split (Python or multicast split fn).
         # A device-only branch emitter cannot accept host items, but that is

@@ -52,6 +52,7 @@ import numpy as np
 
 from windflow_tpu.analysis import debug_concurrency as _dbg
 from windflow_tpu.analysis.hotpath import hot_path
+from windflow_tpu.monitoring import recorder as flightrec
 
 #: retained buffers per distinct buffer size (the recycling queue depth);
 #: 4 covers the driver loop's double buffering with margin for the keyed
@@ -177,12 +178,13 @@ class StagingPool:
             if not ready:
                 self.gate_waits += 1
                 import jax
-                try:
-                    jax.block_until_ready(gate)
-                except RuntimeError:
-                    # deleted between the check and the sync: nothing
-                    # left to wait on
-                    pass
+                with flightrec.span("wf.pool.wait"):
+                    try:
+                        jax.block_until_ready(gate)
+                    except RuntimeError:
+                        # deleted between the check and the sync: nothing
+                        # left to wait on
+                        pass
         return buf
 
     def release(self, buf: np.ndarray, gate=None) -> None:
