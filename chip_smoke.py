@@ -217,12 +217,15 @@ class Evidence:
         ws = st["Staging"]["Wire"]
         wire = {"engaged": bool(ws["batches"]),
                 "compression_ratio": ws["compression_ratio"],
-                "batches": ws["batches"], "raw_batches": ws["raw_batches"]}
+                "batches": ws["batches"], "raw_batches": ws["raw_batches"],
+                "decisions": ws["decisions"]}
         if not wire["engaged"]:
             wire["reason"] = (
                 "resolved off" if not ws["enabled"] else
                 "no packed staging edge with a record spec (a mesh "
-                "stages per shard, unpacked)")
+                "stages per shard, unpacked)" if not ws["encoders"] else
+                "every edge measured its link faster than its codec "
+                "and ships raw")
         mode = resolve_pallas(g.config)
         mosaic = {n: c - self._mosaic.get(n, 0)
                   for n, c in _mosaic_calls().items()

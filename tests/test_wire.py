@@ -278,9 +278,10 @@ def test_openmetrics_wire_families_round_trip():
 
 
 def test_wire_auto_resolution():
-    """The default is "auto": off on the CPU backend (host==device, a
-    memcpy wire — compression is pure overhead), on for accelerators;
-    explicit values force either way."""
+    """The default is "auto": nothing attaches on the CPU backend
+    (host==device: no link to measure); on an accelerator the plane
+    attaches and each edge decides by measurement (below); explicit
+    values force either way."""
     cfg = dataclasses.replace(wf.default_config)
     assert cfg.wire_compression == "auto" or isinstance(
         cfg.wire_compression, bool)
@@ -507,6 +508,216 @@ def test_key_aligned_skew_retention_caps_watermark():
     assert total == m                           # nothing lost
     # once nothing is retained, the frontier stamp flows again
     assert dest.batches[-1].watermark == 10**6
+
+
+# ---------------------------------------------------------------------------
+# the edge's decision: link time saved against the codec's steady pass
+# ---------------------------------------------------------------------------
+
+class _Clock:
+    """Scripted ``perf_counter``: ``encode`` reads it at entry, where
+    the steady pass starts, and at the end."""
+
+    def __init__(self, *stamps):
+        self.stamps = list(stamps)
+
+    def __call__(self):
+        return self.stamps.pop(0)
+
+
+def _finished(lane, cap, pool=None):
+    b = staging.PackedBatchBuilder((str(lane.dtype),), cap, pool=pool)
+    b.append([lane], np.arange(cap, dtype=np.int64) * 17)
+    return b.finish()
+
+
+_LOW_CARD = _RNG.integers(0, 200, 4096).astype(np.int32)
+#: logical bytes of one int32 lane + ts at 4096 rows
+_LOGICAL = (3 * 4096 + 1) * 4
+
+
+@pytest.mark.parametrize("link,reseed_s,steady_s,expect", [
+    # the tunnel the codec was written for: ~35 KB saved is ~1.9 ms of
+    # link; the 5 s choice pass is NOT what is compared
+    (19e6, 5.0, 1e-3, wire.ENCODE),
+    # the same link with a codec slower than the bytes it saves
+    (19e6, 0.0, 50e-3, wire.RAW),
+    # a host-attached chip: the same batch, the same codec
+    (5e9, 0.0, 1e-3, wire.RAW),
+])
+def test_edge_decides_from_link_and_steady_codec_time(link, reseed_s,
+                                                      steady_s, expect):
+    cap = 4096
+    asked = []
+    pool = staging.StagingPool(depth=4)
+    enc = wire.WireEncoder(
+        ("int32",), cap, reseed_every=64,
+        link_rate=lambda nwords: asked.append(nwords) or link,
+        clock=_Clock(0.0, reseed_s, reseed_s + steady_s))
+    # the link is asked once, at the edge's logical size
+    assert asked == [3 * cap + 1] and enc.decision == wire.PENDING
+    assert enc.link_bytes_per_sec == link
+    buf = _finished(_LOW_CARD, cap, pool)
+    out, fmt = enc.encode(buf, pool=pool)
+    d = enc.decision_json()
+    assert d["decision"] == enc.decision == expect
+    assert d["codec_usec"] == pytest.approx(steady_s * 1e6)
+    assert d["codec_bytes_per_sec"] == pytest.approx(_LOGICAL / steady_s)
+    assert 0 < d["saved_bytes"] < _LOGICAL
+    assert (d["saved_bytes"] / link > steady_s) == (expect == wire.ENCODE)
+    # the measured batch ships by the decision it produced
+    if expect == wire.ENCODE:
+        assert fmt is not None and out is not buf
+        assert (enc.stats.batches, enc.stats.raw_batches) == (1, 0)
+        assert not enc.ships_raw
+    else:
+        assert fmt is None and out is buf
+        assert (enc.stats.batches, enc.stats.raw_batches) == (0, 1)
+        assert enc.stats.wire_bytes == enc.stats.logical_bytes == _LOGICAL
+        assert enc.ships_raw
+        # ... and the wire scratch went back to the pool
+        assert pool.releases == 1
+    assert asked == [3 * cap + 1]
+
+
+def test_decided_raw_stays_raw_and_keeps_counting():
+    """Once raw, the emitter's route is a counter: ``ships_raw`` and
+    ``stats.note_raw``, no codec pass.  A direct ``encode`` still never
+    hands out a WireFormat (so no decode variant is ever compiled)."""
+    cap = 4096
+    enc = wire.WireEncoder(("int32",), cap, link_rate=lambda n: 5e9,
+                           clock=_Clock(0.0, 0.0, 1e-3, 7.0, 7.0, 8.0))
+    buf = _finished(_LOW_CARD, cap)
+    assert enc.encode(buf)[1] is None and enc.ships_raw
+    reseeds, usec = enc.stats.reseeds, enc.stats.encode_usec
+    enc.stats.note_raw(buf.nbytes)              # what the emitter does
+    assert (enc.stats.reseeds, enc.stats.encode_usec) == (reseeds, usec)
+    out, fmt = enc.encode(buf)                  # what it no longer does
+    assert out is buf and fmt is None and enc.decision == wire.RAW
+    assert enc.stats.raw_batches == 3 and enc.stats.batches == 0
+    assert enc.decision_json()["codec_usec"] == pytest.approx(1e3)
+    assert enc.stats.to_json()["compression_ratio"] == 1.0
+
+
+def test_a_batch_the_codec_cannot_shrink_decides_raw_on_any_link():
+    cap = 4096
+    lane = _RNG.integers(-2**31, 2**31, cap).astype(np.int32)
+    enc = wire.WireEncoder(("int32",), cap, link_rate=lambda n: 1.0,
+                           clock=_Clock(0.0, 0.0, 1e-6))
+    b = staging.PackedBatchBuilder(("int32",), cap)
+    b.append([lane], _RNG.integers(-2**62, 2**62, cap))
+    out, fmt = enc.encode(b.finish())
+    assert fmt is None and enc.decision == wire.RAW
+    assert enc.decision_json()["saved_bytes"] == 0
+
+
+def test_forced_codec_measures_nothing():
+    """``wire_compression=True``: no link is asked for, nothing is
+    decided, every compressible batch is encoded."""
+    enc = wire.WireEncoder(("int32",), 4096)
+    assert enc.decision == wire.FORCED and enc.link_bytes_per_sec is None
+    for _ in range(3):
+        assert enc.encode(_finished(_LOW_CARD, 4096))[1] is not None
+    assert enc.decision == wire.FORCED and not enc.ships_raw
+    assert enc.decision_json()["codec_bytes_per_sec"] is None
+    assert (enc.stats.batches, enc.stats.raw_batches) == (3, 0)
+
+
+def test_link_probe_times_the_pooled_transfer_once_per_size(monkeypatch):
+    pool = staging.StagingPool(depth=4)
+    rate = staging.probe_h2d(5000, pool=pool)
+    assert rate > 0
+    # one pooled buffer of that size, acquired and given back
+    assert (pool.misses, pool.releases) == (1, 1)
+    calls = []
+    monkeypatch.setattr(staging, "probe_h2d",
+                        lambda n, pool=None: calls.append(n) or 123.0)
+    assert pool.link_rate(5000) == pool.link_rate(5000) == 123.0
+    assert pool.link_rate(6000) == 123.0
+    assert calls == [5000, 6000]
+
+
+def test_wf_calibrate_reads_the_same_probe(monkeypatch):
+    import os
+    import sys
+    tools = os.path.join(os.path.dirname(__file__), "..", "tools")
+    monkeypatch.syspath_prepend(tools)
+    import wf_calibrate
+    seen = []
+    monkeypatch.setattr(
+        staging, "probe_h2d",
+        lambda n, pool=None, reps=3: seen.append((n, reps)) or 7e8)
+    value, detail = wf_calibrate.probe_h2d(jax, np)
+    assert value == 7e8 and seen == [(detail["buffer_bytes"] // 4, 7)]
+
+
+def _auto_graph(monkeypatch, link, cap):
+    """``_ab_graph`` under "auto" with the plane attached, as on an
+    accelerator backend, and the link's rate injected."""
+    monkeypatch.setattr(wire, "wire_enabled", lambda cfg: True)
+    monkeypatch.setattr(staging.StagingPool, "link_rate",
+                        lambda self, nwords: link)
+    return _ab_graph("auto", cap=cap)
+
+
+def _unpack_compiles():
+    return default_registry().snapshot().get(
+        "staging.unpack", {}).get("compiles", 0)
+
+
+def test_auto_on_a_fast_link_ships_every_batch_raw(monkeypatch):
+    """The plane is attached and counts, the codec never engages: every
+    batch goes out with ``fmt=None`` through the ONE unpack program of
+    its lane layout, the very program of the ``wire_compression=False``
+    run, and the rows are that run's."""
+    cap = 320                       # a layout no other test compiles
+    base = _unpack_compiles()
+    rows, g = _auto_graph(monkeypatch, 1e12, cap)
+    assert _unpack_compiles() == base + 1
+    st = g.stats()
+    ws = st["Staging"]["Wire"]
+    assert ws["enabled"] and ws["encoders"] == 1
+    assert ws["batches"] == 0 and ws["fallback_lanes"] == 0
+    assert ws["raw_batches"] == st["Staging"]["batches"] > 5
+    assert ws["wire_bytes"] == ws["logical_bytes"] == st["Bytes_H2D_total"]
+    [d] = ws["decisions"]
+    assert d["decision"] == wire.RAW and d["link_bytes_per_sec"] == 1e12
+    assert d["codec_bytes_per_sec"] > 0 and d["capacity"] == cap
+    for _src, _route, em in wire.iter_stage_emitters(g):
+        assert em._wire_on and all(e.ships_raw
+                                   for e in em._wire_encoders.values())
+    monkeypatch.undo()
+    off, g_off = _ab_graph(False, cap=cap)
+    assert _unpack_compiles() == base + 1       # the same program
+    key = lambda r: (r["key"], round(float(r["v"]), 6))
+    assert sorted(map(key, rows)) == sorted(map(key, off))
+    assert g_off.stats()["Staging"]["batches"] == st["Staging"]["batches"]
+
+
+def test_auto_on_a_slow_link_keeps_the_codec(monkeypatch):
+    rows, g = _auto_graph(monkeypatch, 1e3, 256)
+    ws = g.stats()["Staging"]["Wire"]
+    assert ws["batches"] > 0 and ws["compression_ratio"] > 1.5
+    assert [d["decision"] for d in ws["decisions"]] == [wire.ENCODE]
+    monkeypatch.undo()
+    off, _ = _ab_graph(False)
+    key = lambda r: (r["key"], round(float(r["v"]), 6))
+    assert sorted(map(key, rows)) == sorted(map(key, off))
+
+
+def test_forced_on_still_encodes_every_batch(monkeypatch):
+    """``wire_compression=True`` is the codec whatever the link: the
+    A/B tests of three files rely on it."""
+    def no_probe(self, nwords):
+        raise AssertionError("a forced codec must not time the link")
+    monkeypatch.setattr(staging.StagingPool, "link_rate", no_probe)
+    _, g = _ab_graph(True)
+    st = g.stats()
+    ws = st["Staging"]["Wire"]
+    assert ws["raw_batches"] == 0
+    assert ws["batches"] == st["Staging"]["batches"] > 0
+    assert [d["decision"] for d in ws["decisions"]] == [wire.FORCED]
+    assert ws["decisions"][0]["link_bytes_per_sec"] is None
 
 
 # ---------------------------------------------------------------------------

@@ -15,10 +15,12 @@ changes (docs/OBSERVABILITY.md "Calibration plane").
 
 Probes (all seeded, a few seconds total):
 
-* ``h2d_bytes_per_sec`` — median host→device transfer rate of a
-  packed staging buffer (the SAME ``PackedBatchBuilder`` path the
-  runtime stages batches through, so the number is the link the
-  staged e2e leg actually pays).  No modeled default exists for it.
+* ``h2d_bytes_per_sec`` — best host→device transfer rate of a packed
+  staging buffer after one warm transfer (``staging.probe_h2d``: the
+  SAME pooled buffer and transfer call the runtime stages batches
+  through, and the same probe each wire-plane edge decides from, so
+  the number is the link the staged e2e leg actually pays).  No
+  modeled default exists for it.
 * ``dispatch_overhead_usec`` — wall cost of dispatching one cached
   trivial jitted program (the per-dispatch floor the megastep fold
   amortizes).
@@ -83,27 +85,13 @@ def _median(xs):
 # ---------------------------------------------------------------------------
 
 def probe_h2d(jax, np, reps: int = 7):
-    """Host→device staging rate over the runtime's own packed path."""
-    from windflow_tpu.staging import PackedBatchBuilder
-    cap = 1 << 18                         # 256k rows ≈ 3 MB packed
-    rng = np.random.default_rng(0)
-    keys = rng.integers(0, 1 << 20, cap).astype(np.int32)
-    vals = rng.random(cap, dtype=np.float32)
-    tss = np.arange(cap, dtype=np.int64)
-    dev = jax.devices()[0]
-    rates = []
-    buf_bytes = None
-    for _ in range(reps):
-        b = PackedBatchBuilder([np.int32, np.float32], cap)
-        b.append([keys, vals], tss)
-        host = b.finish()
-        buf_bytes = host.nbytes
-        t0 = time.perf_counter()
-        d = jax.device_put(host, dev)
-        jax.block_until_ready(d)
-        rates.append(host.nbytes / (time.perf_counter() - t0))
-        b.pool.release(host, d)
-    return _median(rates), {"buffer_bytes": buf_bytes, "reps": reps}
+    """Host→device staging rate over the runtime's own packed path:
+    ``staging.probe_h2d``, the one probe the wire plane also decides
+    from, at the size of a 256k-row int32 + float32 + ts batch."""
+    from windflow_tpu import staging
+    nwords = 4 * (1 << 18) + 1            # ≈ 4 MB packed
+    return staging.probe_h2d(nwords, reps=reps), \
+        {"buffer_bytes": nwords * 4, "reps": reps, "stat": "best"}
 
 
 def probe_dispatch(jax, np, reps: int = 200):

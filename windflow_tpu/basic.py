@@ -345,14 +345,19 @@ class Config:
     # spec-less source downgrades to raw passthrough with a WF606
     # preflight warning.  Per-lane codec choice re-evaluates on the
     # key_compaction_reseed cadence and surfaces in
-    # stats()["Staging"]["Wire"].  Default "auto": ON whenever the
-    # default backend is a real accelerator (the wire is a slow link
-    # worth shrinking — the tentpole case) and OFF on the CPU fallback,
-    # where host and "device" share memory and encode/decode would be
-    # pure overhead on the staged path.  WF_TPU_WIRE=1 forces on
-    # anywhere (the bench wire leg and the A/B tests do), =0 is the
-    # kill switch: no encoder attaches and each staged batch keeps one
-    # flag check.  Typed loosely: True/False/"auto"/"1"/"0" all work
+    # stats()["Staging"]["Wire"].  Default "auto": the plane attaches
+    # whenever the default backend is a real accelerator, and each
+    # staging edge then DECIDES BY MEASUREMENT whether it encodes: it
+    # times its own link once (staging.probe_h2d) and the steady encode
+    # pass of its first batch, and keeps the codec only if the link
+    # time of the bytes saved exceeds the codec time — a host-attached
+    # chip ships raw, a slow tunnel keeps the codec
+    # (wire.WireEncoder).  Nothing attaches on the CPU fallback, where
+    # host and "device" share memory and there is no link.
+    # WF_TPU_WIRE=1 forces the codec anywhere, whatever the link (the
+    # bench wire leg and the A/B tests do), =0 is the kill switch: no
+    # encoder attaches and each staged batch keeps one flag check.
+    # Typed loosely: True/False/"auto"/"1"/"0" all work
     # (wire.wire_enabled resolves it).
     wire_compression: object = os.environ.get("WF_TPU_WIRE", "auto")
     # Pallas TPU kernels for the FFAT hot loop (windflow_tpu/kernels,

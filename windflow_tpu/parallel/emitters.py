@@ -529,6 +529,7 @@ class DeviceStageEmitter(Emitter):
         # Off/downgraded leaves exactly one flag check per finalize.
         self._wire_on = False
         self._wire_reseed = 64
+        self._wire_measured = False
         self._wire_encoders = {}
         # megastep plane (windflow_tpu/megastep.py): attached by
         # PipeGraph._build when this edge feeds an eligible device tail
@@ -586,22 +587,29 @@ class DeviceStageEmitter(Emitter):
             return nbytes // jax.process_count()
         return nbytes
 
-    def enable_wire(self, reseed_every: int = 64) -> None:
-        """Turn on columnar wire compression for this emitter's packed
-        staging (called by ``wire.attach_wire`` at graph build — only
-        for edges whose record spec is declared/inferred, the WF606
-        contract).  Mesh-sharded targets ignore the flag: their
-        transfers are assembled per shard, not packed."""
+    def enable_wire(self, reseed_every: int = 64,
+                    measured: bool = False) -> None:
+        """Attach the wire plane to this emitter's packed staging
+        (called by ``wire.attach_wire`` at graph build — only for edges
+        whose record spec is declared/inferred, the WF606 contract).
+        ``measured`` (``Config.wire_compression`` "auto") lets each
+        encoder decide from its own link and codec times whether the
+        edge encodes at all; otherwise the codec is forced.  Mesh-
+        sharded targets ignore the flag: their transfers are assembled
+        per shard, not packed."""
         self._wire_on = self._stage_target is None
         self._wire_reseed = max(1, reseed_every)
+        self._wire_measured = measured
 
-    def _wire_encoder(self, dtypes, capacity: int):
+    def _wire_encoder(self, dtypes, capacity: int, pool):
         key = (dtypes, capacity)
         enc = self._wire_encoders.get(key)
         if enc is None:
             from windflow_tpu.wire import WireEncoder
             enc = WireEncoder(dtypes, capacity,
-                              reseed_every=self._wire_reseed)
+                              reseed_every=self._wire_reseed,
+                              link_rate=pool.link_rate
+                              if self._wire_measured else None)
             self._wire_encoders[key] = enc
         return enc
 
@@ -707,9 +715,14 @@ class DeviceStageEmitter(Emitter):
                 # wire plane (windflow_tpu/wire.py): lane-wise re-encode
                 # of the finished logical buffer; a batch compression
                 # cannot shrink ships the logical buffer unchanged (fmt
-                # None)
-                enc = self._wire_encoder(self._b_dtypes, b.capacity)
-                buf, fmt = enc.encode(buf, pool=b.pool)
+                # None), and so does every batch of an edge that
+                # measured its link faster than its codec
+                enc = self._wire_encoder(self._b_dtypes, b.capacity,
+                                         b.pool)
+                if enc.ships_raw:
+                    enc.stats.note_raw(logical_nbytes)
+                else:
+                    buf, fmt = enc.encode(buf, pool=b.pool)
             sp.note(bytes=buf.nbytes, logical=logical_nbytes)
         if self.stats is not None:
             # the packed path's H2D transfer is exactly this buffer;

@@ -1,10 +1,14 @@
 """Wire plane: columnar compression of staged batches with device decode.
 
-The staged path feeds the chip over the host→device link, orders of
-magnitude slower than the kernel reads pre-staged HBM; where that link
-bounds end-to-end numbers, fewer bytes on it is the lever (the
+The staged path feeds the chip over the host→device link; where that
+link bounds end-to-end numbers, fewer bytes on it is the lever (the
 compile-the-pipeline stance of arXiv 2207.00257 extended to the decode
-step).  This module shrinks the wire: the staging plane's packed
+step).  Whether it does is measured, not assumed: under
+``Config.wire_compression="auto"`` every staging edge times its link
+and its codec and encodes only where the link time saved exceeds the
+codec time (:class:`WireEncoder`) — a 19 MB/s tunnel keeps the codec, a
+host-attached chip ships raw and compiles no decode variant.  This
+module shrinks the wire: the staging plane's packed
 uint32 buffer (``staging.PackedBatchBuilder``) is re-encoded lane by
 lane with cheap columnar codecs before the ONE fused transfer, and the
 inverse decode is a traced stage folded into the SAME device unpack
@@ -49,7 +53,8 @@ downgrades to raw passthrough with a named preflight warning (WF606)
 instead of silently guessing lane semantics.  Mesh-sharded staging keeps
 the uncompressed per-lane path (its transfers are assembled per shard,
 not packed); ``Config.wire_compression`` / ``WF_TPU_WIRE=0`` is the kill
-switch, leaving one ``is not None`` check per staged batch.
+switch, leaving one flag check per staged batch, and ``=1`` forces the
+codec whatever the link.
 
 Host packing uses little-endian byte views (every supported host);
 device-side unpacking is pure 32-bit word arithmetic, endian-agnostic.
@@ -67,6 +72,10 @@ from windflow_tpu import staging
 #: codec kind tags (descriptor fields are plain strings/ints so the
 #: descriptor tuple is hashable — it keys the cached decode program)
 RAW, CONST, DELTA, DELTA2, DICT = "raw", "const", "delta", "delta2", "dict"
+
+#: an encoder's standing decision (WireEncoder.decision); the fourth
+#: value is RAW
+FORCED, PENDING, ENCODE = "forced", "pending", "encode"
 
 #: largest dictionary a lane may ship per batch (16-bit indices)
 DICT_MAX = 1 << 16
@@ -206,6 +215,16 @@ class WireStats:
         self.wire_bytes = 0       # bytes actually transferred
         self.encode_usec = 0.0
 
+    def note_raw(self, nbytes: int) -> None:
+        """One batch shipped as its logical buffer (compression lost on
+        it, or the edge decided raw): accrued at FULL size on both byte
+        counters, so ``compression_ratio`` is the blended transfer
+        truth, not the compressed-batches-only flatter (the honesty
+        contract)."""
+        self.raw_batches += 1
+        self.wire_bytes += nbytes
+        self.logical_bytes += nbytes
+
     def merge(self, other: "WireStats") -> None:
         self.batches += other.batches
         self.raw_batches += other.raw_batches
@@ -236,14 +255,32 @@ class WireEncoder:
     :class:`WireFormat`.  Codec choice per lane is re-evaluated every
     ``reseed_every`` encoded batches; in between, each batch pays one
     vectorized fit-check+encode pass per lane.  A batch compression
-    cannot shrink ships the logical buffer unchanged (``fmt=None``)."""
+    cannot shrink ships the logical buffer unchanged (``fmt=None``).
+
+    With ``link_rate`` (a callable: words of one transfer -> measured
+    host→device bytes per second; the runtime passes
+    ``StagingPool.link_rate``) the encoder DECIDES, once, whether its
+    edge encodes at all.  The link is timed when the encoder is made,
+    on a buffer of the edge's logical size; the first batch is encoded
+    as usual with the steady pass clocked apart from the codec choice
+    (``np.unique`` and friends run once per reseed cadence and would
+    bias a slow link towards raw); the edge keeps the codec only if
+    the link time of the bytes it saved on that batch exceeds that
+    pass.  The batch itself ships by the decision, so an edge that
+    decides raw never hands out a :class:`WireFormat` and no decode
+    variant of ``staging.unpack`` is compiled for it; from then on
+    :attr:`ships_raw` tells the emitter to skip the encoder.  Without
+    ``link_rate`` the codec is forced (``Config.wire_compression``
+    True): every batch is encoded as before."""
 
     def __init__(self, dtypes: Sequence, capacity: int,
-                 reseed_every: int = 64) -> None:
+                 reseed_every: int = 64, link_rate=None,
+                 clock=time.perf_counter) -> None:
         self.dtypes = tuple(np.dtype(d) for d in dtypes) \
             + (np.dtype(np.int64),)             # + implicit ts lane
         self.capacity = capacity
         self.reseed_every = max(1, reseed_every)
+        self._clock = clock
         self._lane_words = [staging.lane_words(d) for d in self.dtypes]
         self._offsets = []
         off = 0
@@ -254,6 +291,42 @@ class WireEncoder:
         self._lanes = [_LaneState() for _ in self.dtypes]
         self._since = self.reseed_every     # force choice on first batch
         self.stats = WireStats()
+        #: "forced" (no link given), else "pending" until the first
+        #: batch was measured, then "encode" or "raw" for good
+        self.decision = FORCED if link_rate is None else PENDING
+        #: what the decision was taken from (stats()["Staging"]["Wire"])
+        self.link_bytes_per_sec = None if link_rate is None \
+            else float(link_rate(self._logical_words))
+        self.codec_bytes_per_sec = None
+        self.codec_usec = None      # steady encode pass of the batch
+        self.saved_bytes = None     # logical - wire bytes of that batch
+
+    @property
+    def ships_raw(self) -> bool:
+        """The edge measured its link faster than its codec: callers
+        ship the logical buffer and count it (``stats.note_raw``)
+        without calling :meth:`encode` (which would still ship raw,
+        after a codec pass nobody needs)."""
+        return self.decision == RAW
+
+    def _decide(self, codec_s: float, wire_words: int) -> None:
+        """The one comparison: link time of the bytes the codec saved
+        on this batch against the steady encode pass that saved them."""
+        logical = self._logical_words * 4
+        self.saved_bytes = max(0, logical - wire_words * 4)
+        self.codec_usec = round(codec_s * 1e6, 1)
+        self.codec_bytes_per_sec = logical / max(codec_s, 1e-9)
+        saved_s = self.saved_bytes / max(self.link_bytes_per_sec, 1e-9)
+        self.decision = ENCODE if saved_s > codec_s else RAW
+
+    def decision_json(self) -> dict:
+        return {"dtypes": [str(d) for d in self.dtypes],
+                "capacity": self.capacity,
+                "decision": self.decision,
+                "link_bytes_per_sec": self.link_bytes_per_sec,
+                "codec_bytes_per_sec": self.codec_bytes_per_sec,
+                "codec_usec": self.codec_usec,
+                "saved_bytes": self.saved_bytes}
 
     # -- lane value views ---------------------------------------------------
     def _values(self, buf: np.ndarray, i: int) -> np.ndarray:
@@ -390,7 +463,8 @@ class WireEncoder:
         ``buf`` is released back (host-only use, no gate) — or
         ``(buf, None)`` when compression would not shrink the transfer
         (the caller ships the logical buffer exactly as before)."""
-        t0 = time.perf_counter()
+        clock = self._clock
+        t0 = clock()
         if buf.shape[0] != self._logical_words:
             # capacity drift (defensive): ship raw rather than corrupt
             return buf, None
@@ -400,6 +474,7 @@ class WireEncoder:
             self._since = 0
             self.stats.reseeds += 1
         self._since += 1
+        t_steady = clock()      # what every batch pays starts here
         parts: List[List[np.ndarray]] = []
         used: List[LaneCodec] = []
         total = 1
@@ -414,33 +489,36 @@ class WireEncoder:
             parts.append(arrs)
             used.append(c)
             total += lane_wire_words(c, self.dtypes[i], self.capacity)
-        padded = staging.size_class(total)
-        if padded >= self._logical_words:
-            # compression lost: the logical buffer ships unchanged —
-            # accrue it at FULL size on both counters so the reported
-            # compression_ratio is the blended transfer truth, not the
-            # compressed-batches-only flatter (the honesty contract)
-            self.stats.raw_batches += 1
-            self.stats.wire_bytes += self._logical_words * 4
-            self.stats.logical_bytes += self._logical_words * 4
-            self.stats.encode_usec += (time.perf_counter() - t0) * 1e6
+        padded = min(staging.size_class(total), self._logical_words)
+        wire = None
+        if padded < self._logical_words:
+            wire = pool.acquire(padded) if pool is not None \
+                else np.empty(padded, np.uint32)
+            off = 0
+            for arrs in parts:
+                for a in arrs:
+                    wire[off:off + len(a)] = a
+                    off += len(a)
+            # pad gap is never read by the decode program; recycled
+            # buffers arrive with undefined contents anyway
+            # (StagingPool contract)
+            wire[-1] = buf[-1]
+        t1 = clock()
+        self.stats.encode_usec += (t1 - t0) * 1e6
+        if self.decision == PENDING:
+            self._decide(t1 - t_steady, padded)
+        if wire is None or self.decision == RAW:
+            # compression lost on this batch, or loses to the link on
+            # this edge: the logical buffer ships unchanged
+            if wire is not None and pool is not None:
+                pool.release(wire, None)    # host-only scratch: no gate
+            self.stats.note_raw(buf.nbytes)
             return buf, None
-        wire = pool.acquire(padded) if pool is not None \
-            else np.empty(padded, np.uint32)
-        off = 0
-        for arrs in parts:
-            for a in arrs:
-                wire[off:off + len(a)] = a
-                off += len(a)
-        # pad gap is never read by the decode program; recycled buffers
-        # arrive with undefined contents anyway (StagingPool contract)
-        wire[-1] = buf[-1]
         if pool is not None:
             pool.release(buf, None)     # host-only scratch: no gate
         self.stats.batches += 1
         self.stats.logical_bytes += self._logical_words * 4
         self.stats.wire_bytes += padded * 4
-        self.stats.encode_usec += (time.perf_counter() - t0) * 1e6
         return wire, WireFormat(tuple(used), padded)
 
     def codec_table(self) -> list:
@@ -570,20 +648,31 @@ def build_wire_decode(fmt: WireFormat, dtypes, capacity: int):
 # graph attachment + stats surfaces
 # ---------------------------------------------------------------------------
 
-def wire_enabled(cfg) -> bool:
-    """Resolve ``Config.wire_compression``: True/False ("1"/"0") are
-    explicit; "auto" (the default) enables compression exactly when the
-    default backend is a real accelerator — on the CPU backend host and
-    "device" share memory, so the wire is a memcpy and encode/decode
-    would be pure overhead on the staged path (measured ~40% at the e2e
-    capacity), while across a host→device link every wire byte is
-    transfer time the plane exists to shrink.  A backend that cannot
-    initialize raises here: it is never read as "no compression"."""
+def _wire_setting(cfg) -> Optional[bool]:
+    """``Config.wire_compression`` as True (forced), False (off) or
+    None ("auto")."""
     v = getattr(cfg, "wire_compression", "auto")
     if v in (True, 1, "1", "on", "true"):
         return True
     if v in (False, 0, None, "", "0", "off", "false"):
         return False
+    return None
+
+
+def wire_enabled(cfg) -> bool:
+    """Whether ``Config.wire_compression`` attaches the plane at all:
+    True/False ("1"/"0") are explicit; "auto" (the default) attaches it
+    exactly when the default backend is a real accelerator.  On the CPU
+    backend host and "device" share memory, so there is no link to
+    measure and nothing attaches.  Attached under "auto" is not
+    "encoding": each staging edge then times its own link and its own
+    codec and encodes only where the link is the slower
+    (:class:`WireEncoder`); only an explicit True forces the codec.  A
+    backend that cannot initialize raises here: it is never read as
+    "no compression"."""
+    v = _wire_setting(cfg)
+    if v is not None:
+        return v
     import jax
     return jax.default_backend() != "cpu"
 
@@ -639,22 +728,27 @@ def attach_wire(graph) -> None:
         # passthrough, it must never take the build down)
         in_specs = {}
     reseed = getattr(graph.config, "key_compaction_reseed", 64)
+    # only an explicit True forces the codec; under "auto" each edge
+    # decides from its own measured link and codec times
+    forced = _wire_setting(graph.config) is True
     for _src, route_op, em in iter_stage_emitters(graph):
         if em._stage_target is not None:
             continue    # mesh staging: per-shard assembly, not packed
         spec = in_specs.get(id(route_op))
         if spec is None or spec is _UNKNOWN:
             continue    # WF606: documented raw-passthrough downgrade
-        em.enable_wire(reseed)
+        em.enable_wire(reseed, measured=not forced)
 
 
 def wire_section(graph) -> dict:
     """``stats()["Staging"]["Wire"]``: merged wire-plane counters over
-    the graph's staging emitters plus the current per-lane codec table
-    (one table per distinct lane layout)."""
+    the graph's staging emitters, the current per-lane codec table
+    (one table per distinct lane layout) and, per encoder, what it
+    decided and from which two measurements (``decisions``)."""
     enabled = wire_enabled(graph.config)
     agg = WireStats()
     codecs = []
+    decisions = []
     emitters = 0
     for _src, _route, em in iter_stage_emitters(graph):
         for enc in getattr(em, "_wire_encoders", {}).values():
@@ -662,7 +756,10 @@ def wire_section(graph) -> dict:
             agg.merge(enc.stats)
             if enc.stats.batches and len(codecs) < 8:
                 codecs.append(enc.codec_table())
+            if len(decisions) < 8:
+                decisions.append(enc.decision_json())
     out = {"enabled": enabled, "encoders": emitters}
     out.update(agg.to_json())
     out["codecs"] = codecs[0] if len(codecs) == 1 else codecs
+    out["decisions"] = decisions
     return out
