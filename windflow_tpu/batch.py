@@ -28,6 +28,7 @@ driver that tracks watermarks per channel, so one scalar per batch suffices.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Sequence
 
 import jax
@@ -196,6 +197,23 @@ def transfer_nbytes(batch: DeviceBatch) -> int:
         + getattr(batch.ts, "nbytes", 0) + getattr(batch.valid, "nbytes", 0)
 
 
+def staged_nbytes(batch: DeviceBatch) -> int:
+    """Bytes the host really shipped to place a staged batch: every lane's
+    shard, once per device of this process that holds one.  Equal to
+    :func:`transfer_nbytes` on one device; on a mesh a lane replicated
+    along an axis is shipped to each chip of that axis (``P("data")`` on
+    a ``(data=1, key=4)`` mesh: the whole batch four times), and on a
+    multi-host mesh only this process's shards count."""
+    def moved(a) -> int:
+        sh = getattr(a, "sharding", None)
+        if sh is None:
+            return getattr(a, "nbytes", 0)
+        return math.prod(sh.shard_shape(a.shape)) * a.dtype.itemsize \
+            * len(sh.addressable_devices)
+    return sum(moved(l) for l in jax.tree.leaves(batch.payload)) \
+        + moved(batch.ts) + moved(batch.valid)
+
+
 # ---------------------------------------------------------------------------
 # Host <-> device conversion (the reference's pinned-staging H2D/D2H path,
 # forward_emitter_gpu.hpp:254-300 and Batch_GPU_t::transfer2CPU).
@@ -337,7 +355,7 @@ def stage_packed(buf: np.ndarray, treedef, dtypes, capacity: int, n: int,
 
 def _stage_soa(soa, tss, n: int, capacity: int, watermark: int,
                device, frontier: Optional[int] = None,
-               trace: Optional[tuple] = None) -> DeviceBatch:
+               trace: Optional[tuple] = None, seq: int = 0) -> DeviceBatch:
     """Shared staging tail: pad an SoA numpy pytree + timestamps to
     ``capacity``, build the validity mask, optionally pin to a device.
 
@@ -403,30 +421,40 @@ def _stage_soa(soa, tss, n: int, capacity: int, watermark: int,
         return stage_packed(b.finish(), treedef, dtypes, capacity, n,
                             watermark=watermark, device=device,
                             frontier=frontier, ts_max=ts_max,
-                            ts_min=ts_min, pool=pool, trace=trace)
+                            ts_min=ts_min, pool=pool, trace=trace, seq=seq)
     # host buffers go STRAIGHT to their placement: a sharding splits on
     # the host and each chip receives only its own shard (staging through
     # jnp.asarray first would land the whole batch on device 0 and
-    # re-shard from there)
+    # re-shard from there).  The same two layer spans as the packed path
+    # (docs/OBSERVABILITY.md): the assembly of the padded host lanes is
+    # the pack, the per-lane, per-shard puts are the transfer.
     def put(a):
         return jnp.asarray(a) if device is None \
             else jax.device_put(a, device)
-    payload = jax.tree.map(
-        lambda a: put(_pad_leading(np.ascontiguousarray(a), capacity)), soa)
-    ts = put(_pad_leading(np.asarray(tss, dtype=np.int64), capacity))
-    valid = put(np.arange(capacity) < n)
-    out = DeviceBatch(payload, ts, valid, watermark=watermark, size=n,
-                      frontier=frontier, ts_max=ts_max, ts_min=ts_min,
-                      trace=trace)
-    # unpackable-lane fallback (per-lane transfers): still a staged batch
-    # for the device-plane accounting stage_packed credits on the fused path
-    staging.device_bytes.note(transfer_nbytes(out))
+    with flightrec.span("wf.pack", n=n):
+        lanes = jax.tree.map(
+            lambda a: _pad_leading(np.ascontiguousarray(a), capacity), soa)
+        ts_h = _pad_leading(np.asarray(tss, dtype=np.int64), capacity)
+        valid_h = np.arange(capacity) < n
+    with flightrec.span("wf.h2d", batch=seq, n=n, cap=capacity) as sp:
+        out = DeviceBatch(jax.tree.map(put, lanes), put(ts_h), put(valid_h),
+                          watermark=watermark, size=n, frontier=frontier,
+                          ts_max=ts_max, ts_min=ts_min, trace=trace,
+                          seq=seq)
+        moved, logical = staged_nbytes(out), transfer_nbytes(out)
+        sp.note(bytes=moved, logical=logical,
+                shards=len(device.addressable_devices)
+                if isinstance(device, jax.sharding.Sharding) else 1)
+    # per-lane transfers: still a staged batch for the device-plane
+    # accounting stage_packed credits on the fused path
+    staging.device_bytes.note(moved, logical)
     return out
 
 
 def host_to_device(batch: HostBatch, capacity: Optional[int] = None,
                    device=None, frontier: Optional[int] = None,
-                   trace: Optional[tuple] = None) -> DeviceBatch:
+                   trace: Optional[tuple] = None,
+                   seq: int = 0) -> DeviceBatch:
     """Stage a HostBatch into device buffers, padding to ``capacity``."""
     n = len(batch)
     if n == 0:
@@ -436,12 +464,14 @@ def host_to_device(batch: HostBatch, capacity: Optional[int] = None,
         raise ValueError(f"batch of {n} items exceeds capacity {cap}")
     return _stage_soa(_stack_records(batch.items), batch.tss, n, cap,
                       batch.watermark, device, frontier,
-                      trace=trace if trace is not None else batch.trace)
+                      trace=trace if trace is not None else batch.trace,
+                      seq=seq)
 
 
 def columns_to_device(cols, tss, capacity: int, watermark: int = WM_NONE,
                       device=None, frontier: Optional[int] = None,
-                      trace: Optional[tuple] = None) -> DeviceBatch:
+                      trace: Optional[tuple] = None,
+                      seq: int = 0) -> DeviceBatch:
     """Stage columnar (SoA numpy) data directly into a DeviceBatch — the
     zero-per-tuple-Python path used by bulk sources (windflow_tpu/io) and the
     columnar staging emitter.  ``cols`` is a dict of [n]-leading numpy
@@ -452,7 +482,7 @@ def columns_to_device(cols, tss, capacity: int, watermark: int = WM_NONE,
     if n > capacity:
         raise ValueError(f"column batch of {n} exceeds capacity {capacity}")
     return _stage_soa(dict(cols), tss, n, capacity, watermark, device,
-                      frontier, trace=trace)
+                      frontier, trace=trace, seq=seq)
 
 
 #: cached pack programs for single-transfer egress, keyed by the payload's

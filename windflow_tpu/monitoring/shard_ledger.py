@@ -781,7 +781,9 @@ class ShardLedger:
             # is derived, never measured on CPU
             "provenance": calibration.MODELED,
             "model": "structural (XLA cost tables carry no collective "
-                     "terms; see docs/OBSERVABILITY.md shard plane)",
+                     "terms; see docs/OBSERVABILITY.md shard plane); the "
+                     "measured figure is the benchmark's "
+                     "collective_dev_share.sat, from a device trace",
         }
 
     # -- read paths ----------------------------------------------------------

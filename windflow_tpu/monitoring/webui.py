@@ -261,7 +261,7 @@ async function render(id) {
            `${imb}${hot ? ` hot keys: ${hot}` : ""}` +
            `${ici != null ? ` <span title="provenance: modeled ` +
              `(structural collective model; bandwidth ${esc(iciProv)})">` +
-             `ICI≈${ici} B/tuple</span>` : ""}</small>` +
+             `modeled ICI≈${ici} B/tuple</span>` : ""}</small>` +
            `</td></tr>`;
   };
   window._openShards = window._openShards || new Set();

@@ -60,8 +60,11 @@ class _TPUReplica(Replica):
     def process_device_batch(self, batch: DeviceBatch) -> None:
         # the stable name of this dispatch in a profiler capture is the
         # host span's op= (the XLA module is jit_step for every operator)
-        with flightrec.span("wf.dispatch", op=self.op.name,
-                            batch=batch.seq):
+        counts = {"op": self.op.name, "batch": batch.seq}
+        if self.op.mesh is not None:
+            # one dispatch drives a program on every chip of the mesh
+            counts["mesh"] = self.op.mesh.size
+        with flightrec.span("wf.dispatch", **counts):
             out = self._op_step(batch)
         self.stats.device_programs_launched += 1
         if self.ring is not None and batch.trace is not None:

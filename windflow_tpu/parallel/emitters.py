@@ -37,7 +37,7 @@ from windflow_tpu.analysis.hotpath import hot_path
 from windflow_tpu.basic import RoutingMode, WindFlowError, int32_key
 from windflow_tpu.batch import (DeviceBatch, HostBatch, Punctuation, WM_NONE,
                                 columns_to_device, host_to_device,
-                                stage_packed, transfer_nbytes)
+                                stage_packed, staged_nbytes, transfer_nbytes)
 from windflow_tpu.monitoring import recorder as flightrec
 
 
@@ -587,6 +587,15 @@ class DeviceStageEmitter(Emitter):
             return nbytes // jax.process_count()
         return nbytes
 
+    def _count_h2d(self, db: DeviceBatch) -> None:
+        """Credit one unpacked staged batch: what the host shipped (on a
+        mesh every shard of every lane, a replicated lane once per chip:
+        the ``bytes`` of its ``wf.h2d`` span) and the batch counted once."""
+        if self.stats is not None:
+            self.stats.h2d_bytes += staged_nbytes(db)
+            self.stats.h2d_logical_bytes += \
+                self._local_share(_db_nbytes(db))
+
     def enable_wire(self, reseed_every: int = 64,
                     measured: bool = False) -> None:
         """Attach the wire plane to this emitter's packed staging
@@ -650,7 +659,11 @@ class DeviceStageEmitter(Emitter):
             # falling back mid-stream: ship the open packed rows first so
             # per-destination arrival order is preserved
             self._finalize_builder()
-        self._emit_columns_chunked(cols, tss, wm, row_wms)
+        # the pack of a mesh (or unpackable-lane) edge: the chunk joins
+        # the open batch, and a batch that fills is assembled and
+        # shipped (its wf.h2d nests here and is not this span's time)
+        with flightrec.span("wf.pack", n=len(tss)):
+            self._emit_columns_chunked(cols, tss, wm, row_wms)
 
     def _emit_columns_packed(self, leaves, treedef, tss, wm, row_wms):
         """Streaming packed staging (see emit_columns).  Watermark lane
@@ -798,12 +811,9 @@ class DeviceStageEmitter(Emitter):
         db = columns_to_device(cols, tss, self.output_batch_size,
                                watermark=wm, device=self._stage_target,
                                frontier=self._frontier,
-                               trace=self._trace_of(seq, flightrec.STAGED))
-        db.seq = seq
-        if self.stats is not None:
-            self.stats.h2d_bytes += self._local_share(_db_nbytes(db))
-            self.stats.h2d_logical_bytes += \
-                self._local_share(_db_nbytes(db))
+                               trace=self._trace_of(seq, flightrec.STAGED),
+                               seq=seq)
+        self._count_h2d(db)
         d = self._next
         self._next = (self._next + 1) % len(self.dests)
         self._send(d, db)
@@ -822,17 +832,18 @@ class DeviceStageEmitter(Emitter):
         if self._builder is not None:
             self._finalize_builder(fallback_wm=wm)
         if self._col_chunks:
-            names = list(self._col_chunks[0][0])
-            cat = {n: _concat([c[0][n] for c in self._col_chunks])
-                   for n in names}
-            tcat = _concat([c[1] for c in self._col_chunks])
-            # everything buffered is fully staged by this batch, so the
-            # newest row frontier applies
-            w = int(max(int(c[2].max()) for c in self._col_chunks))
-            self._col_chunks = []
-            self._col_rows = 0
-            self._advance_frontier(w)
-            self._stage_columns(cat, tcat, w if w != WM_NONE else wm)
+            with flightrec.span("wf.pack", n=self._col_rows):
+                names = list(self._col_chunks[0][0])
+                cat = {n: _concat([c[0][n] for c in self._col_chunks])
+                       for n in names}
+                tcat = _concat([c[1] for c in self._col_chunks])
+                # everything buffered is fully staged by this batch, so
+                # the newest row frontier applies
+                w = int(max(int(c[2].max()) for c in self._col_chunks))
+                self._col_chunks = []
+                self._col_rows = 0
+                self._advance_frontier(w)
+                self._stage_columns(cat, tcat, w if w != WM_NONE else wm)
         self._advance_frontier(wm)
         if not self._ob.items:
             return
@@ -878,12 +889,9 @@ class DeviceStageEmitter(Emitter):
         db = host_to_device(hb, capacity=self.output_batch_size,
                             device=self._stage_target,
                             frontier=self._frontier,
-                            trace=self._trace_of(seq, flightrec.STAGED))
-        db.seq = seq
-        if self.stats is not None:
-            self.stats.h2d_bytes += self._local_share(_db_nbytes(db))
-            self.stats.h2d_logical_bytes += \
-                self._local_share(_db_nbytes(db))
+                            trace=self._trace_of(seq, flightrec.STAGED),
+                            seq=seq)
+        self._count_h2d(db)
         d = self._next
         self._next = (self._next + 1) % len(self.dests)
         self._send(d, db)
@@ -1344,9 +1352,16 @@ class AlignedMeshStageEmitter(Emitter):
         return lo
 
     def _ship_one(self) -> None:
+        # the pack of an aligned edge: per-column takes assembled into
+        # the (data, key) blocks; the transfer nests as wf.h2d
+        with flightrec.span("wf.pack") as sp:
+            sp.note(n=self._assemble_ship())
+
+    def _assemble_ship(self) -> int:
+        """Rows shipped (0: nothing was buffered)."""
         takes = [self._col_take(c) for c in range(self._kk)]
         if not any(t is not None for t in takes):
-            return
+            return 0
         cap, kk, dd, blk = (self.output_batch_size, self._kk, self._dd,
                             self._blk)
         first = next(t for t in takes if t is not None)
@@ -1376,7 +1391,7 @@ class AlignedMeshStageEmitter(Emitter):
                 ts[seg] = colt[lo:hi]
                 valid[seg] = True
         if total == 0:
-            return
+            return 0
         # watermark capped at the minimum buffered data timestamp: a
         # skew-retained row must never become late against this
         # channel's own stamp (frontier capped identically — the
@@ -1388,22 +1403,26 @@ class AlignedMeshStageEmitter(Emitter):
         on = ts[valid]
         ts_lo, ts_hi = int(on.min()), int(on.max())
         seq = self._new_seq()
-        payload = {n: jax.device_put(a, self._sharding)
-                   for n, a in lanes.items()}
-        db = DeviceBatch(payload, jax.device_put(ts, self._sharding),
-                         jax.device_put(valid, self._sharding),
-                         watermark=wm, size=total, frontier=wm,
-                         ts_max=ts_hi, ts_min=ts_lo,
-                         trace=self._trace_of(seq, flightrec.STAGED),
-                         seq=seq)
-        if self.stats is not None:
+        with flightrec.span("wf.h2d", batch=seq, n=total, cap=cap) as sp:
+            payload = {n: jax.device_put(a, self._sharding)
+                       for n, a in lanes.items()}
+            db = DeviceBatch(payload, jax.device_put(ts, self._sharding),
+                             jax.device_put(valid, self._sharding),
+                             watermark=wm, size=total, frontier=wm,
+                             ts_max=ts_hi, ts_min=ts_lo,
+                             trace=self._trace_of(seq, flightrec.STAGED),
+                             seq=seq)
+            # every chip is shipped its own block only: moved == logical
             nb = _db_nbytes(db)
+            sp.note(bytes=nb, logical=nb, shards=kk * dd)
+        if self.stats is not None:
             self.stats.h2d_bytes += nb
             self.stats.h2d_logical_bytes += nb
-        staging.device_bytes.note(_db_nbytes(db))
+        staging.device_bytes.note(nb)
         self.batches_shipped += 1
         self.rows_shipped += total
         self._send(0, db)
+        return total
 
     def flush(self, wm):
         self._note_wm(wm)
