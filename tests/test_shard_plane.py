@@ -199,12 +199,11 @@ def test_device_keyby_in_program_sketch_zero_extra_dispatches(tmp_path):
     assert load["tuples"] == [int(c) for c in expected]
 
 
-def test_fused_chain_sketch_rides_the_chain_program(tmp_path):
-    """A chained pair forwarding a downstream KEYBY consumer's keys
-    extracts them in-program (PR 7); the sketch folds into that SAME
-    program — dispatches per batch stay 1.0 and the hot key surfaces."""
+def _chain_sketch_graph(cfg, name, par=1):
+    """Map + Filter chained into a keyed consumer: at parallelism 1 the
+    chain program extracts the consumer's keys and carries the sketch;
+    above it the edge is a DeviceKeyByEmitter whose split program does."""
     import jax.numpy as jnp
-    cfg = _cfg(tmp_path, whole_chain_fusion=False)
     src = (wf.Source_Builder(_records).withOutputBatchSize(CAP)
            .withName("src").build())
     ma = (wf.MapTPU_Builder(lambda t: {"key": t["key"], "v": t["v"] * 2.0})
@@ -215,13 +214,22 @@ def test_fused_chain_sketch_rides_the_chain_program(tmp_path):
         lambda t, s: ({"key": t["key"], "run": s + t["v"]}, s + t["v"]))
         .withInitialState(jnp.zeros((), jnp.float32))
         .withKeyBy(lambda t: t["key"]).withNumKeySlots(64).withDenseKeys()
-        .withName("st").build())
+        .withParallelism(par).withName("st").build())
     snk = wf.Sink_Builder(lambda t, ctx=None: None).withName("snk").build()
-    g = wf.PipeGraph("fused_sketch", wf.ExecutionMode.DEFAULT, config=cfg)
+    g = wf.PipeGraph(name, wf.ExecutionMode.DEFAULT, config=cfg)
     pipe = g.add_source(src)
     pipe.add(ma)
     pipe.chain(fb)
     pipe.add(st).add_sink(snk)
+    return g
+
+
+def test_fused_chain_sketch_rides_the_chain_program(tmp_path):
+    """A chained pair forwarding a downstream KEYBY consumer's keys
+    extracts them in-program (PR 7); the sketch folds into that SAME
+    program — dispatches per batch stay 1.0 and the hot key surfaces."""
+    g = _chain_sketch_graph(_cfg(tmp_path, whole_chain_fusion=False),
+                            "fused_sketch")
     g.run()
     sweep = g.stats()["Sweep"]
     assert sweep["per_hop"]["ma|fb"]["dispatches_per_batch"] == 1.0
@@ -235,26 +243,8 @@ def test_chain_into_parallel_keyby_counts_once(tmp_path):
     through a DeviceKeyByEmitter whose split program sketches the
     stream; the chain program must NOT sketch it again (regression:
     total_tuples would read 2x)."""
-    import jax.numpy as jnp
-    cfg = _cfg(tmp_path, whole_chain_fusion=False)
-    src = (wf.Source_Builder(_records).withOutputBatchSize(CAP)
-           .withName("src").build())
-    ma = (wf.MapTPU_Builder(lambda t: {"key": t["key"], "v": t["v"] * 2.0})
-          .withName("ma").build())
-    fb = (wf.FilterTPU_Builder(lambda t: t["v"] >= 0.0)
-          .withName("fb").build())
-    st = (wf.MapTPU_Builder(
-        lambda t, s: ({"key": t["key"], "run": s + t["v"]}, s + t["v"]))
-        .withInitialState(jnp.zeros((), jnp.float32))
-        .withKeyBy(lambda t: t["key"]).withNumKeySlots(64).withDenseKeys()
-        .withParallelism(2).withName("st").build())
-    snk = wf.Sink_Builder(lambda t, ctx=None: None).withName("snk").build()
-    g = wf.PipeGraph("chain_par_keyby", wf.ExecutionMode.DEFAULT,
-                     config=cfg)
-    pipe = g.add_source(src)
-    pipe.add(ma)
-    pipe.chain(fb)
-    pipe.add(st).add_sink(snk)
+    g = _chain_sketch_graph(_cfg(tmp_path, whole_chain_fusion=False),
+                            "chain_par_keyby", par=2)
     g.run()
     load = g.stats()["Shard"]["per_op"]["st"]["load"]
     assert load["total_tuples"] == N          # counted exactly once
@@ -563,6 +553,291 @@ def test_health_verdict_names_hot_shard(tmp_path):
     red.replicas[2].inbox.clear()
     for rep in red.replicas:
         rep.done = True
+
+
+# ---------------------------------------------------------------------------
+# the in-program update: bit-exact against the host paths, no 64-bit
+# scatter, engaged on every batch of the four-chip cell's graph
+# ---------------------------------------------------------------------------
+
+def _one_key_full(rng, cap):
+    return np.full(cap, 40_961, np.int32), np.ones(cap, bool)
+
+
+def _invalid_mixed(rng, cap):
+    return (rng.integers(-2**31, 2**31, cap).astype(np.int32),
+            rng.random(cap) < 0.7)
+
+
+def _negative_keys(rng, cap):
+    return (rng.integers(-1000, 0, cap).astype(np.int32),
+            rng.random(cap) < 0.9)
+
+
+def _filtered_uniform(rng, cap):
+    k = rng.integers(0, 4096, cap).astype(np.int32)
+    return k, (k & 7) != 7
+
+
+def _none_valid(rng, cap):
+    return rng.integers(0, 64, cap).astype(np.int32), np.zeros(cap, bool)
+
+
+#: (id, capacity, batch maker): the full-capacity case puts a whole
+#: default-capacity batch on ONE counter (the f32 accumulator's
+#: exactness); 8 lanes is under CAND_PER_BATCH's stride
+SKETCH_CASES = [
+    ("invalid_mixed", 1024, _invalid_mixed),
+    ("full_capacity_one_key", 262144, _one_key_full),
+    ("negative_keys", 512, _negative_keys),
+    ("short_capacity", 8, _invalid_mixed),
+    ("filtered_uniform", 4096, _filtered_uniform),
+    ("none_valid", 256, _none_valid),
+]
+
+
+def _host_reference(batches, n_shards):
+    """What the host paths give on the same keys: the count-min rows
+    and ``total`` of ``ShardSketch.update_host``, the shard counts of
+    ``np.bincount`` over the splitmix placement."""
+    from windflow_tpu.monitoring import shard_ledger as sl
+    ref = sl.ShardSketch(n_shards)
+    counts = np.zeros(n_shards, np.int64)
+    for keys, valid in batches:
+        k = keys[valid].astype(np.int64)
+        ref.update_host(k)
+        d = (sl._splitmix64_np(k) % np.uint64(n_shards)).astype(np.intp)
+        counts += np.bincount(d, minlength=n_shards)
+    return ref.cms, counts, ref.total
+
+
+def _run_device_sketch(batches, n_shards, with_dest):
+    import jax
+    import jax.numpy as jnp
+    from windflow_tpu.monitoring import shard_ledger as sl
+    # as the sites do: one jitted program, the state donated
+    step = jax.jit(
+        lambda st, k, v, d: sl.device_sketch_update(
+            st, k, v, n_shards, dest=d if with_dest else None),
+        donate_argnums=(0,))
+    st = sl.device_sketch_init(n_shards)
+    for keys, valid in batches:
+        h = sl._splitmix64_np(keys.astype(np.int64))
+        dest = np.where(valid, (h % np.uint64(n_shards)).astype(np.int32),
+                        n_shards).astype(np.int32)
+        st = step(st, jnp.asarray(keys), jnp.asarray(valid),
+                  jnp.asarray(dest))
+    return st
+
+
+@pytest.mark.parametrize("with_dest", [False, True],
+                         ids=["dest_none", "dest_given"])
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("case", SKETCH_CASES,
+                         ids=[c[0] for c in SKETCH_CASES])
+def test_device_sketch_bit_identical_to_host(case, n_shards, with_dest):
+    from windflow_tpu.monitoring import shard_ledger as sl
+    _, cap, make = case
+    rng = np.random.default_rng(cap + n_shards)
+    batches = [make(rng, cap) for _ in range(3)]
+    st = _run_device_sketch(batches, n_shards, with_dest)
+    cms, counts, total = _host_reference(batches, n_shards)
+    assert np.array_equal(np.asarray(st["cms"]), cms)
+    assert np.array_equal(np.asarray(st["counts"]), counts)
+    assert int(st["total"]) == total == sum(int(v.sum()) for _, v in batches)
+    assert int(st["batches"]) == len(batches)
+    init = sl.device_sketch_init(n_shards)
+    assert {k: (v.shape, v.dtype) for k, v in st.items()} \
+        == {k: (v.shape, v.dtype) for k, v in init.items()}
+    # the candidate ring holds strided valid lanes of the last batches
+    keys, valid = batches[-1]
+    c = min(sl.CAND_PER_BATCH, cap)
+    lanes = slice(None, None, max(1, cap // c))
+    want = keys[lanes][:c][valid[lanes][:c]]
+    assert set(want.tolist()) <= set(np.asarray(st["cand"]).tolist())
+
+
+@pytest.mark.parametrize("n_bins", [1, 5, 64, 100, 2048])
+def test_device_hist32_is_bincount(n_bins):
+    import jax
+    import jax.numpy as jnp
+    from windflow_tpu.monitoring import shard_ledger as sl
+    rng = np.random.default_rng(n_bins)
+    # indices past the last bin (an invalid lane's ``dest``) are dropped
+    idx = rng.integers(0, n_bins + 3, 5000).astype(np.int32)
+    valid = rng.random(5000) < 0.8
+    got = jax.jit(lambda i, v: sl.device_hist32(i, v, n_bins))(
+        jnp.asarray(idx), jnp.asarray(valid))
+    assert got.dtype == np.int32 and got.shape == (n_bins,)
+    want = np.bincount(idx[valid & (idx < n_bins)], minlength=n_bins)
+    assert np.array_equal(np.asarray(got), want)
+
+
+def test_hist32_past_the_f32_exact_range_scatters_in_32_bits(monkeypatch):
+    """A lane count an f32 accumulator could not hold takes the int32
+    scatter-add, decided from the traced shape: same counts."""
+    import jax
+    import jax.numpy as jnp
+    from windflow_tpu.monitoring import shard_ledger as sl
+    monkeypatch.setattr(sl, "HIST_EXACT_LANES", 16)
+    rng = np.random.default_rng(3)
+    batches = [_invalid_mixed(rng, 64) for _ in range(2)]
+    st = _run_device_sketch(batches, 4, False)
+    cms, counts, total = _host_reference(batches, 4)
+    assert np.array_equal(np.asarray(st["cms"]), cms)
+    assert np.array_equal(np.asarray(st["counts"]), counts)
+    assert int(st["total"]) == total
+    jaxpr = jax.make_jaxpr(lambda i, v: sl.device_hist32(i, v, 2048))(
+        jnp.zeros(64, jnp.int32), jnp.zeros(64, bool))
+    assert _scatters(jaxpr.jaxpr) and not _wide_scatters(jaxpr.jaxpr)
+
+
+def _scatters(jaxpr):
+    """Every scatter equation of a jaxpr, nested jaxprs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if "scatter" in eqn.primitive.name:
+            out.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)     # a ClosedJaxpr
+                if hasattr(sub, "eqns"):
+                    out.extend(_scatters(sub))
+    return out
+
+
+def _wide_scatters(jaxpr):
+    """Scatters whose operand or update lane is 64 bits wide (a pair of
+    u32 scatters on a v5e: 18.5 ms each over 262144 lanes, PERF.md)."""
+    return [e for e in _scatters(jaxpr)
+            if any(np.dtype(e.invars[i].aval.dtype).itemsize >= 8
+                   for i in (0, 2))]
+
+
+def _state_spec(n_shards):
+    import jax
+    from windflow_tpu.monitoring import shard_ledger as sl
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        sl.device_sketch_init(n_shards))
+
+
+def _assert_sketched_program_is_narrow(fn, args, n_shards):
+    """``fn(*args)`` ends in the sketch state: no 64-bit scatter in the
+    program, and the state leaves with the layout it came in with."""
+    import jax
+    closed, out = jax.make_jaxpr(fn, return_shape=True)(*args)
+    assert not _wide_scatters(closed.jaxpr), _wide_scatters(closed.jaxpr)
+    assert "dot_general" in str(closed)      # the sketch is in there
+    spec = _state_spec(n_shards)
+    assert {k: (v.shape, v.dtype) for k, v in out[-1].items()} \
+        == {k: (v.shape, v.dtype) for k, v in spec.items()}
+
+
+def test_wide_scatter_detector_sees_a_64_bit_scatter():
+    """The structural tests below cannot pass vacuously: the parent's
+    form of one count-min row is found."""
+    import jax
+    import jax.numpy as jnp
+    old = jax.make_jaxpr(jax.jit(
+        lambda c, i, v: c.at[0, i].add(v.astype(jnp.int64))))(
+            jnp.zeros((4, 2048), jnp.int64), jnp.zeros(256, jnp.int32),
+            jnp.zeros(256, bool))
+    assert len(_wide_scatters(old.jaxpr)) == 1
+
+
+def test_sketched_chain_program_has_no_64_bit_scatter(tmp_path):
+    import jax
+    g = _chain_sketch_graph(_cfg(tmp_path, whole_chain_fusion=False),
+                            "chain_struct")
+    g.run()
+    execs = [e for op in g._operators
+             for e in (op._fusion_exec, getattr(op, "_chain", None))
+             if e is not None and e._sketch is not None]
+    assert len(execs) == 1 and execs[0]._sk_state is not None
+    S = jax.ShapeDtypeStruct
+    args = ({"key": S((CAP,), np.int32), "v": S((CAP,), np.float32)},
+            S((CAP,), np.bool_), _state_spec(execs[0]._sk_n))
+    _assert_sketched_program_is_narrow(execs[0]._jit._fn, args,
+                                       execs[0]._sk_n)
+
+
+def test_sketched_keyby_split_program_has_no_64_bit_scatter(tmp_path):
+    import jax
+    from windflow_tpu.parallel.emitters import DeviceKeyByEmitter
+    g = _dev_keyby_graph(_cfg(tmp_path), "dk_struct")
+    g.run()
+    ems = [rep.emitter for op in g._operators for rep in op.replicas
+           if isinstance(rep.emitter, DeviceKeyByEmitter)]
+    assert ems and all(em._sk_state is not None for em in ems)
+    em = ems[0]
+    S = jax.ShapeDtypeStruct
+    n = len(em.dests)
+    args = ({"key": S((CAP,), np.int32), "v": S((CAP,), np.float32)},
+            S((CAP,), np.int64), S((CAP,), np.bool_), S((CAP,), np.int32),
+            _state_spec(n))
+    _assert_sketched_program_is_narrow(em._get_split(CAP)._fn, args, n)
+
+
+BENCH_SIZES = dict(batch=1024, n_keys=32, win=64, slide=16, ring_batches=4,
+                   campaigns=10, ads_per_campaign=4, window_usec=100_000)
+
+
+def _bench_graph(config_name):
+    """A graph of ``benchmark/configs/`` at a tiny size, fed its own
+    ring once: (graph, ring)."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark import harness
+    mod = harness.load_module("configs", config_name)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           config_name + ".json")) as f:
+        cfg = harness.with_sizes(json.load(f), BENCH_SIZES)
+    ring = mod.make_ring(2**31 + 27, cfg)
+    rec = ring["rec"].copy()
+    rec["t"] = np.arange(len(rec)) * 10      # event time, monotone
+    buf = rec.tobytes()
+
+    def chunks():
+        for i in range(0, len(buf), 4096):
+            yield buf[i:i + 4096]
+
+    return mod.build_graph(cfg, ring, chunks, lambda c: None), ring
+
+
+def _sketch_sites(g):
+    return [getter for sk in g._shard._sketches.values()
+            for getter in sk._device_states]
+
+
+def test_mesh_cell_graph_sketches_every_batch_and_every_tuple():
+    """The four-chip cell's graph (``ffat_sum_mesh4`` on the CPU
+    device-count mesh): the chain program carries the sketch, it ran on
+    every dispatch and counted every tuple that passed the filter —
+    nothing sampled, no batch skipped."""
+    g, ring = _bench_graph("ffat_sum_mesh4")
+    g.run()
+    assert g.config.mesh is not None and len(_sketch_sites(g)) == 1
+    chain = [op for op in g._operators
+             if getattr(op, "_chain", None) is not None
+             and op._chain._sketch is not None]
+    assert len(chain) == 1
+    dispatches = chain[0]._chain._jit.dispatches
+    load = g.stats()["Shard"]["per_op"]["ffat"]["load"]
+    k = ring["rec"]["k"]
+    assert dispatches >= len(k) // BENCH_SIZES["batch"]
+    assert load["batches"] == dispatches
+    assert load["total_tuples"] == int(((k & 7) != 7).sum())
+
+
+@pytest.mark.parametrize("config_name", ["ffat_sum", "ysb"])
+def test_one_chip_bench_graphs_register_no_in_program_sketch(config_name):
+    """What "the one-chip cells bypass the mechanism" rests on: whole-
+    chain fusion folds their chains into the window step, and no
+    program of theirs carries ``device_sketch_update``."""
+    g, _ = _bench_graph(config_name)
+    g.run()
+    assert g.config.mesh is None and g._shard is not None
+    assert _sketch_sites(g) == []
 
 
 # ---------------------------------------------------------------------------

@@ -17,9 +17,12 @@ ledger → fusion advisor → fusion executor):
     state is threaded through the existing ``wf_jit`` programs (the
     keyby split, the fused chain's downstream key extraction) as one
     donated extra operand, so the update costs **zero extra
-    dispatches**; the accumulated device state is merged to host only
-    at monitor/stats cadence (the Julia-GPU-primitives stance: keep the
-    measurement on device, never pull keys to host per batch);
+    dispatches** and no 64-bit scatter (per-batch int32 histograms,
+    one matmul of one-hots each, widened into the int64 totals by a
+    dense add: ``device_hist32``); the accumulated device state is
+    merged to host only at monitor/stats cadence (the
+    Julia-GPU-primitives stance: keep the measurement on device, never
+    pull keys to host per batch);
   - *host-side numpy* at the keyed staging boundary, where
     ``native.keyby_partition`` already materializes the key lane and
     per-destination counts (the counts are free; the count-min rows are
@@ -121,29 +124,70 @@ def device_sketch_init(n_shards: int):
     }
 
 
+#: the histogram's bin index splits as ``hi * HIST_LO + lo``: the two
+#: one-hot operands of the fold below are [bins / HIST_LO, N] and
+#: [HIST_LO, N]
+HIST_LO = 64
+#: an f32 accumulator holds every integer up to 2^24, so a bin that
+#: takes a whole batch stays exact while the batch has no more lanes
+HIST_EXACT_LANES = 1 << 24
+
+
+def device_hist32(idx, valid, n_bins: int):
+    """Exact ``[n_bins]`` int32 histogram of the int32 lane ``idx`` over
+    the ``valid`` lanes, with no scatter: the bin index is factored
+    ``hi * HIST_LO + lo`` and the counts are ONE matmul of the two
+    one-hot operands, ``onehot(hi) & valid`` ``[n_bins / HIST_LO, N]``
+    against ``onehot(lo)`` ``[HIST_LO, N]``, contracted over the lanes.
+    The operands are 0/1 in bfloat16 (every product exact) and the
+    accumulator is f32 (exact to 2^24 per bin).  An index outside
+    ``[0, n_bins)`` matches no bin and is dropped.  A lane count the
+    accumulator could not hold exactly — read off the traced shape,
+    the one thing this adapts to — takes an int32 scatter-add."""
+    import jax
+    import jax.numpy as jnp
+    n = int(idx.shape[0])
+    if n > HIST_EXACT_LANES:
+        return jnp.zeros(n_bins, jnp.int32).at[idx].add(
+            valid.astype(jnp.int32), mode="drop")
+    lo_n = min(n_bins, HIST_LO)
+    hi_n = -(-n_bins // lo_n)
+    hi_hot = (((idx // lo_n)[None, :]
+               == jnp.arange(hi_n, dtype=jnp.int32)[:, None])
+              & valid[None, :]).astype(jnp.bfloat16)
+    lo_hot = ((idx % lo_n)[None, :]
+              == jnp.arange(lo_n, dtype=jnp.int32)[:, None]) \
+        .astype(jnp.bfloat16)
+    hist = jax.lax.dot_general(hi_hot, lo_hot, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    return hist.reshape(hi_n * lo_n)[:n_bins].astype(jnp.int32)
+
+
 def device_sketch_update(state, keys, valid, n_shards: int, dest=None):
     """The in-program sketch update, TRACED INTO the host program (the
-    keyby split / the fused chain step) — zero extra dispatches, a few
-    fused scatter-adds.  ``dest`` is the per-lane destination the keyby
-    split already computed (invalid lanes == ``n_shards``); ``None``
-    derives it from the same splitmix placement the emitters use."""
+    keyby split / the fused chain step) — zero extra dispatches and no
+    64-bit scatter: a batch's increments fit 32 bits, so each count-min
+    row and the shard counts are a per-batch int32 histogram
+    (:func:`device_hist32`) widened into the int64 totals by one dense
+    add.  Every valid lane of every batch is counted; the state is
+    bit-identical to what :meth:`ShardSketch.update_host` accumulates.
+    ``dest`` is the per-lane destination the keyby split already
+    computed (invalid lanes == ``n_shards``); ``None`` derives it from
+    the same splitmix placement the emitters use."""
     import jax
     import jax.numpy as jnp
     from windflow_tpu.parallel.emitters import _splitmix64_dev
     k32 = keys.astype(jnp.int32)
     h = _splitmix64_dev(k32)
-    vi = valid.astype(jnp.int64)
-    cms = state["cms"]
-    for i in range(SKETCH_DEPTH):
-        idx = ((h >> jnp.uint64(16 * i))
-               % jnp.uint64(SKETCH_WIDTH)).astype(jnp.int32)
-        cms = cms.at[i, idx].add(vi)
+    n_sh = max(1, n_shards)
+    rows = [device_hist32(((h >> jnp.uint64(16 * i))
+                           % jnp.uint64(SKETCH_WIDTH)).astype(jnp.int32),
+                          valid, SKETCH_WIDTH)
+            for i in range(SKETCH_DEPTH)]
     if dest is None:
-        dest = jnp.where(valid,
-                         (h % jnp.uint64(max(1, n_shards))).astype(jnp.int32),
-                         jnp.int32(n_shards))
-    counts = jnp.zeros(max(1, n_shards) + 1, jnp.int64) \
-        .at[dest].add(1, mode="drop")[:max(1, n_shards)]
+        dest = (h % jnp.uint64(n_sh)).astype(jnp.int32)
+    # an invalid lane's ``dest`` is ``n_shards``: out of range, dropped
+    counts = device_hist32(dest, valid, n_sh)
     cap = int(k32.shape[0])
     c = min(CAND_PER_BATCH, cap)
     stride = max(1, cap // c)
@@ -152,9 +196,11 @@ def device_sketch_update(state, keys, valid, n_shards: int, dest=None):
     slots = max(1, CAND_RING // c)
     start = (state["batches"] % jnp.int32(slots)) * jnp.int32(c)
     cand = jax.lax.dynamic_update_slice(state["cand"], cand_new, (start,))
-    return {"cms": cms, "counts": state["counts"] + counts, "cand": cand,
-            "batches": state["batches"] + 1, "total": state["total"]
-            + jnp.sum(vi)}
+    return {"cms": state["cms"] + jnp.stack(rows).astype(jnp.int64),
+            "counts": state["counts"] + counts.astype(jnp.int64),
+            "cand": cand, "batches": state["batches"] + 1,
+            "total": state["total"]
+            + jnp.sum(valid, dtype=jnp.int32).astype(jnp.int64)}
 
 
 # ---------------------------------------------------------------------------

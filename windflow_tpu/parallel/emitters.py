@@ -1519,8 +1519,9 @@ class DeviceKeyByEmitter(Emitter):
                 if sk is None:
                     return keys, masks
                 # shard plane: the key-skew sketch updates INSIDE this
-                # same program (a few fused scatter-adds on the donated
-                # state) — the dispatch count is unchanged
+                # same program (int32 histograms of the batch, added
+                # densely to the donated state) — the dispatch count is
+                # unchanged
                 return keys, masks, device_sketch_update(
                     sk, keys, valid, n, dest=dest)
 
