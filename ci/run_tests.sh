@@ -2,10 +2,9 @@
 # CI entry point: run the whole suite on the CPU backend (the conftest pins
 # JAX to CPU and forces an 8-device virtual mesh so every multi-chip
 # sharding path compiles and executes without TPU hardware), then the
-# multi-chip dry run and an explicit CPU-backend bench run (a plumbing
-# check: `python bench.py` without BENCH_PLATFORM=cpu exits non-zero when
-# JAX finds no TPU).  The chip itself is checked by `python chip_smoke.py`
-# on a machine that has one.
+# multi-chip dry run.  Nothing here measures: the chip is checked by
+# `python chip_smoke.py` and measured by `benchmark/run.py` on a machine
+# that has one (PERF.md, benchmark/README.md).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -15,7 +14,7 @@ cd "$(dirname "$0")/.."
 python tools/wf_lint.py
 
 # wfverify stage (object-level, imports jax + the graphs): every kernel
-# the repo ships — the bench e2e pipeline and one graph per chaos
+# the repo ships — the representative e2e pipeline and one graph per chaos
 # family — must verify clean under --strict (zero unsuppressed
 # trace-safety/recompile/donation/determinism findings) before the test
 # legs spend minutes.  The deliberately-violating determinism family
@@ -78,9 +77,9 @@ python tools/wf_ir.py --strict --drive 8192 \
 # fusion soak matrix is slow-marked for the nightly leg) fail
 # in seconds, before the full suite spends minutes.  The full-suite run
 # below repeats them — accepted: the gate's job is fast failure.  The
-# full suite deselects `slow` like the tier-1 gate does (same filter =
-# comparable pass counts, and the ~3min of slow-marked soak/two-process/
-# fuzz-tail tests stay inside the gate's timeout budget); run them
+# full suite is the tier-1 gate's own line (same filter, same six xdist
+# workers with one file to a worker = the same pass count; the ~3min of
+# slow-marked soak/two-process/fuzz-tail tests stay out of it); run them
 # explicitly with `pytest -m slow` on the nightly leg.
 python -m pytest tests/test_staging.py tests/test_observability.py \
     tests/test_analysis.py tests/test_device_metrics.py \
@@ -92,20 +91,8 @@ python -m pytest tests/test_staging.py tests/test_observability.py \
     tests/test_megastep.py tests/test_latency_plane.py \
     tests/test_ir_audit.py tests/test_tenant_plane.py \
     tests/test_calibration.py -q -m 'not slow'
-python -m pytest tests/ -q -m 'not slow'
+python -m pytest tests/ -q -m 'not slow' -p xdist -n 6 --dist loadfile
 python __graft_entry__.py 8
-# (pipefail above: a bench section that raises fails this line)
-BENCH_PLATFORM=cpu BENCH_E2E_TUPLES=131072 python bench.py | tee bench_ci_out.txt
-# the e2e decomposition keys (ratio_vs_kernel, staging_share_of_staged_run)
-# are the staging plane's evidence trail — fail if a bench refactor drops them
-python tools/check_bench_keys.py bench_ci_out.txt
-rm -f bench_ci_out.txt
-# run-over-run perf tripwire on the guarded bench_history.json scalars
-# (a local, git-ignored record: a fresh checkout has only the row the
-# bench leg above just appended, and nothing to compare it with):
-# >10% regression vs the previous same-methodology run fails under CI=1
-# (warns locally)
-CI="${CI:-1}" python tools/check_bench_regress.py
 # calibration gate: probe the CI backend, then verify the written store
 # is fresh + valid for THIS device kind (exit 1 = stale/corrupt/missing,
 # exit 2 = kill switch set — CI must never silently run uncalibrated
@@ -114,8 +101,6 @@ CI="${CI:-1}" python tools/check_bench_regress.py
 python tools/wf_calibrate.py --out /tmp/wf_ci_calibration.json
 python tools/wf_calibrate.py --check /tmp/wf_ci_calibration.json
 rm -f /tmp/wf_ci_calibration.json
-# host worker-pool smoke (reduced size; reports pool overhead on 1 core)
-BENCH_HOST_TUPLES=4000 BENCH_HOST_VEC=2048 BENCH_HOST_REPS=1 python bench_host.py
 # nightly leg (CI_NIGHTLY=1): the slow-marked tail — the RSS soaks, the
 # two-OS-process DCN validation, the 100k ordering-perf pair, the
 # heaviest fuzz seeds and spec-sweep cells, the grouping/sketch-overhead

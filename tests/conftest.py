@@ -1,6 +1,6 @@
 """Test configuration: run everything on a virtual 8-device CPU mesh so
 multi-chip sharding paths compile and execute without TPU hardware (the
-driver's dryrun does the same; real-chip benchmarking lives in bench.py)."""
+driver's dryrun does the same; measurement on the chip lives in benchmark/)."""
 
 import os
 

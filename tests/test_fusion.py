@@ -1,5 +1,5 @@
-"""Whole-chain fusion executor contracts (windflow_tpu/fusion,
-docs/PERF.md round 10): record-for-record equivalence of fused vs.
+"""Whole-chain fusion executor contracts (windflow_tpu/fusion):
+record-for-record equivalence of fused vs.
 unfused execution across the graph families (window tails CB/TB, keyed
 reduce, dense-key stateful, all-stateless, split/merge boundaries),
 the exact one-jitted-dispatch-per-batch accounting through the sweep

@@ -1,5 +1,5 @@
-"""Device-side key compaction (windflow_tpu/parallel/compaction.py,
-docs/PERF.md round 12): record-for-record A/B of the compacted dense
+"""Device-side key compaction (windflow_tpu/parallel/compaction.py):
+record-for-record A/B of the compacted dense
 fast path against the sorted arbitrary-key path and the declared-dense
 baseline across the reduce / stateful / FFAT-keyed families,
 overflow-to-sorted correctness under adversarial key streams (all-cold,

@@ -8,8 +8,8 @@ events, so their per-graph totals MUST telescope to the end-to-end
 histogram's sum exactly — at every megastep K, with and without
 map/filter fusion, with and without wire compression.  A decomposition
 that does not sum to the whole is attributing latency that never
-happened (or hiding latency that did), and the adaptive sizer
-(analysis/latency.py) would plan against fiction.
+happened (or hiding latency that did), and the SLO verdict would
+blame the wrong segment.
 """
 
 import dataclasses

@@ -1,5 +1,5 @@
-"""Megastep executor suite (windflow_tpu/megastep.py, docs/PERF.md
-round 15): fold K consecutive batch sweeps into ONE compiled scan
+"""Megastep executor suite (windflow_tpu/megastep.py):
+fold K consecutive batch sweeps into ONE compiled scan
 program on eligible staged edges.
 
 The contracts pinned here:

@@ -6,8 +6,6 @@ reduce via psum and via gather+fold, and FFAT window state sharded along the
 key axis, against host oracles."""
 
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -15,9 +13,6 @@ import numpy as np
 import pytest
 
 from windflow_tpu.parallel import mesh as M
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench  # noqa: E402  (repo-root module: the scaling harness under test)
 
 
 def _rand_batch(cap, K, seed=0):
@@ -183,26 +178,6 @@ def test_sharded_ffat_matches_single_chip():
                           np.asarray(out["value"])[f].tolist()))
 
     assert fired_set(rout, rfired) == fired_set(sout, sfired)
-
-def test_scaling_harness_loop_body():
-    """One width-2 rung of bench.py's weak-scaling harness (the per-n body
-    run_bench_scaling executes on real multi-chip hardware; refused on
-    virtual devices) must compose and reduce correctly — built via the
-    SHARED bench.scaling_step so this test and the harness cannot drift."""
-    K, per_chip = 64, 4096
-    fn, payload, valid, cap = bench.scaling_step(jax, n=2, K=K,
-                                                 per_chip=per_chip)
-    assert cap == 2 * per_chip
-    table, has = fn(payload, valid)
-    exp = np.zeros(K, np.float64)
-    np.add.at(exp, np.asarray(payload["k"]), np.asarray(payload["v"]))
-    np.testing.assert_allclose(np.asarray(table["v"]), exp, rtol=1e-5)
-    assert bool(np.asarray(has).all())
-
-
-def test_scaling_harness_refuses_virtual_mesh():
-    out = bench.run_bench_scaling(jax)
-    assert "skipped" in out and "virtual" in out["skipped"]
 
 
 def _drive_sharded_ffat_pair(comb, values, step_kwargs):

@@ -1,5 +1,5 @@
-"""Pallas TPU kernels for the FFAT hot loop (windflow_tpu/kernels,
-docs/PERF.md round 14): record-for-record A/B of the kernel-backed
+"""Pallas TPU kernels for the FFAT hot loop (windflow_tpu/kernels):
+record-for-record A/B of the kernel-backed
 programs against the ``WF_TPU_PALLAS=0`` lax path across the
 window_cb / window_tb / dense-reduce / compacted families (including
 TB ring regrow and CB EOS-flush edges), kernel-level bit-equality

@@ -866,10 +866,7 @@ def test_kill_switch_off_path_budget(tmp_path):
         f"disabled shard section costs {per_call * 1e6:.2f}us/call"
 
 
-@pytest.mark.slow  # ~15s: bench.py's shard.sketch_overhead_pct guards
-# the same <2% budget in every CI run (check_bench_keys hard-fails >2%),
-# so this on/off A/B rides the nightly leg (wfverify-round headroom
-# pass)
+@pytest.mark.slow  # ~15s: this on/off A/B rides the nightly leg
 def test_sketch_overhead_within_budget(tmp_path_factory):
     """Overhead smoke (documented budget <2%): ledger on vs off over
     the same seeded keyed pipeline.  CPU CI timing is noisy, so the

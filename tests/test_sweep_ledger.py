@@ -152,8 +152,7 @@ def test_per_hop_bytes_match_independent_cost(tmp_path, monkeypatch):
     assert measured > 0
     assert abs(hop["bytes_per_batch"] - measured) / measured < 0.10, \
         (hop, measured)
-    # the per-hop bytes sum to the totals the roofline decomposition
-    # reads (bench.py roofline.per_hop / attributed_fraction)
+    # the per-hop bytes sum to the section's totals
     total = sum(h["bytes_per_tuple"] for h in sweep["per_hop"].values()
                 if h.get("bytes_per_tuple") is not None)
     assert abs(sweep["totals"]["bytes_per_tuple"] - total) < 0.1
@@ -168,8 +167,8 @@ def test_per_hop_bytes_match_independent_cost(tmp_path, monkeypatch):
 def test_window_hop_bytes_match_kernel_measurement(tmp_path, monkeypatch):
     """Acceptance-shaped: on a bench-shaped pipeline the WINDOW hop's
     per-batch attributed bytes land within 10% of the raw FFAT kernel
-    step's measured bytes (the roofline.measured_bytes_per_step
-    methodology of bench.py, same shape, measured independently)."""
+    step's measured bytes (XLA's compiled cost analysis of the same
+    shape, taken independently)."""
     import math
 
     import jax
@@ -212,8 +211,7 @@ def test_window_hop_bytes_match_kernel_measurement(tmp_path, monkeypatch):
     assert abs(hop["bytes_per_batch"] - measured) / measured < 0.10, \
         (hop, measured)
     # the steady-state number excludes the EOS flush entirely: exact
-    # (same program, same cost table) — what bench.py's
-    # roofline.attributed_fraction compares against the kernel step
+    # (same program, same cost table)
     steady = hop["steady_bytes_per_tuple"] * CAP
     assert abs(steady - measured) / measured < 0.01, (steady, measured)
 
@@ -257,7 +255,7 @@ def test_ffat_state_donation_recorded(tmp_path):
 # ---------------------------------------------------------------------------
 
 def _bench_shape_graph():
-    """The bench.py staged-e2e pipeline shape (map + chained filter ->
+    """The staged end-to-end pipeline shape (map + chained filter ->
     keyed FFAT window -> sink) the advisor's golden plan targets."""
     src = (wf.Source_Builder(lambda: iter(()))
            .withOutputBatchSize(4096).withName("src")

@@ -123,9 +123,8 @@ def test_staged_fraction_reconciles(two_tenants):
     _, sec, _ = two_tenants
     att = sec["attributed"]
     assert att["staged_bytes_process_total"] > 0
-    # the CI floor (check_bench_keys): >= 90% of the process's staged
-    # device bytes must attribute to tenants; the seeded two-graph run
-    # attributes everything
+    # at least 90% of the process's staged device bytes must attribute
+    # to tenants; the seeded two-graph run attributes everything
     assert att["staged_fraction"] >= 0.9
     assert att["staged_bytes_tenants_total"] == \
         sum(t["h2d_bytes"] for t in sec["tenants"].values())
@@ -348,45 +347,7 @@ def test_advisor_golden_plan():
     kinds = [a["kind"] for a in by_tenant["warm"]["actions"]]
     assert kinds == ["rebalance_hot_tenant"]
     assert by_tenant["idle"]["actions"] == []
-    json.dumps(p)    # the PR-20 wire contract is JSON-clean
-
-
-# ---------------------------------------------------------------------------
-# PR-20 scheduler stub: the plan contract is consumed + validated
-# ---------------------------------------------------------------------------
-
-def test_tenant_scheduler_consumes_plan():
-    from windflow_tpu.serving import TenantScheduler
-    sched = TenantScheduler()
-    p = tenancy.plan(_synthetic_section())
-    assert sched.ingest(p) == 4     # 3 hog actions + 1 warm action
-    assert sched.plans_ingested == 1
-    pending = sched.pending()
-    assert [a["kind"] for a in pending] == [
-        "throttle_admission", "rescale_tenant", "drain_shards",
-        "rebalance_hot_tenant"]
-    assert pending[0]["tenant"] == "hog"
-    # the PR-20 seam: pops in order, records applied=False
-    first = sched.apply_next()
-    assert first["kind"] == "throttle_admission"
-    assert first["applied"] is False
-    assert len(sched.pending()) == 3
-    assert sched.section()["timeline"] == [first]
-
-
-def test_tenant_scheduler_rejects_contract_drift():
-    from windflow_tpu.serving import TenantScheduler
-    sched = TenantScheduler()
-    with pytest.raises(ValueError, match="tenancy/1"):
-        sched.ingest({"advisor": "tenancy/2", "tenants": []})
-    with pytest.raises(ValueError, match="unknown action kind"):
-        sched.ingest({"advisor": "tenancy/1", "tenants": [
-            {"tenant": "x", "actions": [{"kind": "evict_tenant"}]}]})
-    with pytest.raises(ValueError, match="missing required field"):
-        sched.ingest({"advisor": "tenancy/1", "tenants": [
-            {"tenant": "x",
-             "actions": [{"kind": "throttle_admission"}]}]})
-    assert sched.rejected_plans == 3 and not sched.pending()
+    json.dumps(p)    # the plan is JSON-clean
 
 
 # ---------------------------------------------------------------------------

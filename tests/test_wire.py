@@ -2,8 +2,7 @@
 in-prelude device decode, key-aligned mesh ingest, and the byte-
 accounting honesty split.
 
-Contracts pinned here (docs/OBSERVABILITY.md "Wire plane", docs/PERF.md
-round 13):
+Contracts pinned here (docs/OBSERVABILITY.md "Wire plane"):
 
 * every codec round-trips BIT-EXACTLY over adversarial lanes (constant,
   random, sorted-with-gaps, all-null, dtype extremes incl. int64

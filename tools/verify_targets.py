@@ -24,8 +24,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def bench_e2e():
-    """The representative bench pipeline shape (bench.py ``_e2e_graph``):
-    columnar source spec → MapTPU → chained FilterTPU → FFAT CB window →
+    """The representative pipeline shape (the `ffat_sum` graph of
+    ``benchmark/`` and ``chip_smoke.py``, small): columnar source spec → MapTPU → chained FilterTPU → FFAT CB window →
     columnar sink."""
     import numpy as np
 

@@ -10,7 +10,7 @@ and writes a versioned ``calibration.json`` keyed by device kind +
 jax version.  Point ``Config.calibration`` / ``WF_TPU_CALIBRATION``
 at the file and every read site flips from ``modeled`` to
 ``calibrated(<age>)`` provenance until the store goes stale
-(``WF_TPU_CALIBRATION_TTL_S``, default 7 days) or the device kind
+(``calibration.TTL_S``, 7 days) or the device kind
 changes (docs/OBSERVABILITY.md "Calibration plane").
 
 Probes (all seeded, a few seconds total):

@@ -2,14 +2,14 @@
 """wf_tenant: rank tenants by budget pressure and emit a scheduler plan.
 
 CLI face of the tenancy advisor (windflow_tpu/analysis/tenancy.py),
-mirroring ``tools/wf_slo.py``/``tools/wf_shard.py``: point it at a
+mirroring ``tools/wf_shard.py``: point it at a
 stats dump carrying a ``Tenant`` section (a ``dump_stats`` JSON, a
 postmortem ``stats.json`` / ``tenant.json``, or a bare section file)
 and get every tenant in the process ranked by HBM budget pressure,
 with the concrete ``throttle_admission``/``rescale_tenant``/
-``drain_shards``/``rebalance_hot_tenant`` actions the PR-20 tenant
-scheduler executes (``plan(...)`` is that executor's contract, exactly
-as ``wf_shard.plan`` was the reshard executor's).
+``drain_shards``/``rebalance_hot_tenant`` actions for an operator to
+take: no executor in the package consumes ``plan(...)`` (``wf_shard.plan``
+has one, the reshard executor).
 
 Usage::
 

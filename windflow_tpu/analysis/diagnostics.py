@@ -57,7 +57,7 @@ CODES = {
                        "axis"),
     "WF403": ("error", "merged upstream paths deliver unequal fixed "
                        "batch capacities"),
-    # key compaction (parallel/compaction.py, docs/PERF.md round 12):
+    # key compaction (parallel/compaction.py):
     # a declared-bounded reduce without a monoid runs the SORTED path —
     # declared dense beats both sorting and the compacted remap
     "WF404": ("warning", "bounded key space declared but no monoid "
@@ -99,7 +99,7 @@ CODES = {
     "WF606": ("warning", "wire compression downgraded to raw "
                          "passthrough: the staging edge has no "
                          "declared/inferred record spec"),
-    # Pallas kernels (windflow_tpu/kernels, docs/PERF.md round 14):
+    # Pallas kernels (windflow_tpu/kernels):
     # ``WF_TPU_PALLAS=1`` forces the hand-written FFAT kernels on, but
     # three downgrades are built in — a backend with no lowering
     # (neither TPU Mosaic nor the CPU interpreter) keeps the lax path,
@@ -113,8 +113,8 @@ CODES = {
                          "the lax path (unsupported backend, mesh "
                          "graph, or a generic combiner on the MXU "
                          "pane-combine path)"),
-    # Megastep executor (windflow_tpu/megastep.py, docs/PERF.md round
-    # 15): ``WF_TPU_MEGASTEP=K`` forces K staged sweeps folded into one
+    # Megastep executor (windflow_tpu/megastep.py):
+    # ``WF_TPU_MEGASTEP=K`` forces K staged sweeps folded into one
     # compiled scan program, but the fold only exists for a
     # single-dest device staging edge whose tail steps entirely on
     # device — a host operator, a mesh-sharded or host-interning

@@ -36,8 +36,7 @@ of the user kernels over the preflight record specs — into the
 preflight table, and ``tools/wf_ir.py --strict`` audits every shipped
 graph in CI.  Kill switch ``Config.ir_audit`` / ``WF_TPU_IR_AUDIT=0``
 leaves one flag check on the (already cold) first-compile path; capture
-rides the cost-analysis lowering, so ``WF_TPU_COST_ANALYSIS=off`` also
-disables it.  Suppression shares wfverify's inline syntax: a
+rides the cost-analysis lowering (``jit_registry.COST_MODE``).  Suppression shares wfverify's inline syntax: a
 ``# wfverify: ok (reason)`` on (or two lines above) the kernel's
 ``def`` line suppresses that operator's wfir findings, counted in the
 report like tracecheck's.
@@ -668,8 +667,8 @@ def audit_orphans(claimed) -> IRAuditReport:
 
 
 def process_report() -> IRAuditReport:
-    """Context-free audit of EVERY program captured in this process —
-    the bench's "shipped programs audit clean" stat (WF902-WF906 only;
+    """Context-free audit of EVERY program captured in this process
+    (WF902-WF906 only;
     WF901/WF907 need graph context the process store does not keep)."""
     t0 = time.perf_counter()
     report = IRAuditReport()

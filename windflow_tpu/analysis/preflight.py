@@ -226,8 +226,7 @@ def _pallas_pass(graph, ops, diags) -> None:
             "(shard_map step factories) keep the lax bodies this "
             "round, so no kernels build",
             hint="single-chip graphs take the kernels; kernels inside "
-                 "shard_map are a future round (docs/PERF.md round "
-                 "14)"))
+                 "shard_map are not built"))
         return
     mode = resolve_pallas(graph.config)
     if mode is None:
@@ -289,7 +288,7 @@ def _megastep_pass(graph, ops, edges, upstreams, diags) -> None:
             "per-shard aligned ingest with collectives every batch, so "
             "every edge keeps the per-batch (K=1) cadence",
             hint="single-chip graphs take the fold; scanning sharded "
-                 "programs is a future round (docs/PERF.md round 15)"))
+                 "programs is not built"))
         return
 
     down: Dict[int, list] = {}
@@ -319,8 +318,7 @@ def _megastep_pass(graph, ops, edges, upstreams, diags) -> None:
             hint="the downgrade is correctness-neutral (the per-batch "
                  "path is the reference semantics); unset "
                  "WF_TPU_MEGASTEP or restructure the edge to a "
-                 "single-destination device tail (docs/PERF.md round "
-                 "15)"))
+                 "single-destination device tail"))
 
     for src in roots:
         if getattr(src, "record_spec", None) is None and not (
@@ -947,8 +945,9 @@ def _compaction_pass(graph, ops, diags) -> None:
                 "WF404",
                 f"operator '{op.name}': withMaxKeys({op.max_keys}) "
                 "declares a bounded key space but no monoid combiner — "
-                "the reduce takes the sorted arbitrary-key path "
-                "(BENCH_r05: 3-42x slower than the dense table)",
+                "the reduce takes the sorted arbitrary-key path, an "
+                "argsort and a whole-record scan where the dense "
+                "table is one scatter-combine pass",
                 node=op.name,
                 hint="declare withMonoidCombiner/withSumCombiner for "
                      "the dense fast path; an undeclared key space "

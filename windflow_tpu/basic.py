@@ -143,10 +143,6 @@ class Config:
     # tests/debugging only; the overhead budget assumes the default).
     trace_sample_every: int = int(os.environ.get("WF_TPU_TRACE_SAMPLE",
                                                  "64"))
-    # Total span events retained across all replica rings (split evenly;
-    # old events are overwritten when a ring wraps — no allocation).
-    trace_ring_events: int = int(os.environ.get("WF_TPU_TRACE_RING",
-                                                "65536"))
     # Every M-th TRACED batch additionally records `device_done` by calling
     # block_until_ready on the operator's output — a real device sync, so
     # it runs 1 in (trace_sample_every * M) batches.  0 disables the sync
@@ -218,9 +214,6 @@ class Config:
     # is flagged as in a recompilation storm (BACKPRESSURED verdict).
     health_recompile_storm: int = int(os.environ.get(
         "WF_TPU_HEALTH_RECOMPILE_STORM", "4"))
-    # Health state-change timeline entries retained for the postmortem.
-    health_history: int = int(os.environ.get("WF_TPU_HEALTH_HISTORY",
-                                             "256"))
     # Black-box postmortem bundle directory written by
     # PipeGraph.dump_postmortem — best-effort on the wait_end crash path
     # and on watchdog-confirmed stalls ("" = "{log_dir}/{name}_postmortem";
@@ -249,9 +242,7 @@ class Config:
     # When set, the ledger evaluates the recent staged→sunk p99 against
     # the budget at watchdog cadence and the health plane raises an
     # SLO_VIOLATED verdict attributed to the dominant segment of the
-    # dominant operator; analysis/latency.py + tools/wf_slo.py turn the
-    # measured decomposition into the per-operator megastep/tick-chunk
-    # plan the adaptive sizer consumes.
+    # dominant operator.
     latency_slo_ms: float = float(os.environ.get("WF_TPU_LATENCY_SLO_MS",
                                                  "0"))
     # Tenant plane (monitoring/tenant_ledger.py, docs/OBSERVABILITY.md
@@ -278,8 +269,8 @@ class Config:
     # enters a latched OVER_BUDGET health verdict attributed to the
     # tenant's heaviest op (the SLO_VIOLATED contract applied to
     # memory), and analysis/tenancy.py + tools/wf_tenant.py turn the
-    # measured pressure into the drain/rescale/throttle plan the PR-20
-    # tenant scheduler consumes.
+    # measured pressure into a drain/rescale/throttle plan for a person
+    # to read: nothing in the package executes it.
     hbm_budget_bytes: int = int(os.environ.get(
         "WF_TPU_HBM_BUDGET_BYTES", "0"))
     # Sweep ledger (monitoring/sweep_ledger.py, docs/OBSERVABILITY.md):
@@ -305,11 +296,8 @@ class Config:
     # `is not None` check (micro-asserted by tests/test_shard_plane.py).
     shard_ledger: bool = bool(int(os.environ.get("WF_TPU_SHARD_LEDGER",
                                                  "1")))
-    # Hot keys retained per keyed edge in the shard ledger's top-K table
-    # (stats()["Shard"] hot_keys, the reshard advisor's move candidates).
-    shard_topk: int = int(os.environ.get("WF_TPU_SHARD_TOPK", "8"))
-    # Device-side key compaction (parallel/compaction.py, docs/PERF.md
-    # round 12): keyed consumers over UNDECLARED int32 key spaces get a
+    # Device-side key compaction (parallel/compaction.py):
+    # keyed consumers over UNDECLARED int32 key spaces get a
     # device-resident key→dense-slot remap table — hot keys run the
     # dense scatter-combine / dense-slot stateful path, the cold tail
     # falls back to the sorted lane inside the SAME program (zero extra
@@ -333,7 +321,7 @@ class Config:
     # pays, at this cadence.
     key_compaction_reseed: int = int(os.environ.get(
         "WF_TPU_KEY_COMPACTION_RESEED", "64"))
-    # Wire compression (windflow_tpu/wire.py, docs/PERF.md round 13 /
+    # Wire compression (windflow_tpu/wire.py,
     # docs/OBSERVABILITY.md "Wire plane"): staged batches' packed
     # buffers are re-encoded lane by lane (delta/delta-of-delta for
     # monotone ts/id lanes, dictionary for low-cardinality int lanes,
@@ -355,30 +343,29 @@ class Config:
     # (wire.WireEncoder).  Nothing attaches on the CPU fallback, where
     # host and "device" share memory and there is no link.
     # WF_TPU_WIRE=1 forces the codec anywhere, whatever the link (the
-    # bench wire leg and the A/B tests do), =0 is the kill switch: no
+    # A/B tests do), =0 is the kill switch: no
     # encoder attaches and each staged batch keeps one flag check.
     # Typed loosely: True/False/"auto"/"1"/"0" all work
     # (wire.wire_enabled resolves it).
     wire_compression: object = os.environ.get("WF_TPU_WIRE", "auto")
-    # Pallas TPU kernels for the FFAT hot loop (windflow_tpu/kernels,
-    # docs/PERF.md round 14): hand-written kernels for segmented
+    # Pallas TPU kernels for the FFAT hot loop (windflow_tpu/kernels):
+    # hand-written kernels for segmented
     # grouping, the pane-level sliding fold, and the dense segmented
     # reduce drop into the hottest regions of the SAME wf_jit programs
     # the lax compositions occupied — zero dispatch-count change,
     # record-for-record identical output.  Default "auto": compiled
     # Mosaic kernels on TPU backends, interpret=True on the CPU
     # fallback so tier-1 executes the real kernel bodies (the
-    # interpreter emulation is a correctness vehicle, not a perf path —
-    # bench's legacy sections pin =0 on CPU to keep their history
-    # comparable).  =1 forces (downgrades get a WF607 preflight
+    # interpreter emulation is a correctness vehicle, not a perf path).
+    # =1 forces (downgrades get a WF607 preflight
     # warning: non-TPU/CPU backends have no lowering, and windows with
     # GENERIC traced combiners keep the lax fold — only declared
     # sum/max/min monoids ride the MXU pane combine); =0 is the kill
     # switch restoring the lax path verbatim (no kernel builds, one
     # resolve per program build).
     pallas_kernels: object = os.environ.get("WF_TPU_PALLAS", "auto")
-    # Device-resident sweep megastep (windflow_tpu/megastep.py,
-    # docs/PERF.md round 15): fold K consecutive batch sweeps of a
+    # Device-resident sweep megastep (windflow_tpu/megastep.py):
+    # fold K consecutive batch sweeps of a
     # host→TPU staged edge into ONE wf_jit program — a lax.scan over a
     # super-batch of K packed wire buffers whose body is the existing
     # fused per-sweep program (unpack decode + prelude + tail step), so
@@ -390,7 +377,7 @@ class Config:
     # non-mesh, non-compacted); everything else keeps the per-batch
     # cadence.  Default "auto": K=8 on real accelerator backends, K=1
     # on the CPU fallback (tier-1 cadence unchanged).  An explicit
-    # integer forces that K anywhere (bench/tests set it directly);
+    # integer forces that K anywhere (tests set it directly);
     # graphs that cannot honor a forced K>1 downgrade to per-batch with
     # a WF608 preflight warning.  =1 is the kill switch: no plane
     # attaches and the per-batch path runs verbatim.  Durability epochs
@@ -419,11 +406,9 @@ class Config:
     # programs that lost their Mosaic lowering (WF907).  Findings land in
     # stats()["IR_audit"], the postmortem's ir_audit.json, and the
     # preflight table; =0 is the kill switch — no capture, no parsing,
-    # one flag check on the (already cold) first-compile path.  Capture
-    # rides the cost-analysis lowering, so WF_TPU_COST_ANALYSIS=off also
-    # disables it.
+    # one flag check on the (already cold) first-compile path.
     ir_audit: bool = bool(int(os.environ.get("WF_TPU_IR_AUDIT", "1")))
-    # Whole-chain fusion (windflow_tpu/fusion, docs/PERF.md round 10):
+    # Whole-chain fusion (windflow_tpu/fusion):
     # at graph build, maximal fusible runs of adjacent TPU operators
     # (the fusion advisor's plan — analysis/fusion.py) lower into ONE
     # wf_jit program per batch sweep: the stateless members' record
@@ -504,15 +489,15 @@ class Config:
     # set, the shard ledger's ICI model, the tenant ledger and the live
     # roofline compute from the calibrated
     # constants and their provenance tags flip `modeled` →
-    # `calibrated(<age>)`; stale past WF_TPU_CALIBRATION_TTL_S (default
-    # 7 days) or a device-kind mismatch degrades back to `modeled` with
+    # `calibrated(<age>)`; stale past calibration.TTL_S (7
+    # days) or a device-kind mismatch degrades back to `modeled` with
     # a one-time warning.  "" (default) runs uncalibrated;
     # WF_TPU_CALIBRATION=0 is the kill switch — no store loads anywhere
     # and every read site keeps one `is not None` check (micro-asserted
     # by tests/test_calibration.py).
     calibration: str = os.environ.get("WF_TPU_CALIBRATION", "")
-    # Live roofline plane (monitoring/calibration.RooflineLedger): the
-    # bench-only roofline decomposition as a monitor-cadence gauge —
+    # Live roofline plane (monitoring/calibration.RooflineLedger): a
+    # roofline decomposition as a monitor-cadence gauge —
     # per-hop achieved tup/s (deltas over counters the replicas already
     # keep; zero per-batch work) joined with the sweep ledger's
     # bytes/tuple and the calibrated bandwidth into stats()["Roofline"]

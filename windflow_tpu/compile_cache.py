@@ -1,5 +1,5 @@
 """Where JAX's persistent compilation cache lives — THE one place that
-decides (chip_smoke.py, bench.py and the tools/ profilers all call
+decides (chip_smoke.py, benchmark/ and tools/wf_calibrate.py all call
 :func:`setup_compile_cache`).
 
 The cache directory is part of every entry's key, so it must not move

@@ -6,7 +6,7 @@ package *executes* them — at ``PipeGraph._build`` each executable chain
 lowers into ONE ``wf_jit`` program per batch sweep, with the sweep
 ledger (monitoring/sweep_ledger.py) attributing the before/after
 dispatch and HBM-byte savings.  See ``fusion/executor.py`` for the
-mechanism and ``docs/PERF.md`` round 10 for the measured effect.
+mechanism.
 """
 
 from windflow_tpu.fusion.executor import (apply_fusion,

@@ -649,7 +649,7 @@ class Ffat_WindowsTPU_Builder(_WindowBuilderBase):
 
     def withCompactedKeys(self):
         """ARBITRARY int32 keys via device-side key compaction
-        (parallel/compaction.py, docs/PERF.md round 12): the graph build
+        (parallel/compaction.py): the graph build
         attaches a key→dense-slot remap table sized by
         ``Config.key_compaction_slots``, so the dense pane rings work
         without a declared key bound — new keys are admitted at the

@@ -18,7 +18,9 @@ that capability with a worker pool: each sweep, host replicas with pending
 input drain concurrently (one task per replica, so per-replica processing
 stays serial and keyed routing still pins a key to one replica); sources and
 TPU replicas stay on the driver thread.  GIL-releasing host work (numpy,
-native calls) then scales across cores; see ``bench_host.py``.
+native calls) then scales across cores; ``tests/test_host_pool.py``
+pins pooled results equal to single-thread ones, and that one slow
+replica does not starve its siblings.
 
 End of run mirrors ``PipeGraph::wait_end`` (``pipegraph.hpp:703-768``): EOS
 punctuations cascade, window state flushes, and per-operator stats JSON is
@@ -181,7 +183,7 @@ class PipeGraph:
         self._pool_replicas = []
         self._main_replicas = []
         # pre-flight analysis (windflow_tpu/analysis): last check()'s
-        # diagnostics + wall cost, surfaced through stats() and bench.py
+        # diagnostics + wall cost, surfaced through stats()
         self._preflight_diags = None
         self._preflight_ms = None
         # wfverify (analysis/tracecheck.py): the object-level verifier's
@@ -288,7 +290,7 @@ class PipeGraph:
                 self._source_replicas.extend(op.replicas)
         for rep in self._all_replicas:
             rep.config = self.config
-        if getattr(self.config, "preflight", "error") == "off":
+        if self.config.preflight == "off":
             # preflight reported capacity conflicts already (WF403: raised
             # under "error", warned under "warn" — the promised bypass);
             # only an "off" run needs the original hard build-time check
@@ -299,7 +301,7 @@ class PipeGraph:
         # emitter dispatch (create_emitter) and the op's sharded step
         # factory both read the stamp (parallel/mesh.mark_aligned_ingest)
         if self.config.mesh is not None \
-                and getattr(self.config, "key_aligned_ingest", True):
+                and self.config.key_aligned_ingest:
             from windflow_tpu.parallel.mesh import mark_aligned_ingest
             mark_aligned_ingest(self)
 
@@ -310,7 +312,7 @@ class PipeGraph:
         # type-checked as their constituent specs.  Skipped on a mesh:
         # the sharded program factories compose differently.
         from windflow_tpu.fusion import executor as _fusion
-        if getattr(self.config, "whole_chain_fusion", True) \
+        if self.config.whole_chain_fusion \
                 and self.config.mesh is None:
             self._fused_segments = _fusion.apply_fusion(self)
         fused_host = {}         # id(segment head/member) -> host op
@@ -438,7 +440,6 @@ class PipeGraph:
             from windflow_tpu.monitoring.recorder import FlightRecorder
             self._recorder = FlightRecorder(
                 sample_every=cfg.trace_sample_every,
-                ring_events=cfg.trace_ring_events,
                 device_sync_every=cfg.trace_device_sync_every,
                 expected_rings=len(self._all_replicas))
             for rep in self._all_replicas:
@@ -477,7 +478,7 @@ class PipeGraph:
         # emitters and folds the in-program updates into the keyby
         # split / fused-chain programs, all of which must exist and
         # none of which may have compiled yet)
-        if getattr(cfg, "shard_ledger", True):
+        if cfg.shard_ledger:
             from windflow_tpu.monitoring.shard_ledger import ShardLedger
             self._shard = ShardLedger(self)
 
@@ -487,7 +488,7 @@ class PipeGraph:
         # fusion (preludes installed, fused hosts known) and the shard
         # plane (sketches exist to seed from), before anything compiles.
         # Off attaches nothing: every step keeps one `is not None` check.
-        if getattr(cfg, "key_compaction", True):
+        if cfg.key_compaction:
             from windflow_tpu.parallel.compaction import attach_compaction
             attach_compaction(self)
 
@@ -523,13 +524,13 @@ class PipeGraph:
         # Window replicas get the ledger bound for the fire-freshness
         # gauge at their existing sampled-sync site; everything else
         # keeps `latency = None` (one check, micro-asserted).
-        if getattr(cfg, "latency_ledger", True) \
+        if cfg.latency_ledger \
                 and self._recorder is not None:
             from windflow_tpu.monitoring.latency_ledger import LatencyLedger
             from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
             self._latency = LatencyLedger(
                 self._recorder,
-                slo_ms=getattr(cfg, "latency_slo_ms", 0.0) or 0.0)
+                slo_ms=cfg.latency_slo_ms or 0.0)
             self._latency.megastep_plane = self._megastep_plane
             for op in self._operators:
                 if isinstance(op, FfatWindowsTPU):
@@ -546,18 +547,18 @@ class PipeGraph:
         # name; Config.hbm_budget_bytes > 0 arms the budget state
         # machine whose latched OVER_BUDGET verdict the health plane
         # paints on the tenant's heaviest op.
-        if getattr(cfg, "tenant_ledger", True):
+        if cfg.tenant_ledger:
             from windflow_tpu.monitoring.tenant_ledger import default_ledger
-            tenant = getattr(cfg, "tenant", "") or self.name
+            tenant = cfg.tenant or self.name
             self._tenant = default_ledger().register(
-                self, tenant, getattr(cfg, "hbm_budget_bytes", 0))
+                self, tenant, cfg.hbm_budget_bytes)
             if self._health is not None:
                 self._health.tenant = self._tenant
 
         # 3f'''''. calibration store + roofline plane (monitoring/
         # calibration.py): Config.calibration installs the probe-measured
         # constants process-wide (the shard ICI model, the tenant
-        # ledger, gap_diagnosis, and the roofline ceiling all read
+        # ledger and the roofline ceiling all read
         # through calibration.constant — their provenance tags flip
         # `modeled` → `calibrated(<age>)`), and the RooflineLedger turns
         # the replicas' existing throughput counters into the live
@@ -565,7 +566,7 @@ class PipeGraph:
         # the sweep/tenant planes (the bytes join reads the sweep
         # section) and before the reshard executor.
         from windflow_tpu.monitoring import calibration as _calib
-        if getattr(cfg, "calibration", "") and not _calib.killed():
+        if cfg.calibration and not _calib.killed():
             try:
                 _calib.set_default_store(_calib.load(cfg.calibration))
             except Exception as e:  # lint: broad-except-ok (a corrupt
@@ -575,7 +576,7 @@ class PipeGraph:
                 _w.warn(f"Config.calibration={cfg.calibration!r} failed "
                         f"to load ({e}) — running uncalibrated",
                         RuntimeWarning)
-        if getattr(cfg, "roofline_plane", True):
+        if cfg.roofline_plane:
             self._roofline = _calib.RooflineLedger(self)
             if self._health is not None:
                 self._health.roofline = self._roofline
@@ -587,7 +588,7 @@ class PipeGraph:
         # not executor targets (their reshard mechanism is the rescale
         # restore, docs/DURABILITY.md); replica-sharded keyed operators
         # are.
-        if getattr(cfg, "reshard_executor", False) \
+        if cfg.reshard_executor \
                 and self.config.mesh is None:
             from windflow_tpu.serving import ReshardExecutor
             self._reshard = ReshardExecutor(self)
@@ -737,7 +738,7 @@ class PipeGraph:
         return diags
 
     def _run_preflight(self) -> None:
-        mode = getattr(self.config, "preflight", "error")
+        mode = self.config.preflight
         if mode not in ("error", "warn", "off"):
             raise WindFlowError(
                 f"Config.preflight must be 'error', 'warn' or 'off', "
@@ -1385,7 +1386,7 @@ class PipeGraph:
             # pre-flight analysis (windflow_tpu/analysis): check() cost +
             # finding counts, so preflight stays visible in every dump
             "Preflight": {
-                "mode": getattr(self.config, "preflight", "error"),
+                "mode": self.config.preflight,
                 "check_ms": self._preflight_ms,
                 "diagnostics": (None if self._preflight_diags is None
                                 else [str(d) for d in
@@ -1394,14 +1395,12 @@ class PipeGraph:
             "Latency": self._latency_section(),
             # latency ledger (monitoring/latency_ledger.py): per-batch
             # critical-path segment decomposition, window freshness,
-            # and the SLO verdict — the measurement layer the adaptive
-            # sizer (analysis/latency.py, tools/wf_slo.py) plans against
+            # and the SLO verdict
             "Latency_plane": self._latency_plane_section(),
             # tenant plane (monitoring/tenant_ledger.py): per-tenant
             # HBM/ICI/dispatch attribution + budget verdicts across
-            # every PipeGraph in the process — the measurement layer
-            # the tenant advisor (analysis/tenancy.py, tools/
-            # wf_tenant.py) and PR 20's tenant scheduler plan against
+            # every PipeGraph in the process — what the tenant advisor
+            # (analysis/tenancy.py, tools/wf_tenant.py) plans against
             "Tenant": self._tenant_section(),
             # roofline plane (monitoring/calibration.RooflineLedger):
             # per-hop achieved tup/s vs the calibrated bandwidth
@@ -1570,7 +1569,7 @@ class PipeGraph:
         write("durability.json", self._durability_section)
         write("reshard.json", self._reshard_section)
         write("preflight.json", lambda: {
-            "mode": getattr(self.config, "preflight", "error"),
+            "mode": self.config.preflight,
             "check_ms": self._preflight_ms,
             "diagnostics": (None if self._preflight_diags is None
                             else [str(dg) for dg in self._preflight_diags]),

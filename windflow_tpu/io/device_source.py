@@ -6,17 +6,13 @@ source has a cheaper option the reference lacks: run the generator itself
 as an XLA program so the batch is BORN in HBM and the host link never
 carries the hot path.  Uses:
 
-* synthetic/benchmark feeds — the bench's ``e2e_device_source`` mode uses
-  this to measure pure framework dispatch overhead, decoupled from
-  host→device link bandwidth (VERDICT r4 item 3);
+* synthetic feeds: a run fed this way pays framework dispatch only,
+  decoupled from host→device link bandwidth;
 * replay of device-resident datasets (arrays already in HBM);
 * load generators for soak tests.
 
 Device-born batches never touch the wire plane (windflow_tpu/wire.py):
-there is no host→device transfer to compress, which is exactly why the
-bench's ``e2e_device_source`` leg anchors the staging-share
-decomposition the wire round's ``staging_share`` number is read
-against.  ``batch_fn`` still matters to the wire plane indirectly: the
+there is no host→device transfer to compress.  ``batch_fn`` still matters to the wire plane indirectly: the
 preflight spec walk infers this source's record spec from it
 (``analysis/preflight.propagate_specs``), so a DeviceSource feeding a
 host stage that later re-stages to a device edge keeps that edge

@@ -1,6 +1,7 @@
 """Pallas TPU kernels for the FFAT hot loop (ROADMAP item 3).
 
-Three kernels, chosen from the PROFILE_r05 component shares, each a
+Three kernels, one per region that dominated the fused step's profile
+(key grouping, pane fold, dense-table scatter), each a
 drop-in replacement for a lax composition INSIDE the same wf_jit
 program (zero dispatch-count change — the kernels are traced into the
 programs the jit registry already pins):
@@ -37,7 +38,7 @@ tier-1 exercises the real kernel bodies; ``"1"`` forces (downgrading
 with a WF607 preflight warning where no lowering exists); ``"0"`` is
 the kill switch — no kernel builds, the lax path verbatim.
 
-Float-sum caveat (the psum tolerance, docs/PERF.md round 14): the MXU
+Float-sum caveat (the psum tolerance): the MXU
 banded matmul accumulates f32 sums in contraction order where the lax
 fold uses a doubling tree — exact whenever the summands are integers
 below 2**24 (every record-for-record A/B family), reassociation-grade

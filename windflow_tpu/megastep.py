@@ -65,7 +65,12 @@ from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.monitoring.jit_registry import wf_jit
 
 #: default K on real accelerator backends ("auto"); the CPU fallback
-#: stays per-batch so the tier-1 suite exercises the verbatim cadence
+#: stays per-batch so the tier-1 suite exercises the verbatim cadence.
+#: Not 2: a group of two only breaks even, the K x L super-batch copy
+#: costing what the one saved dispatch gives, and the saving compounds
+#: from 4 up.  A group also puts a K x batch-span floor under latency
+#: (the first batch staged waits for the group to fill).  K=8 against
+#: K=1 has not been A/B'd on the chip (ROADMAP.md queue 1 item 8).
 AUTO_K = 8
 
 
@@ -73,7 +78,7 @@ def resolve_megastep(config) -> int:
     """Resolved megastep width K from ``Config.megastep_sweeps`` /
     ``WF_TPU_MEGASTEP``: "auto" → AUTO_K on tpu/gpu backends and 1 on
     the CPU fallback; an explicit integer forces that K anywhere
-    (including CPU — the bench's A/B lever); K <= 1 is the kill
+    (including CPU — the tests' A/B lever); K <= 1 is the kill
     switch."""
     raw = getattr(config, "megastep_sweeps", "auto")
     if raw is None:
@@ -188,7 +193,7 @@ class MegastepEdge:
         self._scan_wrapper = None
         self._scan_fmt = None
         self._scan = None
-        # counters (plane summary / bench / observability docs)
+        # counters (plane summary, docs/OBSERVABILITY.md)
         self.megasteps = 0
         self.batches = 0            # logical batches served by scans
         self.fallback_batches = 0   # per-batch ships while warm

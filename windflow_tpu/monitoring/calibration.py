@@ -2,9 +2,9 @@
 
 Three planes (wire, Pallas kernels, megastep) default auto-on for TPU
 backends, yet most numbers the repo holds for them are *models* — the
-structural ICI collective model (``WF_TPU_ICI_BYTES_PER_SEC``), the XLA
+structural ICI collective model (``shard_ledger.ICI_BYTES_PER_SEC``), the XLA
 cost-table bytes the sweep ledger attributes per hop — or an
-*interpret-mode* run.  Nothing in stats()/OpenMetrics/bench said which,
+*interpret-mode* run.  Nothing in stats()/OpenMetrics said which,
 so a stale model read exactly like ground truth (ROADMAP item 1).
 
 This module closes that gap in the PR 6/9/17/19 plane mold:
@@ -25,14 +25,14 @@ This module closes that gap in the PR 6/9/17/19 plane mold:
   returns ``(value, provenance)`` — the calibrated value while the
   store is fresh and matches the live device kind, the modeled default
   (with a one-time warning) once it goes stale past
-  ``WF_TPU_CALIBRATION_TTL_S`` or mismatches, and ``(None, None)``
+  ``TTL_S`` or mismatches, and ``(None, None)``
   where there is neither: a quantity nobody measured or published is
   not surfaced at all.  ``WF_TPU_CALIBRATION=0``
   is the kill switch: no store loads anywhere and every read site
   degrades to its modeled default in one check.
 
-* **Live roofline.**  :class:`RooflineLedger` promotes the bench-only
-  roofline decomposition to a monitor-cadence gauge: per-hop achieved
+* **Live roofline.**  :class:`RooflineLedger` is a roofline
+  decomposition as a monitor-cadence gauge: per-hop achieved
   tuples/sec (a delta over counters the replicas already keep — zero
   per-batch work) joined with the sweep ledger's bytes/tuple and the
   calibrated memory bandwidth into ``stats()["Roofline"]`` +
@@ -78,7 +78,7 @@ SCHEMA = "wf-calibration/1"
 #: calibration freshness TTL in seconds (default 7 days): past it the
 #: store degrades to the modeled defaults with a one-time warning —
 #: last week's link measurement must not masquerade as today's
-TTL_S = float(os.environ.get("WF_TPU_CALIBRATION_TTL_S", str(7 * 86400)))
+TTL_S = 7 * 86400.0
 
 #: published peak HBM bandwidth of one chip, keyed by the device kind
 #: JAX reports.  v5e: Google Cloud documentation, "TPU v5e" (819 GB/s).
@@ -134,8 +134,8 @@ def is_calibrated(tag: str) -> bool:
 
 
 def legal_provenance(tag) -> bool:
-    """True for any tag of the four-value vocabulary (the bench checker
-    and wf_doctor validate surfaced tags against this)."""
+    """True for any tag of the four-value vocabulary (the tests hold
+    every surfaced tag to it; wf_doctor validates with its own copy)."""
     return tag in (MEASURED, MODELED, INTERPRET) or is_calibrated(tag)
 
 
@@ -224,7 +224,7 @@ def load(path: str) -> CalibrationStore:
     return CalibrationStore(doc, path=path)
 
 
-# -- process-default store (the shard/tenant/bench read path) ---------------
+# -- process-default store (the shard/tenant read path) ---------------------
 
 _lock = threading.Lock()
 _store: Optional[CalibrationStore] = None
@@ -321,7 +321,7 @@ def constant(key: str, default: Optional[float] = None,
     kind; the modeled default + ``modeled`` otherwise (stale or
     kind-mismatched stores warn once and degrade — a dead measurement
     must never outrank a live model silently); ``(None, None)`` when
-    the key has no modeled default either.  Called at stats/bench
+    the key has no modeled default either.  Called at stats
     cadence only, never per batch."""
     if default is None:
         default = modeled_default(key)
@@ -383,7 +383,7 @@ def provenance_summary(now: Optional[float] = None) -> dict:
 
 #: throughput-collapse threshold: the dominant hop's current rate below
 #: this fraction of its own trailing baseline is a breach tick
-DEGRADE_RATIO = float(os.environ.get("WF_TPU_ROOFLINE_DEGRADE", "0.5"))
+DEGRADE_RATIO = 0.5
 
 
 class RooflineLedger:

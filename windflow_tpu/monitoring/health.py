@@ -78,6 +78,10 @@ _SEVERITY = {s: i for i, s in enumerate(STATES)}
 #: postmortem bundle schema tag (tools/wf_doctor.py validates against it)
 POSTMORTEM_SCHEMA = "wf-postmortem/1"
 
+#: state-change timeline entries retained for the postmortem (the
+#: reshard executor's timeline keeps as many)
+HISTORY = 256
+
 
 class _OpTrack:
     """Watchdog memory for one operator: the previous sample's counters
@@ -159,11 +163,11 @@ class HealthPlane:
         self._tracks: Dict[str, _OpTrack] = {
             op.name: _OpTrack(op.name, now) for op in graph._operators}
         #: state-change timeline: {"t_usec", "changes": {op: state}}
-        self.timeline: deque = deque(maxlen=max(8, int(cfg.health_history)))
+        self.timeline: deque = deque(maxlen=HISTORY)
         self.stall_events = 0
         self.last_stall: Optional[dict] = None
         self.samples_taken = 0
-        self.sample_usec_total = 0.0   # watchdog self-cost (bench overhead)
+        self.sample_usec_total = 0.0   # watchdog self-cost
         self._stall_bundle_written = False   # cadence auto-bundle: once
         #: thread id of a bundle write in progress (set by the graph's
         #: bundle writer around its locked write): an auto-bundle fired

@@ -28,19 +28,18 @@ process-scope stance as ``staging.default_pool``):
   tripwire).
 * **cost table** — on the first compile of an op name the watcher
   captures XLA cost analysis (FLOPs, bytes accessed) and, in ``compiled``
-  mode, the executable's memory footprint.  ``WF_TPU_COST_ANALYSIS``
-  picks the mode: ``lowered`` (default) uses the client-side
+  mode, the executable's memory footprint.  The module constant
+  ``COST_MODE`` is the mode: ``lowered`` (its value) uses the client-side
   ``Lowered.cost_analysis()`` estimate — a few ms, no second backend
   compile; ``compiled`` runs ``lowered.compile().cost_analysis()`` for
   optimized-HLO numbers plus ``memory_analysis()`` (one extra backend
-  compile per op name per process — bench.py opts in, the test gate's
-  tight wall budget keeps the default cheap); ``off`` disables capture.
+  compile per op name per process: the sweep-ledger tests that need
+  optimized-HLO bytes set it on the module); ``off`` disables capture.
 
 Steady-state cost per call (the hot path): one pytree flatten, one
 shape/dtype tuple, one set hash-compare — the ``@hot_path`` contract
 ``tools/wf_lint.py`` enforces on :meth:`WfJit._signature` /
-:meth:`WfJit.__call__`.  ``WF_TPU_JIT_WATCH=0`` removes even that:
-:func:`wf_jit` then returns the plain ``jax.jit`` callable.
+:meth:`WfJit.__call__`.
 
 ``PipeGraph.stats()["Device"]`` ships the registry snapshot (see
 monitoring/device_metrics.py); ``tools/wf_metrics.py`` and the dashboard
@@ -49,7 +48,6 @@ monitoring/device_metrics.py); ``tools/wf_metrics.py`` and the dashboard
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 import warnings
@@ -62,10 +60,7 @@ from windflow_tpu.monitoring import recorder as flightrec
 
 #: cost-analysis capture mode on an op name's first compile (see module
 #: docstring): "lowered" | "compiled" | "off"
-COST_MODE = os.environ.get("WF_TPU_COST_ANALYSIS", "lowered")
-#: kill switch: WF_TPU_JIT_WATCH=0 turns wf_jit into plain jax.jit
-WATCH_ENABLED = os.environ.get("WF_TPU_JIT_WATCH", "1").lower() \
-    not in ("0", "", "false", "off")
+COST_MODE = "lowered"
 
 
 def _leaf_sig(x):
@@ -185,7 +180,8 @@ class JitRegistry:
                 if e.compiles or e.recompiles}
 
     def totals(self) -> dict:
-        """Graph-agnostic aggregates (bench.py's ``device`` section)."""
+        """Graph-agnostic aggregates (``stats()["Device"]["jit_totals"]``
+        and the postmortem bundle's ``jit.json``)."""
         with self._lock:
             entries = tuple(self._entries.values())
         return {
@@ -256,8 +252,7 @@ class WfJit:
         # serializes the cold compile path only: replicas of one operator
         # share one wrapper and may first-call concurrently from the host
         # worker pool — without this, both would count a compile and the
-        # loser could mint a spurious same-signature "recompile" (which
-        # would trip check_bench_keys' recompile tripwire).  The hot path
+        # loser could mint a spurious same-signature "recompile".  The hot path
         # stays lock-free; a racy miss there lands here and re-checks.
         self._lock = threading.Lock()
 
@@ -413,7 +408,7 @@ class WfJit:
                     f"wf_jit('{self.op_name}'): lowering capture failed "
                     f"({type(capture_err).__name__}: {capture_err}) — "
                     "this program has no cost table and no IR-audit "
-                    "record (WF_TPU_COST_ANALYSIS="
+                    "record (jit_registry.COST_MODE="
                     f"{COST_MODE}); wfir reports it as pending, not "
                     "clean.  Warning shown once per op.",
                     RuntimeWarning, stacklevel=2)
@@ -429,7 +424,7 @@ class WfJit:
         with entry.lock:
             entry.cost_by_sig[sig] = cost
             if entry.cost is None and cost is not None:
-                # the entry-level table (snapshot/bench back-compat)
+                # the entry-level table (snapshot back-compat)
                 # stays first-come; per-program consumers read the
                 # signature-keyed table through their wrapper
                 entry.cost = cost
@@ -523,6 +518,4 @@ def wf_jit(fn: Optional[Callable] = None, *, op_name: str,
     decorator (``@wf_jit(op_name=...)``)."""
     if fn is None:
         return lambda f: wf_jit(f, op_name=op_name, **jit_kwargs)
-    if not WATCH_ENABLED:
-        return jax.jit(fn, **jit_kwargs)
     return WfJit(fn, op_name, jit_kwargs)

@@ -6,9 +6,7 @@ async enqueue, ``device_done`` on the sampled sync, ``collected`` at each
 inbox pull, ``sunk`` at the sink — but nothing decomposes those stamps:
 ``stats()["Latency"]`` reports the staged→sunk total and per-operator
 service times, so "p99 is 2 s" never says WHERE the 2 s went.  This
-module is the measurement plane ROADMAP item 3's adaptive sizer needs
-(the same ledger-then-executor sequence as PR 6→7 and PR 9→12): it
-harvests the existing span rings at monitor/stats cadence — **zero new
+module harvests the existing span rings at monitor/stats cadence — **zero new
 hot-path work** — and lands every completed trace in five per-operator
 segment histograms:
 
@@ -42,10 +40,7 @@ attributed to the dominant (operator, segment) pair of the same window
 consecutive in-budget evaluations.  The health plane surfaces the
 verdict (monitoring/health.py), OpenMetrics exports ``wf_slo_*`` /
 ``wf_latency_segment_*`` families, the postmortem bundle gains
-``latency.json`` (tools/wf_doctor.py renders it), and
-``analysis/latency.py`` / ``tools/wf_slo.py`` turn the decomposition
-into the per-operator megastep/tick-chunk plan contract the PR-18
-adaptive sizer implements.
+``latency.json`` (tools/wf_doctor.py renders it).
 
 Off (``Config.latency_ledger = False`` or no flight recorder) the plane
 is never built: every call site keeps one ``is not None`` check
@@ -366,8 +361,7 @@ class LatencyLedger:
     # -- export --------------------------------------------------------------
     def section(self) -> dict:
         """The ``stats()["Latency_plane"]`` payload — also the postmortem
-        ``latency.json`` body and the input contract of
-        ``analysis/latency.py`` / ``tools/wf_slo.py``."""
+        ``latency.json`` body."""
         graph_total = sum(self.segment_totals.values()) or 0.0
         per_op = {}
         for op_name, track in sorted(self.per_op.items()):

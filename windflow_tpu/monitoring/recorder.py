@@ -85,6 +85,10 @@ SUNK = 5        # batch reached a terminal (sink) replica
 STAGE_NAMES = ("staged", "emitted", "dispatched", "device_done",
                "collected", "sunk")
 
+#: span events retained across all replica rings of a graph (split evenly;
+#: old events are overwritten when a ring wraps, no allocation)
+RING_EVENTS = 65536
+
 
 class LatencyHistogram:
     """Log2-bucketed latency histogram (microseconds).
@@ -330,11 +334,10 @@ class FlightRecorder:
     ``Config.flight_recorder`` is on; replicas and emitters hold direct
     references to their ring (no indirection on the hot path)."""
 
-    def __init__(self, sample_every: int = 64, ring_events: int = 65536,
+    def __init__(self, sample_every: int = 64,
                  device_sync_every: int = 8,
                  expected_rings: int = 1) -> None:
         self.sample_every = max(1, int(sample_every))
-        self.ring_events = max(8, int(ring_events))
         self.device_sync_every = max(0, int(device_sync_every))
         self.expected_rings = max(1, int(expected_rings))
         self.rings: List[ReplicaRing] = []
@@ -396,10 +399,10 @@ class FlightRecorder:
 
     # -- ring registry -------------------------------------------------------
     def ring_for(self, op_name: str, replica_index: int) -> ReplicaRing:
-        # ring_events splits evenly over the graph's replicas (the builder
+        # RING_EVENTS splits evenly over the graph's replicas (the builder
         # passes the replica count), so total retained events stay bounded
         # regardless of graph width; the floor keeps narrow rings useful
-        per = max(64, self.ring_events // self.expected_rings)
+        per = max(64, RING_EVENTS // self.expected_rings)
         ring = ReplicaRing(op_name, replica_index, per)
         self.rings.append(ring)
         return ring

@@ -53,7 +53,6 @@ same stance as the health/sweep/durability planes).
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Dict, List, Optional
@@ -80,10 +79,13 @@ _CAND_POOL_LIMIT = 1024
 #: nominal per-chip ICI bandwidth for the collective TIME model
 #: (bytes/sec; ~90 GB/s per direction is the TPU-v4-class figure).  The
 #: model is structural — the CPU backend moves nothing over ICI — so
-#: the time is labeled with the assumption and overridable for other
-#: fabrics.
-ICI_BYTES_PER_SEC = float(os.environ.get("WF_TPU_ICI_BYTES_PER_SEC",
-                                         str(90e9)))
+#: the time is labeled with the assumption; a calibration store's
+#: probed rate replaces it (monitoring/calibration.py).
+ICI_BYTES_PER_SEC = 90e9
+
+#: hot keys retained per keyed edge in the top-K table
+#: (stats()["Shard"] hot_keys, the reshard advisor's move candidates)
+TOPK = 8
 
 
 def _splitmix64_np(k: np.ndarray) -> np.ndarray:
@@ -608,7 +610,7 @@ class ShardLedger:
 
     def __init__(self, graph) -> None:
         self._graph = graph
-        self.topk = max(1, int(getattr(graph.config, "shard_topk", 8)))
+        self.topk = TOPK
         #: id(consumer op) -> ShardSketch (one per keyed consumer; all
         #: edges feeding that consumer share it)
         self._sketches: Dict[int, ShardSketch] = {}
@@ -806,8 +808,8 @@ class ShardLedger:
             kind = "all_gather(data)"
         # the TIME half divides by the link bandwidth — a probe-measured
         # value while a fresh calibration store covers it (provenance
-        # `calibrated(<age>)`), the nominal WF_TPU_ICI_BYTES_PER_SEC
-        # default otherwise (`modeled`)
+        # `calibrated(<age>)`), the nominal ICI_BYTES_PER_SEC
+        # otherwise (`modeled`)
         from windflow_tpu.monitoring import calibration
         ici_bps, ici_prov = calibration.constant("ici_bytes_per_sec",
                                                  ICI_BYTES_PER_SEC)

@@ -1,8 +1,8 @@
 """Sweep ledger: per-operator-hop dispatch & HBM-traffic attribution.
 
-The roofline block in ``bench.py`` measures ~8x more HBM traffic per
-tuple than the declared record model, and the staged e2e rate sits well
-below the raw kernel — but until now nothing said *which hop* pays it.
+XLA's cost analysis counts several times more HBM traffic per tuple
+than the declared record model, and the staged end-to-end rate sits
+well below the raw kernel — this module says *which hop* pays it.
 Every operator hop in the PipeGraph sweep is its own jitted dispatch
 that round-trips HBM; whole-chain fusion (ROADMAP item 1) cannot be
 planned, sized, or verified without per-hop accounting.
@@ -38,7 +38,7 @@ columns, and the postmortem bundle's ``sweep.json``
 (``tools/wf_doctor.py`` renders it jax-free).  ``Config.sweep_ledger``
 off leaves one ``is not None`` check at each read site — the per-batch
 path is untouched either way (the dispatch counter belongs to the
-compile watcher and rides its ``WF_TPU_JIT_WATCH`` kill switch).
+compile watcher).
 """
 
 from __future__ import annotations

@@ -16,7 +16,7 @@ monitor/stats cadence, with ZERO per-batch hot-path work —
 - **compile wall-ms** from the process jit registry, diffed against a
   per-graph baseline snapshotted at register (per-NAME table, so two
   graphs sharing an op name split ambiguously — documented, and the
-  bench/tests use distinct names per tenant),
+  tests use distinct names per tenant),
 - **H2D/D2H wire + logical bytes** from the per-replica transfer
   counters (the same counters ``stats()["Bytes_H2D_total"]`` sums, so
   per-tenant attribution sums to the graph totals by construction),
@@ -40,8 +40,7 @@ Off, the graph never registers and every call site keeps exactly one
 
 The section feeds ``stats()["Tenant"]``, the ``wf_tenant_*``
 OpenMetrics families, postmortem ``tenant.json`` (wf_doctor renders it
-jax-free), ``analysis/tenancy.py`` and ``tools/wf_tenant.py`` — and is
-the plan contract PR 20's tenant scheduler executes.
+jax-free), ``analysis/tenancy.py`` and ``tools/wf_tenant.py``.
 """
 
 from __future__ import annotations
@@ -376,7 +375,7 @@ class TenantLedger:
 
     def reset(self) -> None:
         """Drop every registration and re-anchor the process baselines
-        (tests + bench legs: staged-byte totals are cumulative)."""
+        (tests: staged-byte totals are cumulative)."""
         with self._lock:
             self._graphs.clear()
             self._tracks.clear()

@@ -210,8 +210,8 @@ class Replica:
         self.stats.end_sample()
         if tr is not None and self.op.is_terminal:
             # staged→sunk span closes at sink RECEIPT (a deferred columnar
-            # sink converts later; its extra defer rides the bench's own
-            # delivery-latency measurement, not this histogram)
+            # sink converts later; its extra defer is in the benchmark's
+            # delivery latency, not in this histogram)
             now = current_time_usecs()
             self.ring.record(tr[0], flightrec.SUNK, now)
             self.stats.e2e_hist.add(now - tr[1])

@@ -1,9 +1,9 @@
 """Device-side key compaction: the dense fast path for arbitrary keys.
 
-BENCH_r05 put the declared-monoid dense reduce at 139.7M tup/s against
-3.3M for the sorted arbitrary-key path — a 3–42× gap only ``withMaxKeys``
-users could reach, because the dense scatter-combine tables need a
-bounded key space.  This module closes the gap for UNDECLARED int32 key
+The declared-monoid dense reduce is one scatter-combine pass where the
+sorted arbitrary-key path pays an argsort and a whole-record scan — a
+path only ``withMaxKeys`` users could reach, because the dense
+scatter-combine tables need a bounded key space.  This module closes the gap for UNDECLARED int32 key
 spaces with a **device-resident key→dense-slot remap table** (the
 Julia-GPU-primitives stance: keep fully generic operators on the
 specialized fast path via a runtime remap):

@@ -1169,7 +1169,7 @@ class AlignedMeshStageEmitter(Emitter):
     key)``-sharded batch owned by the key shard that owns its key, so
     the consumer's sharded program skips the data-axis ``all_gather``
     the ICI model names dominant (~232 modeled B/tuple vs ~17 B
-    payload, docs/PERF.md r11) — the consuming FFAT step compiles its
+    payload on the sharded FFAT graphs) — the consuming FFAT step compiles its
     ``ingest="aligned"`` variant (parallel/mesh.py) whose gather is the
     identity on a 1-wide data axis and a kk-times-smaller within-column
     gather otherwise.

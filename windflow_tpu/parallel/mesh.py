@@ -482,7 +482,7 @@ def _ffat_shard_layout(mesh: Mesh, capacity: int, K: int,
       ``key // K_local`` ownership ``key_base_fn`` rebases by).  The
       gather collapses to the within-column data-axis hop — identity on
       a 1-wide data axis — killing the all_gather that dominates the
-      modeled ICI bytes/tuple (docs/PERF.md r11): each key shard
+      modeled ICI bytes/tuple: each key shard
       processes only its own ``capacity/kk`` lanes."""
     kk = mesh.shape[KEY_AXIS]
     dd = mesh.shape[DATA_AXIS]

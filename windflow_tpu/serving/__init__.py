@@ -10,6 +10,5 @@ plan can help.  docs/OBSERVABILITY.md "Reshard executor".
 """
 
 from windflow_tpu.serving.executor import ReshardExecutor
-from windflow_tpu.serving.tenant_scheduler import TenantScheduler
 
-__all__ = ["ReshardExecutor", "TenantScheduler"]
+__all__ = ["ReshardExecutor"]

@@ -56,6 +56,7 @@ from collections import deque
 from typing import Dict, Optional
 
 from windflow_tpu.basic import current_time_usecs
+from windflow_tpu.monitoring.health import HISTORY
 
 #: per-operator executor states (stats()["Reshard"].ops[..].state)
 E_OK = "OK"
@@ -140,8 +141,7 @@ class ReshardExecutor:
         #: window ACCUMULATES across ticks until it is judgeable, so
         #: bursty per-shard flush cadences average out
         self._min_window = 256
-        self.timeline: deque = deque(maxlen=max(
-            8, int(getattr(cfg, "health_history", 64))))
+        self.timeline: deque = deque(maxlen=HISTORY)
 
     # -- sweep hook (the whole per-sweep cost) -------------------------------
     def on_sweep(self) -> None:

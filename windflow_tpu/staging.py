@@ -48,7 +48,6 @@ where a 4-byte lane contributes 1 word/row and an int64 lane 2 words/row
 from __future__ import annotations
 
 import itertools
-import os
 import threading
 import time
 from collections import deque
@@ -63,12 +62,11 @@ from windflow_tpu.monitoring import recorder as flightrec
 #: retained buffers per distinct buffer size (the recycling queue depth);
 #: 4 covers the driver loop's double buffering with margin for the keyed
 #: staging emitter's per-partition builders
-DEFAULT_DEPTH = int(os.environ.get("WF_TPU_STAGING_POOL_DEPTH", "4"))
+DEFAULT_DEPTH = 4
 #: global cap on bytes RETAINED by the pool (buffers out on loan are the
 #: caller's); beyond it releases drop their buffer (graceful degradation
 #: to plain allocation, never a deadlock)
-DEFAULT_MAX_BYTES = int(os.environ.get("WF_TPU_STAGING_POOL_BYTES",
-                                       str(256 << 20)))
+DEFAULT_MAX_BYTES = 256 << 20
 
 
 def lane_words(dt) -> int:

@@ -129,7 +129,9 @@ def _sliding_reduce(comb, flags, values, R: int, axis: int):
     build power-of-two window aggregates, then the binary decomposition of
     ``R`` stitches them — the log-depth trick of the reference's FlatFAT
     levels (``flatfat_gpu.hpp:60-139``) expressed as shifts instead of a
-    tree, so nothing larger than the pane sequence is ever materialized."""
+    tree, so nothing larger than the pane sequence is ever materialized.
+    A cumsum-difference fold (sums only) was tried and lost to this one at
+    the pane counts in use (R = 8): it stays out."""
     op = _flag_comb(comb)
     # pow2[j] aggregates windows of width 2^j ending at each position
     pow2 = [(flags, values)]
@@ -288,7 +290,7 @@ def make_ffat_step(capacity: int, K: int, P: int, R: int, D: int,
     None for the pure-lax program): the grouping/rank pass and the
     declared-monoid sliding fold trace their Pallas kernel bodies into
     this SAME program where the kernel gates hold — no extra dispatch,
-    record-for-record identical output (docs/PERF.md round 14)."""
+    record-for-record identical output (tests/test_pallas_kernels.py)."""
     monoid = resolve_monoid(sum_like, monoid)
     NP1 = capacity // P + 2           # pane cells incl. continuation cell
     # total fired across all keys: sum_k panes_k/D + per-key partials
