@@ -427,3 +427,315 @@ def test_single_chip_dense_no_drop_no_warning():
         st = op.dump_stats()
     assert st["Out_of_range_keys_dropped"] == 0
     assert "Out_of_range_keys_note" not in st
+
+
+# ---------------------------------------------------------------------------
+# dense placement (time-based step, declared monoid): one one-hot
+# contraction in place of the scatter-combine, bit for bit
+# ---------------------------------------------------------------------------
+
+from windflow_tpu.windows import ffat_kernels as fk  # noqa: E402
+
+TB = dict(B=512, K=6, P=1000, R=4, D=2, NP=32)
+_COMB = {"sum": lambda a, b: a + b, "max": jnp.maximum, "min": jnp.minimum}
+
+
+def _tb_stream(dtype, values, rng, n=5, **quirks):
+    """``n`` batches of (payload, ts, valid, wm_pane) for the TB kernel.
+    Event time advances ~40 panes a batch with jitter, so lanes land in
+    several panes of one batch; a tenth of the keys lie outside
+    ``[0, K)`` and a fifth of the lanes are invalid.  ``quirks``:
+    ``late`` stamps an eighth of the lanes far behind the ring's base,
+    ``dead_batch`` makes one batch invalid in every lane."""
+    B, K, P = TB["B"], TB["K"], TB["P"]
+    info = np.iinfo(dtype)
+    out = []
+    for i in range(n):
+        if values == "ones":
+            v = np.ones(B, dtype)
+        elif values == "mixed":
+            v = rng.integers(-1000, 1000, B).astype(dtype)
+        else:       # a quarter to half of the range each: cells wrap
+            v = (rng.integers(info.max // 4, info.max // 2, B)
+                 * rng.choice([1, 1, 1, -1], B)).astype(dtype)
+        ts = (i * 40 * P + rng.integers(0, 12 * P, B)).astype(np.int64)
+        valid = rng.random(B) > 0.2
+        if quirks.get("late") and i >= 2:
+            ts[::8] = rng.integers(0, 2 * P, len(ts[::8]))
+        if quirks.get("dead_batch") and i == 2:
+            valid[:] = False
+        keys = rng.integers(-1, K + 1, B).astype(np.int32)
+        out.append(({"k": jnp.asarray(keys), "v": jnp.asarray(v)},
+                    jnp.asarray(ts), jnp.asarray(valid),
+                    jnp.asarray(i * 40 - 4, jnp.int64)))
+    return out
+
+
+def _run_tb(monoid, stream, zero, D=TB["D"]):
+    """The whole record of a run: every output lane of every step, and
+    the final state."""
+    step = jax.jit(make_ffat_tb_step(
+        TB["B"], TB["K"], TB["P"], TB["R"], D, TB["NP"],
+        lambda x: x["v"], _COMB[monoid], lambda x: x["k"], monoid=monoid))
+    st = make_ffat_tb_state(zero, TB["K"], TB["NP"])
+    trail = []
+    for payload, ts, valid, wm in stream:
+        st, out, fired, out_ts, n_adv = step(st, payload, ts, valid, wm)
+        f = np.asarray(fired)
+        trail.append((np.asarray(out["key"])[f], np.asarray(out["wid"])[f],
+                      np.asarray(out["value"])[f], np.asarray(out_ts)[f],
+                      int(n_adv)))
+    return trail, jax.tree.map(np.asarray, st)
+
+
+def _assert_same_run(a, b):
+    (trail_a, st_a), (trail_b, st_b) = a, b
+    assert sum(len(t[0]) for t in trail_a) > 0
+    for x, y in zip(jax.tree.leaves(trail_a), jax.tree.leaves(trail_b)):
+        assert np.array_equal(x, y)
+    # cells of panes no tuple reached hold whatever the merge left there
+    for name in st_a:
+        x, y = st_a[name], st_b[name]
+        if name == "cells":
+            x, y = (np.where(s["cell_valid"], s["cells"], 0)
+                    for s in (st_a, st_b))
+        assert np.array_equal(x, y), name
+        assert x.dtype == y.dtype
+
+
+def _dense_and_scatter(monkeypatch, monoid, stream, zero, **kw):
+    dense = _run_tb(monoid, stream, zero, **kw)
+    monkeypatch.setattr(fk, "DENSE_PLACE_MAX_CELLS", 0)   # the parent's form
+    return dense, _run_tb(monoid, stream, zero, **kw)
+
+
+@pytest.mark.parametrize("values", ["ones", "mixed", "huge"])
+@pytest.mark.parametrize("dtype", [np.int64, np.int32])
+def test_tb_dense_placement_is_the_scatter_bit_for_bit(monkeypatch, dtype,
+                                                       values):
+    """An integer sum placed by the contraction == the scatter-add, in
+    the leaf's own width, wrap-around included."""
+    plan = fk.tb_placement("sum", [np.zeros((), dtype)], TB["K"], TB["NP"],
+                           TB["B"])
+    assert plan["placement"] == "dense" \
+        and plan["limbs"] == [np.dtype(dtype).itemsize]     # 8-bit limbs
+    stream = _tb_stream(dtype, values, np.random.default_rng(29))
+    dense, scatter = _dense_and_scatter(monkeypatch, "sum", stream,
+                                        jnp.zeros((), dtype))
+    _assert_same_run(dense, scatter)
+    assert dense[1]["cells"].dtype == dtype
+    if values == "huge":        # some cell's sum did leave the range
+        p, ts, ok, _ = (jax.tree.map(np.asarray, x) for x in stream[0])
+        cell = {}
+        for k, t, v in zip(p["k"][ok], ts[ok] // TB["P"], p["v"][ok]):
+            cell[(k, t)] = cell.get((k, t), 0) + int(v)
+        assert max(abs(c) for c in cell.values()) > np.iinfo(dtype).max
+
+
+@pytest.mark.parametrize("quirk,D", [("late", 2), ("dead_batch", 2),
+                                     ("plain", 6)])
+def test_tb_dense_placement_drops_what_the_scatter_drops(monkeypatch,
+                                                         quirk, D):
+    """Late lanes, an all-invalid batch, and the gap panes of hopping
+    windows (``D > R``: slide 6 panes, window 4) never reach a cell on
+    either form; ``n_late`` and the fired rows agree."""
+    stream = _tb_stream(np.int64, "mixed", np.random.default_rng(31),
+                        **{quirk: True})
+    dense, scatter = _dense_and_scatter(monkeypatch, "sum", stream,
+                                        jnp.zeros((), jnp.int64), D=D)
+    _assert_same_run(dense, scatter)
+    assert (dense[1]["n_late"] > 0) == (quirk == "late")
+
+
+@pytest.mark.parametrize("monoid,dtype", [("max", np.int64),
+                                          ("min", np.float32),
+                                          ("sum", np.float32)])
+def test_tb_count_by_contraction_leaves_the_values_alone(monkeypatch,
+                                                         monoid, dtype):
+    """``max`` / ``min`` and float sums keep their scatter-combine (same
+    values, same rounding order); only ``partial_has`` comes from the
+    contraction's count column."""
+    plan = fk.tb_placement(monoid, [np.zeros((), dtype)], TB["K"], TB["NP"],
+                           TB["B"])
+    assert plan == {"placement": "scatter", "limbs": [0], "count": True,
+                    "limb_bits": 8}
+    rng = np.random.default_rng(37)
+    stream = _tb_stream(np.int64, "mixed", rng)
+    if dtype is np.float32:
+        stream = [({"k": p["k"], "v": -1.0 - jnp.asarray(
+            rng.random(TB["B"], dtype=np.float32))}, *rest)
+            for p, *rest in stream]
+    dense, scatter = _dense_and_scatter(monkeypatch, monoid, stream,
+                                        jnp.zeros((), dtype))
+    _assert_same_run(dense, scatter)
+
+
+def ysb_step_shapes(K, NP, B, dtype, monoid="sum"):
+    """The TB step as YSB's graph builds it (a 10 s tumbling window, one
+    ``count_lane`` of ``dtype`` under a declared monoid) with the shapes
+    of its arguments: (step, state, batch)."""
+    S = jax.ShapeDtypeStruct
+    step = make_ffat_tb_step(B, K, 10_000_000, 1, 1, NP,
+                             lambda e: e["one"], _COMB[monoid],
+                             lambda e: e["campaign"], monoid=monoid)
+    state = jax.eval_shape(lambda: make_ffat_tb_state(
+        jnp.zeros((), dtype), K, NP))
+    return step, state, (
+        {"campaign": S((B,), np.int32), "one": S((B,), dtype)},
+        S((B,), np.int64), S((B,), np.bool_), S((), np.int64))
+
+
+def _placement_jaxpr(K, NP, B, dtype, monoid="sum"):
+    step, state, batch = ysb_step_shapes(K, NP, B, dtype, monoid)
+    return jax.make_jaxpr(step)(state, *batch).jaxpr
+
+
+def _lane_scatters(jaxpr, B):
+    """Scatters of the jaxpr whose indices or updates run over the
+    batch's ``B`` lanes: the placement's, nothing else in the step."""
+    from test_shard_plane import _scatters
+    return [e for e in _scatters(jaxpr)
+            if any(B in e.invars[i].aval.shape for i in (1, 2))]
+
+
+def test_a_grid_past_the_constant_keeps_the_scatter(monkeypatch):
+    """The decision is static and by size: a constant just under this
+    grid's cells leaves the count and the sum on the scatter, with the
+    same results."""
+    B, K, NP = TB["B"], TB["K"], TB["NP"]
+    stream = _tb_stream(np.int64, "huge", np.random.default_rng(41))
+    dense = _run_tb("sum", stream, jnp.zeros((), jnp.int64))
+    assert "dot_general" in str(_placement_jaxpr(K, NP, B, np.int64))
+    monkeypatch.setattr(fk, "DENSE_PLACE_MAX_CELLS", K * NP - 1)
+    plan = fk.tb_placement("sum", [np.zeros((), np.int64)], K, NP, B)
+    assert plan["placement"] == "scatter" and not plan["count"]
+    jaxpr = _placement_jaxpr(K, NP, B, np.int64)
+    assert "dot_general" not in str(jaxpr)
+    assert len(_lane_scatters(jaxpr, B)) == 2
+    _assert_same_run(dense, _run_tb("sum", stream, jnp.zeros((), jnp.int64)))
+
+
+def test_ysb_sized_step_places_with_a_contraction_and_no_scatter():
+    """The TB step as ``benchmark/configs/ysb.py`` builds it (100
+    campaigns, the auto-sized 65 panes, 262144 lanes, the int64 ``1`` of
+    ``count_lane`` under a declared sum): the placement is a
+    ``dot_general`` and no scatter runs over the batch's lanes, so the
+    18 ms int64 scatter-add (PERF.md section 6, PR 29) cannot come back
+    unseen.  A float leaf keeps its one scatter."""
+    from test_shard_plane import _wide_scatters
+    K, NP, B = 100, 65, 262144
+    plan = fk.tb_placement("sum", [np.zeros((), np.int64)], K, NP, B)
+    assert plan == {"placement": "dense", "limbs": [11], "count": True,
+                    "limb_bits": 6}
+    jaxpr = _placement_jaxpr(K, NP, B, np.int64)
+    assert "dot_general" in str(jaxpr)
+    assert _lane_scatters(jaxpr, B) == [] and _wide_scatters(jaxpr) == []
+    assert make_ffat_tb_state(jnp.zeros((), jnp.int64), K, NP)[
+        "cells"].dtype == jnp.int64         # count_lane is as wide as ever
+    flt = _placement_jaxpr(K, NP, B, np.float32)
+    assert "dot_general" in str(flt) and len(_lane_scatters(flt, B)) == 1
+    assert fk.tb_placement("sum", [np.zeros((), np.float32)], K, NP,
+                           B)["placement"] == "scatter"
+    # lanes the f32 accumulator could not count exactly: no contraction
+    assert not fk.tb_placement("sum", [np.zeros((), np.int64)], K, NP,
+                               (1 << 24) + 1)["count"]
+
+
+# -- the whole YSB graph of the benchmark, small ---------------------------
+
+YSB_SIZES = dict(batch=1024, ring_batches=4, campaigns=10,
+                 ads_per_campaign=4, window_usec=20_000)
+YSB_RATE = 100_000           # events per second of event time
+
+
+def _run_ysb(monkeypatch, n_total, **cfg_kw):
+    """``benchmark/configs/ysb.py``'s graph over ``n_total`` tuples of
+    its own ring, stamped as the benchmark's generator stamps them:
+    (graph, result columns, the reference's windows)."""
+    import dataclasses
+    import json
+    import os
+    import sys
+    repo = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark import harness
+    mod = harness.load_module("configs", "ysb")
+    with open(os.path.join(repo, "benchmark", "configs", "ysb.json")) as f:
+        cfg = harness.with_sizes(json.load(f), YSB_SIZES)
+    ring = mod.make_ring(2**31 + 29, cfg)
+    rec = np.tile(ring["rec"], -(-n_total // len(ring["rec"])))[:n_total]
+    rec["t"] = np.arange(n_total) * 1_000_000 // YSB_RATE
+    buf = rec.tobytes()
+
+    def chunks():
+        for i in range(0, len(buf), 4096):
+            yield buf[i:i + 4096]
+
+    cols = []
+    base = wf.Config()
+    monkeypatch.setattr(wf, "Config",
+                        lambda: dataclasses.replace(base, **cfg_kw))
+    g = mod.build_graph(cfg, ring, chunks,
+                        lambda c: cols.append(c.cols) if c is not None
+                        else None)
+    g.run()
+    got = {n: np.concatenate([np.asarray(c[n]) for c in cols])
+           for n in ("key", "wid", "value")}
+    exp = mod.expected(cfg, ring, n_total, {"event_rate": YSB_RATE})
+    return g, mod.compare(cfg, got, exp), got
+
+
+@pytest.mark.parametrize("path", ["per_batch", "megastep"])
+def test_ysb_graph_counts_match_the_reference_on_both_paths(monkeypatch,
+                                                            path):
+    """Every (campaign, window) count of the benchmark's YSB graph
+    against ``benchmark/reference.py``, with the window step on its own
+    and inside the K = 4 ``lax.scan`` of ``megastep.ffat_tb``; the
+    operator says which placement its program holds, in ``g.stats()``,
+    in the OpenMetrics export and on the ``wf.compile`` span."""
+    from test_layer_spans import _Annotation
+    from windflow_tpu.monitoring.openmetrics import (parse_exposition,
+                                                     render_openmetrics)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    _Annotation.made = []
+    kw = dict(megastep_sweeps=4, punctuation_interval_usec=10 ** 12,
+              wire_compression=False) if path == "megastep" else {}
+    g, checks, got = _run_ysb(monkeypatch, 24 * 1024, **kw)
+    assert all(c["ok"] for c in checks), checks
+    assert len(got["key"]) > 50 and got["value"].dtype == np.int64
+    scanned = sum(e["batches"] for e in g.stats()["Megastep"]["edges"])
+    assert (scanned >= 8) == (path == "megastep")
+    op = next(o for o in g.stats()["Operators"]
+              if o["Operator_name"] == "campaign_counts")
+    assert op["TB_placement"] == "dense" and op["TB_placement_limbs"] == 8
+    fams = parse_exposition(render_openmetrics(g.stats()))
+    assert {s[1]["placement"]: s[2] for s in
+            fams["wf_operator_tb_placement"]["samples"]} \
+        == {"dense": 1, "scatter": 0}
+    compiles = {a.counts["op"]: a.counts.get("placement")
+                for a in _Annotation.made if a.name == "wf.compile"}
+    steps = {k: v for k, v in compiles.items() if v is not None}
+    assert set(steps.values()) == {"dense"}
+    assert any(k.startswith("megastep.") for k in steps) \
+        == (path == "megastep")
+
+
+def test_float_window_reports_the_scatter_placement():
+    stream = [{"key": i % 3, "value": float(i), "ts": i * 1000}
+              for i in range(200)]
+    src = (wf.Source_Builder(lambda: iter(stream))
+           .withTimestampExtractor(lambda t: t["ts"])
+           .withOutputBatchSize(32).build())
+    op = (wf.Ffat_WindowsTPU_Builder(lambda t: t["value"],
+                                     lambda a, b: a + b)
+          .withKeyBy(lambda t: t["key"]).withMaxKeys(3)
+          .withTBWindows(16_000, 4_000).withSumCombiner().build())
+    assert "TB_placement" not in op.dump_stats()     # nothing built yet
+    g = wf.PipeGraph("ffat_tb_float", wf.ExecutionMode.DEFAULT,
+                     wf.TimePolicy.EVENT)
+    g.add_source(src).add(op).add_sink(
+        wf.Sink_Builder(lambda r: None).build())
+    g.run()
+    st = op.dump_stats()
+    assert st["TB_placement"] == "scatter" and st["TB_placement_limbs"] == 0

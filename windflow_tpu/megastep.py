@@ -354,8 +354,11 @@ class MegastepEdge:
         # None or a host-referenced drop scalar — nothing to donate
         donate = (0,) if kind in ("ffat_cb", "ffat_tb", "stateful") \
             else ()
-        return wf_jit(mega, op_name=f"megastep.{self.op.name}",
+        scan = wf_jit(mega, op_name=f"megastep.{self.op.name}",
                       donate_argnums=donate)
+        # the scan holds the tail's step: its compile span says the same
+        scan.compile_args = wrapper.compile_args
+        return scan
 
     def _carry_init(self):
         op, kind = self.op, self.kind

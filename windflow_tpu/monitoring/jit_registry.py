@@ -226,7 +226,7 @@ class WfJit:
     process-wide registry."""
 
     __slots__ = ("op_name", "_jit", "_fn", "_seen", "_last_sig", "_lock",
-                 "_entry", "_donate", "dispatches", "cost")
+                 "_entry", "_donate", "dispatches", "cost", "compile_args")
 
     def __init__(self, fn: Callable, op_name: str, jit_kwargs: dict) -> None:
         self.op_name = op_name
@@ -243,6 +243,11 @@ class WfJit:
         #: cost table of THIS wrapper's compiled program (bound from the
         #: entry's per-signature table at compile time — same reason)
         self.cost: Optional[dict] = None
+        #: what the owner knows of the program that is about to compile
+        #: (a time window's ``placement=``): a callable returning the
+        #: extra arguments of the ``wf.compile`` span, read on the cold
+        #: path alone
+        self.compile_args: Optional[Callable[[], dict]] = None
         # cached so the hot path's dispatch count is one attribute add —
         # no registry lookup per call; refreshed on every compile so a
         # registry reset() re-binds at the next compile
@@ -282,7 +287,9 @@ class WfJit:
     def _compile_call(self, sig, args, kwargs):
         # the whole cold path holds the calling thread: the wait for a
         # sibling's compile, the cost capture's lowering, trace + compile
-        with flightrec.span("wf.compile", op=self.op_name), self._lock:
+        extra = self.compile_args() if self.compile_args else {}
+        with flightrec.span("wf.compile", op=self.op_name, **extra), \
+                self._lock:
             return self._compile_call_locked(sig, args, kwargs)
 
     def _compile_call_locked(self, sig, args, kwargs):

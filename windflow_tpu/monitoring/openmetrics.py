@@ -174,8 +174,22 @@ def _families(stats: dict,
                 "windows)")
     f_prog = fam("wf_operator_device_programs_total", "counter",
                  "Compiled-program dispatches per operator replica")
+    f_place = fam("wf_operator_tb_placement", "gauge",
+                  "Placement of a declared-monoid time window's compiled "
+                  "step (enum gauge: 1 on the active form; dense = one "
+                  "one-hot contraction, scatter = a scatter-combine per "
+                  "lane)")
+    f_limbs = fam("wf_operator_tb_placement_limbs", "gauge",
+                  "Limb columns the dense placement contracts for the "
+                  "window's integer sums")
     for op in ops:
         name = op.get("Operator_name") or op.get("Name") or "?"
+        if op.get("TB_placement"):
+            for form in ("dense", "scatter"):
+                f_place.add(1 if op["TB_placement"] == form else 0,
+                            dict(base, operator=name, placement=form))
+            f_limbs.add(op.get("TB_placement_limbs", 0),
+                        dict(base, operator=name))
         for idx, r in enumerate(op.get("Replicas") or []):
             lab = dict(base, operator=name,
                        replica=str(r.get("Replica_id", idx)))

@@ -206,6 +206,121 @@ def _monoid_scatter(buf_at, kind: str):
     return getattr(buf_at, _MONOID_OPS[kind][0])
 
 
+#: Dense placement (time-based step, declared monoid): the batch's
+#: ``[K, NP]`` partial grid is ONE contraction of two one-hot operands
+#: over the lanes on the MXU instead of a scatter-add per lane.  The
+#: contraction's time grows with the grid (3.6 ns a cell and column at
+#: 262144 lanes on a v5e, over a floor of 0.4 ms), a scatter-add's with
+#: the lanes alone (1.9 ms in 32 bits), so a scatter is replaced while
+#: the ``K * NP`` cells times the columns that replace it stay under
+#: this: where the two forms met (PERF.md section 6, PR 29).
+DENSE_PLACE_MAX_CELLS = 1 << 19
+#: ... and a 64-bit scatter-add costs ten 32-bit ones there (18.5 ms),
+#: so its columns may cover ten times the grid
+_WIDE_SCATTER_COST = 10
+#: either one-hot operand may be materialized in HBM (bfloat16, one row
+#: a lane): together they stay under this
+_DENSE_MAX_OPERAND_BYTES = 1 << 30
+#: lanes whose 0/1 products the f32 accumulator still counts exactly
+_DENSE_EXACT_LANES = 1 << 24
+
+
+def _limb_bits(B: int) -> int:
+    """Widest limb, in bits, that dense placement sums exactly over
+    ``B`` lanes: a limb is exact in bfloat16 up to 8 bits, and a cell's
+    sum of limbs must stay under the f32 accumulator's 2^24
+    (``(2^b - 1) * B <= 2^24``).  0: no such limb, not even the count."""
+    if B > _DENSE_EXACT_LANES:
+        return 0
+    return min(8, (_DENSE_EXACT_LANES // B + 1).bit_length() - 1)
+
+
+def tb_placement(monoid: Optional[str], leaves, K: int, NP: int,
+                 B: int) -> dict:
+    """The static plan of the time-based step's placement, from what its
+    builder can observe: the monoid kind, each lift leaf's dtype and
+    per-lane shape (``leaves``: anything with ``.shape`` / ``.dtype`` of
+    ONE aggregate), and the sizes.  ``limbs[i]`` is the number of limb
+    columns leaf ``i`` rides the contraction with (0: it keeps the
+    scatter-combine: float sums keep their rounding order, ``max`` /
+    ``min`` have no limb form, and past :data:`DENSE_PLACE_MAX_CELLS`
+    the columns cost more than the scatter they replace); ``count`` says
+    whether ``partial_has`` comes from the contraction's count column;
+    ``placement`` is ``"dense"`` when no scatter is left."""
+    b = _limb_bits(B)
+    fits = monoid is not None and b > 0 \
+        and 2 * B * (K + NP) <= _DENSE_MAX_OPERAND_BYTES
+    count = fits and K * NP <= DENSE_PLACE_MAX_CELLS
+    limbs = []
+    for leaf in leaves:
+        dt = jnp.dtype(leaf.dtype)
+        n = 0
+        if count and monoid == "sum" and tuple(leaf.shape) == () \
+                and jnp.issubdtype(dt, jnp.integer):
+            n = -(-dt.itemsize * 8 // b)
+            if K * NP * n > DENSE_PLACE_MAX_CELLS * (
+                    _WIDE_SCATTER_COST if dt.itemsize == 8 else 1):
+                n = 0
+        limbs.append(n)
+    return {"placement": "dense" if count and all(limbs) else "scatter",
+            "limbs": limbs, "count": count, "limb_bits": b}
+
+
+def _to_limbs(leaf, n: int, b: int):
+    """``[B, n]`` bfloat16: the two's-complement bits of the integer
+    lane ``leaf`` cut into ``n`` limbs of ``b`` bits, lowest first."""
+    udt = jnp.dtype(f"uint{leaf.dtype.itemsize * 8}")
+    u = jax.lax.bitcast_convert_type(leaf, udt)
+    shifts = jnp.arange(n, dtype=udt) * b
+    return ((u[:, None] >> shifts[None, :]) & udt.type((1 << b) - 1)) \
+        .astype(jnp.int32).astype(jnp.bfloat16)
+
+
+def _from_limbs(part, b: int, dtype):
+    """Inverse of :func:`_to_limbs` over per-cell limb SUMS ``part``
+    ``[K, n, NP]`` (f32 holding integers under 2^24): each is shifted
+    back into place in the unsigned twin of ``dtype``, where the total
+    wraps exactly as a scatter-add in ``dtype`` does."""
+    udt = jnp.dtype(f"uint{jnp.dtype(dtype).itemsize * 8}")
+    shifts = jnp.arange(part.shape[1], dtype=udt) * b
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(part.astype(jnp.int32).astype(udt) << shifts[None, :, None],
+                axis=1, dtype=udt), dtype)
+
+
+def _dense_place(keys, cols, ok, K: int, NP: int, leaves, limbs, b: int):
+    """Per ``(key, col)`` cell of the ``[K, NP]`` grid: the int32 count
+    of ``ok`` lanes and, for every leaf with ``limbs[i] > 0``, the
+    wrap-around integer sum of its values, bit-identical to a
+    scatter-add (None for the others).  ``onehot(key) & ok`` ``[K, B]``
+    is contracted over the lanes with ``onehot(col)`` ``[B, NP]`` scaled
+    by each column of ``[B, 1 + sum(limbs)]``: the count's column of
+    ones, then every leaf's limbs.  All operands are small integers in
+    bfloat16 and every partial sum stays under 2^24 in the f32
+    accumulator (:func:`_limb_bits`), so nothing rounds."""
+    bf16 = jnp.bfloat16
+    key_hot = ((keys[None, :] == jnp.arange(K, dtype=jnp.int32)[:, None])
+               & ok[None, :]).astype(bf16)
+    pane_hot = (cols[:, None]
+                == jnp.arange(NP, dtype=jnp.int32)[None, :]).astype(bf16)
+    columns = jnp.concatenate(
+        [jnp.ones((keys.shape[0], 1), bf16)]
+        + [_to_limbs(a, n, b) for a, n in zip(leaves, limbs) if n], axis=1)
+    # the right operand stays 3-D: XLA:TPU lowers the column axis as a
+    # convolution window and never materializes [B, columns * NP]
+    # (flattened by hand it does, and compiles for minutes)
+    grid = jax.lax.dot_general(
+        key_hot, columns[:, :, None] * pane_hot[:, None, :],
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)            # [K, columns, NP]
+    sums, at = [], 1
+    for leaf, n in zip(leaves, limbs):
+        sums.append(_from_limbs(grid[:, at:at + n], b, leaf.dtype)
+                    if n else None)
+        at += n
+    return grid[:, 0].astype(jnp.int32), sums
+
+
 def _monoid_fill(kind: str, flags, values):
     """Replace invalid entries with the monoid identity, leafwise."""
     return jax.tree.map(
@@ -670,7 +785,10 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
     arithmetic, so lifts scatter-COMBINE (add/max/min) into the ring and
     the whole sort/segmented-scan machinery disappears (for "sum", float
     rounding order may differ from the sequential fold, the psum
-    tolerance; max/min are idempotent — identical either way).
+    tolerance; max/min are idempotent — identical either way).  On a
+    grid small beside the batch (:func:`tb_placement`, static per built
+    step) even the scatter goes: the cell counts, and integer sums bit
+    for bit, come out of one one-hot contraction on the MXU.
     """
     monoid = resolve_monoid(sum_like, monoid)
     MW = NP // D + 2
@@ -820,10 +938,25 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
                                leaf.dtype)
                 return _monoid_scatter(buf.at[row_u, col_u], monoid)(
                     jnp.where(_b(ok, leaf), leaf, ident))[:K]
-            partial = jax.tree.map(scat, jax.vmap(lift)(payload))
-            partial_has = (jnp.zeros((K + 1, NP), jnp.int32)
-                           .at[row_u, col_u].add(ok.astype(jnp.int32))[:K]
-                           > 0)
+            lifted, tree = jax.tree.flatten(jax.vmap(lift)(payload))
+            # a grid this small is placed by one one-hot contraction on
+            # the MXU, exact in the leaf's own width, where a 64-bit
+            # scatter-add over the lanes costs ~18 ms (tb_placement)
+            plan = tb_placement(
+                monoid, [jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
+                         for a in lifted], K, NP, B)
+            if plan["count"]:
+                n_cell, sums = _dense_place(keys, rel_c, ok, K, NP, lifted,
+                                            plan["limbs"], plan["limb_bits"])
+                partial_has = n_cell > 0
+            else:
+                sums = [None] * len(lifted)
+                partial_has = (jnp.zeros((K + 1, NP), jnp.int32)
+                               .at[row_u, col_u]
+                               .add(ok.astype(jnp.int32))[:K] > 0)
+            partial = jax.tree.unflatten(
+                tree, [scat(a) if s is None else s
+                       for a, s in zip(lifted, sums)])
             mop = _MONOID_OPS[monoid][1]
 
             def merge_m(old_leaf, new_leaf):

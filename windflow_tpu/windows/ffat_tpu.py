@@ -51,7 +51,8 @@ from windflow_tpu.windows.ffat_kernels import (agg_spec_for,
                                                make_ffat_step,
                                                make_ffat_tb_state,
                                                make_ffat_tb_step,
-                                               resolve_monoid)
+                                               resolve_monoid,
+                                               tb_placement)
 
 
 class FfatTPUReplica(_TPUReplica):
@@ -245,6 +246,42 @@ class FfatWindowsTPU(Operator):
         return make_ffat_state(agg_spec, self.max_keys, self.R)
 
     # -- per-batch program ---------------------------------------------------
+    def _ingest(self) -> str:
+        """Staged-batch layout the mesh step consumes (mesh.py
+        ``_ffat_shard_layout``)."""
+        return getattr(self, "_ingest_mode", None) \
+            or ("flat" if jax.process_count() > 1 else "data")
+
+    def _tb_plan(self) -> Optional[dict]:
+        """The static placement plan of the built time-based step
+        (``ffat_kernels.tb_placement``, on the sizes the kernel itself
+        sees: a key shard's on a mesh); None where there is none —
+        count-based, an undeclared combiner, or no batch has sized the
+        state yet."""
+        if not self.is_tb or self.monoid is None or not self._states \
+                or self._capacity is None:
+            return None
+        K, B = self.max_keys, self._capacity
+        if self.mesh is not None:
+            from windflow_tpu.parallel.mesh import _ffat_shard_layout
+            K, *_, B = _ffat_shard_layout(self.mesh, B, K, self._ingest())
+        cells = jax.tree.leaves(next(iter(self._states.values()))["cells"])
+        return tb_placement(
+            self.monoid, [jax.ShapeDtypeStruct(c.shape[2:], c.dtype)
+                          for c in cells], K, self.NP, B)
+
+    def _placement_args(self) -> dict:
+        """``placement=`` of this step's ``wf.compile`` span."""
+        plan = self._tb_plan()
+        return {"placement": plan["placement"]} if plan else {}
+
+    def _with_placement(self, step):
+        """``step``, its ``wf.compile`` span saying which placement the
+        program holds (kept a call INSIDE ``_build_step``: tracecheck
+        reads the donation of ``_jit_step`` off that method's source)."""
+        step.compile_args = self._placement_args
+        return step
+
     def _build_step(self, capacity: int):
         if self.mesh is not None:
             # Multi-chip: key-sharded state, data-sharded batches riding an
@@ -262,16 +299,15 @@ class FfatWindowsTPU(Operator):
             # key-owner column, so the step skips the all_gather that
             # dominates the modeled ICI bytes (parallel/emitters.
             # AlignedMeshStageEmitter; docs/OBSERVABILITY.md wire plane).
-            ingest = getattr(self, "_ingest_mode", None) \
-                or ("flat" if jax.process_count() > 1 else "data")
+            ingest = self._ingest()
             if self.is_tb:
-                return make_sharded_ffat_tb_step(
+                return self._with_placement(make_sharded_ffat_tb_step(
                     self.mesh, capacity, self.max_keys, self.P, self.R,
                     self.D, self.NP, self.lift, self.comb,
                     self.key_extractor,
                     drop_tainted=self.overflow_policy == "drop",
                     grouping=self._grouping(), ingest=ingest,
-                    monoid=self.monoid, op_name=f"{self.name}.mesh")
+                    monoid=self.monoid, op_name=f"{self.name}.mesh"))
             return make_sharded_ffat_step(
                 self.mesh, capacity, self.max_keys, self.P, self.R, self.D,
                 self.lift, self.comb, self.key_extractor,
@@ -352,8 +388,9 @@ class FfatWindowsTPU(Operator):
         donate = (0,)
         if comp is not None:
             donate = (0, 7 if self.is_tb else 6)
-        return wf_jit(step, op_name=self._fused_name or self.name,
-                      donate_argnums=donate)
+        return self._with_placement(
+            wf_jit(step, op_name=self._fused_name or self.name,
+                   donate_argnums=donate))
 
     def _pallas_mode(self):
         """Resolved Pallas gate for this operator's compiled programs
@@ -922,6 +959,12 @@ class FfatWindowsTPU(Operator):
             st["Pane_cells_evicted"] = self._tb_counter("n_evicted")
             st["Windows_dropped_on_overflow"] = \
                 self._tb_counter("n_win_dropped")
+        plan = self._tb_plan()
+        if plan is not None:
+            # static per built step: "dense" = the batch is placed by one
+            # one-hot contraction and no scatter is left in the placement
+            st["TB_placement"] = plan["placement"]
+            st["TB_placement_limbs"] = sum(plan["limbs"])
         return st
 
     def _build_flush(self):
