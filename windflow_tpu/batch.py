@@ -530,7 +530,9 @@ def _egress_packable(batch: DeviceBatch):
     # numpy-leaf batches (the megastep drain's zero-copy per-batch
     # slices) must take the host fallback: device-packing them would
     # round-trip already-host-resident lanes through HBM
-    ok = all(getattr(l, "ndim", 0) == 1 and l.shape[0] == cap
+    # a lane may carry a small record a row (trailing dimensions: a
+    # window aggregate of several numbers); it rides flattened
+    ok = all(getattr(l, "ndim", 0) >= 1 and l.shape[0] == cap
              and (_packable_dtype(l.dtype) or l.dtype == jnp.bool_)
              and isinstance(l, jax.Array) and l.is_fully_addressable
              for l in leaves)
@@ -539,13 +541,15 @@ def _egress_packable(batch: DeviceBatch):
 
 def _egress_pack(batch: DeviceBatch, leaves, treedef, cap):
     """Device program producing the batch's single uint32 egress buffer."""
-    specs = tuple(str(np.dtype(l.dtype)) for l in leaves)
+    specs = tuple((str(np.dtype(l.dtype)), tuple(l.shape[1:]))
+                  for l in leaves)
     key = (treedef, specs, cap)
     pack = _EGRESS_PACK_CACHE.get(key)
     if pack is None:
         def to_words(l):
             # only 32-bit device bitcasts (see packing note above):
             # 64-bit lanes leave as arithmetic lo/hi uint32 pairs
+            l = l.reshape(-1)
             if l.dtype == jnp.bool_:
                 return [l.astype(jnp.uint32)]
             if np.dtype(l.dtype).itemsize == 8:
@@ -568,21 +572,25 @@ def _egress_pack(batch: DeviceBatch, leaves, treedef, cap):
 
 
 def _egress_unpack(raw, batch: DeviceBatch, treedef, specs, cap):
-    def take(off, dt):
+    def take(off, dt, trail=()):
         d = np.dtype(dt)
+        n = cap * math.prod(trail)
         if d == np.bool_:
-            return raw[off:off + cap].astype(np.bool_), off + cap
-        if d.itemsize == 8:
-            lo = raw[off:off + cap].astype(np.uint64)
-            hi = raw[off + cap:off + 2 * cap].astype(np.uint64)
-            return ((hi << np.uint64(32)) | lo).view(np.int64) \
-                .astype(d, copy=False), off + 2 * cap
-        return raw[off:off + cap].view(d), off + cap
+            col = raw[off:off + n].astype(np.bool_)
+        elif d.itemsize == 8:
+            lo = raw[off:off + n].astype(np.uint64)
+            hi = raw[off + n:off + 2 * n].astype(np.uint64)
+            col = ((hi << np.uint64(32)) | lo).view(np.int64) \
+                .astype(d, copy=False)
+            off += n
+        else:
+            col = raw[off:off + n].view(d)
+        return col.reshape((cap,) + trail), off + n
 
     off = 0
     cols_flat = []
-    for dt in specs:
-        col, off = take(off, dt)
+    for dt, trail in specs:
+        col, off = take(off, dt, trail)
         cols_flat.append(col)
     tss, off = take(off, "int64")
     valid = raw[off:off + cap].astype(np.bool_)

@@ -402,6 +402,8 @@ class PipeGraph:
         # un-fused sweeps too (sweep-ledger tripwire).
         from windflow_tpu.ops.chained import ChainedTPU
         upstreams = _fusion._upstream_edges(self)
+        from windflow_tpu.windows.ffat_tpu import number_window_stages
+        number_window_stages(self._operators, upstreams)
         for a, kx in key_forward.values():
             if a._fusion_exec is not None:
                 a._fusion_exec.set_downstream_key_extractor(kx)

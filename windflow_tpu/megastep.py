@@ -609,6 +609,13 @@ def attach_plane(config, source_replicas) -> MegastepPlane:
         if tail.emitter is None \
                 or not hasattr(tail.emitter, "emit_device_batch"):
             continue
+        # the scan's ONE drain copies the stacked outputs to the host,
+        # which is where a sink would pull them anyway; a tail that
+        # feeds another device operator (a second window stage) would
+        # pay that copy for nothing and ship every batch back up
+        if any(rep.op.is_tpu
+               for rep, _ch in getattr(tail.emitter, "dests", ())):
+            continue
         edge = MegastepEdge(plane.k, top, tail, em, kind)
         em._megastep = edge
         plane.edges.append(edge)

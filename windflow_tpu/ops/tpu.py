@@ -64,8 +64,12 @@ class _TPUReplica(Replica):
         if self.op.mesh is not None:
             # one dispatch drives a program on every chip of the mesh
             counts["mesh"] = self.op.mesh.size
-        with flightrec.span("wf.dispatch", **counts):
+        with flightrec.span("wf.dispatch", **counts) as sp:
             out = self._op_step(batch)
+            if out is not None and out.capacity != batch.capacity:
+                # a window step hands on a batch sized by what it can
+                # fire, not by what it was given
+                sp.note(out_cap=out.capacity)
         self.stats.device_programs_launched += 1
         if self.ring is not None and batch.trace is not None:
             # `dispatched` stamps the ASYNC enqueue (the host is already

@@ -705,6 +705,22 @@ def make_ffat_flush(K: int, P: int, R: int, D: int, comb: Callable,
     return flush
 
 
+def tb_out_capacity(capacity: int, K: int, R: int, D: int, NP: int) -> int:
+    """Lanes of the batch one time-based step hands on, from its static
+    shapes alone.  A step makes three fire passes over at most
+    ``NP // D + 2`` windows each, so the whole grid of what it could
+    fire is ``K * 3 * (NP // D + 2)`` lanes; but a tuple lies in at most
+    ``ceil(R / D)`` windows, so the rows a stream fires are at most
+    ``ceil(R / D)`` a tuple, and one window holds at most ``K``.  A
+    batch of ``K + capacity * ceil(R / D)`` lanes therefore always takes
+    one more whole window while fewer than a batch's worth of rows are
+    in it: the step keeps up with any stream it is fed and its output
+    does not grow with the ring.  The smaller of the two is used; where
+    that is the second, the fired rows are compacted on the device and
+    windows that do not fit wait for the next pass or step."""
+    return min(K * 3 * (NP // D + 2), K + capacity * (-(-R // D)))
+
+
 def make_ffat_tb_state(agg_spec, K: int, NP: int):
     """Dense pane-ring state for time-based FFAT: column ``i`` of ``cells``
     holds the aggregate of time pane ``base + i`` (pane = ts // P_usec) for
@@ -793,6 +809,19 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
     monoid = resolve_monoid(sum_like, monoid)
     MW = NP // D + 2
     N_PASSES = 3                     # A1, A2 (pre-place), B (post-place)
+    # the output batch: the whole [K, N_PASSES * MW] grid while that is
+    # the smaller form, else OC lanes with the fired rows compacted to
+    # the front, window by window (tb_out_capacity)
+    OC = tb_out_capacity(capacity, K, R, D, NP)
+    compact = OC < K * N_PASSES * MW
+    # a compacting pass looks at no more windows than its output could
+    # hold with every key in each (plus the one that overflows it), so
+    # its work follows the output, not the ring
+    MWP = min(MW, -(-OC // K) + 1) if compact else MW
+    # undeclared combiner: the grid's cells are looked up in the sorted
+    # batch (a bisection a cell) while that is less work than a scatter
+    # a lane
+    seek_cells = K * NP * (capacity.bit_length() + 1) <= capacity
 
     def roll_left(flags, values, k):
         # advance the ring by k panes (k is traced); vacated tail = invalid
@@ -804,7 +833,7 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
         return f, v
 
     def fire_pass(cells, cell_valid, base, win_next, frontier, max_seen,
-                  horizon):
+                  horizon, acc=None):
         """Fire windows ending <= frontier whose end pane is inside the
         ring; returns the rolled ring + firing outputs.  Firing is capped to
         in-ring ends: if the frontier outruns the ring, later windows wait
@@ -812,19 +841,26 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
         fired fold is exactly over its own panes.  It is also capped to
         windows starting at or before the newest data pane (``max_seen``):
         later windows can never emit, so advancing past them would let an
-        infinite-watermark flush loop run forever."""
-        j = jnp.arange(MW, dtype=jnp.int64)
+        infinite-watermark flush loop run forever.
+
+        Compacting (``acc``: the step's output so far): the pass fires
+        the longest prefix of those windows whose rows still fit behind
+        the ``acc["n"]`` rows already there, and appends them; the rest
+        wait like windows beyond the ring.  The first pass of a step
+        finds the batch empty and one window is at most ``K <= OC`` rows,
+        so a step with a window to fire always fires one."""
+        j = jnp.arange(MWP, dtype=jnp.int64)
         w = win_next + j
-        end_local = (w * D + R - 1 - base)                     # [MW]
+        end_local = (w * D + R - 1 - base)                     # [MWP]
         fire = ((w * D + R) <= frontier) & (end_local < NP) \
-            & (w * D <= max_seen)                              # [MW] prefix
+            & (w * D <= max_seen)                              # [MWP] prefix
         # end_local < 0 happens only when a capacity roll evicted the whole
         # window (overload); such windows must not fire with pane-0 data
         emitable = fire & (end_local >= 0)
         eidx = jnp.clip(end_local, 0, NP - 1).astype(jnp.int32)
-        n_fired = jnp.sum(fire.astype(jnp.int64))
+        n_ready = jnp.sum(fire.astype(jnp.int64))
 
-        def do_fold(_):
+        def fold():
             # the O(K*NP*log R) sliding fold + gathers, only when this pass
             # actually fires something (on an ordered stream the pre-place
             # passes usually fire nothing — the previous step's post-place
@@ -832,40 +868,91 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             sflag, swin = _sliding_reduce(comb, cell_valid, cells, R, axis=1)
 
             def pick_leaf(a):
-                idx = eidx.reshape(1, MW, *([1] * (a.ndim - 2)))
-                idx = jnp.broadcast_to(idx, (K, MW) + a.shape[2:])
+                idx = eidx.reshape(1, MWP, *([1] * (a.ndim - 2)))
+                idx = jnp.broadcast_to(idx, (K, MWP) + a.shape[2:])
                 return jnp.take_along_axis(a, idx, axis=1)
             wvals = jax.tree.map(pick_leaf, swin)
             any_data = jnp.take_along_axis(
-                sflag, jnp.broadcast_to(eidx[None, :], (K, MW)), axis=1)
+                sflag, jnp.broadcast_to(eidx[None, :], (K, MWP)), axis=1)
             # advance past fully-evicted windows (fire) but never emit them
             # (emitable): their eidx clips to pane 0, which they don't cover
-            f = emitable[None, :] & any_data
+            return emitable[None, :] & any_data, wvals
+
+        def untainted():
+            # the drop-window policy: (key, window) cells whose span lost
+            # no data to an eviction; the others are suppressed
+            return (w * D)[None, :] >= horizon[:, None]
+
+        def n_suppressed(f, clean, gone_w):
+            # counted per tainted key — including windows whose WHOLE
+            # span was evicted (``gone_w``: advanced past, not emitable),
+            # which can never emit but did lose that key's data
+            return jnp.sum((f & ~clean).astype(jnp.int64)) \
+                + jnp.sum((gone_w[None, :] & ~clean).astype(jnp.int64))
+
+        def do_fold(_):
+            f, wvals = fold()
             n_drop = jnp.zeros((), jnp.int64)
             if drop_tainted:
-                # suppress windows whose span lost data to an eviction;
-                # count them per tainted key — including windows whose
-                # WHOLE span was evicted (fire & ~emitable), which can
-                # never emit but did lose that key's data
-                clean = (w * D)[None, :] >= horizon[:, None]
-                gone = (fire & ~emitable)[None, :] & ~clean
-                n_drop = jnp.sum((f & ~clean).astype(jnp.int64)) \
-                    + jnp.sum(gone.astype(jnp.int64))
+                clean = untainted()
+                n_drop = n_suppressed(f, clean, fire & ~emitable)
                 f = f & clean
             return f, wvals, n_drop
 
         def no_fold(_):
             zvals = jax.tree.map(
-                lambda a: jnp.zeros((K, MW) + a.shape[2:], a.dtype), cells)
-            return jnp.zeros((K, MW), bool), zvals, jnp.zeros((), jnp.int64)
+                lambda a: jnp.zeros((K, MWP) + a.shape[2:], a.dtype), cells)
+            return jnp.zeros((K, MWP), bool), zvals, jnp.zeros((), jnp.int64)
 
-        fired, wvals, n_drop = jax.lax.cond(n_fired > 0, do_fold, no_fold,
-                                            None)
+        def do_fold_compact(acc):
+            f, wvals = fold()
+            clean = untainted() if drop_tainted else True
+            # the prefix of windows whose rows fit behind those in acc
+            rows = jnp.cumsum(jnp.sum(f & clean, axis=0, dtype=jnp.int32))
+            fits = fire & (acc["n"] + rows <= OC)
+            f = f & fits[None, :]
+            n_drop = jnp.zeros((), jnp.int64)
+            if drop_tainted:
+                n_drop = n_suppressed(f, clean, fits & ~emitable)
+                f = f & clean
+            # window-major, so a window's rows lie together and in order
+            ff = f.T.reshape(-1)                               # [MWP * K]
+            pos = acc["n"] + jnp.cumsum(ff.astype(jnp.int32)) - 1
+            # ONE 32-bit scatter: which cell each output lane takes; the
+            # lanes then gather their values
+            src = jnp.full((OC,), -1, jnp.int32) \
+                .at[jnp.where(ff, pos, OC)] \
+                .set(jnp.arange(MWP * K, dtype=jnp.int32), mode="drop")
+            hit = src >= 0
+            at = jnp.maximum(src, 0)
+
+            def put(old, a):
+                flat = jnp.swapaxes(a, 0, 1).reshape((MWP * K,) + a.shape[2:])
+                return jnp.where(_b(hit, old), flat[at], old)
+            return {
+                "key": jnp.where(hit, at % K, acc["key"]),
+                "wid": jnp.where(hit, w[at // K], acc["wid"]),
+                "value": jax.tree.map(put, acc["value"], wvals),
+                "fired": acc["fired"] | hit,
+                "n": acc["n"] + jnp.sum(ff, dtype=jnp.int32),
+            }, jnp.sum(fits.astype(jnp.int64)), n_drop
+
+        def no_fold_compact(acc):
+            return acc, jnp.zeros((), jnp.int64), jnp.zeros((), jnp.int64)
+
+        if compact:
+            acc, n_fired, n_drop = jax.lax.cond(
+                n_ready > 0, do_fold_compact, no_fold_compact, acc)
+            fired = wvals = None
+        else:
+            n_fired = n_ready
+            fired, wvals, n_drop = jax.lax.cond(n_ready > 0, do_fold,
+                                                no_fold, None)
         new_next = win_next + n_fired
         shift = jnp.clip(new_next * D - base, 0, NP)
         cell_valid, cells = roll_left(cell_valid, cells, shift)
         return (cells, cell_valid, base + shift, new_next,
-                fired, wvals, w, n_fired, n_drop)
+                fired, wvals, w, n_fired, n_drop, acc)
 
     def step(state, payload, ts, valid, wm_pane):
         B = capacity
@@ -892,11 +979,22 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             state["win_next"])
         a_outs = []
         n_win_dropped = state["n_win_dropped"]
+        acc = None
+        if compact:
+            acc = {
+                "key": jnp.zeros((OC,), jnp.int32),
+                "wid": jnp.zeros((OC,), jnp.int64),
+                "value": jax.tree.map(
+                    lambda a: jnp.zeros((OC,) + a.shape[2:], a.dtype),
+                    state["cells"]),
+                "fired": jnp.zeros((OC,), bool),
+                "n": jnp.zeros((), jnp.int32),
+            }
         for _ in range(2):
             (cells, cell_valid, base, win_next,
-             fired_i, wvals_i, w_i, n_i, nd_i) = fire_pass(
+             fired_i, wvals_i, w_i, n_i, nd_i, acc) = fire_pass(
                 cells, cell_valid, base, win_next, frontier_a,
-                state["max_seen"], state["horizon"])
+                state["max_seen"], state["horizon"], acc)
             a_outs.append((fired_i, wvals_i, w_i, n_i))
             n_win_dropped = n_win_dropped + nd_i
 
@@ -968,47 +1066,73 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
                 return mop(new_leaf, old)
             cells = jax.tree.map(merge_m, cells, partial)
         else:
-            sid = jnp.where(ok, keys.astype(jnp.int64) * NP + rel_c,
-                            jnp.int64(K) * NP)
-            if K * NP + 1 < (1 << 31):   # counting ids are int32
-                order = _group_order(sid.astype(jnp.int32), K * NP + 1,
-                                     grouping, pallas)
-            else:
-                order = jnp.argsort(sid, stable=True)
-            ssid = sid[order]
-            slift = jax.tree.map(lambda a: a[order],
-                                 jax.vmap(lift)(payload))
-            starts = jnp.concatenate([jnp.array([True]),
-                                      ssid[1:] != ssid[:-1]])
-            scanned = _seg_scan(comb, starts, slift)
-            ends = jnp.concatenate([ssid[1:] != ssid[:-1],
-                                    jnp.array([True])])
-            row = jnp.where(ends, ssid // NP, K).astype(jnp.int32)
-            col = jnp.where(ends, ssid % NP, 0).astype(jnp.int32)
+            def place(cells):
+                sid = jnp.where(ok, keys.astype(jnp.int64) * NP + rel_c,
+                                jnp.int64(K) * NP)
+                if K * NP + 1 < (1 << 31):   # counting ids are int32
+                    sid = sid.astype(jnp.int32)
+                    order = _group_order(sid, K * NP + 1, grouping, pallas)
+                else:
+                    order = jnp.argsort(sid, stable=True)
+                ssid = sid[order]
+                slift = jax.tree.map(lambda a: a[order],
+                                     jax.vmap(lift)(payload))
+                starts = jnp.concatenate([jnp.array([True]),
+                                          ssid[1:] != ssid[:-1]])
+                scanned = _seg_scan(comb, starts, slift)
+                if seek_cells:
+                    # a grid small beside the batch looks its cells up:
+                    # a cell's fold is the last lane of its run, found
+                    # by bisection, where a scatter walks every lane
+                    cell = jnp.arange(K * NP, dtype=ssid.dtype)
+                    last = jnp.searchsorted(ssid, cell, side="right") - 1
+                    at = jnp.maximum(last, 0)
+                    partial_has = ((last >= 0) & (ssid[at] == cell)) \
+                        .reshape(K, NP)
 
-            def scat(leaf):
-                buf = jnp.zeros((K + 1, NP) + leaf.shape[1:], leaf.dtype)
-                return buf.at[row, col].set(
-                    jnp.where(_b(ends, leaf), leaf, 0))[:K]
-            partial = jax.tree.map(scat, scanned)
-            partial_has = jnp.zeros((K + 1, NP), bool) \
-                .at[row, col].set(ends)[:K]
+                    def seek(leaf):
+                        got = leaf[at].reshape((K, NP) + leaf.shape[1:])
+                        return jnp.where(_b(partial_has, got), got, 0)
+                    partial = jax.tree.map(seek, scanned)
+                else:
+                    ends = jnp.concatenate([ssid[1:] != ssid[:-1],
+                                            jnp.array([True])])
+                    row = jnp.where(ends, ssid // NP, K).astype(jnp.int32)
+                    col = jnp.where(ends, ssid % NP, 0).astype(jnp.int32)
 
-            # comb is a whole-pytree combiner (see CB merge above)
-            both_cells = comb(cells, partial)
+                    def scat(leaf):
+                        buf = jnp.zeros((K + 1, NP) + leaf.shape[1:],
+                                        leaf.dtype)
+                        return buf.at[row, col].set(
+                            jnp.where(_b(ends, leaf), leaf, 0))[:K]
+                    partial = jax.tree.map(scat, scanned)
+                    partial_has = jnp.zeros((K + 1, NP), bool) \
+                        .at[row, col].set(ends)[:K]
 
-            def merge(old_leaf, new_leaf, both_leaf):
-                return jnp.where(_b(cell_valid & partial_has, both_leaf),
-                                 both_leaf,
-                                 jnp.where(_b(partial_has, both_leaf),
-                                           new_leaf, old_leaf))
-            cells = jax.tree.map(merge, cells, partial, both_cells)
+                # comb is a whole-pytree combiner (see CB merge above)
+                both_cells = comb(cells, partial)
+
+                def merge(old_leaf, new_leaf, both_leaf):
+                    return jnp.where(
+                        _b(cell_valid & partial_has, both_leaf), both_leaf,
+                        jnp.where(_b(partial_has, both_leaf), new_leaf,
+                                  old_leaf))
+                return (jax.tree.map(merge, cells, partial, both_cells),
+                        partial_has)
+
+            # the sort and the scan run over every lane whatever it
+            # holds: a batch with nothing to place (a window stage fed
+            # by another's fired rows sees mostly those) skips them
+            cells, partial_has = jax.lax.cond(
+                jnp.any(ok), place,
+                lambda cells: (cells, jnp.zeros((K, NP), bool)), cells)
         cell_valid = cell_valid | partial_has
 
         # 4. pass B: fire what this batch completed under the watermark
         (cells, cell_valid, base, win_next,
-         fired_b, wvals_b, w_b, n_b, nd_b) = fire_pass(
-            cells, cell_valid, base, win_next, wm_pane, max_seen, horizon)
+         fired_b, wvals_b, w_b, n_b, nd_b, acc) = fire_pass(
+            cells, cell_valid, base, win_next, wm_pane, max_seen, horizon,
+            acc)
         n_win_dropped = n_win_dropped + nd_b
 
         new_state = {
@@ -1022,8 +1146,16 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             "n_evicted": state["n_evicted"] + evicted,
             "n_win_dropped": n_win_dropped,
         }
-        # outputs: pass A1, A2, then B rows, [K, N_PASSES*MW] flattened
         all_passes = a_outs + [(fired_b, wvals_b, w_b, n_b)]
+        n_adv = sum(p[3] for p in all_passes)
+        if compact:
+            # outputs: the passes' rows in firing order, window by window
+            out = {"key": acc["key"]
+                   + (jnp.int32(kb) if kb is not None else 0),
+                   "wid": acc["wid"], "value": acc["value"]}
+            return new_state, out, acc["fired"], \
+                (acc["wid"] * D + R) * P_usec - 1, n_adv      # end-1 (TB)
+        # outputs: pass A1, A2, then B rows, [K, N_PASSES*MW] flattened
         w2 = jnp.concatenate([p[2] for p in all_passes])
         fired = jnp.concatenate([p[0] for p in all_passes], axis=1)
         wvals = jax.tree.map(
@@ -1039,7 +1171,6 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             "value": jax.tree.map(
                 lambda a: a.reshape((K * NM,) + a.shape[2:]), wvals),
         }
-        n_adv = sum(p[3] for p in all_passes)
         return new_state, out, fired.reshape(-1), \
             jnp.broadcast_to(out_ts[None, :], (K, NM)).reshape(-1), n_adv
 

@@ -55,6 +55,27 @@ from windflow_tpu.windows.ffat_kernels import (agg_spec_for,
                                                tb_placement)
 
 
+def number_window_stages(operators, upstreams: dict) -> None:
+    """Set ``window_stage`` on every :class:`FfatWindowsTPU` of a graph
+    from its edges (``upstreams``: ``id(op) -> [(upstream op, _)]``, as
+    ``fusion.executor._upstream_edges`` gives them): one more than the
+    deepest window operator anywhere upstream of it."""
+    depth: dict = {}
+
+    def above(op) -> int:
+        """Window operators on the deepest path into ``op``."""
+        if id(op) not in depth:
+            depth[id(op)] = 0           # a cycle cannot be built; be safe
+            depth[id(op)] = max(
+                (above(up) + isinstance(up, FfatWindowsTPU)
+                 for up, _ in upstreams.get(id(op), ())), default=0)
+        return depth[id(op)]
+
+    for op in operators:
+        if isinstance(op, FfatWindowsTPU):
+            op.window_stage = above(op) + 1
+
+
 class FfatTPUReplica(_TPUReplica):
     def _op_step(self, batch):
         return self.op._step(batch, self.index)
@@ -101,6 +122,11 @@ class FfatWindowsTPU(Operator):
 
     replica_class = FfatTPUReplica
     fixed_capacity_label = "FfatWindowsTPU"
+
+    #: 1 for the first window operator of a pipeline, n + 1 for one fed
+    #: (through whatever operators) by a stage-n window's rows; set by
+    #: the graph build (:func:`number_window_stages`)
+    window_stage = 1
 
     #: compacted key space (parallel/compaction.py): True when the graph
     #: build attached a KeyCompactor — ``max_keys`` then bounds the SLOT
@@ -230,6 +256,15 @@ class FfatWindowsTPU(Operator):
         self._compact_keys = True
         self.max_keys = comp.slots
         comp.register_device_stats(lambda: self._cstats)
+
+    @property
+    def program_name(self) -> str:
+        """Name of the step's function, so of its XLA module
+        (``jit_step``; ``jit_step_w2`` for a second window stage): every
+        operator's program is ``jit_step`` in a device trace, and two
+        window stages of one graph have to be told apart there."""
+        return "step" if self.window_stage <= 1 \
+            else f"step_w{self.window_stage}"
 
     # -- state layout --------------------------------------------------------
     def _init_state(self, agg_spec):
@@ -388,6 +423,9 @@ class FfatWindowsTPU(Operator):
         donate = (0,)
         if comp is not None:
             donate = (0, 7 if self.is_tb else 6)
+        # the program's name in a device trace (jit_<name>): a window
+        # stage fed by another window's rows says which stage it is
+        step.__name__ = self.program_name
         return self._with_placement(
             wf_jit(step, op_name=self._fused_name or self.name,
                    donate_argnums=donate))
