@@ -8,6 +8,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.sharding import PartitionSpec as P
 
 import windflow_tpu as wf
@@ -70,10 +71,18 @@ def test_ffat_tpu_cb_on_mesh():
     assert op._states[0]["cur"].sharding.spec == P(KEY_AXIS)
 
 
-def test_ffat_tpu_tb_on_mesh():
+@pytest.mark.parametrize("form,batch", [("generic", 64), ("narrow", 8),
+                                        ("wide", 64)])
+def test_ffat_tpu_tb_on_mesh(monkeypatch, form, batch):
     """Time-based FFAT windows through the mesh path (VERDICT r2 item 2):
     key-sharded pane rings with per-shard clocks, watermark frontier
-    replicated, results exact vs the host oracle."""
+    replicated, results exact vs the host oracle.  With a declared sum
+    past the contraction's constant each key shard scatters its batch
+    under ``shard_map``: into the panes it spans (8 tuples: 3 panes) or,
+    past ``NARROW_PLACE_PANES`` (64 tuples: 16 panes), into its whole
+    ring, counted a shard."""
+    from windflow_tpu.windows import ffat_kernels
+    monkeypatch.setattr(ffat_kernels, "DENSE_PLACE_MAX_CELLS", 0)
     TWIN, TSLIDE = 16_000, 4_000
     per_key = {}
     for t in stream():
@@ -94,12 +103,13 @@ def test_ffat_tpu_tb_on_mesh():
     got = {}
     src = (wf.Source_Builder(lambda: iter(stream()))
            .withTimestampExtractor(lambda t: t["ts"])
-           .withOutputBatchSize(64).build())
+           .withOutputBatchSize(batch).build())
     op = (wf.Ffat_WindowsTPU_Builder(lambda t: t["value"],
                                      lambda a, b: a + b)
           .withTBWindows(TWIN, TSLIDE)
           .withKeyBy(lambda t: t["key"])
-          .withMaxKeys(N_KEYS).build())
+          .withMaxKeys(N_KEYS))
+    op = (op if form == "generic" else op.withSumCombiner()).build()
     snk = wf.Sink_Builder(
         lambda r: got.__setitem__((r["key"], r["wid"]), r["value"])
         if r is not None else None).build()
@@ -114,6 +124,12 @@ def test_ffat_tpu_tb_on_mesh():
     assert op._states[0]["base"].sharding.spec == P(KEY_AXIS)
     st = op.dump_stats()
     assert st["Late_tuples_dropped"] == 0
+    assert op._states[0]["n_wide"].sharding.spec == P(KEY_AXIS)
+    if form == "generic":
+        assert "TB_placement" not in st
+    else:
+        assert st["TB_placement"] == "scatter"
+        assert (st["TB_wide_placements"] > 0) == (form == "wide")
 
 
 def test_keyed_reduce_tpu_on_mesh_fold():

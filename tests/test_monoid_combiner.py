@@ -471,12 +471,14 @@ def _tb_stream(dtype, values, rng, n=5, **quirks):
     return out
 
 
-def _run_tb(monoid, stream, zero, D=TB["D"]):
+def _run_tb(monoid, stream, zero, D=TB["D"], key_base=None):
     """The whole record of a run: every output lane of every step, and
     the final state."""
     step = jax.jit(make_ffat_tb_step(
         TB["B"], TB["K"], TB["P"], TB["R"], D, TB["NP"],
-        lambda x: x["v"], _COMB[monoid], lambda x: x["k"], monoid=monoid))
+        lambda x: x["v"], _COMB[monoid], lambda x: x["k"], monoid=monoid,
+        key_base_fn=None if key_base is None
+        else (lambda: jnp.int32(key_base))))
     st = make_ffat_tb_state(zero, TB["K"], TB["NP"])
     trail = []
     for payload, ts, valid, wm in stream:
@@ -495,6 +497,8 @@ def _assert_same_run(a, b):
         assert np.array_equal(x, y)
     # cells of panes no tuple reached hold whatever the merge left there
     for name in st_a:
+        if name == "n_wide":    # says which form placed, not what
+            continue
         x, y = st_a[name], st_b[name]
         if name == "cells":
             x, y = (np.where(s["cell_valid"], s["cells"], 0)
@@ -602,6 +606,7 @@ def test_a_grid_past_the_constant_keeps_the_scatter(monkeypatch):
     """The decision is static and by size: a constant just under this
     grid's cells leaves the count and the sum on the scatter, with the
     same results."""
+    from test_shard_plane import _wide_scatters
     B, K, NP = TB["B"], TB["K"], TB["NP"]
     stream = _tb_stream(np.int64, "huge", np.random.default_rng(41))
     dense = _run_tb("sum", stream, jnp.zeros((), jnp.int64))
@@ -611,7 +616,11 @@ def test_a_grid_past_the_constant_keeps_the_scatter(monkeypatch):
     assert plan["placement"] == "scatter" and not plan["count"]
     jaxpr = _placement_jaxpr(K, NP, B, np.int64)
     assert "dot_general" not in str(jaxpr)
-    assert len(_lane_scatters(jaxpr, B)) == 2
+    # the wide placement's two, and the narrow one's count and 1 to 3
+    # limb scatters of the switch (23-bit limbs at 512 lanes), in 32 bits
+    lane = _lane_scatters(jaxpr, B)
+    assert len(lane) == 2 + 1 + (1 + 2 + 3)
+    assert len([e for e in lane if e in _wide_scatters(jaxpr)]) == 1
     _assert_same_run(dense, _run_tb("sum", stream, jnp.zeros((), jnp.int64)))
 
 
@@ -639,6 +648,234 @@ def test_ysb_sized_step_places_with_a_contraction_and_no_scatter():
     # lanes the f32 accumulator could not count exactly: no contraction
     assert not fk.tb_placement("sum", [np.zeros((), np.int64)], K, NP,
                                (1 << 24) + 1)["count"]
+
+
+# ---------------------------------------------------------------------------
+# narrow placement (PR 31): a scatter-placed batch goes into the panes it
+# spans, a 64-bit sum in 32-bit limbs; identical to the wide placement
+# ---------------------------------------------------------------------------
+
+def _span_stream(rng, dtype, values, span, n=7, stride=3, wm_lag=4,
+                 key_lo=-1, **quirks):
+    """``n`` batches whose valid lanes fall in ``span`` consecutive panes
+    starting ``stride`` panes after the previous batch's first, then an
+    all-invalid flush under an infinite watermark.  ``wm_lag=None``: the
+    watermark never moves before the flush, so the ring fills to its end
+    and rolls by capacity.  ``quirks``: ``late``, ``dead_batch`` (as
+    ``_tb_stream``), ``one_cell`` (every lane of every batch on one
+    cell)."""
+    B, K, P = TB["B"], TB["K"], TB["P"]
+    out = []
+    for i in range(n):
+        if values == "ones":
+            v = np.ones(B)
+        elif values == "small":
+            v = rng.integers(0, 1000, B)
+        elif values == "mixed":
+            v = rng.integers(-1000, 1000, B)
+        elif values == "float":
+            v = -1.0 - rng.random(B)
+        else:   # a quarter to half of the range each: cells wrap
+            info = np.iinfo(dtype)
+            v = rng.integers(info.max // 4, info.max // 2, B) \
+                * rng.choice([1, 1, 1, -1], B)
+        ts = ((i * stride + rng.integers(0, span, B)) * P
+              + rng.integers(0, P, B)).astype(np.int64)
+        ts[:span] = (i * stride + np.arange(span)) * P     # every pane hit
+        valid = rng.random(B) > 0.2
+        valid[:span] = True
+        keys = rng.integers(key_lo, key_lo + K + 2, B).astype(np.int32)
+        keys[:span] = key_lo + 1
+        if quirks.get("late") and i >= 5:
+            ts[span::8] = rng.integers(0, 2 * P, len(ts[span::8]))
+        if quirks.get("dead_batch") and i == 2:
+            valid[:] = False
+        if quirks.get("one_cell"):
+            ts[:], keys[:], valid[:] = i * stride * P, key_lo + 3, True
+        wm = -100 if wm_lag is None else i * stride - wm_lag
+        out.append(({"k": jnp.asarray(keys), "v": jnp.asarray(v.astype(dtype))},
+                    jnp.asarray(ts), jnp.asarray(valid),
+                    jnp.asarray(wm, jnp.int64)))
+    for _ in range(3):
+        out.append(({"k": jnp.zeros(B, jnp.int32), "v": jnp.zeros(B, dtype)},
+                    jnp.zeros(B, jnp.int64), jnp.zeros(B, bool),
+                    jnp.asarray(1 << 40, jnp.int64)))
+    return out
+
+
+def _narrow_and_wide(monkeypatch, monoid, stream, zero, **kw):
+    """The same run with the narrow placement and with the whole-ring
+    scatter alone (a span no ring is narrower than), both past the
+    contraction's constant."""
+    monkeypatch.setattr(fk, "DENSE_PLACE_MAX_CELLS", 0)
+    narrow = _run_tb(monoid, stream, zero, **kw)
+    monkeypatch.setattr(fk, "NARROW_PLACE_PANES", TB["NP"])
+    wide = _run_tb(monoid, stream, zero, **kw)
+    assert wide[1]["n_wide"] == 0           # no narrow form to miss
+    return narrow, wide
+
+
+S_ = fk.NARROW_PLACE_PANES
+NARROW_CASES = {
+    # name: (monoid, dtype, values, stream kwargs, run kwargs, wide steps)
+    "span_below": ("sum", np.int64, "mixed", dict(span=S_ - 2), {}, 0),
+    "span_at": ("sum", np.int64, "mixed", dict(span=S_), {}, 0),
+    "span_above": ("sum", np.int64, "mixed", dict(span=S_ + 1), {}, 7),
+    "ring_end": ("sum", np.int64, "mixed",
+                 dict(span=2, n=14, wm_lag=None), {}, 0),
+    "ring_end_wide": ("sum", np.int64, "mixed",
+                      dict(span=S_ + 2, n=12, wm_lag=None, stride=4), {}, 12),
+    "dead_batch": ("sum", np.int64, "mixed",
+                   dict(span=2, dead_batch=True), {}, 0),
+    "late": ("sum", np.int64, "mixed", dict(span=2, late=True), {}, 0),
+    "key_base": ("sum", np.int64, "mixed", dict(span=3, key_lo=39),
+                 dict(key_base=40), 0),
+    "ones": ("sum", np.int64, "ones", dict(span=2), {}, 0),
+    "small": ("sum", np.int64, "small", dict(span=2), {}, 0),
+    "wraps": ("sum", np.int64, "huge", dict(span=2), {}, 0),
+    "uint64": ("sum", np.uint64, "huge", dict(span=2), {}, 0),
+    "int32": ("sum", np.int32, "huge", dict(span=2), {}, 0),
+    "float32": ("sum", np.float32, "float", dict(span=2), {}, 0),
+    "max": ("max", np.int64, "mixed", dict(span=3), {}, 0),
+    "min": ("min", np.float32, "float", dict(span=3), {}, 0),
+    "gaps": ("sum", np.int64, "mixed", dict(span=3, stride=6),
+             dict(D=6), 0),
+    "one_cell": ("sum", np.int64, "huge", dict(span=1, one_cell=True), {}, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NARROW_CASES))
+def test_tb_narrow_placement_is_the_wide_one(monkeypatch, case):
+    """Cells, flags, every fired row and every counter agree between a
+    batch scattered into the panes it spans and the same batch scattered
+    into the whole ring; ``n_wide`` counts the steps that spanned more."""
+    monoid, dtype, values, skw, rkw, n_wide = NARROW_CASES[case]
+    stream = _span_stream(np.random.default_rng(43), dtype, values, **skw)
+    narrow, wide = _narrow_and_wide(monkeypatch, monoid, stream,
+                                    jnp.zeros((), dtype), **rkw)
+    _assert_same_run(narrow, wide)
+    st = narrow[1]
+    assert st["n_wide"] == n_wide and st["n_wide"].dtype == np.int64
+    assert st["cells"].dtype == dtype
+    assert (st["n_late"] > 0) == (case == "late")
+    assert (st["n_evicted"] > 0) == case.startswith("ring_end")
+
+
+@pytest.mark.parametrize("bits", [14, 23])
+def test_narrow_limbs_wrap_as_the_64_bit_scatter_add_does(monkeypatch, bits):
+    """Values over the whole int64 range, several to a cell: the uint32
+    limb sums widened in uint64 give the int64 scatter-add's wrapped
+    total, at the limb width of 262144 lanes (14 bits, five limbs) and
+    at this batch's own (23 bits, three)."""
+    assert fk.narrow_limb_bits(TB["B"]) == 23
+    monkeypatch.setattr(fk, "narrow_limb_bits", lambda B: bits)
+    stream = _span_stream(np.random.default_rng(47), np.int64, "huge",
+                          span=2)
+    narrow, wide = _narrow_and_wide(monkeypatch, "sum", stream,
+                                    jnp.zeros((), jnp.int64))
+    _assert_same_run(narrow, wide)
+    p, ts, ok, _ = (jax.tree.map(np.asarray, x) for x in stream[0])
+    cell = {}
+    for k, t, v in zip(p["k"][ok], ts[ok] // TB["P"], p["v"][ok]):
+        cell[(k, t)] = cell.get((k, t), 0) + int(v)
+    assert max(abs(c) for c in cell.values()) > np.iinfo(np.int64).max
+
+
+@pytest.mark.parametrize("B", [1, 512, 262144, 262145, 1 << 24, 1 << 31])
+def test_narrow_limb_bits_cannot_wrap_a_uint32(B):
+    b = fk.narrow_limb_bits(B)
+    assert ((1 << b) - 1) * B < 1 << 32 <= ((1 << (b + 1)) - 1) * B
+    assert fk.narrow_limb_bits(262144) == 14
+
+
+def test_every_lane_of_a_real_batch_on_one_cell_fills_the_limbs():
+    """262144 lanes of -1 on one cell: every 14-bit limb is full in every
+    lane, so each uint32 limb sum is the largest there can be
+    ((2^14 - 1) x 2^18 = 2^32 - 2^18) and the cell reads -262144."""
+    B, K, NP, P = 262144, 3, 2 * S_ + 2, 1000
+    step = jax.jit(make_ffat_tb_step(
+        B, K, P, 2, 1, NP, lambda e: e["v"], _COMB["sum"],
+        lambda e: e["k"], monoid="sum"))
+    st = make_ffat_tb_state(jnp.zeros((), jnp.int64), K, NP)
+    real = fk.DENSE_PLACE_MAX_CELLS
+    fk.DENSE_PLACE_MAX_CELLS = 0
+    try:
+        st, *_ = step(st, {"k": jnp.full(B, 1, jnp.int32),
+                           "v": jnp.full(B, -1, jnp.int64)},
+                      jnp.full(B, 5 * P, jnp.int64), jnp.ones(B, bool),
+                      jnp.asarray(-10, jnp.int64))
+    finally:
+        fk.DENSE_PLACE_MAX_CELLS = real
+    cells = np.where(st["cell_valid"], st["cells"], 0)
+    assert cells[1, 5] == -B and np.count_nonzero(cells) == 1
+    assert int(st["n_wide"]) == 0
+
+
+def _placement_cond(jaxpr, B):
+    """The ``cond`` of a step's jaxpr whose two branches each scatter
+    over the batch's lanes: (narrow, wide) in the order ``lax.cond``
+    keeps them (index 0 is the false branch: the wide placement)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond" and len(eqn.params["branches"]) == 2:
+            wide, narrow = (b.jaxpr for b in eqn.params["branches"])
+            if _lane_scatters(wide, B) and _lane_scatters(narrow, B):
+                return narrow, wide
+    return None
+
+
+def test_q5_sized_step_scatters_narrow_and_in_32_bits():
+    """The first window stage of ``benchmark/configs/nexmark_q5.py`` at
+    its own sizes (655 360 keys x 66 panes, 262144 lanes, a declared
+    int64 sum of ones): the narrow branch holds no 64-bit scatter and no
+    scatter into ``(K + 1) x NP`` cells: every target is ``[K + 1, S]``
+    of 32 bits; the wide branch is the parent's two scatters, and
+    nothing else in the step scatters over the lanes."""
+    from test_shard_plane import _wide_scatters
+    K, NP, B = 655360, 66, 262144
+    S = fk.NARROW_PLACE_PANES
+    plan = fk.tb_placement("sum", [np.zeros((), np.int64)], K, NP, B)
+    assert plan["placement"] == "scatter" and not plan["count"]
+    step = make_ffat_tb_step(B, K, 5_000_000, 2, 1, NP,
+                             lambda e: jnp.int64(1), _COMB["sum"],
+                             lambda e: e["key"], monoid="sum")
+    SD = jax.ShapeDtypeStruct
+    state = jax.eval_shape(lambda: make_ffat_tb_state(
+        jnp.zeros((), jnp.int64), K, NP))
+    jaxpr = jax.make_jaxpr(step)(
+        state, {"key": SD((B,), np.int32)}, SD((B,), np.int64),
+        SD((B,), np.bool_), SD((), np.int64)).jaxpr
+    narrow, wide = _placement_cond(jaxpr, B)
+    assert _wide_scatters(narrow) == []
+    targets = {tuple(e.invars[0].aval.shape) for e in _lane_scatters(narrow, B)}
+    assert targets == {(K + 1, S)}
+    assert {np.dtype(e.invars[0].aval.dtype).itemsize
+            for e in _lane_scatters(narrow, B)} == {4}
+    # five limbs at 14 bits: the switch holds 1 + ... + 5, the count 1
+    assert len(_lane_scatters(narrow, B)) == 16
+    assert [tuple(e.invars[0].aval.shape) for e in _lane_scatters(wide, B)] \
+        == [(K + 1, NP)] * 2 and len(_wide_scatters(wide)) == 1
+    assert len(_lane_scatters(jaxpr, B)) == 16 + 2
+
+
+def test_ysb_step_never_traces_the_scatter_branch(monkeypatch):
+    """YSB's plan is ``dense``: its step lowers to the same text whatever
+    the narrow span is, holds no placement ``cond``, and carries
+    ``n_wide`` through untouched."""
+    K, NP, B = 100, 65, 32768
+    def lowered():
+        step, state, batch = ysb_step_shapes(K, NP, B, np.int64)
+        return jax.jit(step).lower(state, *batch).as_text()
+    text = lowered()
+    monkeypatch.setattr(fk, "NARROW_PLACE_PANES", 2)
+    assert lowered() == text
+    step, state, batch = ysb_step_shapes(K, NP, B, np.int64)
+    jaxpr = jax.make_jaxpr(step)(state, *batch).jaxpr
+    assert _placement_cond(jaxpr, B) is None
+    assert _lane_scatters(jaxpr, B) == []
+    out_state = jaxpr.outvars[:len(jax.tree.leaves(state))]
+    names = sorted(state)
+    assert out_state[names.index("n_wide")] \
+        is jaxpr.invars[names.index("n_wide")]
 
 
 # -- the whole YSB graph of the benchmark, small ---------------------------
@@ -719,6 +956,23 @@ def test_ysb_graph_counts_match_the_reference_on_both_paths(monkeypatch,
     assert set(steps.values()) == {"dense"}
     assert any(k.startswith("megastep.") for k in steps) \
         == (path == "megastep")
+
+
+def test_ysb_graph_scatters_narrow_inside_the_scan(monkeypatch):
+    """The same graph past the contraction's constant, its window step
+    inside the K = 4 ``lax.scan``: the ``cond`` on the observed span and
+    the limb ``switch`` run under the scan, every count matches the
+    reference, and no batch of the ordered stream needed the whole ring."""
+    monkeypatch.setattr(fk, "DENSE_PLACE_MAX_CELLS", 0)
+    g, checks, got = _run_ysb(
+        monkeypatch, 24 * 1024, megastep_sweeps=4,
+        punctuation_interval_usec=10 ** 12, wire_compression=False)
+    assert all(c["ok"] for c in checks), checks
+    assert sum(e["batches"] for e in g.stats()["Megastep"]["edges"]) >= 8
+    op = next(o for o in g.stats()["Operators"]
+              if o["Operator_name"] == "campaign_counts")
+    assert op["TB_placement"] == "scatter"
+    assert op["TB_wide_placements"] == 0
 
 
 def test_float_window_reports_the_scatter_placement():

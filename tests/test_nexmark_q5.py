@@ -175,6 +175,32 @@ def test_q5_graph_matches_the_per_tuple_oracle(case):
         assert rows[:, 1].tolist() == [0, 1, 4, 5, 6]
 
 
+@pytest.mark.parametrize("case,wide", [("moving_hot_key", False),
+                                       ("an_empty_pane", True)])
+def test_q5_counts_its_wide_placements(monkeypatch, case, wide):
+    """Past the contraction's constant (the cell's 43 M cells are; this
+    small grid is made to be) the first stage scatters each batch into
+    the panes it spans: ``TB_wide_placements`` stays 0 on the ordered
+    bid stream and counts the batch that straddles a gap of more than
+    ``NARROW_PLACE_PANES`` panes, with the oracle's rows either way."""
+    from windflow_tpu.monitoring.openmetrics import (parse_exposition,
+                                                     render_openmetrics)
+    monkeypatch.setattr(fk, "DENSE_PLACE_MAX_CELLS", 0)
+    rec = CASES[case][0]()
+    rows, g = run_q5(rec)
+    assert np.array_equal(
+        rows, oracle_hot_items(rec["k"], rec["t"], rec[q5.KIND]))
+    st = g.stats()
+    op = next(o for o in st["Operators"]
+              if o["Operator_name"] == "bids_per_auction")
+    assert op["TB_placement"] == "scatter"
+    assert (op["TB_wide_placements"] > 0) == wide
+    fams = parse_exposition(render_openmetrics(st))
+    assert [(s[1]["operator"], s[2]) for s in
+            fams["wf_operator_tb_wide_placements_total"]["samples"]] \
+        == [("bids_per_auction", op["TB_wide_placements"])]
+
+
 def test_q5_graph_matches_the_plain_reference():
     """Through ``expected`` and ``compare``, as a run of the cell does."""
     cfg = tiny_cfg()
@@ -367,6 +393,7 @@ def test_ysb_step_is_bit_identical_to_the_parents():
             jnp.int64((ts[-1] - 500) // P))
         trail.append((out, fired, out_ts, n_adv))
     assert fired.shape == (20100,)
+    assert int(st.pop("n_wide")) == 0       # new in PR 31; the rest as was
     assert _digest((trail, st)) == ("c692f131125060f60866a92e023ae6a0"
                                     "d6622524764aefd23e6f96d975db7880")
 

@@ -44,10 +44,12 @@ from windflow_tpu.basic import WindFlowError, int32_key, stable_hash
 #: per-replica states: shape ()) — mirror of parallel/mesh._TB_SCALARS,
 #: duplicated so this module never imports jax at module scope
 TB_SCALARS = ("base", "win_next", "max_seen", "n_late", "n_evicted",
-              "n_win_dropped")
+              "n_win_dropped", "n_wide")
 #: TB clock lanes that must AGREE across merged shards (the ring
 #: alignment invariants); the remaining scalars merge (max / sum)
 TB_ALIGNED = ("base", "win_next")
+#: ... the ones that sum (a blob from before ``n_wide`` lacks it)
+TB_COUNTERS = ("n_late", "n_evicted", "n_win_dropped", "n_wide")
 
 
 class RescaleError(WindFlowError):
@@ -200,7 +202,9 @@ def _rebucket_ffat(op, blob, old_p: int, new_p: int,
             st[name] = lane(name, agreed[name])
         st["max_seen"] = lane("max_seen",
                               int(_tb_scalar(st["max_seen"]).max()))
-        for name in ("n_late", "n_evicted", "n_win_dropped"):
+        for name in TB_COUNTERS:
+            if name not in st:
+                continue
             total = int(_tb_scalar(st[name]).sum())
             a = np.zeros((lanes,), _tb_scalar(st[name]).dtype)
             a[0] = total
@@ -218,8 +222,8 @@ def _rebucket_ffat(op, blob, old_p: int, new_p: int,
     max_seen = max(int(_tb_scalar(st["max_seen"]).max())
                    for st in live.values())
     counters = {name: sum(int(_tb_scalar(st[name]).sum())
-                          for st in live.values())
-                for name in ("n_late", "n_evicted", "n_win_dropped")}
+                          for st in live.values() if name in st)
+                for name in TB_COUNTERS}
     if kind == "slot_mod":
         # compacted rings index rows by SLOT; executor overrides are
         # keyed by USER key — translate through the checkpointed remap

@@ -182,6 +182,10 @@ def _families(stats: dict,
     f_limbs = fam("wf_operator_tb_placement_limbs", "gauge",
                   "Limb columns the dense placement contracts for the "
                   "window's integer sums")
+    f_wide = fam("wf_operator_tb_wide_placements_total", "counter",
+                 "Steps of a scatter-placed time window whose batch "
+                 "spanned more panes than a narrow placement holds and "
+                 "scattered into the whole ring")
     for op in ops:
         name = op.get("Operator_name") or op.get("Name") or "?"
         if op.get("TB_placement"):
@@ -190,6 +194,8 @@ def _families(stats: dict,
                             dict(base, operator=name, placement=form))
             f_limbs.add(op.get("TB_placement_limbs", 0),
                         dict(base, operator=name))
+            f_wide.add(op.get("TB_wide_placements", 0),
+                       dict(base, operator=name))
         for idx, r in enumerate(op.get("Replicas") or []):
             lab = dict(base, operator=name,
                        replica=str(r.get("Replica_id", idx)))

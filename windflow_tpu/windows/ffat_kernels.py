@@ -8,6 +8,7 @@ distribution layer can use them without cycles.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional
 
 import jax
@@ -223,6 +224,33 @@ _WIDE_SCATTER_COST = 10
 _DENSE_MAX_OPERAND_BYTES = 1 << 30
 #: lanes whose 0/1 products the f32 accumulator still counts exactly
 _DENSE_EXACT_LANES = 1 << 24
+#: Narrow placement (time-based step, declared monoid, a grid past the
+#: contraction): a batch whose lanes fall within this many panes of its
+#: oldest scatters into ``[K + 1, NARROW_PLACE_PANES]`` targets and is
+#: merged into those columns of the ring alone; a wider one (an idle
+#: gap, heavy disorder, tiny panes) scatters into the whole ``[K + 1,
+#: NP]`` grid as before, counted in ``n_wide``.  At 655 360 keys x 66
+#: panes and 262144 lanes on a v5e (PERF.md section 6, PR 31), six
+#: 32-bit scatters with their merge: 18.1 ms into 2 columns, 18.2 into
+#: 4, 22.5 into 8 (a result's column costs 0.18 ms to lay out), where
+#: the whole-ring int64 placement takes 64.4: 4 is free, 8 is not.
+NARROW_PLACE_PANES = 4
+
+
+def narrow_limb_bits(B: int) -> int:
+    """Widest limb, in bits, whose sum over ``B`` lanes a uint32
+    scatter-add cannot wrap: ``(2^b - 1) * B < 2^32`` (14 bits, five
+    limbs of an int64, at 262144 lanes).  0: no such limb."""
+    return ((2 ** 32 - 1) // B + 1).bit_length() - 1
+
+
+def _rides_limbs(monoid: str, leaf) -> bool:
+    """A 64-bit integer ``sum`` leaf with no trailing dims: the one kind
+    of leaf whose scatter-add is worth cutting into 32-bit limbs (a
+    64-bit scatter-add costs 10-13 32-bit ones on a v5e)."""
+    dt = jnp.dtype(leaf.dtype)
+    return monoid == "sum" and leaf.ndim == 1 \
+        and jnp.issubdtype(dt, jnp.integer) and dt.itemsize == 8
 
 
 def _limb_bits(B: int) -> int:
@@ -246,7 +274,11 @@ def tb_placement(monoid: Optional[str], leaves, K: int, NP: int,
     ``min`` have no limb form, and past :data:`DENSE_PLACE_MAX_CELLS`
     the columns cost more than the scatter they replace); ``count`` says
     whether ``partial_has`` comes from the contraction's count column;
-    ``placement`` is ``"dense"`` when no scatter is left."""
+    ``placement`` is ``"dense"`` when no scatter is left.  What
+    ``"scatter"`` leaves is sized by the step itself, batch by batch:
+    into the :data:`NARROW_PLACE_PANES` panes the batch spans (a 64-bit
+    integer sum as uint32 limbs, :func:`narrow_limb_bits`) or, past that
+    span, into the whole ring (``n_wide`` counts those steps)."""
     b = _limb_bits(B)
     fits = monoid is not None and b > 0 \
         and 2 * B * (K + NP) <= _DENSE_MAX_OPERAND_BYTES
@@ -266,25 +298,29 @@ def tb_placement(monoid: Optional[str], leaves, K: int, NP: int,
             "limbs": limbs, "count": count, "limb_bits": b}
 
 
-def _to_limbs(leaf, n: int, b: int):
-    """``[B, n]`` bfloat16: the two's-complement bits of the integer
-    lane ``leaf`` cut into ``n`` limbs of ``b`` bits, lowest first."""
+def _to_limbs(leaf, n: int, b: int, acc=jnp.bfloat16):
+    """``[B, n]`` of ``acc``: the two's-complement bits of the integer
+    lane ``leaf`` cut into ``n`` limbs of ``b`` bits, lowest first
+    (``acc``: what the limbs are summed in: bfloat16 operands of the
+    contraction, uint32 updates of the narrow scatter)."""
     udt = jnp.dtype(f"uint{leaf.dtype.itemsize * 8}")
     u = jax.lax.bitcast_convert_type(leaf, udt)
     shifts = jnp.arange(n, dtype=udt) * b
     return ((u[:, None] >> shifts[None, :]) & udt.type((1 << b) - 1)) \
-        .astype(jnp.int32).astype(jnp.bfloat16)
+        .astype(jnp.int32).astype(acc)
 
 
 def _from_limbs(part, b: int, dtype):
     """Inverse of :func:`_to_limbs` over per-cell limb SUMS ``part``
-    ``[K, n, NP]`` (f32 holding integers under 2^24): each is shifted
-    back into place in the unsigned twin of ``dtype``, where the total
-    wraps exactly as a scatter-add in ``dtype`` does."""
+    ``[K, n, NP]`` (f32 holding integers under 2^24, or uint32): each is
+    shifted back into place in the unsigned twin of ``dtype``, where the
+    total wraps exactly as a scatter-add in ``dtype`` does."""
     udt = jnp.dtype(f"uint{jnp.dtype(dtype).itemsize * 8}")
     shifts = jnp.arange(part.shape[1], dtype=udt) * b
+    if jnp.issubdtype(part.dtype, jnp.floating):
+        part = part.astype(jnp.int32)
     return jax.lax.bitcast_convert_type(
-        jnp.sum(part.astype(jnp.int32).astype(udt) << shifts[None, :, None],
+        jnp.sum(part.astype(udt) << shifts[None, :, None],
                 axis=1, dtype=udt), dtype)
 
 
@@ -745,6 +781,9 @@ def make_ffat_tb_state(agg_spec, K: int, NP: int):
         "n_late": jnp.zeros((), jnp.int64),    # dropped late tuples
         "n_evicted": jnp.zeros((), jnp.int64),  # pane cells lost to overflow
         "n_win_dropped": jnp.zeros((), jnp.int64),  # windows suppressed
+        # steps whose batch spanned more panes than a narrow placement
+        # holds, and scattered into the whole ring (NARROW_PLACE_PANES)
+        "n_wide": jnp.zeros((), jnp.int64),
     }
 
 
@@ -804,7 +843,15 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
     tolerance; max/min are idempotent — identical either way).  On a
     grid small beside the batch (:func:`tb_placement`, static per built
     step) even the scatter goes: the cell counts, and integer sums bit
-    for bit, come out of one one-hot contraction on the MXU.
+    for bit, come out of one one-hot contraction on the MXU.  Past that
+    grid the scatter's cost follows the batch, not the ring: a batch
+    whose lanes span at most :data:`NARROW_PLACE_PANES` panes (one
+    ``lax.cond`` on the span the step observes) scatters into ``[K + 1,
+    NARROW_PLACE_PANES]`` targets and is merged into those columns
+    alone, a 64-bit integer ``sum`` as uint32 limbs (only those some
+    lane fills) widened in the leaf's unsigned twin, bit for bit the
+    int64 scatter-add; a wider batch takes the whole-ring scatter and
+    is counted in the state's ``n_wide`` (``TB_wide_placements``).
     """
     monoid = resolve_monoid(sum_like, monoid)
     MW = NP // D + 2
@@ -1019,6 +1066,7 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
         late = ok & (rel < 0)
         ok = ok & (rel >= 0)
         rel_c = jnp.clip(rel, 0, NP - 1).astype(jnp.int32)
+        n_wide = state["n_wide"]
         if monoid is not None:
             # declared leafwise-monoid combiner: a tuple's pane cell is
             # pure timestamp arithmetic (no within-key rank exists in
@@ -1028,43 +1076,110 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             # every TB batch regardless (thrust::sort_by_key,
             # ffat_replica_gpu.hpp:917).
             row_u = jnp.where(ok, keys, K)
-            col_u = jnp.where(ok, rel_c, 0)
-
-            def scat(leaf):
-                ident = _monoid_identity(monoid, leaf.dtype)
-                buf = jnp.full((K + 1, NP) + leaf.shape[1:], ident,
-                               leaf.dtype)
-                return _monoid_scatter(buf.at[row_u, col_u], monoid)(
-                    jnp.where(_b(ok, leaf), leaf, ident))[:K]
             lifted, tree = jax.tree.flatten(jax.vmap(lift)(payload))
-            # a grid this small is placed by one one-hot contraction on
-            # the MXU, exact in the leaf's own width, where a 64-bit
-            # scatter-add over the lanes costs ~18 ms (tb_placement)
             plan = tb_placement(
                 monoid, [jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
                          for a in lifted], K, NP, B)
+            mop = _MONOID_OPS[monoid][1]
+
+            def scat(leaf, width, col, limb_bits=0):
+                """One leaf of the batch's partial grid ``[K, width]``,
+                scatter-combined at ``(row_u, col)``; with ``limb_bits``
+                a 64-bit integer sum rides as uint32 limbs, widened in
+                the leaf's unsigned twin (:func:`narrow_limb_bits`)."""
+                ident = _monoid_identity(monoid, leaf.dtype)
+                vals = jnp.where(_b(ok, leaf), leaf, ident)
+                if limb_bits and _rides_limbs(monoid, leaf):
+                    n = -(-leaf.dtype.itemsize * 8 // limb_bits)
+                    limbs = _to_limbs(vals, n, limb_bits, jnp.uint32)
+
+                    def lowest(used):
+                        return _from_limbs(jnp.stack(
+                            [jnp.zeros((K + 1, width), jnp.uint32)
+                             .at[row_u, col].add(limbs[:, j])[:K]
+                             for j in range(used)], axis=1),
+                            limb_bits, leaf.dtype)
+                    # a limb that is zero in every lane adds nothing:
+                    # scatter up to the highest one some lane fills.
+                    # Counts and small non-negative values ride one
+                    # scatter, negative ones (sign limbs set) every one
+                    top = jnp.max(jnp.where(
+                        jnp.any(limbs != 0, axis=0),
+                        jnp.arange(n, dtype=jnp.int32), 0))
+                    return jax.lax.switch(
+                        top, [functools.partial(lowest, u + 1)
+                              for u in range(n)])
+                buf = jnp.full((K + 1, width) + leaf.shape[1:], ident,
+                               leaf.dtype)
+                return _monoid_scatter(buf.at[row_u, col], monoid)(vals)[:K]
+
+            def scat_has(width, col):
+                return (jnp.zeros((K + 1, width), jnp.int32)
+                        .at[row_u, col].add(ok.astype(jnp.int32))[:K] > 0)
+
+            def merge(cells, valid, partial):
+                """``partial`` (one grid a leaf) combined into ``cells``
+                of the same width, cells not ``valid`` read as the
+                identity."""
+                def merge_m(old_leaf, new_leaf):
+                    # declared op with dtype PROMOTION, exactly like the
+                    # grouped path's comb merge — a wider (e.g. f64)
+                    # state stays wide; no scatter is involved so no
+                    # cast is needed
+                    old = jnp.where(_b(valid, old_leaf), old_leaf,
+                                    _monoid_identity(monoid, old_leaf.dtype))
+                    return mop(new_leaf, old)
+                return jax.tree.map(merge_m, cells,
+                                    jax.tree.unflatten(tree, partial))
+
+            def place_wide(cells, cell_valid):
+                col = jnp.where(ok, rel_c, 0)
+                return (merge(cells, cell_valid,
+                              [scat(a, NP, col) for a in lifted]),
+                        cell_valid | scat_has(NP, col))
+
+            def place_narrow(cells, cell_valid):
+                # the batch's own pane span: targets, re-layout of the
+                # scatters' results and the merge all follow K x S
+                S = NARROW_PLACE_PANES
+                col = jnp.where(ok, rel_c - c0, 0)
+                cut = lambda a: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                    a, c0, S, axis=1)
+                paste = lambda a, new: (  # noqa: E731
+                    jax.lax.dynamic_update_slice_in_dim(
+                        a.astype(new.dtype), new, c0, axis=1))
+                old_valid = cut(cell_valid)
+                new = merge(jax.tree.map(cut, cells), old_valid,
+                            [scat(a, S, col, narrow_limb_bits(B))
+                             for a in lifted])
+                return (jax.tree.map(paste, cells, new),
+                        paste(cell_valid, old_valid | scat_has(S, col)))
+
+            # a grid small beside the batch is placed by one one-hot
+            # contraction on the MXU, exact in the leaf's own width
+            # (tb_placement); past it the batch scatters, into the
+            # panes it spans where those are few: a 64-bit scatter-add
+            # over the lanes costs 18.5 ms on a small grid and 34 ms
+            # into 43 M cells, a 32-bit one 1.9 and 2.6 ms (PERF.md
+            # section 6, PR 29 and PR 31)
             if plan["count"]:
                 n_cell, sums = _dense_place(keys, rel_c, ok, K, NP, lifted,
                                             plan["limbs"], plan["limb_bits"])
-                partial_has = n_cell > 0
+                col = jnp.where(ok, rel_c, 0)
+                cells = merge(cells, cell_valid,
+                              [scat(a, NP, col) if s is None else s
+                               for a, s in zip(lifted, sums)])
+                cell_valid = cell_valid | (n_cell > 0)
+            elif NP > NARROW_PLACE_PANES:
+                c0 = jnp.clip(jnp.min(jnp.where(ok, rel_c, NP)), 0,
+                              NP - NARROW_PLACE_PANES)
+                narrow = jnp.max(jnp.where(ok, rel_c, 0)) - c0 \
+                    < NARROW_PLACE_PANES
+                cells, cell_valid = jax.lax.cond(
+                    narrow, place_narrow, place_wide, cells, cell_valid)
+                n_wide = n_wide + jnp.where(narrow, 0, 1)
             else:
-                sums = [None] * len(lifted)
-                partial_has = (jnp.zeros((K + 1, NP), jnp.int32)
-                               .at[row_u, col_u]
-                               .add(ok.astype(jnp.int32))[:K] > 0)
-            partial = jax.tree.unflatten(
-                tree, [scat(a) if s is None else s
-                       for a, s in zip(lifted, sums)])
-            mop = _MONOID_OPS[monoid][1]
-
-            def merge_m(old_leaf, new_leaf):
-                # declared op with dtype PROMOTION, exactly like the
-                # grouped path's comb merge — a wider (e.g. f64) state
-                # stays wide; no scatter is involved so no cast is needed
-                old = jnp.where(_b(cell_valid, old_leaf), old_leaf,
-                                _monoid_identity(monoid, old_leaf.dtype))
-                return mop(new_leaf, old)
-            cells = jax.tree.map(merge_m, cells, partial)
+                cells, cell_valid = place_wide(cells, cell_valid)
         else:
             def place(cells):
                 sid = jnp.where(ok, keys.astype(jnp.int64) * NP + rel_c,
@@ -1126,7 +1241,7 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             cells, partial_has = jax.lax.cond(
                 jnp.any(ok), place,
                 lambda cells: (cells, jnp.zeros((K, NP), bool)), cells)
-        cell_valid = cell_valid | partial_has
+            cell_valid = cell_valid | partial_has
 
         # 4. pass B: fire what this batch completed under the watermark
         (cells, cell_valid, base, win_next,
@@ -1145,6 +1260,7 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             "n_late": state["n_late"] + jnp.sum(late.astype(jnp.int64)),
             "n_evicted": state["n_evicted"] + evicted,
             "n_win_dropped": n_win_dropped,
+            "n_wide": n_wide,
         }
         all_passes = a_outs + [(fired_b, wvals_b, w_b, n_b)]
         n_adv = sum(p[3] for p in all_passes)

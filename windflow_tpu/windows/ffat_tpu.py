@@ -922,6 +922,10 @@ class FfatWindowsTPU(Operator):
             place = jnp.asarray
         self._states = {k: jax.tree.map(place, st)
                         for k, st in blob["states"].items()}
+        for st in self._states.values():
+            # a checkpoint from before the step counted its wide placements
+            if "n_late" in st:
+                st.setdefault("n_wide", jnp.zeros_like(st["n_late"]))
         if blob["payload_zero"] is not None:
             self._payload_zero = jax.tree.map(jnp.asarray,
                                               blob["payload_zero"])
@@ -1003,6 +1007,9 @@ class FfatWindowsTPU(Operator):
             # one-hot contraction and no scatter is left in the placement
             st["TB_placement"] = plan["placement"]
             st["TB_placement_limbs"] = sum(plan["limbs"])
+            # steps whose batch spanned more than NARROW_PLACE_PANES
+            # panes and scattered into the whole ring (0 on "dense")
+            st["TB_wide_placements"] = self._tb_counter("n_wide")
         return st
 
     def _build_flush(self):
