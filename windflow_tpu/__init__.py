@@ -37,6 +37,7 @@ from windflow_tpu.graph.builders import (Ffat_Windows_Builder,
                                          Paned_Windows_Builder,
                                          Parallel_Windows_Builder,
                                          Reduce_Builder, ReduceTPU_Builder,
+                                         Session_WindowsTPU_Builder,
                                          Sink_Builder, Source_Builder)
 from windflow_tpu.graph.multipipe import MultiPipe
 from windflow_tpu.graph.pipegraph import PipeGraph
@@ -53,6 +54,7 @@ from windflow_tpu.windows.engine import WindowSpec
 from windflow_tpu.windows.ffat_op import FfatWindows
 from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
 from windflow_tpu.windows.flatfat import FlatFAT
+from windflow_tpu.windows.session_tpu import SessionWindowsTPU
 from windflow_tpu.windows.ops import (KeyedWindows, MapReduceWindows,
                                       PanedWindows, ParallelWindows,
                                       WindowResult)
@@ -88,6 +90,7 @@ __all__ = [
     "FlatFAT", "Keyed_Windows_Builder", "Parallel_Windows_Builder",
     "Paned_Windows_Builder", "MapReduce_Windows_Builder",
     "Ffat_Windows_Builder", "Ffat_WindowsTPU_Builder",
+    "SessionWindowsTPU", "Session_WindowsTPU_Builder",
     "DBHandle", "LogKV", "PMap", "PFilter", "PFlatMap", "PReduce", "PSink",
     "PKeyedWindows", "P_Map_Builder", "P_Filter_Builder",
     "P_FlatMap_Builder", "P_Reduce_Builder", "P_Sink_Builder",

@@ -323,6 +323,10 @@ def rebucket_blob(op, blob: dict, old_p: int, new_p: int,
         return _rebucket_stateful(op, blob, new_kk)
     if kind == "reduce_tpu":
         return blob     # drop counters + remap: shard-shape independent
+    if kind == "session_tpu":
+        # one replica, no mesh (the operator refuses both at build): its
+        # dense table has no shard shape to change
+        return blob
     raise RescaleError(
         op.name,
         f"state of kind {kind!r} has no re-bucketing rule (the operator "

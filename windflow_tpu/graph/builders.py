@@ -479,6 +479,7 @@ from windflow_tpu.windows.ops import (KeyedWindows, MapReduceWindows,  # noqa: E
                                       PanedWindows, ParallelWindows)
 from windflow_tpu.windows.ffat_op import FfatWindows  # noqa: E402
 from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU  # noqa: E402
+from windflow_tpu.windows.session_tpu import SessionWindowsTPU  # noqa: E402
 
 
 class _WindowBuilderBase(_BuilderBase):
@@ -721,3 +722,55 @@ class Ffat_WindowsTPU_Builder(_WindowBuilderBase):
             pane_capacity=self._pane_capacity,
             overflow_policy=self._overflow_policy,
             monoid=self._monoid)
+
+
+class Session_WindowsTPU_Builder(_BuilderBase):
+    """Session windows per key on the device
+    (:class:`~windflow_tpu.windows.session_tpu.SessionWindowsTPU`): a
+    window's boundaries come from the data, a maximal run of a key's
+    tuples each less than the gap after the last.  ``lift`` maps a record
+    to an aggregate, ``comb`` folds two (associative; applied to whole
+    lanes, as the FFAT combiners are)."""
+
+    _default_name = "session_windows_tpu"
+
+    def __init__(self, lift_fn, comb_fn):
+        super().__init__()
+        self._lift = lift_fn
+        self._comb = comb_fn
+        self._gap = None
+        self._max_keys = 1
+        self._lateness = 0
+
+    def withRebalancing(self):
+        raise WindFlowError(
+            "window operators route by key / broadcast; REBALANCING does "
+            "not apply")
+
+    def withGap(self, gap_usec: int):
+        """The session gap in event-time microseconds: a tuple less than
+        this after the previous one of its key extends the session, one
+        exactly this much later starts the next."""
+        self._gap = int(gap_usec)
+        return self
+
+    def withMaxKeys(self, n: int):
+        """Size of the dense device key space [0, n); keys outside it are
+        masked invalid."""
+        self._max_keys = int(n)
+        return self
+
+    def withLateness(self, lateness_usec: int):
+        """Sessions close ``lateness_usec`` after the watermark passes
+        their end, and a tuple is late once it is older than the
+        watermark by more than this."""
+        self._lateness = int(lateness_usec)
+        return self
+
+    def build(self) -> SessionWindowsTPU:
+        if self._gap is None:
+            raise WindFlowError("session windows need withGap(usec)")
+        return SessionWindowsTPU(
+            self._lift, self._comb, self._gap, max_keys=self._max_keys,
+            name=self._name, parallelism=self._parallelism,
+            key_extractor=self._key_extractor, lateness=self._lateness)

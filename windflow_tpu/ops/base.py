@@ -272,6 +272,10 @@ class Operator:
     #: ``multipipe.hpp:441-444`` rejects bad GPU predecessors at build).
     #: The value is the label used in the error message.
     fixed_capacity_label = None
+    #: True on device operators whose output batch is sized by what a
+    #: step can fire or close rather than by its input: their
+    #: ``wf.dispatch`` span says ``out_cap`` even where the two agree
+    notes_out_cap = False
     #: whole-chain fusion (windflow_tpu/fusion): non-None on the MEMBER
     #: operators of a fused segment — the name of the fused hop their
     #: execution folded into.  Member replicas are inert (wired with no

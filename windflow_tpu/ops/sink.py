@@ -68,7 +68,7 @@ class SinkReplica(Replica):
             return
         from windflow_tpu.batch import device_to_host
         with flightrec.span("wf.sink.d2h", batch=batch.seq, batches=1,
-                            bytes=nbytes):
+                            bytes=nbytes, lanes=batch.capacity):
             hb = device_to_host(batch)
         with flightrec.span("wf.sink.deliver", batch=batch.seq,
                             rows=len(hb.items)):
@@ -80,9 +80,11 @@ class SinkReplica(Replica):
         from windflow_tpu.batch import device_to_columns_multi
         nbytes, self._pending_bytes = self._pending_bytes, 0
         # one transfer for the whole queue: the span carries its first
-        # batch's number and how many ride with it
+        # batch's number, how many ride with it and the lanes they hold
+        # (rows and padding alike: the copy moves whole batches)
         with flightrec.span("wf.sink.d2h", batch=batches[0].seq,
-                            batches=len(batches), bytes=nbytes):
+                            batches=len(batches), bytes=nbytes,
+                            lanes=sum(b.capacity for b in batches)):
             columns = device_to_columns_multi(batches)
         for b, (cols, tss) in zip(batches, columns):
             if len(tss):

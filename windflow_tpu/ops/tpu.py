@@ -66,7 +66,8 @@ class _TPUReplica(Replica):
             counts["mesh"] = self.op.mesh.size
         with flightrec.span("wf.dispatch", **counts) as sp:
             out = self._op_step(batch)
-            if out is not None and out.capacity != batch.capacity:
+            if out is not None and (out.capacity != batch.capacity
+                                    or self.op.notes_out_cap):
                 # a window step hands on a batch sized by what it can
                 # fire, not by what it was given
                 sp.note(out_cap=out.capacity)
