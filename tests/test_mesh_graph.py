@@ -71,6 +71,125 @@ def test_ffat_tpu_cb_on_mesh():
     assert op._states[0]["cur"].sharding.spec == P(KEY_AXIS)
 
 
+def _run_cb_on_mesh(records, batch=64, n_keys=8):
+    """The count-window sum of ``records`` on the (data=2, key=4) mesh:
+    rows ``{(key, wid): value}``, the operator and the graph."""
+    got = {}
+    src = (wf.Source_Builder(lambda: iter(records))
+           .withOutputBatchSize(batch).build())
+    op = (wf.Ffat_WindowsTPU_Builder(lambda t: t["value"],
+                                     lambda a, b: a + b)
+          .withName("cb").withCBWindows(WIN, SLIDE)
+          .withKeyBy(lambda t: t["key"]).withMaxKeys(n_keys).build())
+    snk = wf.Sink_Builder(
+        lambda r: got.__setitem__((r["key"], r["wid"]), r["value"])
+        if r is not None else None).build()
+    # no punctuation cuts a batch short: the counts below are per batch
+    g = wf.PipeGraph("ffat_mesh_own", wf.ExecutionMode.DEFAULT,
+                     config=dataclasses.replace(
+                         _mesh_cfg(), punctuation_interval_usec=10 ** 12))
+    g.add_source(src).add(wf.MapTPU_Builder(lambda t: t).build()) \
+        .add(op).add_sink(snk)
+    g.run()
+    return got, op, g
+
+
+def _cb_oracle(records):
+    per_key, exp = {}, {}
+    for t in records:
+        per_key.setdefault(t["key"], []).append(t["value"])
+    for k, vals in per_key.items():
+        w = 0
+        while w * SLIDE < len(vals):
+            exp[(k, w)] = sum(vals[w * SLIDE: w * SLIDE + WIN])
+            w += 1
+    return exp
+
+
+@pytest.mark.parametrize("case,wide", [("uniform", 0), ("one_shard", 6)])
+def test_cb_on_mesh_counts_its_many_round_steps(monkeypatch, case, wide):
+    """A key shard's count-window step runs over the lanes it owns, a
+    quarter of a 64-tuple batch on four key shards (``CB_step_lanes``
+    16, ``step_cap`` on its ``wf.dispatch``); a batch whose keys all sit
+    on one shard takes that shard four rounds, a step counted in
+    ``CB_wide_steps`` (six full batches here), with the oracle's rows
+    either way."""
+    from windflow_tpu.monitoring.openmetrics import (parse_exposition,
+                                                     render_openmetrics)
+    import windflow_tpu.ops.tpu as tpu_mod
+    notes, real = [], tpu_mod.flightrec.span
+
+    class Spy:
+        def __init__(self, name, kw):
+            self.kw, self.inner = dict(kw, name=name), real(name, **kw)
+
+        def __enter__(self):
+            self.sp = self.inner.__enter__()
+            return self
+
+        def note(self, **kw):
+            self.kw.update(kw)
+            return self.sp.note(**kw)
+
+        def __exit__(self, *a):
+            notes.append(self.kw)
+            return self.inner.__exit__(*a)
+
+    monkeypatch.setattr(tpu_mod.flightrec, "span",
+                        lambda name, **kw: Spy(name, kw))
+    keys = (lambda i: i % 8) if case == "uniform" else (lambda i: i % 2)
+    records = [{"key": keys(i), "value": i} for i in range(LENGTH)]
+    got, op, g = _run_cb_on_mesh(records)
+    assert got == _cb_oracle(records)
+    st = next(o for o in g.stats()["Operators"]
+              if o["Operator_name"] == "cb")
+    assert st["CB_step_lanes"] == 16 and st["CB_wide_steps"] == wide
+    assert op._states[0]["n_wide"].sharding.spec == P(KEY_AXIS)
+    caps = [kw["step_cap"] for kw in notes
+            if kw["name"] == "wf.dispatch" and kw.get("op") == "cb"]
+    assert caps and set(caps) == {16}
+    assert not [kw for kw in notes if kw.get("op") != "cb"
+                and "step_cap" in kw]
+    fams = parse_exposition(render_openmetrics(g.stats()))
+    for fam, value in (("wf_operator_cb_wide_steps_total", wide),
+                       ("wf_operator_cb_step_lanes", 16)):
+        assert [(s[1]["operator"], s[2]) for s in fams[fam]["samples"]] \
+            == [("cb", value)]
+
+
+@pytest.mark.parametrize("blob_has_it", [True, False])
+def test_cb_many_round_count_survives_snapshot_and_restore(blob_has_it):
+    """The counter lanes ride the checkpoint blob; a blob from before
+    them (or from one chip) restores as 0, and a re-bucketing onto
+    another key axis keeps the total."""
+    from windflow_tpu.durability import rebucket
+    records = [{"key": i % 2, "value": i} for i in range(LENGTH)]
+    _, op, _ = _run_cb_on_mesh(records)
+    assert op.dump_stats()["CB_wide_steps"] == 6
+    blob = op.snapshot_state()
+    assert blob["states"][0]["n_wide"].tolist() == [6, 0, 0, 0]
+    if not blob_has_it:
+        del blob["states"][0]["n_wide"]
+    fresh = (wf.Ffat_WindowsTPU_Builder(lambda t: t["value"],
+                                        lambda a, b: a + b)
+             .withName("cb").withCBWindows(WIN, SLIDE)
+             .withKeyBy(lambda t: t["key"]).withMaxKeys(8).build())
+    fresh.config, fresh.mesh = op.config, op.mesh
+    fresh.restore_state(blob)
+    assert fresh._states[0]["n_wide"].sharding.spec == P(KEY_AXIS)
+    assert fresh.dump_stats()["CB_wide_steps"] == (6 if blob_has_it else 0)
+    assert fresh.dump_stats()["CB_step_lanes"] == 16
+    # four key shards -> two: the total in lane 0; off the mesh: gone
+    old, two = {"data": 2, "key": 4}, {"data": 4, "key": 2}
+    st2 = rebucket.rebucket_blob(op, blob, 1, 1, old, two)["states"][0]
+    st1 = rebucket.rebucket_blob(op, blob, 1, 1, old, None)["states"][0]
+    assert "n_wide" not in st1
+    if blob_has_it:
+        assert st2["n_wide"].tolist() == [6, 0]
+    else:
+        assert "n_wide" not in st2
+
+
 @pytest.mark.parametrize("form,batch", [("generic", 64), ("narrow", 8),
                                         ("wide", 64)])
 def test_ffat_tpu_tb_on_mesh(monkeypatch, form, batch):

@@ -50,6 +50,10 @@ TB_SCALARS = ("base", "win_next", "max_seen", "n_late", "n_evicted",
 TB_ALIGNED = ("base", "win_next")
 #: ... the ones that sum (a blob from before ``n_wide`` lacks it)
 TB_COUNTERS = ("n_late", "n_evicted", "n_win_dropped", "n_wide")
+#: the one shard-shaped lane of a count-based state, on a mesh alone: its
+#: many-round steps, one lane a key shard (parallel/mesh.CB_WIDE_STEPS;
+#: a blob from before it, or from one chip, lacks it and restores as 0)
+CB_COUNTER = "n_wide"
 
 
 class RescaleError(WindFlowError):
@@ -167,10 +171,11 @@ def _tree_map(fn, tree):
 
 def _rebucket_ffat(op, blob, old_p: int, new_p: int,
                    old_kk: int, new_kk: int,
-                   override: Optional[dict]) -> dict:
+                   override: Optional[dict], on_mesh: bool) -> dict:
     """FFAT pane rings.  CB state is purely per-key (one shared table,
-    per-key clock lanes) — shape-independent; only the mesh key-axis
-    divisibility needs a check.  TB state carries ring clocks: one
+    per-key clock lanes) — shape-independent but for the mesh step's
+    counter lanes (``CB_COUNTER``); only the mesh key-axis divisibility
+    needs a check.  TB state carries ring clocks: one
     scalar lane per mesh key shard, or one full state per replica when
     keyed at parallelism > 1 — both re-bucket only when the clocks
     agree at the barrier (see :class:`RescaleError`)."""
@@ -185,8 +190,15 @@ def _rebucket_ffat(op, blob, old_p: int, new_p: int,
     old_per_rep = is_tb and op.key_extractor is not None and old_p > 1
     new_per_rep = is_tb and op.key_extractor is not None and new_p > 1
 
+    if not is_tb:
+        # per-key state only, but for the mesh step's counter lanes: their
+        # sum moves to lane 0 of the new key axis (off a mesh it goes)
+        out = dict(blob)
+        out["states"] = {s: _relane_cb_counter(st, new_kk, on_mesh)
+                         for s, st in states.items()}
+        return out
     if not old_per_rep and not new_per_rep:
-        if not is_tb or old_kk == new_kk or not states:
+        if old_kk == new_kk or not states:
             return blob     # per-key state only: nothing shard-local
         # TB scalar lanes re-shaped old_kk -> new_kk (1 == single chip)
         st = dict(states[0])
@@ -267,6 +279,20 @@ def _rebucket_ffat(op, blob, old_p: int, new_p: int,
     return out
 
 
+def _relane_cb_counter(st: dict, new_kk: int, on_mesh: bool) -> dict:
+    """A count-based state with its mesh counter lanes re-shaped for the
+    new key axis: the total in lane 0, or gone off a mesh."""
+    if CB_COUNTER not in st:
+        return st
+    st = dict(st)
+    old = _tb_scalar(st.pop(CB_COUNTER))
+    if on_mesh:
+        lanes = np.zeros((new_kk,), old.dtype)
+        lanes[0] = old.sum()
+        st[CB_COUNTER] = lanes
+    return st
+
+
 def _gather_rows(live, o_old, rows_j, name, leaf, template):
     """One per-key leaf gathered row-wise from the old owner states.
     ``leaf`` is the template's leaf; matching leaves in every old state
@@ -318,7 +344,7 @@ def rebucket_blob(op, blob: dict, old_p: int, new_p: int,
         return _rebucket_reduce_host(op, blob, new_p, override)
     if kind == "ffat_tpu":
         return _rebucket_ffat(op, blob, old_p, new_p, old_kk, new_kk,
-                              override)
+                              override, new_mesh is not None)
     if kind == "stateful_tpu":
         return _rebucket_stateful(op, blob, new_kk)
     if kind == "reduce_tpu":

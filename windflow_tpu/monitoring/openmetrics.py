@@ -186,8 +186,19 @@ def _families(stats: dict,
                  "Steps of a scatter-placed time window whose batch "
                  "spanned more panes than a narrow placement holds and "
                  "scattered into the whole ring")
+    f_lanes = fam("wf_operator_cb_step_lanes", "gauge",
+                  "Lanes the count-window step of one key shard of a mesh "
+                  "is built at: its share of the staged batch")
+    f_whole = fam("wf_operator_cb_wide_steps_total", "counter",
+                  "Steps of a key-sharded count window in which a shard "
+                  "owned more lanes than its share of the batch and took "
+                  "more than one round over them, summed over the shards")
     for op in ops:
         name = op.get("Operator_name") or op.get("Name") or "?"
+        if "CB_step_lanes" in op:
+            f_lanes.add(op["CB_step_lanes"], dict(base, operator=name))
+            f_whole.add(op.get("CB_wide_steps", 0),
+                        dict(base, operator=name))
         if op.get("TB_placement"):
             for form in ("dense", "scatter"):
                 f_place.add(1 if op["TB_placement"] == form else 0,

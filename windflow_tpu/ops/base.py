@@ -276,6 +276,10 @@ class Operator:
     #: step can fire or close rather than by its input: their
     #: ``wf.dispatch`` span says ``out_cap`` even where the two agree
     notes_out_cap = False
+    #: lanes the compiled step of ONE key shard runs over, where a mesh
+    #: step compacts the batch to the lanes a shard owns (set when the
+    #: step is built): ``step_cap`` on the ``wf.dispatch`` span
+    step_cap = None
     #: whole-chain fusion (windflow_tpu/fusion): non-None on the MEMBER
     #: operators of a fused segment — the name of the fused hop their
     #: execution folded into.  Member replicas are inert (wired with no

@@ -71,6 +71,8 @@ class _TPUReplica(Replica):
                 # a window step hands on a batch sized by what it can
                 # fire, not by what it was given
                 sp.note(out_cap=out.capacity)
+            if self.op.step_cap is not None:
+                sp.note(step_cap=self.op.step_cap)
         self.stats.device_programs_launched += 1
         if self.ring is not None and batch.trace is not None:
             # `dispatched` stamps the ASYNC enqueue (the host is already

@@ -322,7 +322,8 @@ class FfatWindowsTPU(Operator):
             # Multi-chip: key-sharded state, data-sharded batches riding an
             # all_gather over ICI (parallel/mesh.py make_sharded_ffat_step).
             # Config.mesh is how the graph API reaches the sharded kernels.
-            from windflow_tpu.parallel.mesh import (make_sharded_ffat_step,
+            from windflow_tpu.parallel.mesh import (ffat_owned_lanes,
+                                                    make_sharded_ffat_step,
                                                     make_sharded_ffat_tb_step)
             # multi-process graphs stage batches fully sharded over
             # (data, key) — the only layout each process can assemble from
@@ -343,6 +344,9 @@ class FfatWindowsTPU(Operator):
                     drop_tainted=self.overflow_policy == "drop",
                     grouping=self._grouping(), ingest=ingest,
                     monoid=self.monoid, op_name=f"{self.name}.mesh"))
+            # lanes a key shard's step runs over (its share of the batch:
+            # mesh.py ffat_owned_lanes); `step_cap` on its wf.dispatch
+            self.step_cap = ffat_owned_lanes(self.mesh, capacity)
             return make_sharded_ffat_step(
                 self.mesh, capacity, self.max_keys, self.P, self.R, self.D,
                 self.lift, self.comb, self.key_extractor,
@@ -926,6 +930,12 @@ class FfatWindowsTPU(Operator):
             # a checkpoint from before the step counted its wide placements
             if "n_late" in st:
                 st.setdefault("n_wide", jnp.zeros_like(st["n_late"]))
+            elif self.mesh is not None:
+                # ... or, count-based on a mesh, its many-round steps
+                from windflow_tpu.parallel.mesh import (CB_WIDE_STEPS,
+                                                        KEY_AXIS)
+                st.setdefault(CB_WIDE_STEPS, place(jnp.zeros(
+                    (self.mesh.shape[KEY_AXIS],), jnp.int64)))
         if blob["payload_zero"] is not None:
             self._payload_zero = jax.tree.map(jnp.asarray,
                                               blob["payload_zero"])
@@ -1010,6 +1020,13 @@ class FfatWindowsTPU(Operator):
             # steps whose batch spanned more than NARROW_PLACE_PANES
             # panes and scattered into the whole ring (0 on "dense")
             st["TB_wide_placements"] = self._tb_counter("n_wide")
+        if self.step_cap is not None and self._states:
+            # count-based on a mesh: the lanes a key shard's step is built
+            # at (static), and the steps that took more than one round
+            # because a shard owned more (parallel/mesh.py)
+            from windflow_tpu.parallel.mesh import CB_WIDE_STEPS
+            st["CB_step_lanes"] = self.step_cap
+            st["CB_wide_steps"] = self._tb_counter(CB_WIDE_STEPS)
         return st
 
     def _build_flush(self):
