@@ -1273,7 +1273,7 @@ def propagate_specs(graph, ops=None, edges=None, upstreams=None,
             return spec
         if isinstance(op, ChainedTPU):
             cur = spec
-            for kind, fn in op.specs:
+            for kind, fn, _ in op.specs:
                 if cur is _UNKNOWN:
                     return _UNKNOWN
                 if kind == "map":

@@ -1369,7 +1369,7 @@ def _graph_callables(graph):
     for op in graph._topo_operators():
         is_tpu = getattr(op, "is_tpu", False)
         if isinstance(op, (ChainedTPU, ChainedHost)):
-            for kind, fn in op.specs:
+            for kind, fn, *_ in op.specs:
                 got = one(fn, op.name, f"{kind} stage", is_tpu)
                 if got:
                     yield got

@@ -263,6 +263,7 @@ def unpack_body(dtypes, capacity: int, wire=None):
         from windflow_tpu.wire import build_wire_decode
         decode = build_wire_decode(wire, dtypes, capacity)
 
+        @flightrec.phase("wf.unpack")
         def unpack_fn(b):
             cols = decode(b)
             n_valid = b[-1].astype(jnp.int32)
@@ -270,6 +271,7 @@ def unpack_body(dtypes, capacity: int, wire=None):
                 jnp.arange(capacity, dtype=jnp.int32) < n_valid, \
                 n_valid
     else:
+        @flightrec.phase("wf.unpack")
         def unpack_fn(b):
             cols, off = [], 0
             for dt in dtypes + ("int64",):
@@ -559,6 +561,7 @@ def _egress_pack(batch: DeviceBatch, leaves, treedef, cap):
                 return [lo, hi]
             return [jax.lax.bitcast_convert_type(l, jnp.uint32)]
 
+        @flightrec.phase("wf.egress.pack")
         def pack_fn(lvs, ts, vld):
             parts = []
             for l in lvs:

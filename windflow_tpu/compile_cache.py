@@ -27,7 +27,13 @@ def setup_compile_cache() -> str:
     # Python tracebacks as locations differ on every re-trace, so each
     # new graph's kernel-bearing programs would compile again (13-52 s
     # apiece on a v5e host); one frame per location keeps the key stable.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # It stays a traceback, of ONE frame, and not a bare file location:
+    # with jax_include_full_tracebacks_in_locations off, this jax (0.9.0)
+    # names an operation by its primitive alone (op_name "gather") and
+    # the name stack, jit(step)/wf.op.<operator>/wf.<phase>, never
+    # reaches the HLO: a profiler capture could not tell the program's
+    # device phases apart (monitoring/recorder.py; PERF.md, PR 34).
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 from windflow_tpu.basic import RoutingMode
 from windflow_tpu.batch import DeviceBatch
 from windflow_tpu.monitoring.jit_registry import wf_jit
+from windflow_tpu.monitoring.recorder import operator_scope, phase
 
 
 def fused_name(members) -> str:
@@ -129,16 +130,20 @@ def build_prelude(members):
     specs = []
     for op in members:
         specs.extend(_tpu_specs(op))
-    has_filter = any(kind == "filter" for kind, _ in specs)
+    has_filter = any(kind == "filter" for kind, _, _ in specs)
 
     def prelude(payload, valid):
-        for kind, fn in specs:
-            if kind == "map":
-                payload = jax.vmap(fn)(payload)
-            elif kind == "batch_map":
-                payload = fn(payload, valid)
-            else:
-                valid = valid & jax.vmap(fn)(payload)
+        # each member's part of the one program under its own name
+        # (device phases, monitoring/recorder.py): a tail opens its own
+        # operator scope AFTER this call, never around it
+        for kind, fn, owner in specs:
+            with operator_scope(owner), phase("wf.fn"):
+                if kind == "map":
+                    payload = jax.vmap(fn)(payload)
+                elif kind == "batch_map":
+                    payload = fn(payload, valid)
+                else:
+                    valid = valid & jax.vmap(fn)(payload)
         return payload, valid
 
     return prelude, has_filter
@@ -248,8 +253,12 @@ class FusedStatelessExec:
 
         def raw(payload, valid):
             payload, valid = prelude(payload, valid)
-            keys = (jax.vmap(kx)(payload).astype(jnp.int32)
-                    if kx is not None else None)
+            keys = None
+            if kx is not None:
+                # the downstream consumer's extractor: no operator of
+                # this chain
+                with phase("wf.fn"):
+                    keys = jax.vmap(kx)(payload).astype(jnp.int32)
             return payload, valid, keys
 
         # the donation aliasing probe always evaluates the sketch-free

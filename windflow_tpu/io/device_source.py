@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from windflow_tpu.basic import RoutingMode, TimePolicy, WindFlowError, \
     current_time_usecs
 from windflow_tpu.batch import DeviceBatch
+from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.monitoring.jit_registry import wf_jit
 from windflow_tpu.ops.base import Operator
 from windflow_tpu.ops.source import BaseSourceReplica, Source
@@ -63,6 +64,8 @@ class DeviceSourceReplica(BaseSourceReplica):
                 "the EVENT time policy (INGRESS stamps arrival time itself)")
         cap = self.op.capacity
 
+        @flightrec.operator_scope(self.op.name)
+        @flightrec.phase("wf.fn")
         def program(i, base_ts):
             payload = self.op.batch_fn(i)
             ts = (self.op.ts_fn(i).astype(jnp.int64)

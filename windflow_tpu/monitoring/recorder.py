@@ -53,6 +53,18 @@ time.  This module adds the missing batch-granular layer:
   recorder's batch sequence number (a sampled batch's trace id is that
   number).
 
+* **Device phases.**  :func:`phase` and :func:`operator_scope` are to the
+  device's ``XLA Ops`` line what :func:`span` is to the host plane: a
+  ``jax.named_scope`` opened while a program is TRACED (once per
+  compile, never per batch), so every operation written under it, the
+  fusion XLA builds around it and everything inside a ``while`` /
+  ``cond`` body opened under it carries ``wf.op.<operator>/wf.<phase>``
+  in its HLO ``op_name``, which a profiler capture shows as the event's
+  ``tf_op``.  It is metadata only: no operation is added and nothing is
+  paid with the profiler off.  :data:`PHASES` is the one place the names
+  are declared (docs/OBSERVABILITY.md "Device phases";
+  ``benchmark/device_phases.py`` reads them).
+
 When ``Config.flight_recorder`` is off, ``PipeGraph`` binds no recorder at
 all: replicas hold ``ring = None`` and emitters ``flight = None``, no root
 span is ever opened, and the hot path's only residue is an ``is None``
@@ -61,8 +73,10 @@ check per site.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
+import re
 import threading
 import time
 from typing import Dict, List, Optional
@@ -326,6 +340,120 @@ def span(name: str, **counts):
     if top is None:
         return _NO_SPAN
     return _Span(top.table, name, counts, top)
+
+
+# ---------------------------------------------------------------------------
+# Device phases: the program's own names on the device's ``XLA Ops`` line
+# ---------------------------------------------------------------------------
+
+#: the one vocabulary of device phases: name -> (layer, as BENCHMARK.json
+#: names it; what the phase covers).  :func:`phase` refuses any other
+#: name; docs/OBSERVABILITY.md "Device phases" carries the same table.
+PHASES: Dict[str, tuple] = {
+    "wf.unpack": (
+        "unpack program (+ wire decode)",
+        "re-typing a packed staging buffer into lanes, wire decode "
+        "included (staging.unpack, and the same decode in a megastep)"),
+    "wf.fn": (
+        "fused operator program",
+        "the user's functions over a batch: map / filter bodies, key "
+        "extractors, window lifts"),
+    "wf.group": (
+        "fused operator program",
+        "bringing a batch into key order: the grouping permutation "
+        "(counting sort, sort or the Pallas kernel) and the gathers by it"),
+    "wf.place": (
+        "fused operator program",
+        "folding a batch into pane cells and merging them into the "
+        "window state: contraction, scatters, the segmented scan of an "
+        "undeclared combiner"),
+    "wf.ring": (
+        "fused operator program",
+        "upkeep of the window state whether anything fires or not: "
+        "rolling the pane ring, eviction, the carried panes of a count "
+        "window"),
+    "wf.fire": (
+        "fused operator program",
+        "the sliding fold over the panes, picking and compacting the "
+        "fired rows, the end-of-stream flush"),
+    "wf.reduce": (
+        "fused operator program",
+        "a keyed reduce's fold of a batch and its merge into the table"),
+    "wf.state": (
+        "fused operator program",
+        "a stateful map / filter: resolving slots and the per-key "
+        "in-order body over the state table"),
+    "wf.session.sort": (
+        "fused operator program",
+        "a session step's sort of its lanes by (key, event time)"),
+    "wf.session.scan": (
+        "fused operator program",
+        "cutting the sorted lanes into runs and folding them"),
+    "wf.session.carry": (
+        "fused operator program",
+        "carrying each key's runs into the key domain (index scatter, "
+        "gathers) and merging them with the open sessions"),
+    "wf.session.close": (
+        "fused operator program",
+        "closing what the watermark passed and compacting the rows to "
+        "the front of the output batch"),
+    "wf.mesh.own": (
+        "mesh collectives (ICI)",
+        "a key shard counting the lanes it owns and moving them to the "
+        "front"),
+    "wf.mesh.exchange": (
+        "mesh collectives (ICI)",
+        "all_gather / psum / all_to_all between the chips of a mesh"),
+    "wf.shard.sketch": (
+        "mesh collectives (ICI)",
+        "the shard plane's key sketch (count-min rows, shard counts)"),
+    "wf.egress.pack": (
+        "egress / sink",
+        "packing an output batch into the one buffer the sink copies"),
+}
+#: ``wf.op.<operator>``: who, where a phase says what
+OP_SCOPE = "wf.op."
+
+# what the tracing thread has open: a program is traced by one thread,
+# and ``lax.cond`` / ``while_loop`` / ``vmap`` trace their bodies inside
+# the ``with`` that calls them
+_traced = threading.local()
+
+
+@contextlib.contextmanager
+def _scope(kind: str, name: str):
+    inside = getattr(_traced, kind, None)
+    if inside is not None:
+        raise ValueError(
+            f"device scope {name!r} opened inside {inside!r}: an op_name "
+            "holds one operator and, innermost, one phase")
+    setattr(_traced, kind, name)
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        setattr(_traced, kind, None)
+
+
+def phase(name: str):
+    """Device phase ``name`` (one of :data:`PHASES`) around the code that
+    writes its operations, as a context manager or a decorator.  Entered
+    while a program is traced; phases do not nest in each other."""
+    if name not in PHASES:
+        raise ValueError(f"{name!r} is not a device phase: declare it in "
+                         "recorder.PHASES (and docs/OBSERVABILITY.md)")
+    return _scope("phase", name)
+
+
+def operator_scope(op_name: str):
+    """``wf.op.<operator>`` around an operator's part of a device
+    program, so that a fused program's device time reads per operator.
+    Opened outside the phases, once per operator on a path."""
+    if getattr(_traced, "phase", None) is not None:
+        raise ValueError(f"operator scope {op_name!r} opened inside the "
+                         f"phase {_traced.phase!r}")
+    return _scope("op", OP_SCOPE + re.sub(r"[^A-Za-z0-9_.\-]", "_",
+                                          op_name))
 
 
 class FlightRecorder:

@@ -176,6 +176,13 @@ def device_sketch_update(state, keys, valid, n_shards: int, dest=None):
     ``dest`` is the per-lane destination the keyby split already
     computed (invalid lanes == ``n_shards``); ``None`` derives it from
     the same splitmix placement the emitters use."""
+    # not at module scope: the recorder pulls jax
+    from windflow_tpu.monitoring.recorder import phase
+    with phase("wf.shard.sketch"):
+        return _sketch_update(state, keys, valid, n_shards, dest)
+
+
+def _sketch_update(state, keys, valid, n_shards: int, dest):
     import jax
     import jax.numpy as jnp
     from windflow_tpu.parallel.emitters import _splitmix64_dev

@@ -121,12 +121,12 @@ class ChainedHost(Operator):
 def _tpu_specs(op):
     if isinstance(op, ChainedTPU):
         return op.specs
+    # (kind, fn, the operator it came from: its wf.op.<name> scope in
+    # the chain's one program)
     if isinstance(op, MapTPU):
-        if op.batch_fn:
-            return [("batch_map", op.fn)]
-        return [("map", op.fn)]
+        return [("batch_map" if op.batch_fn else "map", op.fn, op.name)]
     if isinstance(op, FilterTPU):
-        return [("filter", op.fn)]
+        return [("filter", op.fn, op.name)]
     raise WindFlowError(f"cannot chain TPU operator type {type(op).__name__}")
 
 

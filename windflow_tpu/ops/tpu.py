@@ -136,9 +136,10 @@ class MapTPU(Operator):
         self.batch_fn = batch_fn
 
         def step(payload, valid):
-            if self.batch_fn:
-                return self.fn(payload, valid)
-            return jax.vmap(self.fn)(payload)
+            with flightrec.operator_scope(name), flightrec.phase("wf.fn"):
+                if self.batch_fn:
+                    return self.fn(payload, valid)
+                return jax.vmap(self.fn)(payload)
 
         self._jit_step = wf_jit(step, op_name=name)
 
@@ -174,8 +175,8 @@ class FilterTPU(Operator):
         self.fn = fn
 
         def step(payload, valid):
-            keep = jax.vmap(self.fn)(payload)
-            return valid & keep
+            with flightrec.operator_scope(name), flightrec.phase("wf.fn"):
+                return valid & jax.vmap(self.fn)(payload)
 
         self._jit_step = wf_jit(step, op_name=name)
 
@@ -348,14 +349,21 @@ class ReduceTPU(Operator):
                     # output, below, in-program.
                     payload, valid = prelude(payload, valid)
                     keys = None
+                return reduce(keys, payload, ts, valid)
+
+            @flightrec.operator_scope(self.name)
+            def reduce(keys, payload, ts, valid):
                 if keys is None:
                     if key_fn is not None:
-                        keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+                        with flightrec.phase("wf.fn"):
+                            keys = jax.vmap(key_fn)(payload) \
+                                .astype(jnp.int32)
                     else:
                         # Non-keyed: one global segment (thrust::reduce path).
                         keys = jnp.zeros(capacity, dtype=jnp.int32)
-                return _segmented_reduce(keys, payload, ts, valid, comb,
-                                         capacity)
+                with flightrec.phase("wf.reduce"):
+                    return _segmented_reduce(keys, payload, ts, valid, comb,
+                                             capacity)
 
             # staged-fed fused chain: the sorted reduce's outputs are
             # capacity-shaped like its inputs, so donating the (provably
@@ -415,10 +423,19 @@ class ReduceTPU(Operator):
                     # and keys re-extract from its output
                     payload, valid = prelude(payload, valid)
                     keys = None
+                return reduce(keys, payload, ts, valid)
+
+            @flightrec.operator_scope(self.name)
+            def reduce(keys, payload, ts, valid):
                 if keys is None:
-                    keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
-                        if key_fn is not None \
-                        else jnp.zeros(capacity, jnp.int32)
+                    with flightrec.phase("wf.fn"):
+                        keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
+                            if key_fn is not None \
+                            else jnp.zeros(capacity, jnp.int32)
+                with flightrec.phase("wf.reduce"):
+                    return tables(keys, payload, ts, valid)
+
+            def tables(keys, payload, ts, valid):
                 in_range = (keys >= 0) & (keys < K)
                 ok = valid & in_range
                 n_drop = jnp.sum(valid & ~in_range, dtype=jnp.int64)
@@ -474,7 +491,7 @@ class ReduceTPU(Operator):
                 self.max_keys if bounded else self._compactor.slots,
                 self.monoid, self.comb, self.key_extractor,
                 self._fused_prelude, bounded,
-                pallas=resolve_pallas_for(self))
+                pallas=resolve_pallas_for(self), owner=self.name)
             # the donated operand is the cstats state (last arg); the
             # remap tables are read-only operands shared across steps
             donate = (4,) if bounded else (6,)
@@ -499,7 +516,7 @@ class ReduceTPU(Operator):
                 # remains the faster dense/psum variant for bounded keys.
                 step = make_sharded_reduce_arbitrary(
                     self.mesh, capacity, self.comb, self.key_extractor,
-                    op_name=f"{self.name}.mesh",
+                    op_name=f"{self.name}.mesh", owner=self.name,
                     # key compaction (parallel/compaction.py): the remap
                     # overrides the owner hash per slot — hot keys
                     # balanced over chips; built before the first batch,
@@ -514,7 +531,7 @@ class ReduceTPU(Operator):
                     self.mesh, capacity, K, self.comb, self.key_extractor,
                     monoid=self.monoid,
                     ingest=getattr(self, "_ingest_mode", None) or "data",
-                    op_name=f"{self.name}.mesh")
+                    op_name=f"{self.name}.mesh", owner=self.name)
             self._jit_steps[("mesh", capacity)] = step
         return step
 

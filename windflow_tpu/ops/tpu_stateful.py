@@ -49,6 +49,7 @@ import numpy as np
 
 from windflow_tpu.basic import RoutingMode, WindFlowError
 from windflow_tpu.batch import DeviceBatch
+from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.monitoring.jit_registry import wf_jit
 from windflow_tpu.ops.base import Operator
 from windflow_tpu.ops.tpu import _TPUReplica, _bshape
@@ -306,7 +307,7 @@ class _StatefulTPUBase(Operator):
                 # arrive pre-placed on their slot-owner's column — no
                 # data-axis all_gather, no psum lane merge
                 ingest=getattr(self, "_ingest_mode", None) or "data",
-                op_name=f"{self.name}.mesh")
+                op_name=f"{self.name}.mesh", owner=self.name)
             # shard the state table along the key axis on first use
             self._state = jax.device_put(self._state,
                                          state_sharding(self.mesh))
@@ -338,11 +339,20 @@ class _StatefulTPUBase(Operator):
                         # PRE-chain records — re-extract from its output
                         payload, valid = prelude(payload, valid)
                         keys = None
+                    return on_slots(state, payload, valid, keys)
+
+                @flightrec.operator_scope(self.name)
+                def on_slots(state, payload, valid, keys):
                     if keys is None:
-                        keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
-                    ok = valid & (keys >= 0) & (keys < S)
-                    return body(state, payload, ok, keys)
+                        with flightrec.phase("wf.fn"):
+                            keys = jax.vmap(key_fn)(payload) \
+                                .astype(jnp.int32)
+                    with flightrec.phase("wf.state"):
+                        ok = valid & (keys >= 0) & (keys < S)
+                        return body(state, payload, ok, keys)
             else:
+                @flightrec.operator_scope(self.name)
+                @flightrec.phase("wf.state")
                 def step(state, payload, valid, keys, uniq_keys, uniq_slots):
                     pos = jnp.clip(jnp.searchsorted(uniq_keys, keys),
                                    0, capacity - 1)
@@ -365,13 +375,17 @@ class _StatefulTPUBase(Operator):
             body = self._body(capacity)
             key_fn = self.key_extractor
 
+            @flightrec.operator_scope(self.name)
             def step(state, payload, valid, keys, tk, tsl, cst):
                 if keys is None:
-                    keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
-                slots, hit = compaction.lookup_slots(tk, tsl, keys, valid)
-                cst = compaction.cstats_update(cst, keys, hit,
-                                               valid & ~hit)
-                st, out, ov = body(state, payload, hit, slots)
+                    with flightrec.phase("wf.fn"):
+                        keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+                with flightrec.phase("wf.state"):
+                    slots, hit = compaction.lookup_slots(tk, tsl, keys,
+                                                         valid)
+                    cst = compaction.cstats_update(cst, keys, hit,
+                                                   valid & ~hit)
+                    st, out, ov = body(state, payload, hit, slots)
                 return st, out, ov, cst
 
             step = wf_jit(step, op_name=self._fused_name or self.name,

@@ -210,8 +210,9 @@ def _dec64(c, dtype):
 
 def make_compacted_reduce(capacity: int, table_size: int, monoid: str,
                           comb, key_fn, prelude, bounded: bool,
-                          pallas=None):
-    """Build the compacted keyed-reduce program body.
+                          pallas=None, owner: str = "reduce"):
+    """Build the compacted keyed-reduce program body (``owner``: the
+    operator's name, its ``wf.op.<name>`` scope in a device trace).
 
     ``(keys, payload, ts, valid[, table_keys, table_slots], cstats) ->
     (out_payload, out_ts, out_valid, cstats')`` — remapped lanes
@@ -240,6 +241,7 @@ def make_compacted_reduce(capacity: int, table_size: int, monoid: str,
     import jax
     import jax.numpy as jnp
 
+    from windflow_tpu.monitoring.recorder import operator_scope, phase
     from windflow_tpu.ops.tpu import _bshape, _segmented_reduce
     from windflow_tpu.windows.ffat_kernels import (_monoid_identity,
                                                    _monoid_scatter)
@@ -249,20 +251,26 @@ def make_compacted_reduce(capacity: int, table_size: int, monoid: str,
     I64MIN = jnp.int64(np.iinfo(np.int64).min)
 
     def body(keys, payload, ts, valid, *rest):
-        if bounded:
-            (cst,) = rest
-            table_keys = table_slots = None
-        else:
-            table_keys, table_slots, cst = rest
         if prelude is not None:
             # whole-chain fusion: the stateless members run inside this
             # same program and keys re-extract from its output — the
             # remap operands thread through the fused program unchanged
             payload, valid = prelude(payload, valid)
             keys = None
-        if keys is None:
-            keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
-        keys = keys.astype(jnp.int32)
+        with operator_scope(owner):
+            if keys is None:
+                with phase("wf.fn"):
+                    keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+            with phase("wf.reduce"):
+                return fold(keys.astype(jnp.int32), payload, ts, valid,
+                            *rest)
+
+    def fold(keys, payload, ts, valid, *rest):
+        if bounded:
+            (cst,) = rest
+            table_keys = table_slots = None
+        else:
+            table_keys, table_slots, cst = rest
         if bounded:
             hit = valid & (keys >= 0) & (keys < T)
             slot = keys

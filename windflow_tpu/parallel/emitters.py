@@ -1497,7 +1497,8 @@ class DeviceKeyByEmitter(Emitter):
             def split(payload, ts, valid, keys, sk=None, tk=None,
                       tsl=None):
                 if keys is None:
-                    keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+                    with flightrec.phase("wf.fn"):
+                        keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
                 # splitmix64 placement, bit-identical to the host staging
                 # emitter's — a keyed operator fed by both a host edge and
                 # a device edge must see each key on ONE replica
@@ -1745,7 +1746,8 @@ class SplittingEmitter(Emitter):
             ok = False
         if ok:
             def compiled(payload, ts, valid):
-                idx = jax.vmap(split_fn)(payload).astype(jnp.int32)
+                with flightrec.phase("wf.fn"):
+                    idx = jax.vmap(split_fn)(payload).astype(jnp.int32)
                 dest = jnp.where(valid, idx, jnp.int32(n))
                 # mask-only split: every branch shares the same immutable
                 # buffers with its own validity mask (see DeviceKeyByEmitter)

@@ -44,6 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from windflow_tpu.basic import WindFlowError
 from windflow_tpu.batch import DeviceBatch, HostBatch, host_to_device
 from windflow_tpu.monitoring.jit_registry import wf_jit
+from windflow_tpu.monitoring.recorder import operator_scope, phase
 from windflow_tpu.windows.ffat_kernels import (_b, _masked_reduce_last,
                                            _monoid_identity, _seg_scan,
                                            make_ffat_flush,
@@ -195,6 +196,7 @@ def mark_aligned_ingest(graph) -> None:
 # BASELINE.json: "keyby-sharded Reduce … linear scaling to 8 chips").
 # ---------------------------------------------------------------------------
 
+@phase("wf.reduce")
 def _dense_keyed_partial(keys, vals, valid, comb, K):
     """Per-chip dense partial table: sort by key, segmented scan, scatter the
     segment tails into rows of a ``[K, ...]`` table.  The XLA/ICI-friendly
@@ -224,7 +226,8 @@ def make_sharded_reduce_step(mesh: Mesh, capacity: int, K: int,
                              use_psum: bool = False,
                              monoid: Optional[str] = None,
                              ingest: str = "data",
-                             op_name: str = "mesh.reduce_step"):
+                             op_name: str = "mesh.reduce_step",
+                             owner: Optional[str] = None):
     """Sharded ReduceTPU step with the operator's batch contract: returns
     ``fn(payload, ts, valid) -> (table, ts_out, has, n_dropped)`` where
     ``table`` is the dense ``[K]`` combined-record table, ``ts_out`` the
@@ -271,8 +274,10 @@ def make_sharded_reduce_step(mesh: Mesh, capacity: int, K: int,
                 f"max_keys {K} not divisible by key axis {kk}")
         K_local = K // kk
 
+        @operator_scope(owner or op_name)
         def local_aligned(payload, ts, valid):
-            keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+            with phase("wf.fn"):
+                keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
             base = (jax.lax.axis_index(KEY_AXIS)
                     * K_local).astype(jnp.int32)
             lk = keys - base
@@ -280,16 +285,18 @@ def make_sharded_reduce_step(mesh: Mesh, capacity: int, K: int,
                 & (lk >= 0) & (lk < K_local)
             # out-of-range keys clip onto an edge column host-side and
             # mask out here — counted exactly like the unaligned drop
-            n_drop = jax.lax.psum(
-                jnp.sum(valid & ~in_range, dtype=jnp.int64), axes)
+            with phase("wf.mesh.exchange"):
+                n_drop = jax.lax.psum(
+                    jnp.sum(valid & ~in_range, dtype=jnp.int64), axes)
             ok = valid & in_range
             if dd > 1:
                 # within-column hop only (1/kk of the all_gather bytes):
                 # every data row of a key column folds the same lanes
                 ag = lambda a: jax.lax.all_gather(a, DATA_AXIS, axis=0,
                                                   tiled=True)
-                payload = jax.tree.map(ag, payload)
-                lk, ts, ok = ag(lk), ag(ts), ag(ok)
+                with phase("wf.mesh.exchange"):
+                    payload = jax.tree.map(ag, payload)
+                    lk, ts, ok = ag(lk), ag(ts), ag(ok)
             vals = (payload, ts)
             comb2 = lambda a, b: (comb(a[0], b[0]),
                                   jnp.maximum(a[1], b[1]))
@@ -309,32 +316,40 @@ def make_sharded_reduce_step(mesh: Mesh, capacity: int, K: int,
                        check_vma=False)
         return wf_jit(fn, op_name=op_name)
 
+    @operator_scope(owner or op_name)
     def local(payload, ts, valid):
         if key_fn is not None:
-            keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+            with phase("wf.fn"):
+                keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
         else:
             keys = jnp.zeros(ts.shape[0], jnp.int32)
         n_drop = jnp.sum(valid & ((keys < 0) | (keys >= K)),
                          dtype=jnp.int64)
-        n_drop = jax.lax.psum(n_drop, axes)
+        with phase("wf.mesh.exchange"):
+            n_drop = jax.lax.psum(n_drop, axes)
         # fold ts with the payload so the segment tails carry max-ts too
         vals = (payload, ts)
         comb2 = lambda a, b: (comb(a[0], b[0]), jnp.maximum(a[1], b[1]))
         (table, ts_t), has = _dense_keyed_partial(keys, vals, valid, comb2, K)
         if monoid is not None:
             coll = monoid_collective(monoid)
-            z = jax.tree.map(
-                lambda a: jnp.where(_b(has, a), a,
-                                    _monoid_identity(monoid, a.dtype)),
-                table)
-            out = jax.tree.map(lambda a: coll(a, axes), z)
-            ts_out = jax.lax.pmax(jnp.where(has, ts_t, jnp.int64(-1)), axes)
-            any_has = jax.lax.psum(has.astype(jnp.int32), axes) > 0
+            with phase("wf.mesh.exchange"):
+                z = jax.tree.map(
+                    lambda a: jnp.where(_b(has, a), a,
+                                        _monoid_identity(monoid, a.dtype)),
+                    table)
+                out = jax.tree.map(lambda a: coll(a, axes), z)
+                ts_out = jax.lax.pmax(
+                    jnp.where(has, ts_t, jnp.int64(-1)), axes)
+                any_has = jax.lax.psum(has.astype(jnp.int32), axes) > 0
             return out, ts_out, any_has, n_drop
-        g_t = jax.tree.map(lambda a: jax.lax.all_gather(a, axes),
-                           (table, ts_t))
-        g_h = jax.lax.all_gather(has, axes)
-        anyf, (folded, ts_f) = _masked_reduce_last(comb2, g_h, g_t, axis=0)
+        with phase("wf.mesh.exchange"):
+            g_t = jax.tree.map(lambda a: jax.lax.all_gather(a, axes),
+                               (table, ts_t))
+            g_h = jax.lax.all_gather(has, axes)
+        with phase("wf.reduce"):
+            anyf, (folded, ts_f) = _masked_reduce_last(comb2, g_h, g_t,
+                                                       axis=0)
         return folded, ts_f, anyf, n_drop
 
     fn = shard_map(local, mesh=mesh,
@@ -346,7 +361,8 @@ def make_sharded_reduce_step(mesh: Mesh, capacity: int, K: int,
 def make_sharded_reduce_arbitrary(mesh: Mesh, capacity: int, comb: Callable,
                                   key_fn: Callable,
                                   op_name: str = "mesh.reduce_arbitrary",
-                                  remap: bool = False):
+                                  remap: bool = False,
+                                  owner: Optional[str] = None):
     """Keyed reduce over the mesh for an ARBITRARY int32 key space — no
     ``withMaxKeys`` bound and no dropped keys (VERDICT r2 item 5).
 
@@ -378,9 +394,31 @@ def make_sharded_reduce_arbitrary(mesh: Mesh, capacity: int, comb: Callable,
             f"capacity {capacity} not divisible by {n} devices")
     local_cap = capacity // n
 
+    @operator_scope(owner or op_name)
     def local(payload, ts, valid, *tables):
         from windflow_tpu.ops.tpu import _segmented_reduce
-        keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+        with phase("wf.fn"):
+            keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+        bp, bt, bmask = buckets(keys, payload, ts, valid, tables)
+        # one collective: bucket row i of every chip lands on chip i
+        a2a = lambda x: jax.lax.all_to_all(x, axes, split_axis=0,
+                                           concat_axis=0, tiled=True)
+        flat = lambda a: a.reshape((capacity,) + a.shape[2:])
+        with phase("wf.mesh.exchange"):
+            rp = jax.tree.map(a2a, bp)
+            rt, rm = a2a(bt), a2a(bmask)
+            rp = jax.tree.map(flat, rp)
+            rt, rm = flat(rt), flat(rm)
+        with phase("wf.fn"):
+            rkeys = jax.vmap(key_fn)(rp).astype(jnp.int32)
+        with phase("wf.reduce"):
+            _, out_payload, out_ts, out_valid = _segmented_reduce(
+                rkeys, rp, rt, rm, comb, capacity)
+        return out_payload, out_ts, out_valid, jnp.zeros((), jnp.int64)
+
+    @phase("wf.mesh.own")
+    def buckets(keys, payload, ts, valid, tables):
+        """The local lanes bucketed by the chip that owns their key."""
         own = (keys.astype(jnp.uint32) % n).astype(jnp.int32)
         if tables:
             from windflow_tpu.parallel.compaction import lookup_slots
@@ -408,18 +446,7 @@ def make_sharded_reduce_arbitrary(mesh: Mesh, capacity: int, comb: Callable,
         bt = scat(st)
         bmask = jnp.zeros((n + 1, local_cap), bool) \
             .at[row, rank].set(sv & (so < n))[:n]
-        # one collective: bucket row i of every chip lands on chip i
-        a2a = lambda x: jax.lax.all_to_all(x, axes, split_axis=0,
-                                           concat_axis=0, tiled=True)
-        rp = jax.tree.map(a2a, bp)
-        rt, rm = a2a(bt), a2a(bmask)
-        flat = lambda a: a.reshape((capacity,) + a.shape[2:])
-        rp = jax.tree.map(flat, rp)
-        rt, rm = flat(rt), flat(rm)
-        rkeys = jax.vmap(key_fn)(rp).astype(jnp.int32)
-        _, out_payload, out_ts, out_valid = _segmented_reduce(
-            rkeys, rp, rt, rm, comb, capacity)
-        return out_payload, out_ts, out_valid, jnp.zeros((), jnp.int64)
+        return bp, bt, bmask
 
     in_specs = (P(axes), P(axes), P(axes))
     if remap:
@@ -504,6 +531,7 @@ def _ffat_shard_layout(mesh: Mesh, capacity: int, K: int,
                 f"{dd * kk} devices")
 
     if ingest == "flat":
+        @phase("wf.mesh.exchange")
         def gather(payload, ts, valid):
             def ag(a):
                 a = jax.lax.all_gather(a, KEY_AXIS, axis=0, tiled=True)
@@ -517,6 +545,7 @@ def _ffat_shard_layout(mesh: Mesh, capacity: int, K: int,
                 capacity)
 
     if ingest == "aligned":
+        @phase("wf.mesh.exchange")
         def gather(payload, ts, valid):
             if dd == 1:
                 return payload, ts, valid
@@ -530,6 +559,7 @@ def _ffat_shard_layout(mesh: Mesh, capacity: int, K: int,
         return (K_local, key_base_fn, gather, P((DATA_AXIS, KEY_AXIS)),
                 capacity // kk)
 
+    @phase("wf.mesh.exchange")
     def gather(payload, ts, valid):
         if dd == 1:
             return payload, ts, valid
@@ -605,17 +635,21 @@ def _make_key_shard_ffat_step(step_cap: int, lanes: int, K_local: int,
     def step(state, payload, ts, valid):
         state = dict(state)
         n_wide = state.pop(CB_WIDE_STEPS)
-        keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
-            if key_fn is not None else jnp.zeros(step_cap, jnp.int32)
+        with phase("wf.fn"):
+            keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
+                if key_fn is not None else jnp.zeros(step_cap, jnp.int32)
         lkeys = keys - jnp.int32(key_base_fn())
         ok = valid & (lkeys >= 0) & (lkeys < K_local)
-        n_own = jnp.sum(ok, dtype=jnp.int32)
-        rec = _owned_to_front(
-            ok, {"key": keys, "lift": jax.vmap(lift)(payload)})
-        # whole rounds to slice: the last one may reach past the batch
-        rec = jax.tree.map(lambda a: jnp.pad(
-            a, [(0, n_rounds * lanes - step_cap)] + [(0, 0)] * (a.ndim - 1)),
-            rec)
+        with phase("wf.mesh.own"):
+            n_own = jnp.sum(ok, dtype=jnp.int32)
+        with phase("wf.fn"):
+            lifted = jax.vmap(lift)(payload)
+        with phase("wf.mesh.own"):
+            rec = _owned_to_front(ok, {"key": keys, "lift": lifted})
+            # whole rounds to slice: the last one may reach past the batch
+            rec = jax.tree.map(lambda a: jnp.pad(
+                a, [(0, n_rounds * lanes - step_cap)]
+                + [(0, 0)] * (a.ndim - 1)), rec)
         lane = jnp.arange(lanes, dtype=jnp.int32)
         no_ts = jnp.zeros(lanes, ts.dtype)
 
@@ -671,8 +705,11 @@ def make_sharded_ffat_step(mesh: Mesh, capacity: int, K: int, Pn: int, R: int,
                            grouping: str = "rank_scatter",
                            ingest: str = "data",
                            monoid: Optional[str] = None,
-                           op_name: str = "mesh.ffat_step"):
-    """Compile one FFAT window step sharded over the mesh.
+                           op_name: str = "mesh.ffat_step",
+                           owner: Optional[str] = None):
+    """Compile one FFAT window step sharded over the mesh (``owner``:
+    the operator's name, its ``wf.op.<name>`` scope in a device trace;
+    the program's name where none is given).
 
     State tables are split along ``key`` (chip *i* owns keys
     ``[i*K/kk, (i+1)*K/kk)``); the staged batch arrives data-sharded and is
@@ -705,6 +742,7 @@ def make_sharded_ffat_step(mesh: Mesh, capacity: int, K: int, Pn: int, R: int,
 
     # the benchmark finds this program in a device trace by the name of
     # the wrapped function, the XLA module ``jit_local``
+    @operator_scope(owner or op_name)
     def local(state, payload, ts, valid):
         return shard_step(state, *gather(payload, ts, valid))
 
@@ -718,7 +756,8 @@ def make_sharded_ffat_step(mesh: Mesh, capacity: int, K: int, Pn: int, R: int,
 
 def make_sharded_ffat_flush(mesh: Mesh, K: int, Pn: int, R: int, D: int,
                             comb: Callable,
-                            op_name: str = "mesh.ffat_flush"):
+                            op_name: str = "mesh.ffat_flush",
+                            owner: Optional[str] = None):
     """EOS flush of the key-sharded CB state as an explicit shard_map:
     each key shard flushes its own rows (keys rebased by the shard's
     base) and the outputs stay key-sharded — so each host's sink reads
@@ -732,7 +771,7 @@ def make_sharded_ffat_flush(mesh: Mesh, K: int, Pn: int, R: int, D: int,
     flush_local = make_ffat_flush(K_local, Pn, R, D, comb,
                                   key_base_fn=key_base_fn)
     fn = shard_map(
-        flush_local, mesh=mesh,
+        operator_scope(owner or op_name)(flush_local), mesh=mesh,
         in_specs=(P(KEY_AXIS),),
         out_specs=(P(KEY_AXIS), P(KEY_AXIS), P(KEY_AXIS)),
         check_vma=False)
@@ -751,7 +790,8 @@ def make_sharded_stateful_step(mesh: Mesh, capacity: int, S: int,
                                body_factory: Callable,
                                key_fn: Callable, dense: bool,
                                is_filter: bool, ingest: str = "data",
-                               op_name: str = "mesh.stateful_step"):
+                               op_name: str = "mesh.stateful_step",
+                               owner: Optional[str] = None):
     """Key-sharded stateful Map/Filter step (reference stateful ``Map_GPU``
     whose keyed state is one shared table, ``map_gpu.hpp:114-115``; here the
     dense ``[num_key_slots, ...]`` table is split along ``key`` so each chip
@@ -801,21 +841,25 @@ def make_sharded_stateful_step(mesh: Mesh, capacity: int, S: int,
         blk_col = capacity // (dd * kk)  # one device's block of them
         body_a = body_factory(col_cap, S_local)
 
+        @operator_scope(owner or op_name)
         def local_aligned(state, payload, valid, _uk, _us):
             if dd > 1:
                 ag = lambda a: jax.lax.all_gather(a, DATA_AXIS, axis=0,
                                                   tiled=True)
-                payload = jax.tree.map(ag, payload)
-                valid = ag(valid)
-            keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+                with phase("wf.mesh.exchange"):
+                    payload = jax.tree.map(ag, payload)
+                    valid = ag(valid)
+            with phase("wf.fn"):
+                keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
             base = (jax.lax.axis_index(KEY_AXIS)
                     * S_local).astype(jnp.int32)
             lslot = keys - base
             owned = valid & (keys >= 0) & (keys < S) \
                 & (lslot >= 0) & (lslot < S_local)
             lslot = jnp.where(owned, lslot, jnp.int32(S_local))
-            new_state, out_payload, out_valid = body_a(
-                state, payload, owned, lslot)
+            with phase("wf.state"):
+                new_state, out_payload, out_valid = body_a(
+                    state, payload, owned, lslot)
             d = jax.lax.axis_index(DATA_AXIS) * blk_col
             sl = lambda a: jax.lax.dynamic_slice_in_dim(a, d, blk_col,
                                                         axis=0)
@@ -836,6 +880,7 @@ def make_sharded_stateful_step(mesh: Mesh, capacity: int, S: int,
         return wf_jit(fn, op_name=op_name, donate_argnums=(0,))
     body = body_factory(capacity, S_local)
 
+    @phase("wf.mesh.exchange")
     def merge_lanes(leaf, owned):
         # zero out non-owned lanes, sum across key shards (bool via int32)
         if leaf.dtype == jnp.bool_:
@@ -844,27 +889,32 @@ def make_sharded_stateful_step(mesh: Mesh, capacity: int, S: int,
         z = jnp.where(_b(owned, leaf), leaf, jnp.zeros_like(leaf))
         return jax.lax.psum(z, KEY_AXIS)
 
+    @operator_scope(owner or op_name)
     def local(state, payload, valid, uniq_keys, uniq_slots):
         if dd > 1:
             ag = lambda a: jax.lax.all_gather(a, DATA_AXIS, axis=0,
                                               tiled=True)
-            payload = jax.tree.map(ag, payload)
-            valid = ag(valid)
-        keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+            with phase("wf.mesh.exchange"):
+                payload = jax.tree.map(ag, payload)
+                valid = ag(valid)
+        with phase("wf.fn"):
+            keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
         if dense:
             slots = keys
             ok = valid & (keys >= 0) & (keys < S)
         else:
-            pos = jnp.clip(jnp.searchsorted(uniq_keys, keys),
-                           0, capacity - 1)
-            slots = uniq_slots[pos]
+            with phase("wf.state"):
+                pos = jnp.clip(jnp.searchsorted(uniq_keys, keys),
+                               0, capacity - 1)
+                slots = uniq_slots[pos]
             ok = valid & (slots < S)
         base = (jax.lax.axis_index(KEY_AXIS) * S_local).astype(jnp.int32)
         lslot = slots - base
         owned = ok & (lslot >= 0) & (lslot < S_local)
         lslot = jnp.where(owned, lslot, jnp.int32(S_local))
-        new_state, out_payload, out_valid = body(state, payload, owned,
-                                                 lslot)
+        with phase("wf.state"):
+            new_state, out_payload, out_valid = body(state, payload, owned,
+                                                     lslot)
         # back to the data-sharded layout FIRST: psum over KEY_AXIS and the
         # per-data-row block slice commute, and slicing first divides the
         # collective volume by the data-axis extent
@@ -873,12 +923,16 @@ def make_sharded_stateful_step(mesh: Mesh, capacity: int, S: int,
         owned_b, valid_b = sl(owned), sl(valid)
         # a lane is real only if SOME shard owns its slot — out-of-range
         # keys have no owner and must drop, exactly as on a single chip
-        owned_any = jax.lax.psum(owned_b.astype(jnp.int32), KEY_AXIS) > 0
+        with phase("wf.mesh.exchange"):
+            owned_any = jax.lax.psum(owned_b.astype(jnp.int32),
+                                     KEY_AXIS) > 0
         if is_filter:
             # non-owner shards keep their lanes; the owner's verdict is the
             # only veto (out_valid from the body is owned & keep)
             keep = sl(out_valid) | ~owned_b
-            vetoed = jax.lax.psum((~keep).astype(jnp.int32), KEY_AXIS) > 0
+            with phase("wf.mesh.exchange"):
+                vetoed = jax.lax.psum((~keep).astype(jnp.int32),
+                                      KEY_AXIS) > 0
             return (new_state, jax.tree.map(sl, payload),
                     valid_b & owned_any & ~vetoed)
         merged_payload = jax.tree.map(
@@ -921,7 +975,8 @@ def make_sharded_ffat_tb_step(mesh: Mesh, capacity: int, K: int, P_usec: int,
                               ingest: str = "data",
                               sum_like: bool = False,
                               monoid: Optional[str] = None,
-                              op_name: str = "mesh.ffat_tb_step"):
+                              op_name: str = "mesh.ffat_tb_step",
+                              owner: Optional[str] = None):
     """Compile one time-based FFAT step sharded over the mesh.
 
     Same layout as the CB variant (:func:`make_sharded_ffat_step`): state
@@ -940,6 +995,7 @@ def make_sharded_ffat_tb_step(mesh: Mesh, capacity: int, K: int, P_usec: int,
                                    grouping=grouping, sum_like=sum_like,
                                    monoid=monoid)
 
+    @operator_scope(owner or op_name)
     def local(state, payload, ts, valid, wm_pane):
         payload, ts, valid = gather(payload, ts, valid)
         sstate = {k: (v[0] if k in _TB_SCALARS else v)
@@ -952,7 +1008,8 @@ def make_sharded_ffat_tb_step(mesh: Mesh, capacity: int, K: int, P_usec: int,
         # it).  Along ``data`` the value is already replicated — every data
         # row of a key shard saw the same gathered batch — so summing over
         # KEY_AXIS alone keeps it both exact and mesh-replicated.
-        n_adv = jax.lax.psum(n_adv, KEY_AXIS)
+        with phase("wf.mesh.exchange"):
+            n_adv = jax.lax.psum(n_adv, KEY_AXIS)
         return new_state, out, fired, out_ts, n_adv
 
     sspec = {k: P(KEY_AXIS) for k in

@@ -14,6 +14,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from windflow_tpu.monitoring.recorder import phase
 from windflow_tpu.utils.dtypes import cast_state_update
 from windflow_tpu.windows.grouping import (auto_order, dense_rank,
                                            order_and_hist)
@@ -456,53 +457,57 @@ def make_ffat_step(capacity: int, K: int, P: int, R: int, D: int,
     def step(state, payload, ts, valid):
         B = capacity
         kb = key_base_fn() if key_base_fn is not None else None
-        keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
-            if key_fn is not None else jnp.zeros(B, jnp.int32)
+        with phase("wf.fn"):
+            keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
+                if key_fn is not None else jnp.zeros(B, jnp.int32)
         if kb is not None:
             keys = keys - jnp.int32(kb)
         ok = valid & (keys >= 0) & (keys < K)
         skey_for_sort = jnp.where(ok, keys, K)
 
         if scatter_combine:
-            use_pk = False
-            if pallas is not None:
-                from windflow_tpu import kernels as pk
-                use_pk = pk.grouping_supported(B, K + 1)
-            if use_pk:
-                # fused Pallas grouping: rank + histogram in one pass
-                # (bit-identical to dense_rank — same (id, arrival)
-                # counting), traced into this same program
-                _, rank_u, hist_pk = pk.grouping_rank_hist(
-                    skey_for_sort, K + 1, pallas.interpret)
-                n_k = hist_pk[:K]
-            else:
-                rank_p, counts, _, _ = dense_rank(skey_for_sort, K + 1)
-                rank_u = rank_p[:B]
-                n_k = counts[:K]
-            lifts = jax.vmap(lift)(payload)
-            fill0_u = state["cur_fill"][jnp.minimum(skey_for_sort, K - 1)]
-            col_u = jnp.where(
-                ok, ((fill0_u + rank_u) // P).astype(jnp.int32), 0)
+            with phase("wf.group"):
+                use_pk = False
+                if pallas is not None:
+                    from windflow_tpu import kernels as pk
+                    use_pk = pk.grouping_supported(B, K + 1)
+                if use_pk:
+                    # fused Pallas grouping: rank + histogram in one pass
+                    # (bit-identical to dense_rank — same (id, arrival)
+                    # counting), traced into this same program
+                    _, rank_u, hist_pk = pk.grouping_rank_hist(
+                        skey_for_sort, K + 1, pallas.interpret)
+                    n_k = hist_pk[:K]
+                else:
+                    rank_p, counts, _, _ = dense_rank(skey_for_sort, K + 1)
+                    rank_u = rank_p[:B]
+                    n_k = counts[:K]
+            with phase("wf.fn"):
+                lifts = jax.vmap(lift)(payload)
+            with phase("wf.place"):
+                fill0_u = state["cur_fill"][jnp.minimum(skey_for_sort, K - 1)]
+                col_u = jnp.where(
+                    ok, ((fill0_u + rank_u) // P).astype(jnp.int32), 0)
 
-            def scat(leaf):
-                ident = _monoid_identity(monoid, leaf.dtype)
-                buf = jnp.full((K + 1, NP1) + leaf.shape[1:], ident,
-                               leaf.dtype)
-                return _monoid_scatter(
-                    buf.at[skey_for_sort, col_u], monoid)(
-                    jnp.where(_b(ok, leaf), leaf, ident))[:K]
-            cells = jax.tree.map(scat, lifts)
+                def scat(leaf):
+                    ident = _monoid_identity(monoid, leaf.dtype)
+                    buf = jnp.full((K + 1, NP1) + leaf.shape[1:], ident,
+                                   leaf.dtype)
+                    return _monoid_scatter(
+                        buf.at[skey_for_sort, col_u], monoid)(
+                        jnp.where(_b(ok, leaf), leaf, ident))[:K]
+                cells = jax.tree.map(scat, lifts)
 
-            # carried partial pane merges by the declared op (empty cells
-            # hold the monoid identity, so no has-mask is needed)
-            def merge0(cur_leaf, cell_leaf):
-                ident = _monoid_identity(monoid, cell_leaf.dtype)
-                upd = jnp.where(_b(state["cur_valid"], cur_leaf),
-                                cur_leaf, ident)
-                return _monoid_scatter(cell_leaf.at[:, 0], monoid)(
-                    cast_state_update(upd, cell_leaf.dtype,
-                                      "FFAT pane merge"))
-            cells = jax.tree.map(merge0, state["cur"], cells)
+                # carried partial pane merges by the declared op (empty cells
+                # hold the monoid identity, so no has-mask is needed)
+                def merge0(cur_leaf, cell_leaf):
+                    ident = _monoid_identity(monoid, cell_leaf.dtype)
+                    upd = jnp.where(_b(state["cur_valid"], cur_leaf),
+                                    cur_leaf, ident)
+                    return _monoid_scatter(cell_leaf.at[:, 0], monoid)(
+                        cast_state_update(upd, cell_leaf.dtype,
+                                          "FFAT pane merge"))
+                cells = jax.tree.map(merge0, state["cur"], cells)
         else:
             # after a STABLE grouping by dense key, bucket b's lanes
             # occupy [start_b, start_b + hist_b), so the within-key rank
@@ -512,123 +517,130 @@ def make_ffat_step(capacity: int, K: int, P: int, R: int, D: int,
             # 0.100 ms step; a [K+1] cumsum replaces it).  The histogram
             # itself is the counting permutation's dense_rank byproduct
             # on the single-pass path — free.
-            order, hist = _group_order_hist(skey_for_sort, K + 1,
-                                            grouping, pallas)
-            sk = skey_for_sort[order]
-            slift = jax.tree.map(lambda a: a[order],
-                                 jax.vmap(lift)(payload))
-            pos = jnp.arange(B)
-            bucket_start = jnp.cumsum(hist) - hist        # exclusive
-            rank = pos - bucket_start[sk]
-            starts = rank == 0
+            with phase("wf.group"):
+                order, hist = _group_order_hist(skey_for_sort, K + 1,
+                                                grouping, pallas)
+                sk = skey_for_sort[order]
+            with phase("wf.fn"):
+                lifted = jax.vmap(lift)(payload)
+            with phase("wf.group"):
+                slift = jax.tree.map(lambda a: a[order], lifted)
+            with phase("wf.place"):
+                pos = jnp.arange(B)
+                bucket_start = jnp.cumsum(hist) - hist        # exclusive
+                rank = pos - bucket_start[sk]
+                starts = rank == 0
 
-            n_k = hist[:K]      # buckets < K hold exactly the ok lanes
-            fill0 = state["cur_fill"][jnp.minimum(sk, K - 1)]
-            pane_rel = ((fill0 + rank) // P).astype(jnp.int32)
+                n_k = hist[:K]      # buckets < K hold exactly the ok lanes
+                fill0 = state["cur_fill"][jnp.minimum(sk, K - 1)]
+                pane_rel = ((fill0 + rank) // P).astype(jnp.int32)
 
-            # pane partials: segmented scan over (key, pane) runs
-            pane_starts = starts | jnp.concatenate(
-                [jnp.array([True]), pane_rel[1:] != pane_rel[:-1]])
-            scanned = _seg_scan(comb, pane_starts, slift)
-            ends = jnp.concatenate(
-                [(sk[1:] != sk[:-1]) | (pane_rel[1:] != pane_rel[:-1]),
-                 jnp.array([True])])
-            # scatter segment-end partials into dense [K+1, NP1] cells
-            row = jnp.where(ends, sk, K)
-            col = jnp.where(ends, pane_rel, 0)
+                # pane partials: segmented scan over (key, pane) runs
+                pane_starts = starts | jnp.concatenate(
+                    [jnp.array([True]), pane_rel[1:] != pane_rel[:-1]])
+                scanned = _seg_scan(comb, pane_starts, slift)
+                ends = jnp.concatenate(
+                    [(sk[1:] != sk[:-1]) | (pane_rel[1:] != pane_rel[:-1]),
+                     jnp.array([True])])
+                # scatter segment-end partials into dense [K+1, NP1] cells
+                row = jnp.where(ends, sk, K)
+                col = jnp.where(ends, pane_rel, 0)
 
-            def scat(leaf):
-                buf = jnp.zeros((K + 1, NP1) + leaf.shape[1:], leaf.dtype)
-                return buf.at[row, col].set(
-                    jnp.where(_b(ends, leaf), leaf, 0))[:K]
-            cells = jax.tree.map(scat, scanned)
-            cell_has = jnp.zeros((K + 1, NP1), bool) \
-                .at[row, col].set(ends)[:K]
+                def scat(leaf):
+                    buf = jnp.zeros((K + 1, NP1) + leaf.shape[1:], leaf.dtype)
+                    return buf.at[row, col].set(
+                        jnp.where(_b(ends, leaf), leaf, 0))[:K]
+                cells = jax.tree.map(scat, scanned)
+                cell_has = jnp.zeros((K + 1, NP1), bool) \
+                    .at[row, col].set(ends)[:K]
 
-            # merge continuation cell with the carried partial pane; comb
-            # is a WHOLE-PYTREE combiner (cross-leaf combines are legal —
-            # matrix products etc.), so it runs once on the tree, not per
-            # leaf
-            cell0 = jax.tree.map(lambda cl: cl[:, 0], cells)
-            both0 = comb(state["cur"], cell0)
+                # merge continuation cell with the carried partial pane; comb
+                # is a WHOLE-PYTREE combiner (cross-leaf combines are legal —
+                # matrix products etc.), so it runs once on the tree, not per
+                # leaf
+                cell0 = jax.tree.map(lambda cl: cl[:, 0], cells)
+                both0 = comb(state["cur"], cell0)
 
-            def merge0(cur_leaf, cell_leaf, both_leaf):
-                use_cur = state["cur_valid"]
-                use_cell = cell_has[:, 0]
-                v = jnp.where(_b(use_cur & use_cell, both_leaf), both_leaf,
-                              jnp.where(_b(use_cur, both_leaf), cur_leaf,
-                                        cell_leaf[:, 0]))
-                # carried state may be wider than the batch-derived cells
-                # (e.g. an f64 agg_spec under x64 vs f32 lifts); the cell
-                # dtype is authoritative — a promoting scatter errors in
-                # future JAX, and a kind-crossing cast is state corruption
-                # (utils.dtypes)
-                return cell_leaf.at[:, 0].set(
-                    cast_state_update(v, cell_leaf.dtype,
-                                      "FFAT pane merge"))
-            cells = jax.tree.map(merge0, state["cur"], cells, both0)
+                def merge0(cur_leaf, cell_leaf, both_leaf):
+                    use_cur = state["cur_valid"]
+                    use_cell = cell_has[:, 0]
+                    v = jnp.where(_b(use_cur & use_cell, both_leaf), both_leaf,
+                                  jnp.where(_b(use_cur, both_leaf), cur_leaf,
+                                            cell_leaf[:, 0]))
+                    # carried state may be wider than the batch-derived cells
+                    # (e.g. an f64 agg_spec under x64 vs f32 lifts); the cell
+                    # dtype is authoritative — a promoting scatter errors in
+                    # future JAX, and a kind-crossing cast is state corruption
+                    # (utils.dtypes)
+                    return cell_leaf.at[:, 0].set(
+                        cast_state_update(v, cell_leaf.dtype,
+                                          "FFAT pane merge"))
+                cells = jax.tree.map(merge0, state["cur"], cells, both0)
 
-        m_k = ((state["cur_fill"] + n_k) // P).astype(jnp.int32)
-        new_fill = ((state["cur_fill"] + n_k) % P).astype(jnp.int32)
+        with phase("wf.fire"):
+            m_k = ((state["cur_fill"] + n_k) // P).astype(jnp.int32)
+            new_fill = ((state["cur_fill"] + n_k) % P).astype(jnp.int32)
 
-        # full pane sequence: carry (R-1 trailing) + this batch's panes
-        full = jax.tree.map(
-            lambda c, p: jnp.concatenate([c, p], axis=1),
-            state["carry"], cells)
-        col_ix = jnp.arange(NP1)[None, :]
-        pane_valid = col_ix < m_k[:, None]
-        full_valid = jnp.concatenate([state["carry_valid"], pane_valid],
-                                     axis=1)
+            # full pane sequence: carry (R-1 trailing) + this batch's panes
+            full = jax.tree.map(
+                lambda c, p: jnp.concatenate([c, p], axis=1),
+                state["carry"], cells)
+            col_ix = jnp.arange(NP1)[None, :]
+            pane_valid = col_ix < m_k[:, None]
+            full_valid = jnp.concatenate([state["carry_valid"], pane_valid],
+                                         axis=1)
 
-        # fire windows: key k fires ends e = win_next[k] + j*D while
-        # e <= done[k] — a per-key PREFIX, so no dense [K, MW] firing grid
-        # is ever needed: per-key counts + a searchsorted over their running
-        # sum enumerate the fired (key, window) pairs directly in compacted
-        # order.  The sliding fold (log2(R) dilated combines over the
-        # [K, R-1+NP1] pane sequence) stays dense; window values are
-        # gathered only at the MAXO compacted output slots.
-        done = state["pane_base"] + m_k
-        if monoid is not None:
-            # declared identity-absorbing: the flag lane of the fold is
-            # pure overhead here (the CB step never reads the flag output
-            # — fired windows always contain data)
-            use_fold = False
-            if pallas is not None:
-                from windflow_tpu import kernels as pk
-                use_fold = pk.fold_supported(full, R, monoid,
-                                             pallas.interpret)
-            if use_fold:
-                # Pallas pane combine: identity fill + blocked sliding
-                # fold in one VMEM-resident kernel (MXU banded matmul
-                # for f32 sums, the lax fold's own doubling schedule
-                # on the VPU otherwise — module docstring)
-                swin = pk.sliding_fold(full, full_valid, R, monoid,
-                                       pallas.interpret)
+            # fire windows: key k fires ends e = win_next[k] + j*D while
+            # e <= done[k] — a per-key PREFIX, so no dense [K, MW] firing
+            # grid is ever needed: per-key counts + a searchsorted over
+            # their running sum enumerate the fired (key, window) pairs
+            # directly in compacted order.  The sliding fold (log2(R)
+            # dilated combines over the [K, R-1+NP1] pane sequence) stays
+            # dense; window values are gathered only at the MAXO compacted
+            # output slots.
+            done = state["pane_base"] + m_k
+            if monoid is not None:
+                # declared identity-absorbing: the flag lane of the fold
+                # is pure overhead here (the CB step never reads the flag
+                # output — fired windows always contain data)
+                use_fold = False
+                if pallas is not None:
+                    from windflow_tpu import kernels as pk
+                    use_fold = pk.fold_supported(full, R, monoid,
+                                                 pallas.interpret)
+                if use_fold:
+                    # Pallas pane combine: identity fill + blocked sliding
+                    # fold in one VMEM-resident kernel (MXU banded matmul
+                    # for f32 sums, the lax fold's own doubling schedule
+                    # on the VPU otherwise — module docstring)
+                    swin = pk.sliding_fold(full, full_valid, R, monoid,
+                                           pallas.interpret)
+                else:
+                    swin = _sliding_reduce_plain(comb, full_valid, full, R,
+                                                 axis=1, monoid=monoid)
             else:
-                swin = _sliding_reduce_plain(comb, full_valid, full, R,
-                                             axis=1, monoid=monoid)
-        else:
-            _, swin = _sliding_reduce(comb, full_valid, full, R, axis=1)
+                _, swin = _sliding_reduce(comb, full_valid, full, R, axis=1)
 
-        n_fired = jnp.maximum(
-            jnp.int64(0), (done - state["win_next"]) // D + 1)
-        new_win_next = state["win_next"] + n_fired * D
+            n_fired = jnp.maximum(
+                jnp.int64(0), (done - state["win_next"]) // D + 1)
+            new_win_next = state["win_next"] + n_fired * D
 
-        # new carry: panes [pane_base+m_k-(R-1), pane_base+m_k)
-        cidx = m_k[:, None] + jnp.arange(R - 1)[None, :]       # [K, R-1]
-        def carry_leaf(a):
-            idx = cidx.reshape(K, R - 1, *([1] * (a.ndim - 2)))
-            idx = jnp.broadcast_to(idx, (K, R - 1) + a.shape[2:])
-            return jnp.take_along_axis(a, idx, axis=1)
-        new_carry = jax.tree.map(carry_leaf, full)
-        new_carry_valid = jnp.take_along_axis(full_valid, cidx, axis=1)
+        with phase("wf.ring"):
+            # new carry: panes [pane_base+m_k-(R-1), pane_base+m_k)
+            cidx = m_k[:, None] + jnp.arange(R - 1)[None, :]   # [K, R-1]
+            def carry_leaf(a):
+                idx = cidx.reshape(K, R - 1, *([1] * (a.ndim - 2)))
+                idx = jnp.broadcast_to(idx, (K, R - 1) + a.shape[2:])
+                return jnp.take_along_axis(a, idx, axis=1)
+            new_carry = jax.tree.map(carry_leaf, full)
+            new_carry_valid = jnp.take_along_axis(full_valid, cidx, axis=1)
 
-        def cur_leaf(cell_leaf):
-            idx = m_k.reshape(K, 1, *([1] * (cell_leaf.ndim - 2)))
-            idx = jnp.broadcast_to(idx, (K, 1) + cell_leaf.shape[2:])
-            return jnp.take_along_axis(cell_leaf, idx, axis=1)[:, 0]
-        new_cur = jax.tree.map(cur_leaf, cells)
-        new_cur_valid = new_fill > 0
+            def cur_leaf(cell_leaf):
+                idx = m_k.reshape(K, 1, *([1] * (cell_leaf.ndim - 2)))
+                idx = jnp.broadcast_to(idx, (K, 1) + cell_leaf.shape[2:])
+                return jnp.take_along_axis(cell_leaf, idx, axis=1)[:, 0]
+            new_cur = jax.tree.map(cur_leaf, cells)
+            new_cur_valid = new_fill > 0
 
         new_state = {
             "carry": new_carry,
@@ -640,39 +652,40 @@ def make_ffat_step(capacity: int, K: int, P: int, R: int, D: int,
             "win_next": new_win_next,
         }
 
-        # output batch (see docstring): compacted slot i belongs to the key
-        # whose fired-count running sum first exceeds i; everything else is
-        # per-slot arithmetic + one gather from the sliding fold.
-        # The running sum is int32 and widened after: a key fires at
-        # most capacity/(P*D) + 1 windows per batch and the total is
-        # bounded by MAXO.  XLA:TPU emulates an int64 cumsum as a
-        # variadic u32-pair reduce-window, which it cannot place in
-        # scoped VMEM once the step sits in a lax.scan body (megastep):
-        # "RESOURCE_EXHAUSTED ... reduce-window (u32[8,128], u32[8,128])
-        # ... Scoped allocation 19.07M, limit 16.00M" at every capacity.
-        fired32 = n_fired.astype(jnp.int32)
-        offs = jnp.cumsum(fired32)                             # [K]
-        n_out = offs[K - 1]
-        i_slot = jnp.arange(MAXO, dtype=jnp.int32)
-        k_out = jnp.searchsorted(offs, i_slot, side="right") \
-            .astype(jnp.int32)                                 # [MAXO]
-        k_c = jnp.minimum(k_out, K - 1)
-        j_out = (i_slot - (offs[k_c] - fired32[k_c])) \
-            .astype(jnp.int64)                                 # rank in key
-        e_out = state["win_next"][k_c] + j_out * D
-        # window value: sliding-fold cell at the window's end pane
-        widx_out = jnp.clip(
-            (e_out - state["pane_base"][k_c] + (R - 2)).astype(jnp.int32),
-            0, R - 1 + NP1 - 1)                                # [MAXO]
-        wvals_out = jax.tree.map(lambda a: a[k_c, widx_out], swin)
-        out = {
-            "key": k_c + (jnp.int32(kb) if kb is not None else 0),
-            "wid": (e_out - R) // D,
-            "value": wvals_out,
-        }
-        out_valid = i_slot < n_out
-        batch_ts = jnp.max(jnp.where(valid, ts, 0))
-        out_ts = jnp.where(out_valid, batch_ts, 0)
+        with phase("wf.fire"):
+            # output batch (see docstring): compacted slot i belongs to the
+            # key whose fired-count running sum first exceeds i; everything
+            # else is per-slot arithmetic + one gather from the sliding fold.
+            # The running sum is int32 and widened after: a key fires at
+            # most capacity/(P*D) + 1 windows per batch and the total is
+            # bounded by MAXO.  XLA:TPU emulates an int64 cumsum as a
+            # variadic u32-pair reduce-window, which it cannot place in
+            # scoped VMEM once the step sits in a lax.scan body (megastep):
+            # "RESOURCE_EXHAUSTED ... reduce-window (u32[8,128], u32[8,128])
+            # ... Scoped allocation 19.07M, limit 16.00M" at every capacity.
+            fired32 = n_fired.astype(jnp.int32)
+            offs = jnp.cumsum(fired32)                         # [K]
+            n_out = offs[K - 1]
+            i_slot = jnp.arange(MAXO, dtype=jnp.int32)
+            k_out = jnp.searchsorted(offs, i_slot, side="right") \
+                .astype(jnp.int32)                             # [MAXO]
+            k_c = jnp.minimum(k_out, K - 1)
+            j_out = (i_slot - (offs[k_c] - fired32[k_c])) \
+                .astype(jnp.int64)                             # rank in key
+            e_out = state["win_next"][k_c] + j_out * D
+            # window value: sliding-fold cell at the window's end pane
+            widx_out = jnp.clip(
+                (e_out - state["pane_base"][k_c] + (R - 2))
+                .astype(jnp.int32), 0, R - 1 + NP1 - 1)        # [MAXO]
+            wvals_out = jax.tree.map(lambda a: a[k_c, widx_out], swin)
+            out = {
+                "key": k_c + (jnp.int32(kb) if kb is not None else 0),
+                "wid": (e_out - R) // D,
+                "value": wvals_out,
+            }
+            out_valid = i_slot < n_out
+            batch_ts = jnp.max(jnp.where(valid, ts, 0))
+            out_ts = jnp.where(out_valid, batch_ts, 0)
         return new_state, out, out_valid, out_ts
 
     return step
@@ -689,6 +702,7 @@ def make_ffat_flush(K: int, P: int, R: int, D: int, comb: Callable,
     locally (found by the two-process graph test)."""
     MWF = R // D + 2
 
+    @phase("wf.fire")
     def flush(state):
         kb = key_base_fn() if key_base_fn is not None else None
         # total panes including the partial pane
@@ -881,8 +895,22 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
 
     def fire_pass(cells, cell_valid, base, win_next, frontier, max_seen,
                   horizon, acc=None):
+        """:func:`fire`, then the roll that its windows free."""
+        fired, wvals, w, n_fired, n_drop, acc = fire(
+            cells, cell_valid, base, win_next, frontier, max_seen, horizon,
+            acc)
+        new_next = win_next + n_fired
+        with phase("wf.ring"):
+            shift = jnp.clip(new_next * D - base, 0, NP)
+            cell_valid, cells = roll_left(cell_valid, cells, shift)
+        return (cells, cell_valid, base + shift, new_next,
+                fired, wvals, w, n_fired, n_drop, acc)
+
+    @phase("wf.fire")
+    def fire(cells, cell_valid, base, win_next, frontier, max_seen, horizon,
+             acc):
         """Fire windows ending <= frontier whose end pane is inside the
-        ring; returns the rolled ring + firing outputs.  Firing is capped to
+        ring; returns the firing outputs.  Firing is capped to
         in-ring ends: if the frontier outruns the ring, later windows wait
         for the next pass/step (the roll brings their ends in range) — every
         fired fold is exactly over its own panes.  It is also capped to
@@ -995,26 +1023,25 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             n_fired = n_ready
             fired, wvals, n_drop = jax.lax.cond(n_ready > 0, do_fold,
                                                 no_fold, None)
-        new_next = win_next + n_fired
-        shift = jnp.clip(new_next * D - base, 0, NP)
-        cell_valid, cells = roll_left(cell_valid, cells, shift)
-        return (cells, cell_valid, base + shift, new_next,
-                fired, wvals, w, n_fired, n_drop, acc)
+        return fired, wvals, w, n_fired, n_drop, acc
 
     def step(state, payload, ts, valid, wm_pane):
         B = capacity
         kb = key_base_fn() if key_base_fn is not None else None
-        keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
-            if key_fn is not None else jnp.zeros(B, jnp.int32)
+        with phase("wf.fn"):
+            keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
+                if key_fn is not None else jnp.zeros(B, jnp.int32)
         if kb is not None:
             keys = keys - jnp.int32(kb)
         ok = valid & (keys >= 0) & (keys < K)
-        pane = ts.astype(jnp.int64) // P_usec
-        if D > R:
-            # hopping windows with gaps (slide > win): panes in the
-            # inter-window gap belong to no window — never place or count
-            # them (pane p is covered iff p mod D < R)
-            ok = ok & ((pane % D) < R)
+        with phase("wf.place"):
+            # a lane's pane (64-bit divisions over the batch)
+            pane = ts.astype(jnp.int64) // P_usec
+            if D > R:
+                # hopping windows with gaps (slide > win): panes in the
+                # inter-window gap belong to no window — never place or
+                # count them (pane p is covered iff p mod D < R)
+                ok = ok & ((pane % D) < R)
 
         # 1. pass A (twice): fire everything no tuple of this batch can
         # touch; the second pass reaches windows whose ends the first
@@ -1028,15 +1055,16 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
         n_win_dropped = state["n_win_dropped"]
         acc = None
         if compact:
-            acc = {
-                "key": jnp.zeros((OC,), jnp.int32),
-                "wid": jnp.zeros((OC,), jnp.int64),
-                "value": jax.tree.map(
-                    lambda a: jnp.zeros((OC,) + a.shape[2:], a.dtype),
-                    state["cells"]),
-                "fired": jnp.zeros((OC,), bool),
-                "n": jnp.zeros((), jnp.int32),
-            }
+            with phase("wf.fire"):
+                acc = {
+                    "key": jnp.zeros((OC,), jnp.int32),
+                    "wid": jnp.zeros((OC,), jnp.int64),
+                    "value": jax.tree.map(
+                        lambda a: jnp.zeros((OC,) + a.shape[2:], a.dtype),
+                        state["cells"]),
+                    "fired": jnp.zeros((OC,), bool),
+                    "n": jnp.zeros((), jnp.int32),
+                }
         for _ in range(2):
             (cells, cell_valid, base, win_next,
              fired_i, wvals_i, w_i, n_i, nd_i, acc) = fire_pass(
@@ -1046,20 +1074,23 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             n_win_dropped = n_win_dropped + nd_i
 
         # 2. capacity roll: make room for this batch's newest pane
-        max_pane = jnp.max(jnp.where(ok, pane, base))
-        max_seen = jnp.maximum(state["max_seen"],
-                               jnp.max(jnp.where(ok, pane, -(1 << 60))))
-        shift_cap = jnp.maximum(jnp.int64(0), max_pane - base - (NP - 1))
-        col = jnp.arange(NP, dtype=jnp.int64)[None, :]
-        evict_mask = cell_valid & (col < shift_cap)
-        evicted = jnp.sum(evict_mask.astype(jnp.int64))
-        # per-key taint horizon: one past the newest data pane lost here
-        horizon = jnp.maximum(
-            state["horizon"],
-            jnp.max(jnp.where(evict_mask, base + col + 1, -(1 << 60)),
-                    axis=1))
-        cell_valid, cells = roll_left(cell_valid, cells, shift_cap)
-        base = base + shift_cap
+        with phase("wf.ring"):
+            max_pane = jnp.max(jnp.where(ok, pane, base))
+            max_seen = jnp.maximum(
+                state["max_seen"], jnp.max(jnp.where(ok, pane, -(1 << 60))))
+            shift_cap = jnp.maximum(jnp.int64(0),
+                                    max_pane - base - (NP - 1))
+            col = jnp.arange(NP, dtype=jnp.int64)[None, :]
+            evict_mask = cell_valid & (col < shift_cap)
+            evicted = jnp.sum(evict_mask.astype(jnp.int64))
+            # per-key taint horizon: one past the newest data pane lost
+            # here
+            horizon = jnp.maximum(
+                state["horizon"],
+                jnp.max(jnp.where(evict_mask, base + col + 1, -(1 << 60)),
+                        axis=1))
+            cell_valid, cells = roll_left(cell_valid, cells, shift_cap)
+            base = base + shift_cap
 
         # 3. place the batch: sort by (key, pane), fold runs, merge cells
         rel = pane - base
@@ -1076,7 +1107,8 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             # every TB batch regardless (thrust::sort_by_key,
             # ffat_replica_gpu.hpp:917).
             row_u = jnp.where(ok, keys, K)
-            lifted, tree = jax.tree.flatten(jax.vmap(lift)(payload))
+            with phase("wf.fn"):
+                lifted, tree = jax.tree.flatten(jax.vmap(lift)(payload))
             plan = tb_placement(
                 monoid, [jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
                          for a in lifted], K, NP, B)
@@ -1162,36 +1194,46 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             # over the lanes costs 18.5 ms on a small grid and 34 ms
             # into 43 M cells, a 32-bit one 1.9 and 2.6 ms (PERF.md
             # section 6, PR 29 and PR 31)
-            if plan["count"]:
-                n_cell, sums = _dense_place(keys, rel_c, ok, K, NP, lifted,
-                                            plan["limbs"], plan["limb_bits"])
-                col = jnp.where(ok, rel_c, 0)
-                cells = merge(cells, cell_valid,
-                              [scat(a, NP, col) if s is None else s
-                               for a, s in zip(lifted, sums)])
-                cell_valid = cell_valid | (n_cell > 0)
-            elif NP > NARROW_PLACE_PANES:
-                c0 = jnp.clip(jnp.min(jnp.where(ok, rel_c, NP)), 0,
-                              NP - NARROW_PLACE_PANES)
-                narrow = jnp.max(jnp.where(ok, rel_c, 0)) - c0 \
-                    < NARROW_PLACE_PANES
-                cells, cell_valid = jax.lax.cond(
-                    narrow, place_narrow, place_wide, cells, cell_valid)
-                n_wide = n_wide + jnp.where(narrow, 0, 1)
-            else:
-                cells, cell_valid = place_wide(cells, cell_valid)
+            with phase("wf.place"):
+                if plan["count"]:
+                    n_cell, sums = _dense_place(
+                        keys, rel_c, ok, K, NP, lifted, plan["limbs"],
+                        plan["limb_bits"])
+                    col = jnp.where(ok, rel_c, 0)
+                    cells = merge(cells, cell_valid,
+                                  [scat(a, NP, col) if s is None else s
+                                   for a, s in zip(lifted, sums)])
+                    cell_valid = cell_valid | (n_cell > 0)
+                elif NP > NARROW_PLACE_PANES:
+                    c0 = jnp.clip(jnp.min(jnp.where(ok, rel_c, NP)), 0,
+                                  NP - NARROW_PLACE_PANES)
+                    narrow = jnp.max(jnp.where(ok, rel_c, 0)) - c0 \
+                        < NARROW_PLACE_PANES
+                    cells, cell_valid = jax.lax.cond(
+                        narrow, place_narrow, place_wide, cells, cell_valid)
+                    n_wide = n_wide + jnp.where(narrow, 0, 1)
+                else:
+                    cells, cell_valid = place_wide(cells, cell_valid)
         else:
             def place(cells):
                 sid = jnp.where(ok, keys.astype(jnp.int64) * NP + rel_c,
                                 jnp.int64(K) * NP)
-                if K * NP + 1 < (1 << 31):   # counting ids are int32
-                    sid = sid.astype(jnp.int32)
-                    order = _group_order(sid, K * NP + 1, grouping, pallas)
-                else:
-                    order = jnp.argsort(sid, stable=True)
-                ssid = sid[order]
-                slift = jax.tree.map(lambda a: a[order],
-                                     jax.vmap(lift)(payload))
+                with phase("wf.group"):
+                    if K * NP + 1 < (1 << 31):   # counting ids are int32
+                        sid = sid.astype(jnp.int32)
+                        order = _group_order(sid, K * NP + 1, grouping,
+                                             pallas)
+                    else:
+                        order = jnp.argsort(sid, stable=True)
+                    ssid = sid[order]
+                with phase("wf.fn"):
+                    lifted = jax.vmap(lift)(payload)
+                with phase("wf.group"):
+                    slift = jax.tree.map(lambda a: a[order], lifted)
+                return fold_into(cells, ssid, slift)
+
+            @phase("wf.place")
+            def fold_into(cells, ssid, slift):
                 starts = jnp.concatenate([jnp.array([True]),
                                           ssid[1:] != ssid[:-1]])
                 scanned = _seg_scan(comb, starts, slift)
@@ -1272,23 +1314,24 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             return new_state, out, acc["fired"], \
                 (acc["wid"] * D + R) * P_usec - 1, n_adv      # end-1 (TB)
         # outputs: pass A1, A2, then B rows, [K, N_PASSES*MW] flattened
-        w2 = jnp.concatenate([p[2] for p in all_passes])
-        fired = jnp.concatenate([p[0] for p in all_passes], axis=1)
-        wvals = jax.tree.map(
-            lambda *leaves: jnp.concatenate(leaves, axis=1),
-            *[p[1] for p in all_passes])
-        NM = N_PASSES * MW
-        out_ts = (w2 * D + R) * P_usec - 1                     # end-1 (TB)
-        out = {
-            "key": (jnp.broadcast_to(
-                jnp.arange(K, dtype=jnp.int32)[:, None], (K, NM))
-                + (jnp.int32(kb) if kb is not None else 0)).reshape(-1),
-            "wid": jnp.broadcast_to(w2[None, :], (K, NM)).reshape(-1),
-            "value": jax.tree.map(
-                lambda a: a.reshape((K * NM,) + a.shape[2:]), wvals),
-        }
-        return new_state, out, fired.reshape(-1), \
-            jnp.broadcast_to(out_ts[None, :], (K, NM)).reshape(-1), n_adv
+        with phase("wf.fire"):
+            w2 = jnp.concatenate([p[2] for p in all_passes])
+            fired = jnp.concatenate([p[0] for p in all_passes], axis=1)
+            wvals = jax.tree.map(
+                lambda *leaves: jnp.concatenate(leaves, axis=1),
+                *[p[1] for p in all_passes])
+            NM = N_PASSES * MW
+            out_ts = (w2 * D + R) * P_usec - 1                 # end-1 (TB)
+            out = {
+                "key": (jnp.broadcast_to(
+                    jnp.arange(K, dtype=jnp.int32)[:, None], (K, NM))
+                    + (jnp.int32(kb) if kb is not None else 0)).reshape(-1),
+                "wid": jnp.broadcast_to(w2[None, :], (K, NM)).reshape(-1),
+                "value": jax.tree.map(
+                    lambda a: a.reshape((K * NM,) + a.shape[2:]), wvals),
+            }
+            return new_state, out, fired.reshape(-1), \
+                jnp.broadcast_to(out_ts[None, :], (K, NM)).reshape(-1), n_adv
 
     return step
 

@@ -41,6 +41,7 @@ import numpy as np
 
 from windflow_tpu.basic import RoutingMode, WindFlowError, WinType
 from windflow_tpu.batch import WM_NONE, DeviceBatch
+from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.monitoring.jit_registry import wf_jit
 from windflow_tpu.ops.base import Operator
 from windflow_tpu.ops.tpu import _TPUReplica
@@ -343,7 +344,8 @@ class FfatWindowsTPU(Operator):
                     self.key_extractor,
                     drop_tainted=self.overflow_policy == "drop",
                     grouping=self._grouping(), ingest=ingest,
-                    monoid=self.monoid, op_name=f"{self.name}.mesh"))
+                    monoid=self.monoid, op_name=f"{self.name}.mesh",
+                    owner=self.name))
             # lanes a key shard's step runs over (its share of the batch:
             # mesh.py ffat_owned_lanes); `step_cap` on its wf.dispatch
             self.step_cap = ffat_owned_lanes(self.mesh, capacity)
@@ -351,7 +353,7 @@ class FfatWindowsTPU(Operator):
                 self.mesh, capacity, self.max_keys, self.P, self.R, self.D,
                 self.lift, self.comb, self.key_extractor,
                 monoid=self.monoid, grouping=self._grouping(),
-                ingest=ingest, op_name=f"{self.name}.mesh")
+                ingest=ingest, op_name=f"{self.name}.mesh", owner=self.name)
         # Pallas kernel selection (windflow_tpu/kernels): resolved once
         # per program build against Config.pallas_kernels + the runtime
         # backend; the kernels trace into this same wf_jit program, so
@@ -395,7 +397,8 @@ class FfatWindowsTPU(Operator):
                 # appended after the kernel's own args; cstats is the
                 # donated hit/miss/candidate state (zero extra dispatches)
                 *kargs, tk, tsl, cst = rest
-                raw = jax.vmap(user_key)(payload).astype(jnp.int32)
+                with flightrec.phase("wf.fn"):
+                    raw = jax.vmap(user_key)(payload).astype(jnp.int32)
                 slots, hit = compaction.lookup_slots(tk, tsl, raw, valid)
                 cst = compaction.cstats_update(cst, raw, hit,
                                                valid & ~hit)
@@ -406,6 +409,10 @@ class FfatWindowsTPU(Operator):
                     out["key"], tk, tsl)
                 outs = (outs[0], out) + tuple(outs[2:])
                 return (*outs, cst)
+        # this operator's part of the program under its own name (device
+        # phases, monitoring/recorder.py); a fused prelude's members open
+        # theirs, so the prelude runs outside it
+        step = flightrec.operator_scope(self.name)(step)
         prelude = self._fused_prelude
         if prelude is not None:
             # Whole-chain fusion (windflow_tpu/fusion): the fused
@@ -1035,7 +1042,8 @@ class FfatWindowsTPU(Operator):
             return make_sharded_ffat_flush(self.mesh, self.max_keys,
                                            self.P, self.R, self.D,
                                            self.comb,
-                                           op_name=f"{self.name}.flush")
+                                           op_name=f"{self.name}.flush",
+                                           owner=self.name)
         flush = make_ffat_flush(self.max_keys, self.P, self.R,
                                 self.D, self.comb)
         if self._compactor is not None:
@@ -1051,4 +1059,5 @@ class FfatWindowsTPU(Operator):
                 out["key"] = compaction.slots_to_user_keys(
                     out["key"], tk, tsl)
                 return out, fired, ts
-        return wf_jit(flush, op_name=f"{self.name}.flush")
+        return wf_jit(flightrec.operator_scope(self.name)(flush),
+                      op_name=f"{self.name}.flush")

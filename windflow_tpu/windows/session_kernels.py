@@ -43,6 +43,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from windflow_tpu.monitoring.recorder import phase
 from windflow_tpu.windows.ffat_kernels import _b, _seg_scan
 
 #: "no watermark yet" / "older than any event" in event-time microseconds
@@ -191,6 +192,12 @@ def make_session_step(capacity: int, K: int, gap: int, lift: Callable,
         allows and int64 where not."""
         gap_c = jnp.asarray(min(GAP, int(jnp.iinfo(rel.dtype).max)),
                             rel.dtype)
+        skey, srel, slift, iota, live = ordered(sid, rel, lifted)
+        kstart, rstart, run = runs(skey, srel, slift, gap_c)
+        return carried(skey, srel, iota, live, kstart, rstart, run, t0)
+
+    @phase("wf.session.sort")
+    def ordered(sid, rel, lifted):
         iota = jnp.arange(B, dtype=jnp.int32)
         # a lane of scalars rides the sort as one more operand (the sort
         # is 0.3 ms over 262144 lanes on a v5e, a gather by its
@@ -205,13 +212,20 @@ def make_session_step(capacity: int, K: int, gap: int, lift: Callable,
         slift = jax.tree.unflatten(
             tree, [next(riders) if r else a[order]
                    for a, r in zip(leaves, rides)])
-        live = skey < K
+        return skey, srel, slift, iota, skey < K
+
+    @phase("wf.session.scan")
+    def runs(skey, srel, slift, gap_c):
         kstart = jnp.concatenate([jnp.array([True]), skey[1:] != skey[:-1]])
         # same key: sorted by time, so the difference is >= 0 and in range
         rstart = kstart | jnp.concatenate(
             [jnp.array([False]), srel[1:] - srel[:-1] >= gap_c])
         run = _seg_scan(fold, rstart,
                         {"first": srel, "k0": kstart, "agg": slift})
+        return kstart, rstart, run
+
+    @phase("wf.session.carry")
+    def carried(skey, srel, iota, live, kstart, rstart, run, t0):
         kend = jnp.concatenate([kstart[1:], jnp.array([True])])
         rend = jnp.concatenate([rstart[1:], jnp.array([True])])
         some_cut = jnp.any(rstart & ~kstart & live)
@@ -247,13 +261,15 @@ def make_session_step(capacity: int, K: int, gap: int, lift: Callable,
         return src_l >= 0, src_f != src_l, first_run, last_run, between
 
     def step(state, payload, ts, valid, wm_adj):
-        keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
-            if key_fn is not None else jnp.zeros(B, jnp.int32)
+        with phase("wf.fn"):
+            keys = jax.vmap(key_fn)(payload).astype(jnp.int32) \
+                if key_fn is not None else jnp.zeros(B, jnp.int32)
         ts = ts.astype(jnp.int64)
         ok = valid & (keys >= 0) & (keys < K)
         late = ok & (ts < state["wm"])
         ok = ok & ~late
-        lifted = jax.vmap(lift)(payload)
+        with phase("wf.fn"):
+            lifted = jax.vmap(lift)(payload)
 
         t0 = jnp.min(jnp.where(ok, ts, jnp.int64(TS_MAX)))
         t0 = jnp.where(jnp.any(ok), t0, jnp.int64(0))
@@ -264,79 +280,82 @@ def make_session_step(capacity: int, K: int, gap: int, lift: Callable,
             narrow,
             lambda: lanes(sid, rel.astype(jnp.int32), lifted, t0),
             lambda: lanes(sid, rel, lifted, t0))
-        multi = has & multi
+        with phase("wf.session.carry"):
+            multi = has & multi
 
-        open_, first, last, agg = (state["open"], state["first"],
-                                   state["last"], state["agg"])
-        # the first run joins the open session where their windows
-        # intersect (it may also lie before it, inside the lateness)
-        overlap = open_ & has & (f_run["first"] < last + GAP) \
-            & (first < f_run["last"] + GAP)
-        m_first = jnp.where(overlap, jnp.minimum(first, f_run["first"]),
-                            first)
-        m_last = jnp.where(overlap, jnp.maximum(last, f_run["last"]), last)
-        m_agg = jax.tree.map(
-            lambda both, old: jnp.where(_b(overlap, both), both, old),
-            comb(agg, f_run["agg"]), agg)
-        # displaced: the old session (with the first run, where joined)
-        # by a later run, and a first run that stands alone by a later one
-        take_l = has & (multi | ~overlap)   # the last run takes the slot
-        x_old = open_ & take_l
-        y_first = has & multi & ~overlap
-        new_open = open_ | has
-        new_first = jnp.where(take_l, l_run["first"], m_first)
-        new_last = jnp.where(take_l, l_run["last"], m_last)
-        new_agg = jax.tree.map(
-            lambda l, m: jnp.where(_b(take_l, l), l, m), l_run["agg"], m_agg)
+            open_, first, last, agg = (state["open"], state["first"],
+                                       state["last"], state["agg"])
+            # the first run joins the open session where their windows
+            # intersect (it may also lie before it, inside the lateness)
+            overlap = open_ & has & (f_run["first"] < last + GAP) \
+                & (first < f_run["last"] + GAP)
+            m_first = jnp.where(overlap, jnp.minimum(first, f_run["first"]),
+                                first)
+            m_last = jnp.where(overlap, jnp.maximum(last, f_run["last"]), last)
+            m_agg = jax.tree.map(
+                lambda both, old: jnp.where(_b(overlap, both), both, old),
+                comb(agg, f_run["agg"]), agg)
+            # displaced: the old session (with the first run, where joined)
+            # by a later run, and a first run that stands alone by a later one
+            take_l = has & (multi | ~overlap)   # the last run takes the slot
+            x_old = open_ & take_l
+            y_first = has & multi & ~overlap
+            new_open = open_ | has
+            new_first = jnp.where(take_l, l_run["first"], m_first)
+            new_last = jnp.where(take_l, l_run["last"], m_last)
+            new_agg = jax.tree.map(
+                lambda l, m: jnp.where(_b(take_l, l), l, m), l_run["agg"],
+                m_agg)
 
-        n_forced = jnp.sum(x_old, dtype=jnp.int32) \
-            + jnp.sum(y_first, dtype=jnp.int32) \
-            + jnp.sum(between["flag"], dtype=jnp.int32)
-        early = lambda flag, end: jnp.sum(   # noqa: E731
-            flag & (end + GAP > wm_adj), dtype=jnp.int64)
-        n_early = early(x_old, m_last) + early(y_first, f_run["last"]) \
-            + early(between["flag"], between["last"])
+            n_forced = jnp.sum(x_old, dtype=jnp.int32) \
+                + jnp.sum(y_first, dtype=jnp.int32) \
+                + jnp.sum(between["flag"], dtype=jnp.int32)
+            early = lambda flag, end: jnp.sum(   # noqa: E731
+                flag & (end + GAP > wm_adj), dtype=jnp.int64)
+            n_early = early(x_old, m_last) + early(y_first, f_run["last"]) \
+                + early(between["flag"], between["last"])
 
-        def displaced(_):
-            cat = lambda *a: jnp.concatenate(a)   # noqa: E731
-            flag = cat(x_old, y_first, between["flag"])
-            rows = jnp.arange(K, dtype=jnp.int32)
-            c_key = cat(rows, rows, between["key"])
-            c_first = cat(m_first, f_run["first"], between["first"])
-            c_last = cat(m_last, f_run["last"], between["last"])
-            c_agg = jax.tree.map(cat, m_agg, f_run["agg"], between["agg"])
-            n = flag.shape[0]
-            pos = jnp.cumsum(flag.astype(jnp.int32)) - 1
-            src = jnp.full((OC,), -1, jnp.int32) \
-                .at[jnp.where(flag, pos, OC)] \
-                .set(jnp.arange(n, dtype=jnp.int32), mode="drop")
-            hit = src >= 0
-            at = _spread(src, n)
-            return {
-                "key": jnp.where(hit, c_key[at], 0),
-                "first": jnp.where(hit, c_first[at], 0),
-                "last": jnp.where(hit, c_last[at], 0),
-                "agg": jax.tree.map(
-                    lambda a: jnp.where(_b(hit, a[at]), a[at], 0), c_agg),
-                "fired": hit,
-                "n": n_forced,          # one a run at most: <= B = OC
+        with phase("wf.session.close"):
+            def displaced(_):
+                cat = lambda *a: jnp.concatenate(a)   # noqa: E731
+                flag = cat(x_old, y_first, between["flag"])
+                rows = jnp.arange(K, dtype=jnp.int32)
+                c_key = cat(rows, rows, between["key"])
+                c_first = cat(m_first, f_run["first"], between["first"])
+                c_last = cat(m_last, f_run["last"], between["last"])
+                c_agg = jax.tree.map(cat, m_agg, f_run["agg"], between["agg"])
+                n = flag.shape[0]
+                pos = jnp.cumsum(flag.astype(jnp.int32)) - 1
+                src = jnp.full((OC,), -1, jnp.int32) \
+                    .at[jnp.where(flag, pos, OC)] \
+                    .set(jnp.arange(n, dtype=jnp.int32), mode="drop")
+                hit = src >= 0
+                at = _spread(src, n)
+                return {
+                    "key": jnp.where(hit, c_key[at], 0),
+                    "first": jnp.where(hit, c_first[at], 0),
+                    "last": jnp.where(hit, c_last[at], 0),
+                    "agg": jax.tree.map(
+                        lambda a: jnp.where(_b(hit, a[at]), a[at], 0), c_agg),
+                    "fired": hit,
+                    "n": n_forced,          # one a run at most: <= B = OC
+                }
+
+            acc = jax.lax.cond(n_forced > 0, displaced,
+                               lambda _: _empty_acc(agg, OC), None)
+            new_open, acc, n_held = _close_ready(
+                new_open, new_first, new_last, new_agg, wm_adj, GAP, acc, OC)
+
+            new_state = {
+                "open": new_open, "first": new_first, "last": new_last,
+                "agg": new_agg,
+                "wm": jnp.maximum(state["wm"], wm_adj),
+                "n_late": state["n_late"] + jnp.sum(late, dtype=jnp.int64),
+                "n_closed": state["n_closed"] + acc["n"].astype(jnp.int64),
+                "n_early": state["n_early"] + n_early,
+                "n_held": state["n_held"] + n_held,
             }
-
-        acc = jax.lax.cond(n_forced > 0, displaced,
-                           lambda _: _empty_acc(agg, OC), None)
-        new_open, acc, n_held = _close_ready(
-            new_open, new_first, new_last, new_agg, wm_adj, GAP, acc, OC)
-
-        new_state = {
-            "open": new_open, "first": new_first, "last": new_last,
-            "agg": new_agg,
-            "wm": jnp.maximum(state["wm"], wm_adj),
-            "n_late": state["n_late"] + jnp.sum(late, dtype=jnp.int64),
-            "n_closed": state["n_closed"] + acc["n"].astype(jnp.int64),
-            "n_early": state["n_early"] + n_early,
-            "n_held": state["n_held"] + n_held,
-        }
-        out, fired, out_ts = _rows(acc, GAP)
+            out, fired, out_ts = _rows(acc, GAP)
         return new_state, out, fired, out_ts, n_held
 
     return step

@@ -55,6 +55,7 @@ import numpy as np
 
 from windflow_tpu.basic import RoutingMode, WindFlowError
 from windflow_tpu.batch import WM_NONE, DeviceBatch
+from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.monitoring.jit_registry import wf_jit
 from windflow_tpu.ops.base import Operator
 from windflow_tpu.ops.tpu import _TPUReplica
@@ -138,8 +139,11 @@ class SessionWindowsTPU(Operator):
 
     # -- per-batch program ---------------------------------------------------
     def _build_step(self, capacity: int):
-        step = make_session_step(capacity, self.max_keys, self.gap,
-                                 self.lift, self.comb, self.key_extractor)
+        # this operator's part of the program under its own name (device
+        # phases); a fused prelude's members open theirs outside it
+        step = flightrec.operator_scope(self.name)(make_session_step(
+            capacity, self.max_keys, self.gap, self.lift, self.comb,
+            self.key_extractor))
         prelude = self._fused_prelude
         if prelude is not None:
             # whole-chain fusion: the segment's stateless members (the
