@@ -450,9 +450,11 @@ def _lowered_sha(step, *args):
 
 def test_other_configurations_steps_are_the_parents():
     """YSB's (dense placement) and Q5's (narrow scatter) window steps
-    lower to the text they had at the parent commit (7f08677, this
-    backend): the session operator adds its own program and touches no
-    other."""
+    lower to the text they had when last changed on purpose (PR 35: the
+    ring's advances under conditionals, ``n_ring_advances`` in the
+    state; before it the text of 7f08677, unmoved through PR 32 to 34;
+    this backend): the session operator adds its own program and touches
+    no other."""
     S = jax.ShapeDtypeStruct
 
     def tb(B, K, R, D, NP):
@@ -470,10 +472,10 @@ def test_other_configurations_steps_are_the_parents():
 
 
 PARENT_SHA = {
-    "ysb": ("7e88cbf3158e93f202441f8ec940ff7b"
-            "2fc1c71aacbd02ca8c2caa171ee8f6a6"),
-    "q5": ("b5b96673be83ee62c7a4c99abef1c7a0"
-           "13e2ba4ca91d1c8436cfdc19f3403b48"),
+    "ysb": ("921982b686d6954cd1aa29dc6e6e3443"
+            "aa30fb0d35819e78d9d00527e5bf95c6"),
+    "q5": ("d31879cb37ee0797dbc4801c64dec94a"
+           "658713baaf294438e4b40ac4fb26e066"),
 }
 
 

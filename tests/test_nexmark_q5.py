@@ -394,6 +394,8 @@ def test_ysb_step_is_bit_identical_to_the_parents():
         trail.append((out, fired, out_ts, n_adv))
     assert fired.shape == (20100,)
     assert int(st.pop("n_wide")) == 0       # new in PR 31; the rest as was
+    # new in PR 35: every one of the five steps fired windows and advanced
+    assert int(st.pop("n_ring_advances")) == 5
     assert _digest((trail, st)) == ("c692f131125060f60866a92e023ae6a0"
                                     "d6622524764aefd23e6f96d975db7880")
 

@@ -44,12 +44,14 @@ from windflow_tpu.basic import WindFlowError, int32_key, stable_hash
 #: per-replica states: shape ()) — mirror of parallel/mesh._TB_SCALARS,
 #: duplicated so this module never imports jax at module scope
 TB_SCALARS = ("base", "win_next", "max_seen", "n_late", "n_evicted",
-              "n_win_dropped", "n_wide")
+              "n_win_dropped", "n_wide", "n_ring_advances")
 #: TB clock lanes that must AGREE across merged shards (the ring
 #: alignment invariants); the remaining scalars merge (max / sum)
 TB_ALIGNED = ("base", "win_next")
-#: ... the ones that sum (a blob from before ``n_wide`` lacks it)
-TB_COUNTERS = ("n_late", "n_evicted", "n_win_dropped", "n_wide")
+#: ... the ones that sum (a blob from before ``n_wide`` or
+#: ``n_ring_advances`` lacks it)
+TB_COUNTERS = ("n_late", "n_evicted", "n_win_dropped", "n_wide",
+               "n_ring_advances")
 #: the one shard-shaped lane of a count-based state, on a mesh alone: its
 #: many-round steps, one lane a key shard (parallel/mesh.CB_WIDE_STEPS;
 #: a blob from before it, or from one chip, lacks it and restores as 0)

@@ -186,6 +186,10 @@ def _families(stats: dict,
                  "Steps of a scatter-placed time window whose batch "
                  "spanned more panes than a narrow placement holds and "
                  "scattered into the whole ring")
+    f_adv = fam("wf_operator_tb_ring_advances_total", "counter",
+                "Steps of a time window in which its pane ring advanced "
+                "(fired windows freed panes, or the capacity roll made "
+                "room); the other steps make no pass over the ring")
     f_lanes = fam("wf_operator_cb_step_lanes", "gauge",
                   "Lanes the count-window step of one key shard of a mesh "
                   "is built at: its share of the staged batch")
@@ -199,6 +203,8 @@ def _families(stats: dict,
             f_lanes.add(op["CB_step_lanes"], dict(base, operator=name))
             f_whole.add(op.get("CB_wide_steps", 0),
                         dict(base, operator=name))
+        if "TB_ring_advances" in op:
+            f_adv.add(op["TB_ring_advances"], dict(base, operator=name))
         if op.get("TB_placement"):
             for form in ("dense", "scatter"):
                 f_place.add(1 if op["TB_placement"] == form else 0,

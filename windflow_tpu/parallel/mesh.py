@@ -953,7 +953,7 @@ def make_sharded_stateful_step(mesh: Mesh, capacity: int, S: int,
 # on the panes of the keys it owns — so the scalars become one lane per key
 # shard, sharded the same way as the ``[K, NP]`` cells.
 _TB_SCALARS = ("base", "win_next", "max_seen", "n_late", "n_evicted",
-               "n_win_dropped", "n_wide")
+               "n_win_dropped", "n_wide", "n_ring_advances")
 
 
 def make_sharded_ffat_tb_state(agg_spec, K: int, NP: int, mesh: Mesh):

@@ -798,6 +798,10 @@ def make_ffat_tb_state(agg_spec, K: int, NP: int):
         # steps whose batch spanned more panes than a narrow placement
         # holds, and scattered into the whole ring (NARROW_PLACE_PANES)
         "n_wide": jnp.zeros((), jnp.int64),
+        # steps in which the ring advanced (fired windows freed panes, or
+        # the capacity roll made room); every other step makes no pass
+        # over the ring
+        "n_ring_advances": jnp.zeros((), jnp.int64),
     }
 
 
@@ -836,6 +840,14 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
       windows ending between the batch's oldest pane and the watermark
       (routinely non-empty: on an ordered stream these are the windows the
       batch's own tuples closed).
+
+    The ring moves only in a step in which it has to: the roll after
+    each pass and the capacity roll (with the eviction accounting that
+    is trivial without it) each sit under a ``lax.cond`` on their own
+    shift, so a step that fires nothing and evicts nothing makes no pass
+    over the ``K x NP`` cells; the state's ``n_ring_advances``
+    (``TB_ring_advances``) counts the steps that moved.  Nothing may
+    ``vmap`` the step: a batched ``cond`` runs both branches.
 
     Returns ``(state, out, fired, out_ts, n_advanced)``; ``n_advanced``
     counts windows passed (fired or skipped-as-evicted) so drivers can loop
@@ -893,6 +905,12 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
         v = jax.tree.map(lambda a: jnp.take(a, idxc, axis=1), values)
         return f, v
 
+    def advance(flags, values, k):
+        # a roll by 0 is the identity and most steps move nothing: only a
+        # step whose ring advances pays for a pass over its K x NP cells
+        return jax.lax.cond(k > 0, roll_left, lambda f, v, _k: (f, v),
+                            flags, values, k)
+
     def fire_pass(cells, cell_valid, base, win_next, frontier, max_seen,
                   horizon, acc=None):
         """:func:`fire`, then the roll that its windows free."""
@@ -902,7 +920,7 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
         new_next = win_next + n_fired
         with phase("wf.ring"):
             shift = jnp.clip(new_next * D - base, 0, NP)
-            cell_valid, cells = roll_left(cell_valid, cells, shift)
+            cell_valid, cells = advance(cell_valid, cells, shift)
         return (cells, cell_valid, base + shift, new_next,
                 fired, wvals, w, n_fired, n_drop, acc)
 
@@ -1080,16 +1098,26 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
                 state["max_seen"], jnp.max(jnp.where(ok, pane, -(1 << 60))))
             shift_cap = jnp.maximum(jnp.int64(0),
                                     max_pane - base - (NP - 1))
-            col = jnp.arange(NP, dtype=jnp.int64)[None, :]
-            evict_mask = cell_valid & (col < shift_cap)
-            evicted = jnp.sum(evict_mask.astype(jnp.int64))
-            # per-key taint horizon: one past the newest data pane lost
-            # here
-            horizon = jnp.maximum(
-                state["horizon"],
-                jnp.max(jnp.where(evict_mask, base + col + 1, -(1 << 60)),
-                        axis=1))
-            cell_valid, cells = roll_left(cell_valid, cells, shift_cap)
+
+            def make_room(cell_valid, cells, horizon):
+                col = jnp.arange(NP, dtype=jnp.int64)[None, :]
+                evict_mask = cell_valid & (col < shift_cap)
+                # per-key taint horizon: one past the newest data pane
+                # lost here
+                horizon = jnp.maximum(
+                    horizon,
+                    jnp.max(jnp.where(evict_mask, base + col + 1,
+                                      -(1 << 60)), axis=1))
+                cell_valid, cells = roll_left(cell_valid, cells, shift_cap)
+                return (cell_valid, cells, horizon,
+                        jnp.sum(evict_mask.astype(jnp.int64)))
+
+            # the ring holds the batch's newest pane already (any ring the
+            # operator sized itself): nothing is evicted, nothing moves
+            cell_valid, cells, horizon, evicted = jax.lax.cond(
+                shift_cap > 0, make_room,
+                lambda f, v, h: (f, v, h, jnp.zeros((), jnp.int64)),
+                cell_valid, cells, state["horizon"])
             base = base + shift_cap
 
         # 3. place the batch: sort by (key, pane), fold runs, merge cells
@@ -1303,6 +1331,9 @@ def make_ffat_tb_step(capacity: int, K: int, P_usec: int, R: int, D: int,
             "n_evicted": state["n_evicted"] + evicted,
             "n_win_dropped": n_win_dropped,
             "n_wide": n_wide,
+            # base moves by the four shifts alone, none of them negative
+            "n_ring_advances": state["n_ring_advances"]
+            + jnp.where(base > state["base"], 1, 0),
         }
         all_passes = a_outs + [(fired_b, wvals_b, w_b, n_b)]
         n_adv = sum(p[3] for p in all_passes)

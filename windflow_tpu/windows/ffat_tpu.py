@@ -934,9 +934,11 @@ class FfatWindowsTPU(Operator):
         self._states = {k: jax.tree.map(place, st)
                         for k, st in blob["states"].items()}
         for st in self._states.values():
-            # a checkpoint from before the step counted its wide placements
+            # a checkpoint from before the step counted its wide
+            # placements, or the steps that advanced its ring
             if "n_late" in st:
-                st.setdefault("n_wide", jnp.zeros_like(st["n_late"]))
+                for name in ("n_wide", "n_ring_advances"):
+                    st.setdefault(name, jnp.zeros_like(st["n_late"]))
             elif self.mesh is not None:
                 # ... or, count-based on a mesh, its many-round steps
                 from windflow_tpu.parallel.mesh import (CB_WIDE_STEPS,
@@ -1018,6 +1020,10 @@ class FfatWindowsTPU(Operator):
             st["Pane_cells_evicted"] = self._tb_counter("n_evicted")
             st["Windows_dropped_on_overflow"] = \
                 self._tb_counter("n_win_dropped")
+            # steps in which the pane ring advanced (windows fired and
+            # freed panes, or the capacity roll made room): the others
+            # make no pass over it
+            st["TB_ring_advances"] = self._tb_counter("n_ring_advances")
         plan = self._tb_plan()
         if plan is not None:
             # static per built step: "dense" = the batch is placed by one
