@@ -54,9 +54,15 @@ def tb_window_sums(points, win_us, slide_us):
 #: else as a change), and only a ``benchmark`` PR may edit a file under
 #: ``tests/benchmark``: until one loosens the pins to "present and
 #: unchanged", the tests are expected to fail.
-#: ``tests/benchmark/test_device_phases_reader.py`` holds the manifest to
-#: additions-only against a hash of the parent's, without pinning its own
-#: entries as the last.
+#: ``tests/benchmark/test_device_phases_reader.py`` does not pin its own
+#: entries as the last, but it hashes the WHOLE manifest less its own
+#: entries against PR 33's, so a configuration, a cell or a cell name
+#: appended to a list breaks it all the same, although its comment says a
+#: later PR may append (outdated since PR 36 added ``nexmark_q9``).
+#: ``tests/benchmark/test_nexmark_q9_cell.py`` holds the manifest to
+#: "every entry of the parent's present and unchanged but for appended
+#: cell names" against the parent's manifest kept as data, which the
+#: next addition does not break.
 OUTDATED_MANIFEST_PINS = {
     "test_nexmark_q5_cell.py::"
     "test_the_manifest_lists_the_cell_as_additions_only":
@@ -66,6 +72,11 @@ OUTDATED_MANIFEST_PINS = {
     "test_the_manifest_lists_the_cell_as_additions_only":
         "pins nexmark_q11's three entries as per_layer[-3:]; the "
         "device-phase metrics are appended after them (PR 34)",
+    "test_device_phases_reader.py::"
+    "test_the_manifest_gains_these_entries_and_nothing_else":
+        "hashes the whole manifest less PR 34's entries against PR 33's; "
+        "nexmark_q9, its cell and its name in 24 lists are appended "
+        "(PR 36)",
 }
 
 

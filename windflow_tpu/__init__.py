@@ -31,6 +31,7 @@ from windflow_tpu.graph.builders import (Ffat_Windows_Builder,
                                          Ffat_WindowsTPU_Builder,
                                          Filter_Builder, FilterTPU_Builder,
                                          FlatMap_Builder,
+                                         Interval_JoinTPU_Builder,
                                          Keyed_Windows_Builder, Map_Builder,
                                          MapReduce_Windows_Builder,
                                          MapTPU_Builder,
@@ -54,6 +55,7 @@ from windflow_tpu.windows.engine import WindowSpec
 from windflow_tpu.windows.ffat_op import FfatWindows
 from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
 from windflow_tpu.windows.flatfat import FlatFAT
+from windflow_tpu.windows.join_tpu import IntervalJoinTPU
 from windflow_tpu.windows.session_tpu import SessionWindowsTPU
 from windflow_tpu.windows.ops import (KeyedWindows, MapReduceWindows,
                                       PanedWindows, ParallelWindows,
@@ -91,6 +93,7 @@ __all__ = [
     "Paned_Windows_Builder", "MapReduce_Windows_Builder",
     "Ffat_Windows_Builder", "Ffat_WindowsTPU_Builder",
     "SessionWindowsTPU", "Session_WindowsTPU_Builder",
+    "IntervalJoinTPU", "Interval_JoinTPU_Builder",
     "DBHandle", "LogKV", "PMap", "PFilter", "PFlatMap", "PReduce", "PSink",
     "PKeyedWindows", "P_Map_Builder", "P_Filter_Builder",
     "P_FlatMap_Builder", "P_Reduce_Builder", "P_Sink_Builder",

@@ -69,9 +69,9 @@ def _terminal(op) -> bool:
     from windflow_tpu.ops.tpu import ReduceTPU
     from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
     from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-    from windflow_tpu.windows.session_tpu import SessionWindowsTPU
-    return isinstance(op, (ReduceTPU, FfatWindowsTPU, SessionWindowsTPU,
-                           _StatefulTPUBase))
+    from windflow_tpu.windows.session_tpu import _RowsBoundedByDataTPU
+    return isinstance(op, (ReduceTPU, FfatWindowsTPU,
+                           _RowsBoundedByDataTPU, _StatefulTPUBase))
 
 
 def fusible_chains(graph) -> List[dict]:

@@ -583,13 +583,13 @@ def _checkpoints_unrebucketable_state(op) -> bool:
     from windflow_tpu.ops.tpu import ReduceTPU
     from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
     from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-    from windflow_tpu.windows.session_tpu import SessionWindowsTPU
+    from windflow_tpu.windows.session_tpu import _RowsBoundedByDataTPU
     # identity on the IMPLEMENTATION, not the class: a subclass that
     # overrides snapshot_state checkpoints a kind the re-bucketer has
     # never seen, however familiar its base class is
     known = {Reduce.snapshot_state, ReduceTPU.snapshot_state,
              FfatWindowsTPU.snapshot_state,
-             SessionWindowsTPU.snapshot_state,
+             _RowsBoundedByDataTPU.snapshot_state,   # sessions, the join
              _StatefulTPUBase.snapshot_state}
     return impl not in known
 

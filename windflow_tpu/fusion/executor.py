@@ -82,10 +82,11 @@ def _tail_supported(op) -> bool:
     from windflow_tpu.ops.tpu import ReduceTPU
     from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
     from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-    from windflow_tpu.windows.session_tpu import SessionWindowsTPU
-    if isinstance(op, SessionWindowsTPU):
+    from windflow_tpu.windows.session_tpu import _RowsBoundedByDataTPU
+    if isinstance(op, _RowsBoundedByDataTPU):
         # the step inlines the prelude ahead of its sort (the bid filter
-        # of NEXmark Q11 rides in jit_step_session)
+        # of NEXmark Q11 rides in jit_step_session, the person filter
+        # of Q9 in jit_step_join)
         return True
     if isinstance(op, FfatWindowsTPU):
         # compacted key spaces (withCompactedKeys, max_keys None) stay

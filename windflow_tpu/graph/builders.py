@@ -479,6 +479,7 @@ from windflow_tpu.windows.ops import (KeyedWindows, MapReduceWindows,  # noqa: E
                                       PanedWindows, ParallelWindows)
 from windflow_tpu.windows.ffat_op import FfatWindows  # noqa: E402
 from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU  # noqa: E402
+from windflow_tpu.windows.join_tpu import IntervalJoinTPU  # noqa: E402
 from windflow_tpu.windows.session_tpu import SessionWindowsTPU  # noqa: E402
 
 
@@ -774,3 +775,79 @@ class Session_WindowsTPU_Builder(_BuilderBase):
             self._lift, self._comb, self._gap, max_keys=self._max_keys,
             name=self._name, parallelism=self._parallelism,
             key_extractor=self._key_extractor, lateness=self._lateness)
+
+
+class Interval_JoinTPU_Builder(_BuilderBase):
+    """Keyed interval join on the device
+    (:class:`~windflow_tpu.windows.join_tpu.IntervalJoinTPU`): the build
+    rows of a stream each open an interval of event time on their key,
+    the probe rows are matched to the build row of their key that is open
+    at their time, and one row leaves a build row when the watermark
+    passes its end.  ``lift(build, probe, ts)`` maps a matched pair (and
+    the probe's event time) to an aggregate, ``comb`` folds two
+    (associative, any record; applied to whole lanes, as the FFAT
+    combiners are)."""
+
+    _default_name = "interval_join_tpu"
+
+    def __init__(self, lift_fn, comb_fn):
+        super().__init__()
+        self._lift = lift_fn
+        self._comb = comb_fn
+        self._build_side = None
+        self._length = None
+        self._match = None
+        self._capacity = None
+        self._out_capacity = None
+        self._lateness = 0
+
+    def withRebalancing(self):
+        raise WindFlowError(
+            "the join routes by key; REBALANCING does not apply")
+
+    def withBuildSide(self, fn):
+        """``fn(row) -> bool``: True on the rows that open an interval
+        (the build side), False on those matched to one (the probes)."""
+        self._build_side = fn
+        return self
+
+    def withIntervalLength(self, fn):
+        """``fn(build row) -> int``: the interval's length in event-time
+        microseconds; the row's interval is ``[ts, ts + length)``."""
+        self._length = fn
+        return self
+
+    def withMatch(self, fn):
+        """``fn(build row, probe row) -> bool``: a probe inside the
+        interval matches only where this holds (default: always)."""
+        self._match = fn
+        return self
+
+    def withBuildCapacity(self, n: int):
+        """Build rows the state holds open at once (the carry's lanes);
+        a step that would keep more stops the graph with an error."""
+        self._capacity = int(n)
+        return self
+
+    def withOutputCapacity(self, n: int):
+        """Lanes of the batch a step hands on (default: the input
+        batch's): closed rows beyond them wait in the state, so ``n``
+        is at least what one batch closes on average."""
+        self._out_capacity = int(n)
+        return self
+
+    def withLateness(self, lateness_usec: int):
+        """Build rows close ``lateness_usec`` after the watermark passes
+        their end, and a row is late once it is older than the watermark
+        by more than this."""
+        self._lateness = int(lateness_usec)
+        return self
+
+    def build(self) -> IntervalJoinTPU:
+        return IntervalJoinTPU(
+            self._lift, self._comb, build_side=self._build_side,
+            length=self._length, match=self._match,
+            key_extractor=self._key_extractor,
+            build_capacity=self._capacity,
+            out_capacity=self._out_capacity, name=self._name,
+            parallelism=self._parallelism, lateness=self._lateness)

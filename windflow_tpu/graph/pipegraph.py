@@ -530,13 +530,14 @@ class PipeGraph:
                 and self._recorder is not None:
             from windflow_tpu.monitoring.latency_ledger import LatencyLedger
             from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-            from windflow_tpu.windows.session_tpu import SessionWindowsTPU
+            from windflow_tpu.windows.session_tpu import \
+                _RowsBoundedByDataTPU
             self._latency = LatencyLedger(
                 self._recorder,
                 slo_ms=cfg.latency_slo_ms or 0.0)
             self._latency.megastep_plane = self._megastep_plane
             for op in self._operators:
-                if isinstance(op, (FfatWindowsTPU, SessionWindowsTPU)):
+                if isinstance(op, (FfatWindowsTPU, _RowsBoundedByDataTPU)):
                     for rep in op.replicas:
                         rep.latency = self._latency
             if self._health is not None:

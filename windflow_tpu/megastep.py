@@ -125,11 +125,10 @@ def tail_kind(op):
     from windflow_tpu.ops.tpu import ReduceTPU
     from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
     from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-    from windflow_tpu.windows.session_tpu import SessionWindowsTPU
-    if isinstance(op, SessionWindowsTPU):
-        return None, ("session windows (each step's hand-on watermark "
-                      "waits for the previous step's held-back count: "
-                      "per-batch dispatch, no scan body)")
+    from windflow_tpu.windows.session_tpu import _RowsBoundedByDataTPU
+    if isinstance(op, _RowsBoundedByDataTPU):
+        # session windows, the interval join
+        return None, op.per_batch_reason
     if isinstance(op, FfatWindowsTPU):
         if op.parallelism != 1:
             return None, "parallel window state (per-replica rings)"

@@ -197,8 +197,35 @@ def _families(stats: dict,
                   "Steps of a key-sharded count window in which a shard "
                   "owned more lanes than its share of the batch and took "
                   "more than one round over them, summed over the shards")
+    f_jb = fam("wf_operator_join_build_rows_total", "counter",
+               "Build rows of an interval join by what happened to them: "
+               "opened, closed (left the state), unmatched (closed with "
+               "an empty fold: no result row), displaced (closed by a "
+               "newer build row of their key before their end)")
+    f_jp = fam("wf_operator_join_probes_total", "counter",
+               "Probe rows of an interval join by outcome: matched, or "
+               "missed because no build row of their key stood at or "
+               "before their time, because they lay outside its "
+               "interval, or because the predicate refused them")
+    f_jo = fam("wf_operator_join_build_open", "gauge",
+               "Build rows an interval join holds in its carry now "
+               "(open, or closed and held back)")
+    f_jh = fam("wf_operator_join_rows_held_back_total", "counter",
+               "Closed rows a full output batch left in an interval "
+               "join's carry, summed over the steps that left them")
     for op in ops:
         name = op.get("Operator_name") or op.get("Name") or "?"
+        if "Join_build_opened" in op:
+            lab = dict(base, operator=name)
+            for event in ("opened", "closed", "unmatched", "displaced"):
+                f_jb.add(op.get("Join_build_" + event, 0),
+                         dict(lab, event=event))
+            for outcome in ("matched", "missed_no_build",
+                            "missed_interval", "missed_predicate"):
+                f_jp.add(op.get("Join_probe_" + outcome, 0),
+                         dict(lab, outcome=outcome))
+            f_jo.add(op.get("Join_build_open", 0), lab)
+            f_jh.add(op.get("Join_rows_held_back", 0), lab)
         if "CB_step_lanes" in op:
             f_lanes.add(op["CB_step_lanes"], dict(base, operator=name))
             f_whole.add(op.get("CB_wide_steps", 0),

@@ -397,6 +397,23 @@ PHASES: Dict[str, tuple] = {
         "fused operator program",
         "closing what the watermark passed and compacting the rows to "
         "the front of the output batch"),
+    "wf.join.sort": (
+        "fused operator program",
+        "an interval join bringing both sides into (key, event time) "
+        "order, the carried build rows included"),
+    "wf.join.match": (
+        "fused operator program",
+        "cutting the ordered lanes into runs (one build row and its "
+        "probes), the interval and predicate tests, the segmented fold "
+        "of the matched probes"),
+    "wf.join.carry": (
+        "fused operator program",
+        "what the join keeps for the next step: the open build rows "
+        "gathered into the carry, the counters"),
+    "wf.join.close": (
+        "fused operator program",
+        "picking the build rows that close, ordering them to the front "
+        "and gathering the output batch; what does not fit is held back"),
     "wf.mesh.own": (
         "mesh collectives (ICI)",
         "a key shard counting the lanes it owns and moving them to the "

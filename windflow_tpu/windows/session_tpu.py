@@ -78,43 +78,41 @@ class SessionTPUReplica(_TPUReplica):
             self.emitter.emit_device_batch(out)
 
 
-class SessionWindowsTPU(Operator):
-    """Session windows per key over a dense key space ``[0, max_keys)``
-    (module docstring: semantics, the touching rule, the contract on
-    disorder, the output batch)."""
+class _RowsBoundedByDataTPU(Operator):
+    """What the device operators whose rows close where the DATA says
+    share (session windows, the interval join): one replica on one chip,
+    one fixed-shape program a batch capacity (``program_name`` in a
+    device trace) that returns ``(state, out, fired, out_ts, held)``, the
+    closed rows compacted to the front of an output batch sized by the
+    operator, the rest held back in the state and the hand-on watermark
+    with them, and an end of stream that runs the same program on an
+    empty batch under an infinite watermark.  A subclass says how its
+    step and its state are made."""
 
     replica_class = SessionTPUReplica
-    fixed_capacity_label = "SessionWindowsTPU"
     #: the output batch is sized by what a step can close: its
     #: ``wf.dispatch`` span always says so (``out_cap``)
     notes_out_cap = True
+    #: the program's name in a device trace, less the ``jit_``
+    program_name = None
+    #: ``kind`` of the checkpoint's blob
+    snapshot_kind = None
+    #: why a megastep tail keeps per-batch dispatch (``megastep.tail_kind``)
+    per_batch_reason = None
 
-    def __init__(self, lift: Callable, comb: Callable, gap_usec: int, *,
-                 max_keys: int, name: str = "session_windows_tpu",
-                 parallelism: int = 1,
-                 key_extractor: Optional[Callable] = None,
-                 lateness: int = 0) -> None:
+    def __init__(self, name: str, parallelism: int,
+                 key_extractor: Optional[Callable], lateness: int) -> None:
         routing = (RoutingMode.KEYBY if key_extractor is not None
                    else RoutingMode.FORWARD)
         super().__init__(name, parallelism, routing=routing, is_tpu=True,
                          key_extractor=key_extractor)
         if parallelism != 1:
             raise WindFlowError(
-                f"SessionWindowsTPU '{name}' runs one replica (its state "
-                "is one dense table on one chip); got parallelism "
+                f"{self.fixed_capacity_label} '{name}' runs one replica "
+                "(its state lives on one chip); got parallelism "
                 f"{parallelism}")
-        if int(gap_usec) <= 0:
-            raise WindFlowError("the session gap must be > 0 usec")
-        if max_keys is None or int(max_keys) < 1:
-            raise WindFlowError(
-                f"SessionWindowsTPU '{name}' needs withMaxKeys(n >= 1): "
-                "its state is dense over [0, n)")
         if int(lateness) < 0:
             raise WindFlowError("lateness must be >= 0 usec")
-        self.lift = lift
-        self.comb = comb
-        self.gap = int(gap_usec)
-        self.max_keys = int(max_keys)
         self.lateness = int(lateness)
         self._state = None
         self._capacity = None
@@ -128,12 +126,32 @@ class SessionWindowsTPU(Operator):
         self._prev_wm = WM_NONE
         self._prev_held = None
 
+    def _make_step(self, capacity: int) -> Callable:
+        raise NotImplementedError
+
+    def _make_state(self, payload):
+        """The state for batches of ``payload`` (as the step sees them:
+        behind a fused prelude)."""
+        raise NotImplementedError
+
+    def _held(self, held) -> int:
+        """The held-back count of a step that is done (one device read)."""
+        return int(held)
+
+    def _last_held(self):
+        """What the last step said of its held-back rows, read (it may
+        raise) and kept as the step gave it; None before the first."""
+        if self._prev_held is None:
+            return None
+        self._held(self._prev_held)
+        return np.asarray(self._prev_held)
+
     def build_replicas(self, mode, time_policy):
         if self.mesh is not None:
             raise WindFlowError(
-                f"SessionWindowsTPU '{self.name}' does not run on a mesh "
-                "(its state is one dense table and its step sorts the "
-                "whole batch on one chip): build the graph without "
+                f"{self.fixed_capacity_label} '{self.name}' does not run "
+                "on a mesh (its state and its step's sort of the whole "
+                "batch live on one chip): build the graph without "
                 "Config.mesh")
         return super().build_replicas(mode, time_policy)
 
@@ -141,20 +159,18 @@ class SessionWindowsTPU(Operator):
     def _build_step(self, capacity: int):
         # this operator's part of the program under its own name (device
         # phases); a fused prelude's members open theirs outside it
-        step = flightrec.operator_scope(self.name)(make_session_step(
-            capacity, self.max_keys, self.gap, self.lift, self.comb,
-            self.key_extractor))
+        step = flightrec.operator_scope(self.name)(
+            self._make_step(capacity))
         prelude = self._fused_prelude
         if prelude is not None:
-            # whole-chain fusion: the segment's stateless members (the
-            # bid filter) run inside this program, as in ffat_tpu
+            # whole-chain fusion: the segment's stateless members (a
+            # filter) run inside this program, as in ffat_tpu
             inner = step
 
             def step(state, payload, ts, valid, wm_adj):
                 payload, valid = prelude(payload, valid)
                 return inner(state, payload, ts, valid, wm_adj)
-        # the program's name in a device trace: jit_step_session
-        step.__name__ = PROGRAM_NAME
+        step.__name__ = self.program_name
         return wf_jit(step, op_name=self._fused_name or self.name,
                       donate_argnums=(0,))
 
@@ -165,16 +181,15 @@ class SessionWindowsTPU(Operator):
             self._payload_zero = jax.tree.map(jnp.zeros_like, batch.payload)
         elif batch.capacity != self._capacity:
             raise WindFlowError(
-                "SessionWindowsTPU requires a fixed upstream batch "
-                f"capacity ({self._capacity}), got {batch.capacity}")
+                f"{self.fixed_capacity_label} requires a fixed upstream "
+                f"batch capacity ({self._capacity}), got {batch.capacity}")
         if self._state is None:
             payload = batch.payload
             if self._fused_prelude is not None:
                 from windflow_tpu.fusion.executor import prelude_out_spec
                 payload = prelude_out_spec(self._fused_prelude,
                                            batch.payload, batch.valid)
-            self._state = make_session_state(
-                agg_spec_for(self.lift, payload), self.max_keys)
+            self._state = self._make_state(payload)
 
     def _wm_adj(self, wm: int) -> int:
         return TS_MIN if wm == WM_NONE else wm - self.lateness
@@ -187,7 +202,8 @@ class SessionWindowsTPU(Operator):
         self._state, out, fired, out_ts, held = self._jit_step(
             self._state, batch.payload, batch.ts, batch.valid,
             jnp.int64(wm))
-        if self._prev_held is not None and int(self._prev_held) == 0 \
+        if self._prev_held is not None \
+                and self._held(self._prev_held) == 0 \
                 and self._prev_wm != TS_MIN:
             # the previous step emitted everything its watermark closed
             self._out_wm = max(self._out_wm, self._prev_wm)
@@ -196,12 +212,13 @@ class SessionWindowsTPU(Operator):
                            size=None, trace=batch.trace)
 
     def _flush(self) -> list:
-        """End of stream: close every open session, a whole output batch
-        at a time, by the step's own program on an empty batch under an
+        """End of stream: close every open row, a whole output batch at
+        a time, by the step's own program on an empty batch under an
         infinite watermark (as the time window flushes)."""
         if self._state is None or self._flushed:
             return []
         self._flushed = True
+        self._last_held()               # the last step's: it may raise
         cap = self._capacity
         ts0, none = jnp.zeros(cap, jnp.int64), jnp.zeros(cap, bool)
         outs = []
@@ -212,7 +229,7 @@ class SessionWindowsTPU(Operator):
             if bool(np.asarray(fired).any()):
                 outs.append(DeviceBatch(out, out_ts, fired, watermark=0,
                                         size=None))
-            if int(left) == 0:
+            if self._held(left) == 0:
                 return outs
 
     # -- durable state (windflow_tpu/durability) -----------------------------
@@ -220,14 +237,13 @@ class SessionWindowsTPU(Operator):
         if self._state is None:
             return None     # never stepped: nothing to restore
         return {
-            "kind": "session_tpu",
+            "kind": self.snapshot_kind,
             "state": jax.tree.map(np.asarray, self._state),
             "capacity": self._capacity,
             "flushed": self._flushed,
             "out_wm": self._out_wm,
             "prev_wm": self._prev_wm,
-            "prev_held": (None if self._prev_held is None
-                          else int(self._prev_held)),
+            "prev_held": self._last_held(),
             "payload_zero": jax.tree.map(np.asarray, self._payload_zero),
         }
 
@@ -242,9 +258,6 @@ class SessionWindowsTPU(Operator):
         self._jit_step = self._build_step(self._capacity)
 
     # -- plumbing --------------------------------------------------------------
-    def key_space(self):
-        return self.max_keys if self.key_extractor is not None else None
-
     def _counter(self, name: str) -> int:
         # one device sync at read time, never on the step path
         return int(self._state[name]) if self._state is not None else 0
@@ -258,10 +271,57 @@ class SessionWindowsTPU(Operator):
             self.replicas[0].stats.inputs_ignored = n_late
         st = super().dump_stats()
         if self._state is not None:
+            st["Late_tuples_dropped"] = n_late
+        return st
+
+
+class SessionWindowsTPU(_RowsBoundedByDataTPU):
+    """Session windows per key over a dense key space ``[0, max_keys)``
+    (module docstring: semantics, the touching rule, the contract on
+    disorder, the output batch)."""
+
+    fixed_capacity_label = "SessionWindowsTPU"
+    program_name = PROGRAM_NAME         # jit_step_session
+    snapshot_kind = "session_tpu"
+    per_batch_reason = (
+        "session windows (each step's hand-on watermark waits for the "
+        "previous step's held-back count: per-batch dispatch, no scan "
+        "body)")
+
+    def __init__(self, lift: Callable, comb: Callable, gap_usec: int, *,
+                 max_keys: int, name: str = "session_windows_tpu",
+                 parallelism: int = 1,
+                 key_extractor: Optional[Callable] = None,
+                 lateness: int = 0) -> None:
+        super().__init__(name, parallelism, key_extractor, lateness)
+        if int(gap_usec) <= 0:
+            raise WindFlowError("the session gap must be > 0 usec")
+        if max_keys is None or int(max_keys) < 1:
+            raise WindFlowError(
+                f"SessionWindowsTPU '{name}' needs withMaxKeys(n >= 1): "
+                "its state is dense over [0, n)")
+        self.lift = lift
+        self.comb = comb
+        self.gap = int(gap_usec)
+        self.max_keys = int(max_keys)
+
+    def _make_step(self, capacity: int):
+        return make_session_step(capacity, self.max_keys, self.gap,
+                                 self.lift, self.comb, self.key_extractor)
+
+    def _make_state(self, payload):
+        return make_session_state(agg_spec_for(self.lift, payload),
+                                  self.max_keys)
+
+    def key_space(self):
+        return self.max_keys if self.key_extractor is not None else None
+
+    def dump_stats(self) -> dict:
+        st = super().dump_stats()
+        if self._state is not None:
             st["Sessions_open"] = int(jnp.sum(self._state["open"]))
             st["Sessions_closed"] = self._counter("n_closed")
             st["Session_rows_held_back"] = self._counter("n_held")
-            st["Late_tuples_dropped"] = n_late
             st["Sessions_closed_early"] = self._counter("n_early")
             st["Session_out_capacity"] = session_out_capacity(
                 self._capacity, self.max_keys)
