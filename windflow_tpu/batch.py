@@ -505,8 +505,7 @@ def device_to_columns(batch: DeviceBatch):
     leading numpy arrays and ``tss`` is an int64 ``[n]`` array.  Reference:
     the GPU→CPU boundary is also one bulk pinned D2H copy before any
     per-tuple work (``keyby_emitter_gpu.hpp:594-638``)."""
-    r = device_to_columns_multi([batch])
-    return r[0]
+    return ColumnarEgress(batch).columns()
 
 
 def _np_local(a):
@@ -606,36 +605,50 @@ def _egress_unpack(raw, batch: DeviceBatch, treedef, specs, cap):
     return cols, tss[sel]
 
 
-def device_to_columns_multi(batches):
-    """Columnar egress for SEVERAL device batches in ONE device→host
-    transfer: each batch's lanes are packed on device (cached program) and
-    the packed buffers ride a single concatenated copy — per-transfer link
-    latency is paid once per group instead of once per batch (the deferred
-    columnar sink hands its whole queue here).  Returns a list of
-    ``(cols, tss)`` in input order."""
-    packed = []
-    metas = []
-    fallback = {}
-    for i, b in enumerate(batches):
-        ok, leaves, treedef, cap = _egress_packable(b)
+class ColumnarEgress:
+    """One DeviceBatch's columnar egress, STARTED: the pack program is
+    dispatched (asynchronous, the cached ``staging.egress_pack``) and the
+    packed buffer's device→host copy requested, so the copy follows the
+    step that fills the batch without the host waiting for either.
+    :meth:`is_ready` asks the device whether that step has run;
+    :meth:`columns` blocks for what is still in flight and re-types the
+    bytes.  A batch that cannot be packed (``_egress_packable``: numpy
+    leaves, lanes this process does not hold whole) keeps its lanes and
+    takes ``_columns_fallback``; its readiness is its validity lane's."""
+
+    __slots__ = ("batch", "_packed")
+
+    def __init__(self, batch: DeviceBatch) -> None:
+        self.batch = batch
+        ok, leaves, treedef, cap = _egress_packable(batch)
+        self._packed = None
         if ok:
-            buf, specs = _egress_pack(b, leaves, treedef, cap)
-            metas.append((i, b, treedef, specs, cap, buf.shape[0]))
-            packed.append(buf)
-        else:
-            fallback[i] = _columns_fallback(b)
-    out = [None] * len(batches)
-    for i, v in fallback.items():
-        out[i] = v
-    if packed:
-        raw_all = np.asarray(packed[0] if len(packed) == 1
-                             else jnp.concatenate(packed))  # ONE transfer
-        off = 0
-        for i, b, treedef, specs, cap, nwords in metas:
-            out[i] = _egress_unpack(raw_all[off:off + nwords], b, treedef,
-                                    specs, cap)
-            off += nwords
-    return out
+            buf, specs = _egress_pack(batch, leaves, treedef, cap)
+            buf.copy_to_host_async()
+            self._packed = (buf, treedef, specs, cap)
+
+    def is_ready(self) -> bool:
+        gate = self.batch.valid if self._packed is None else self._packed[0]
+        # a numpy lane (the megastep drain's slices) is on the host already
+        return not isinstance(gate, jax.Array) or gate.is_ready()
+
+    def columns(self):
+        if self._packed is None:
+            return _columns_fallback(self.batch)
+        buf, treedef, specs, cap = self._packed
+        return _egress_unpack(np.asarray(buf), self.batch, treedef, specs,
+                              cap)
+
+
+def device_to_columns_multi(batches):
+    """Columnar egress of device batches, each a :class:`DeviceBatch` or a
+    :class:`ColumnarEgress` started earlier (the columnar sink starts one
+    at receipt and hands it here when it delivers): every batch's pack
+    and copy are started before the first is waited for, one packed
+    buffer a batch.  Returns a list of ``(cols, tss)`` in input order."""
+    started = [b if isinstance(b, ColumnarEgress) else ColumnarEgress(b)
+               for b in batches]
+    return [e.columns() for e in started]
 
 
 def _columns_fallback(batch: DeviceBatch):

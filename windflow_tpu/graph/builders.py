@@ -281,8 +281,13 @@ class Sink_Builder(_BroadcastMixin, _BuilderBase):
         """Deliver TPU→Sink batches as SoA numpy columns (``SinkColumns``)
         instead of per-record dicts — one bulk device→host copy, zero
         per-tuple Python (egress twin of the columnar ingest path).
-        ``defer`` batches are held before conversion so the device→host
-        transfer overlaps later batches' compute (0 = convert eagerly)."""
+        A batch's copy starts when the sink receives it, and the batch is
+        delivered (in receipt order) when the device reports its step
+        done: at a later receipt or driver sweep, without a wait.
+        ``defer`` bounds how far the callback may trail the stream: only
+        while more than ``defer`` batches are in flight does the driver
+        wait, and then for the oldest (0 = convert at receipt).  The end
+        of the stream delivers everything before ``fn(None)``."""
         self._columnar = True
         self._columnar_defer = defer
         return self

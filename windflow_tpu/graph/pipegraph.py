@@ -43,6 +43,7 @@ from windflow_tpu.basic import (Config, ExecutionMode, RoutingMode,
 from windflow_tpu.graph.multipipe import MultiPipe
 from windflow_tpu.monitoring import recorder as flightrec
 from windflow_tpu.ops.base import Operator
+from windflow_tpu.ops.sink import Sink
 from windflow_tpu.ops.source import Source, SourceReplica
 from windflow_tpu.parallel.collectors import create_collector
 from windflow_tpu.parallel.emitters import SplittingEmitter, create_emitter
@@ -182,6 +183,9 @@ class PipeGraph:
         self._pool = None
         self._pool_replicas = []
         self._main_replicas = []
+        #: columnar sink replicas, polled once a sweep for batches the
+        #: device has finished (ops/sink.py SinkReplica.deliver)
+        self._columnar_sinks = []
         # pre-flight analysis (windflow_tpu/analysis): last check()'s
         # diagnostics + wall cost, surfaced through stats()
         self._preflight_diags = None
@@ -625,6 +629,10 @@ class PipeGraph:
                  else self._main_replicas).extend(op.replicas)
         else:
             self._main_replicas = self._all_replicas
+        self._columnar_sinks = [
+            rep for op in self._operators
+            if isinstance(op, Sink) and op.columnar
+            for rep in op.replicas]
 
     # -- execution -----------------------------------------------------------
     def run(self) -> "PipeGraph":
@@ -850,6 +858,16 @@ class PipeGraph:
             for f in futures:
                 if f.result():
                     progress = True
+        for rep in self._columnar_sinks:
+            # a columnar sink delivers a batch when the device reports its
+            # step done, which no inbox announces: ask (after the pool
+            # barrier, so a pooled sink is not drained beside this).  A
+            # sink that holds nothing costs this one attribute check
+            # (micro-asserted); one whose oldest batch is still running
+            # opens no span.
+            if rep._pending and rep.oldest_ready() \
+                    and self._drain(rep, limit):
+                progress = True
         # Staging-plane prefetch (Config.stage_prefetch_depth): the drain
         # above only DISPATCHED device work (JAX dispatch is async), so the
         # host is idle while the chip crunches — use it to pack batch N+1

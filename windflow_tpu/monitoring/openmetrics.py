@@ -213,8 +213,22 @@ def _families(stats: dict,
     f_jh = fam("wf_operator_join_rows_held_back_total", "counter",
                "Closed rows a full output batch left in an interval "
                "join's carry, summed over the steps that left them")
+    f_sd = fam("wf_operator_sink_deliveries_total", "counter",
+               "Batches a columnar sink delivered, by whether the device "
+               "had reported the batch done (ready) or the driver waited "
+               "for it (waited: more batches in flight than the sink's "
+               "defer bound, or the end of the stream)")
+    f_sp = fam("wf_operator_sink_pending_max", "gauge",
+               "Most batches a columnar sink replica has held in flight "
+               "at once")
     for op in ops:
         name = op.get("Operator_name") or op.get("Name") or "?"
+        if "Sink_deliveries_ready" in op:
+            lab = dict(base, operator=name)
+            for outcome in ("ready", "waited"):
+                f_sd.add(op.get("Sink_deliveries_" + outcome, 0),
+                         dict(lab, outcome=outcome))
+            f_sp.add(op.get("Sink_pending_max", 0), lab)
         if "Join_build_opened" in op:
             lab = dict(base, operator=name)
             for event in ("opened", "closed", "unmatched", "displaced"):

@@ -209,9 +209,10 @@ class Replica:
         self._maybe_hook_wm()
         self.stats.end_sample()
         if tr is not None and self.op.is_terminal:
-            # staged→sunk span closes at sink RECEIPT (a deferred columnar
-            # sink converts later; its extra defer is in the benchmark's
-            # delivery latency, not in this histogram)
+            # staged→sunk span closes at sink RECEIPT (a columnar sink
+            # delivers when the device reports the batch done, up to
+            # ``defer`` batches later; that is in the benchmark's delivery
+            # latency, not in this histogram)
             now = current_time_usecs()
             self.ring.record(tr[0], flightrec.SUNK, now)
             self.stats.e2e_hist.add(now - tr[1])

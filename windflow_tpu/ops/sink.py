@@ -14,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional
 
+from windflow_tpu import batch as wfbatch
 from windflow_tpu.basic import RoutingMode
 from windflow_tpu.meta import adapt
 from windflow_tpu.monitoring import recorder as flightrec
@@ -37,8 +38,13 @@ class SinkReplica(Replica):
     def __init__(self, op: "Sink", index: int) -> None:
         super().__init__(op, index)
         self._fn = adapt(op.fn, 1)
-        self._pending = []          # deferred device batches (columnar)
-        self._pending_bytes = 0     # their transfer size (wf.sink.d2h)
+        #: columnar egresses in flight (``batch.ColumnarEgress``), oldest
+        #: first.  Empty on a sink that holds nothing, which is all the
+        #: driver's sweep checks (``PipeGraph._sweep``)
+        self._pending = []
+        self.deliveries_ready = 0   # delivered without a wait
+        self.deliveries_waited = 0  # the bound or end of stream waited
+        self.pending_max = 0
 
     def process_single(self, item, ts, wm):
         self._fn(item, self.context)
@@ -49,55 +55,80 @@ class SinkReplica(Replica):
         # one bulk copy, record sinks get per-tuple dicts.  The egress copy
         # moves the timestamp and validity lanes too, so the D2H counter
         # uses the shared whole-batch definition (batch.transfer_nbytes).
-        from windflow_tpu.batch import transfer_nbytes
-        nbytes = transfer_nbytes(batch)
+        nbytes = wfbatch.transfer_nbytes(batch)
         self.stats.d2h_bytes += nbytes
         if self.op.columnar:
-            # Deferred conversion: hold the last ``defer`` batches and pull
-            # the oldest — JAX dispatch is asynchronous, so the device→host
-            # transfer of batch i overlaps the compute of batches i+1.. and
-            # the per-transfer link latency leaves the critical path (the
-            # reference hides D2H behind per-batch CUDA streams the same
-            # way).  EOS drains the queue.
-            self._pending.append(batch)
-            self._pending_bytes += nbytes
-            if len(self._pending) > self.op.columnar_defer:
-                # drain the whole queue in ONE device->host transfer
-                pend, self._pending = self._pending, []
-                self._deliver_columns(pend)
+            # The copy starts now: the batch's pack program is enqueued
+            # behind the step that fills it and the host copy requested
+            # (JAX dispatch is asynchronous), and nobody waits.  What the
+            # device reports done is delivered, in receipt order, here and
+            # once a driver sweep; only when MORE than ``defer`` batches
+            # are in flight does the driver block, and then for the
+            # oldest, never for the step it was just handed (the reference
+            # hides D2H behind per-batch CUDA streams the same way).  EOS
+            # drains the queue.
+            self._pending.append(wfbatch.ColumnarEgress(batch))
+            self.pending_max = max(self.pending_max, len(self._pending))
+            self.deliver(keep=self.op.columnar_defer)
             return
-        from windflow_tpu.batch import device_to_host
         with flightrec.span("wf.sink.d2h", batch=batch.seq, batches=1,
                             bytes=nbytes, lanes=batch.capacity):
-            hb = device_to_host(batch)
+            hb = wfbatch.device_to_host(batch)
         with flightrec.span("wf.sink.deliver", batch=batch.seq,
                             rows=len(hb.items)):
             for item, ts in zip(hb.items, hb.tss):
                 self.context._set_context(ts, batch.watermark)
                 self._fn(item, self.context)
 
-    def _deliver_columns(self, batches):
-        from windflow_tpu.batch import device_to_columns_multi
-        nbytes, self._pending_bytes = self._pending_bytes, 0
-        # one transfer for the whole queue: the span carries its first
-        # batch's number, how many ride with it and the lanes they hold
-        # (rows and padding alike: the copy moves whole batches)
-        with flightrec.span("wf.sink.d2h", batch=batches[0].seq,
-                            batches=len(batches), bytes=nbytes,
-                            lanes=sum(b.capacity for b in batches)):
-            columns = device_to_columns_multi(batches)
-        for b, (cols, tss) in zip(batches, columns):
-            if len(tss):
-                self.context._set_context(int(tss[-1]), b.watermark)
-                with flightrec.span("wf.sink.deliver", batch=b.seq,
-                                    rows=len(tss)):
-                    self._fn(SinkColumns(cols, tss, b.watermark),
-                             self.context)
+    def oldest_ready(self) -> bool:
+        """Has the device finished the oldest batch in flight?  (Only
+        asked of a replica that holds one.)"""
+        return self._pending[0].is_ready()
+
+    def deliver(self, keep: Optional[int] = None) -> bool:
+        """Deliver, oldest first, the batches in flight whose step the
+        device reports done, and stop at the first that is not, unless
+        more than ``keep`` are in flight: then wait for it (``None``
+        never waits).  Finding nothing to deliver opens no span and
+        allocates nothing."""
+        delivered = False
+        while self._pending:
+            ready = self.oldest_ready()
+            if not ready and (keep is None or len(self._pending) <= keep):
+                break
+            self._deliver_oldest(waited=int(not ready))
+            delivered = True
+        return delivered
+
+    def drain(self, limit: int = 0) -> bool:
+        # the inbox, then what the device has finished meanwhile: the
+        # driver's sweep comes here with an empty inbox when the oldest
+        # batch in flight is ready (PipeGraph._sweep)
+        progressed = super().drain(limit)
+        return self.deliver() or progressed
+
+    def _deliver_oldest(self, waited: int) -> None:
+        egress = self._pending.pop(0)
+        if waited:
+            self.deliveries_waited += 1
+        else:
+            self.deliveries_ready += 1
+        b = egress.batch
+        # the copy moves the whole batch, rows and padding alike;
+        # ``waited`` says whether the driver blocked for the batch's step
+        # here or found it done
+        with flightrec.span("wf.sink.d2h", batch=b.seq, batches=1,
+                            bytes=wfbatch.transfer_nbytes(b),
+                            lanes=b.capacity, waited=waited):
+            (cols, tss), = wfbatch.device_to_columns_multi([egress])
+        if len(tss):
+            self.context._set_context(int(tss[-1]), b.watermark)
+            with flightrec.span("wf.sink.deliver", batch=b.seq,
+                                rows=len(tss)):
+                self._fn(SinkColumns(cols, tss, b.watermark), self.context)
 
     def on_eos(self):
-        if self._pending:
-            self._deliver_columns(self._pending)
-            self._pending = []
+        self.deliver(keep=0)
         self._fn(None, self.context)
 
 
@@ -116,6 +147,20 @@ class Sink(Operator):
         #: columnar sinks receive SinkColumns per device batch instead of
         #: per-record dicts (host-batch edges still deliver records)
         self.columnar = columnar
-        #: batches held before conversion (transfer/compute overlap); the
-        #: user callback trails the stream by up to this many batches
+        #: the user callback trails the stream by up to this many batches:
+        #: a batch is delivered when the device reports its step done, and
+        #: the driver waits (for the oldest) only while more than this
+        #: many are in flight; 0 converts at receipt
         self.columnar_defer = max(0, columnar_defer)
+
+    def dump_stats(self) -> dict:
+        st = super().dump_stats()
+        if self.columnar:
+            reps = self.replicas
+            st["Sink_deliveries_ready"] = sum(r.deliveries_ready
+                                              for r in reps)
+            st["Sink_deliveries_waited"] = sum(r.deliveries_waited
+                                               for r in reps)
+            st["Sink_pending_max"] = max((r.pending_max for r in reps),
+                                         default=0)
+        return st
