@@ -55,7 +55,8 @@ from windflow_tpu.windows.engine import WindowSpec
 from windflow_tpu.windows.ffat_op import FfatWindows
 from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
 from windflow_tpu.windows.flatfat import FlatFAT
-from windflow_tpu.windows.join_tpu import IntervalJoinTPU
+from windflow_tpu.windows.join_tpu import (IntervalJoinPairsTPU,
+                                           IntervalJoinTPU)
 from windflow_tpu.windows.session_tpu import SessionWindowsTPU
 from windflow_tpu.windows.ops import (KeyedWindows, MapReduceWindows,
                                       PanedWindows, ParallelWindows,
@@ -93,7 +94,7 @@ __all__ = [
     "Paned_Windows_Builder", "MapReduce_Windows_Builder",
     "Ffat_Windows_Builder", "Ffat_WindowsTPU_Builder",
     "SessionWindowsTPU", "Session_WindowsTPU_Builder",
-    "IntervalJoinTPU", "Interval_JoinTPU_Builder",
+    "IntervalJoinTPU", "IntervalJoinPairsTPU", "Interval_JoinTPU_Builder",
     "DBHandle", "LogKV", "PMap", "PFilter", "PFlatMap", "PReduce", "PSink",
     "PKeyedWindows", "P_Map_Builder", "P_Filter_Builder",
     "P_FlatMap_Builder", "P_Reduce_Builder", "P_Sink_Builder",

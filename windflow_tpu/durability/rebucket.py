@@ -351,7 +351,8 @@ def rebucket_blob(op, blob: dict, old_p: int, new_p: int,
         return _rebucket_stateful(op, blob, new_kk)
     if kind == "reduce_tpu":
         return blob     # drop counters + remap: shard-shape independent
-    if kind in ("session_tpu", "interval_join_tpu"):
+    if kind in ("session_tpu", "interval_join_tpu",
+                "interval_join_pairs_tpu"):
         # one replica, no mesh (the operators refuse both at build):
         # their state has no shard shape to change
         return blob

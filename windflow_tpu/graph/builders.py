@@ -484,7 +484,8 @@ from windflow_tpu.windows.ops import (KeyedWindows, MapReduceWindows,  # noqa: E
                                       PanedWindows, ParallelWindows)
 from windflow_tpu.windows.ffat_op import FfatWindows  # noqa: E402
 from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU  # noqa: E402
-from windflow_tpu.windows.join_tpu import IntervalJoinTPU  # noqa: E402
+from windflow_tpu.windows.join_tpu import (IntervalJoinPairsTPU,  # noqa: E402
+                                           IntervalJoinTPU)
 from windflow_tpu.windows.session_tpu import SessionWindowsTPU  # noqa: E402
 
 
@@ -784,26 +785,41 @@ class Session_WindowsTPU_Builder(_BuilderBase):
 
 class Interval_JoinTPU_Builder(_BuilderBase):
     """Keyed interval join on the device
-    (:class:`~windflow_tpu.windows.join_tpu.IntervalJoinTPU`): the build
-    rows of a stream each open an interval of event time on their key,
-    the probe rows are matched to the build row of their key that is open
-    at their time, and one row leaves a build row when the watermark
-    passes its end.  ``lift(build, probe, ts)`` maps a matched pair (and
-    the probe's event time) to an aggregate, ``comb`` folds two
-    (associative, any record; applied to whole lanes, as the FFAT
-    combiners are)."""
+    (:class:`~windflow_tpu.windows.join_tpu.IntervalJoinTPU` and
+    :class:`~windflow_tpu.windows.join_tpu.IntervalJoinPairsTPU`), in two
+    forms.
+
+    **One row a build row** (``Interval_JoinTPU_Builder(lift, comb)`` +
+    ``withIntervalLength``): the build rows of a stream each open an
+    interval of event time on their key, the probe rows are matched to
+    the build row of their key that is open at their time, and one row
+    leaves a build row when the watermark passes its end.  ``lift(build,
+    probe, ts)`` maps a matched pair (and the probe's event time) to an
+    aggregate, ``comb`` folds two (associative, any record; applied to
+    whole lanes, as the FFAT combiners are).
+
+    **One row a matched pair** (``Interval_JoinTPU_Builder(join)`` +
+    ``withBoundaries(lower, upper)``, ``withMaxKeys``,
+    ``withProbeCapacity``): a build row at ``t`` is retained, by key,
+    for the probes with ``t - lower <= u < t + upper``; every such probe
+    that ``withMatch`` lets through leaves as ``join(build, probe, u)``
+    in the step in which both are known, and a probe that came before its
+    build row waits for it no longer than ``lower``."""
 
     _default_name = "interval_join_tpu"
 
-    def __init__(self, lift_fn, comb_fn):
+    def __init__(self, lift_or_join_fn, comb_fn=None):
         super().__init__()
-        self._lift = lift_fn
+        self._lift = lift_or_join_fn
         self._comb = comb_fn
         self._build_side = None
         self._length = None
         self._match = None
         self._capacity = None
         self._out_capacity = None
+        self._boundaries = None
+        self._max_keys = None
+        self._probe_capacity = None
         self._lateness = 0
 
     def withRebalancing(self):
@@ -818,8 +834,31 @@ class Interval_JoinTPU_Builder(_BuilderBase):
 
     def withIntervalLength(self, fn):
         """``fn(build row) -> int``: the interval's length in event-time
-        microseconds; the row's interval is ``[ts, ts + length)``."""
+        microseconds; the row's interval is ``[ts, ts + length)`` (the
+        form that folds)."""
         self._length = fn
+        return self
+
+    def withBoundaries(self, lower_usec: int, upper_usec: int):
+        """Static bounds of the form that emits pairs (the reference's
+        ``Interval_Join`` ``withBoundaries``): a build row at ``t``
+        meets the probes with ``t - lower <= u < t + upper``; it is
+        retained for ``upper`` of event time, and a probe that came
+        first waits for it for ``lower``."""
+        self._boundaries = (int(lower_usec), int(upper_usec))
+        return self
+
+    def withMaxKeys(self, n: int):
+        """Keys of the pair form are int32 in ``[0, n)``: its retained
+        build rows are dense over them, one a key."""
+        self._max_keys = int(n)
+        return self
+
+    def withProbeCapacity(self, n: int):
+        """Probes the pair form holds at once while they wait for their
+        build row; a step that would keep more stops the graph with an
+        error."""
+        self._probe_capacity = int(n)
         return self
 
     def withMatch(self, fn):
@@ -829,30 +868,50 @@ class Interval_JoinTPU_Builder(_BuilderBase):
         return self
 
     def withBuildCapacity(self, n: int):
-        """Build rows the state holds open at once (the carry's lanes);
-        a step that would keep more stops the graph with an error."""
+        """Build rows the state holds open at once (the carry's lanes of
+        the form that folds); a step that would keep more stops the
+        graph with an error."""
         self._capacity = int(n)
         return self
 
     def withOutputCapacity(self, n: int):
         """Lanes of the batch a step hands on (default: the input
-        batch's): closed rows beyond them wait in the state, so ``n``
-        is at least what one batch closes on average."""
+        batch's): rows beyond them wait in the state, so ``n`` is at
+        least what one batch closes (or pairs) on average."""
         self._out_capacity = int(n)
         return self
 
     def withLateness(self, lateness_usec: int):
-        """Build rows close ``lateness_usec`` after the watermark passes
-        their end, and a row is late once it is older than the watermark
-        by more than this."""
+        """Build rows close (are evicted) ``lateness_usec`` after the
+        watermark passes their end, and a row is late once it is older
+        than the watermark by more than this."""
         self._lateness = int(lateness_usec)
         return self
 
-    def build(self) -> IntervalJoinTPU:
-        return IntervalJoinTPU(
-            self._lift, self._comb, build_side=self._build_side,
-            length=self._length, match=self._match,
+    def build(self):
+        name = self._name
+        common = dict(
+            build_side=self._build_side, match=self._match,
             key_extractor=self._key_extractor,
-            build_capacity=self._capacity,
-            out_capacity=self._out_capacity, name=self._name,
+            out_capacity=self._out_capacity, name=name,
             parallelism=self._parallelism, lateness=self._lateness)
+        folds = (self._length, self._capacity)
+        pairs = (self._boundaries, self._max_keys, self._probe_capacity)
+        if self._comb is None:
+            if any(x is not None for x in folds):
+                raise WindFlowError(
+                    f"IntervalJoinTPU '{name}': a join function alone "
+                    "emits a row a matched pair; withIntervalLength and "
+                    "withBuildCapacity belong to the form that folds "
+                    "(built with lift and comb)")
+            return IntervalJoinPairsTPU(
+                self._lift, boundaries=self._boundaries,
+                max_keys=self._max_keys,
+                probe_capacity=self._probe_capacity, **common)
+        if any(x is not None for x in pairs):
+            raise WindFlowError(
+                f"IntervalJoinTPU '{name}': withBoundaries, withMaxKeys "
+                "and withProbeCapacity belong to the form that emits "
+                "pairs (built with a join function alone)")
+        return IntervalJoinTPU(self._lift, self._comb, length=self._length,
+                               build_capacity=self._capacity, **common)

@@ -201,18 +201,34 @@ def _families(stats: dict,
                "Build rows of an interval join by what happened to them: "
                "opened, closed (left the state), unmatched (closed with "
                "an empty fold: no result row), displaced (closed by a "
-               "newer build row of their key before their end)")
+               "newer build row of their key before their end); the pair "
+               "form: built (written into the table), replaced (by a "
+               "newer row of their key while retained), evicted (the "
+               "watermark passed t + upper)")
     f_jp = fam("wf_operator_join_probes_total", "counter",
                "Probe rows of an interval join by outcome: matched, or "
                "missed because no build row of their key stood at or "
                "before their time, because they lay outside its "
-               "interval, or because the predicate refused them")
+               "interval, or because the predicate refused them; the pair "
+               "form also: waited (held over a step for a build row not "
+               "yet there; each then ends as one of the others)")
     f_jo = fam("wf_operator_join_build_open", "gauge",
                "Build rows an interval join holds in its carry now "
                "(open, or closed and held back)")
     f_jh = fam("wf_operator_join_rows_held_back_total", "counter",
-               "Closed rows a full output batch left in an interval "
-               "join's carry, summed over the steps that left them")
+               "Closed rows (the pair form: completed pairs) a full "
+               "output batch left in an interval join's state, summed "
+               "over the steps that left them")
+    f_jr = fam("wf_operator_join_build_retained", "gauge",
+               "Build rows the pair form of an interval join retains in "
+               "its keyed table now (written, and neither replaced nor "
+               "evicted)")
+    f_jw = fam("wf_operator_join_probes_pending", "gauge",
+               "Probes the pair form of an interval join holds now while "
+               "they wait for a build row of their key")
+    f_jm = fam("wf_operator_join_probes_pending_max", "gauge",
+               "Most probes that ever waited at once (against "
+               "withProbeCapacity)")
     f_sd = fam("wf_operator_sink_deliveries_total", "counter",
                "Batches a columnar sink delivered, by whether the device "
                "had reported the batch done (ready) or the driver waited "
@@ -229,6 +245,21 @@ def _families(stats: dict,
                 f_sd.add(op.get("Sink_deliveries_" + outcome, 0),
                          dict(lab, outcome=outcome))
             f_sp.add(op.get("Sink_pending_max", 0), lab)
+        if "Join_build_built" in op:
+            # the pair form: the same two families, its own events
+            lab = dict(base, operator=name)
+            for event in ("built", "replaced", "evicted"):
+                f_jb.add(op.get("Join_build_" + event, 0),
+                         dict(lab, event=event))
+            for outcome in ("matched", "missed_no_build",
+                            "missed_interval", "missed_predicate",
+                            "waited"):
+                f_jp.add(op.get("Join_probe_" + outcome, 0),
+                         dict(lab, outcome=outcome))
+            f_jr.add(op.get("Join_build_retained", 0), lab)
+            f_jw.add(op.get("Join_probe_pending", 0), lab)
+            f_jm.add(op.get("Join_probe_pending_max", 0), lab)
+            f_jh.add(op.get("Join_rows_held_back", 0), lab)
         if "Join_build_opened" in op:
             lab = dict(base, operator=name)
             for event in ("opened", "closed", "unmatched", "displaced"):

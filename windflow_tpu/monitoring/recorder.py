@@ -405,15 +405,26 @@ PHASES: Dict[str, tuple] = {
         "fused operator program",
         "cutting the ordered lanes into runs (one build row and its "
         "probes), the interval and predicate tests, the segmented fold "
-        "of the matched probes"),
+        "of the matched probes (the pair form: the build row handed "
+        "down its run, and the sort that brings pairs, waiting probes "
+        "and build rows to the front)"),
     "wf.join.carry": (
         "fused operator program",
         "what the join keeps for the next step: the open build rows "
-        "gathered into the carry, the counters"),
+        "gathered into the carry, the counters (the pair form: the "
+        "probes that go on waiting for their build row)"),
     "wf.join.close": (
         "fused operator program",
         "picking the build rows that close, ordering them to the front "
-        "and gathering the output batch; what does not fit is held back"),
+        "and gathering the output batch; what does not fit is held back "
+        "(the pair form: the pairs a step completed into its output "
+        "batch, through the held-back lanes where they do not fit)"),
+    "wf.join.table": (
+        "fused operator program",
+        "the pair form's retained build side: writing a batch's build "
+        "rows into the keyed table, looking up the probes whose build "
+        "row is not before them in their batch, the validity test that "
+        "evicts"),
     "wf.mesh.own": (
         "mesh collectives (ICI)",
         "a key shard counting the lanes it owns and moving them to the "

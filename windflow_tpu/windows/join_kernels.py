@@ -325,3 +325,384 @@ def make_join_step(capacity: int, C: int, key_fn: Callable,
             jnp.stack([counts["n_held"], counts["n_overflow"]])
 
     return step
+
+
+# ---------------------------------------------------------------------------
+# the pair form: a row a matched pair, the build side retained by key
+# ---------------------------------------------------------------------------
+
+#: a table row that was never written: its time lies under every event's
+NONE_HI = -(1 << 31)
+#: lanes of the windows in which a pair step writes its build rows into
+#: the table and looks its waiting probes up: the batch's, over this (a
+#: scatter and a gather cost by the lane, hit or not; a step with more of
+#: either than a window holds takes the whole batch's width instead)
+TABLE_DIV = 8
+#: what a pair step counts, in the state as int64 scalars
+PAIR_COUNTERS = ("n_late", "n_built", "n_replaced", "n_matched",
+                 "n_miss_build", "n_miss_interval", "n_miss_pred",
+                 "n_waited", "n_held", "n_overflow")
+
+
+def _words(t):
+    """An int64 time as two int32 words (a 64-bit scatter costs 8x a
+    32-bit one): ``t = hi * 2**31 + lo``, ``0 <= lo < 2**31``."""
+    return ((t >> 31).astype(jnp.int32),
+            (t & ((1 << 31) - 1)).astype(jnp.int32))
+
+
+def _time(hi, lo):
+    return (hi.astype(jnp.int64) << 31) | lo.astype(jnp.int64)
+
+
+def retained(hi, lo, upper: int, wm):
+    """Which table rows (time words ``hi`` / ``lo``) stand under the
+    watermark ``wm``: written, and ``t + upper`` still over it.  The test
+    that evicts: the step's lookups and the operator's gauge share it."""
+    return (hi != NONE_HI) & (_time(hi, lo) + upper > wm)
+
+
+def pair_reads(join_fn: Callable, match_fn: Optional[Callable], row_spec):
+    """Which leaves of the build row and of the probe row ``join`` and
+    ``match`` read (two lists of bools in leaf order): the table keeps,
+    and the sorts carry, only those."""
+    fns = [join_fn]
+    if match_fn is not None:
+        fns.append(lambda b, p, ts: match_fn(b, p))
+    return _reads(fns, row_spec)
+
+
+def make_join_pairs_state(row_spec, reads_b, reads_p, K: int, P: int,
+                          HC: int):
+    """The pair form's state: the retained build rows, keyed and dense
+    over ``[0, K)`` (time as two int32 words and the leaves the pair
+    functions read), ``P`` probes that wait for their build row (whole
+    rows), ``HC`` pairs a full output batch held back, and the step's
+    scalars."""
+    leaves, _ = jax.tree.flatten(row_spec)
+    col = lambda n: lambda s: jnp.zeros((n,) + s.shape, s.dtype)  # noqa: E731
+    kept = lambda n, reads: [col(n)(s) for s, r in zip(leaves, reads)  # noqa: E731
+                             if r]
+    state = {
+        "tab": {"hi": jnp.full((K,), NONE_HI, jnp.int32),
+                "lo": jnp.zeros((K,), jnp.int32), "row": kept(K, reads_b)},
+        "pend": {"live": jnp.zeros((P,), bool),
+                 "key": jnp.zeros((P,), jnp.int32),
+                 "at": jnp.zeros((P,), jnp.int64),
+                 "row": jax.tree.map(col(P), row_spec)},
+        "held": {"n": jnp.zeros((), jnp.int32),
+                 "key": jnp.zeros((HC,), jnp.int32),
+                 "t": jnp.zeros((HC,), jnp.int64),
+                 "u": jnp.zeros((HC,), jnp.int64),
+                 "b": kept(HC, reads_b), "p": kept(HC, reads_p)},
+        "wm": jnp.full((), TS_MIN, jnp.int64),
+        # most probes that ever waited at once
+        "pend_max": jnp.zeros((), jnp.int64),
+    }
+    state.update({c: jnp.zeros((), jnp.int64) for c in PAIR_COUNTERS})
+    return state
+
+
+def make_join_pairs_step(capacity: int, K: int, P: int, key_fn: Callable,
+                         build_fn: Callable, match_fn: Optional[Callable],
+                         join_fn: Callable, lower: int, upper: int,
+                         out_capacity: Optional[int] = None):
+    """Per-batch program of the pair form: ``step(state, payload, ts,
+    valid, wm_adj) -> (state, out, fired, out_ts, held)``, the signature
+    of :func:`make_join_step`.  A build row at ``t`` is retained for the
+    probes of its key with ``t - lower <= u < t + upper``; every such
+    probe that ``match`` lets through leaves as one row, ``join(build,
+    probe, u)``, in the step in which both are known.
+
+    One step, over ``N = P + B`` lanes (the waiting probes in front of
+    the batch):
+
+    1. one sort by (key, event time, build before probe), the leaves the
+       pair functions read riding, and one segmented scan that hands each
+       run's build row down its lanes: a probe with a build row of its
+       key at or before it IN ITS BATCH is tested against that one;
+    2. one stable sort by class brings, in this order, the pairs so
+       found, the probes still WAITING (no build row before them in the
+       batch) and the batch's build rows to the front;
+    3. the table: the build rows (the newest a key) are written into it,
+       32-bit words only, then the waiting probes look their key up in
+       it (an earlier step's row, or a later row of their own batch).
+       Both touch a window of ``B // TABLE_DIV`` lanes where the rows
+       fit, the whole width where not.  A row is looked up as live while
+       ``t + upper`` lies over the watermark of the steps before: it is
+       evicted by that test, not by a pass over the table;
+    4. a waiting probe that found no live row stays in the state (the
+       next ``P`` pending lanes) until the watermark reaches ``u +
+       lower``, then it is a miss;
+    5. the output is the front of the class order, ``join`` applied to
+       its lanes: the pairs of (1), then those of (3) where they lie (a
+       waiting probe that stayed unmatched leaves a hole).  Pairs that
+       do not fit, or any while older ones are held back, go through
+       the ``held`` lanes in order (one more sort, only then).
+
+    ``held`` is int64 ``[5]``: pairs held back after this step; pairs
+    LOST for want of held lanes; waiting probes lost for want of pending
+    lanes; rows whose key lies outside ``[0, K)``; the event time of the
+    oldest probe still waiting (:data:`TS_MAX` where none), which the
+    watermark handed on may not pass."""
+    B, K, P = int(capacity), int(K), int(P)
+    LOWER, UPPER = int(lower), int(upper)
+    N, OC = P + B, join_out_capacity(capacity, out_capacity)
+    HC = OC
+    W = min(max(B // TABLE_DIV, 1), N)
+    iota_n = jnp.arange(N, dtype=jnp.int32)
+    count = lambda m: jnp.sum(m, dtype=jnp.int32)   # noqa: E731
+    wide = lambda m: jnp.sum(m, dtype=jnp.int64)   # noqa: E731
+
+    def step(state, payload, ts, valid, wm_adj):
+        with phase("wf.fn"):
+            keys = jax.vmap(key_fn)(payload).astype(jnp.int32)
+            builds = jax.vmap(build_fn)(payload).astype(bool)
+        ts = ts.astype(jnp.int64)
+        in_range = (keys >= 0) & (keys < K)
+        ok = valid & in_range
+        late = ok & (ts < state["wm"])
+        ok = ok & ~late
+        wm_before = state["wm"]
+        wm_now = jnp.maximum(wm_before, wm_adj)
+        tab, pend, held_rows = state["tab"], state["pend"], state["held"]
+
+        with phase("wf.join.sort"):
+            cat = lambda a, b: jnp.concatenate([a, b])   # noqa: E731
+            live = cat(pend["live"], ok)
+            is_build = jnp.pad(ok & builds, (P, 0))
+            sid = jnp.where(live, cat(pend["key"], keys), NO_KEY)
+            at = cat(pend["at"], ts)
+            t0 = jnp.min(jnp.where(live, at, jnp.int64(TS_MAX)))
+            t0 = jnp.where(jnp.any(live), t0, jnp.int64(0))
+            rel2 = jnp.where(live, at - t0, 0) * 2 + (live & ~is_build)
+            hi, lo = _words(rel2)
+            rows = jax.tree.map(cat, pend["row"], payload)
+            leaves, tree = jax.tree.flatten(rows)
+            reads_b, reads_p = pair_reads(join_fn, match_fn, jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), rows))
+            rides = [b or p for b, p in zip(reads_b, reads_p)]
+            (skey, shi, slo), ridden, _, order = sort_lanes(
+                (sid, hi, lo), [a for a, r in zip(leaves, rides) if r])
+
+        def whole(read, which, lanes):
+            """A row tree of ``lanes`` lanes from the leaves ``read``,
+            those ``which`` marks (0 where a leaf was not kept)."""
+            it = iter(read)
+            return jax.tree.unflatten(tree, [
+                next(it) if w else jnp.zeros((lanes,) + a.shape[1:], a.dtype)
+                for a, w in zip(leaves, which)])
+
+        def of(read, have, want):
+            """Of the leaves ``read`` (those ``have`` marks), the ones
+            ``want`` marks."""
+            it = iter(read)
+            out = []
+            for h, w in zip(have, want):
+                a = next(it) if h else None
+                if w:
+                    out.append(a)
+            return out
+
+        with phase("wf.join.match"):
+            live = skey < NO_KEY
+            is_build = live & (slo % 2 == 0)
+            is_probe = live & ~is_build
+            srel = _time(shi, slo) >> 1
+            kstart = jnp.concatenate(
+                [jnp.array([True]), skey[1:] != skey[:-1]])
+            seg = kstart | is_build
+            own_b = of(ridden, rides, reads_b)
+            head = _seg_scan(lambda first, _: first, seg, {
+                "has": is_build, "rel": srel, "row": own_b})
+            before = is_probe & head["has"]
+            inside = before & (srel - head["rel"] < UPPER)
+            waiting = is_probe & ~head["has"]
+        with phase("wf.fn"):
+            fits = jax.vmap(match_fn)(
+                whole(head["row"], reads_b, N),
+                whole(of(ridden, rides, reads_p), reads_p, N)).astype(bool) \
+                if match_fn is not None else jnp.ones((N,), bool)
+        with phase("wf.join.match"):
+            matched1 = inside & fits
+            n0, n1, n2 = count(matched1), count(waiting), count(is_build)
+            # pairs, waiting probes, build rows, then the rest; stable,
+            # so each class stays in (key, time) order
+            cls = jnp.where(matched1, 0, jnp.where(
+                waiting, 1, jnp.where(is_build, 2, 3))).astype(jnp.int32)
+            _, c, _, _ = sort_lanes((cls,), {
+                "key": skey, "rel": srel, "head_rel": head["rel"],
+                "own": ridden, "head": head["row"], "origin": order})
+
+        def table_pass(M):
+            """Steps 3 and 4 over windows of ``M`` lanes of the class
+            order (``M == N``: over all of it)."""
+            def win(a, start):
+                return a if M == N else jax.lax.dynamic_slice_in_dim(
+                    a, start, M)
+
+            def put(a, start, new, mask):
+                if M == N:
+                    return jnp.where(_b(mask, a), new, a)
+                cur = jax.lax.dynamic_slice_in_dim(a, start, M)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    a, jnp.where(_b(mask, cur), new, cur), start, 0)
+
+            def lanes_of(off, n):
+                start = jnp.clip(off, 0, N - M)
+                lane = start + jnp.arange(M, dtype=jnp.int32)
+                return start, (lane >= off) & (lane < off + n)
+
+            def run():
+                with phase("wf.join.table"):
+                    # the batch's build rows, the newest of a key
+                    sb, mb = lanes_of(n0 + n1, n2)
+                    kb = win(c["key"], sb)
+                    tb = t0 + win(c["rel"], sb)
+                    newer = jnp.concatenate(
+                        [mb[1:] & (kb[1:] == kb[:-1]), jnp.array([False])])
+                    winner = mb & ~newer
+                    at_b = jnp.where(mb, kb, 0)
+                    old_hi = tab["hi"][at_b]
+                    old_live = retained(old_hi, tab["lo"][at_b], UPPER,
+                                        wm_before)
+                    n_replaced = wide(winner & old_live) \
+                        + wide(mb) - wide(winner)
+                    idx = jnp.where(winner, kb, K)
+                    hi_b, lo_b = _words(tb)
+                    put_row = lambda t, a: t.at[idx].set(   # noqa: E731
+                        a, mode="drop")
+                    new_tab = {
+                        "hi": put_row(tab["hi"], hi_b),
+                        "lo": put_row(tab["lo"], lo_b),
+                        "row": [put_row(t, win(a, sb)) for t, a in zip(
+                            tab["row"], of(c["own"], rides, reads_b))]}
+                    # the waiting probes, against the table as it now is
+                    sw, mu = lanes_of(n0, n1)
+                    ku = win(c["key"], sw)
+                    u = t0 + win(c["rel"], sw)
+                    at_u = jnp.where(mu, ku, 0)
+                    g_hi, g_lo = new_tab["hi"][at_u], new_tab["lo"][at_u]
+                    g_t = _time(g_hi, g_lo)
+                    g_row = [t[at_u] for t in new_tab["row"]]
+                    found = mu & retained(g_hi, g_lo, UPPER, wm_before)
+                    d = u - g_t
+                    inside2 = found & (d >= -LOWER) & (d < UPPER)
+                with phase("wf.fn"):
+                    fits2 = jax.vmap(match_fn)(
+                        whole(g_row, reads_b, M), whole(
+                            [win(a, sw) for a in of(c["own"], rides,
+                                                    reads_p)],
+                            reads_p, M)).astype(bool) \
+                        if match_fn is not None else jnp.ones((M,), bool)
+                with phase("wf.join.table"):
+                    matched2 = inside2 & fits2
+                    nobuild = mu & ~found
+                    missed = nobuild & (wm_now >= u + LOWER)
+                    pending = nobuild & ~missed
+                    emit = put(iota_n < n0, sw, matched2, mu)
+                    t_abs = put(t0 + c["head_rel"], sw, g_t, mu)
+                    b_rows = [put(a, sw, g, mu)
+                              for a, g in zip(c["head"], g_row)]
+                with phase("wf.join.carry"):
+                    # the probes that go on waiting, to the front
+                    n_pend = count(pending)
+                    _, pick = jax.lax.sort(
+                        ((~pending).astype(jnp.int32),
+                         jnp.arange(M, dtype=jnp.int32)), num_keys=1)
+                    pick = jnp.pad(pick[:P], (0, max(P - M, 0)))
+                    stays = jnp.arange(P, dtype=jnp.int32) < n_pend
+                    origin = win(c["origin"], sw)[pick]
+                    new_pend = {
+                        "live": stays,
+                        "key": jnp.where(stays, ku[pick], 0),
+                        "at": jnp.where(stays, u[pick], 0),
+                        "row": jax.tree.map(lambda a: jnp.where(
+                            _b(stays, a[origin]), a[origin], 0), rows)}
+                return new_tab, new_pend, emit, t_abs, b_rows, {
+                    "n_replaced": n_replaced,
+                    "n_matched": wide(matched2),
+                    "n_miss_build": wide(missed),
+                    "n_miss_interval": wide(found & ~inside2),
+                    "n_miss_pred": wide(inside2 & ~fits2),
+                    # probes of this batch that wait for a later one
+                    "n_waited": wide(pending & (win(c["origin"], sw) >= P)),
+                    "n_pend": n_pend.astype(jnp.int64),
+                    "oldest": jnp.min(jnp.where(pending, u,
+                                                jnp.int64(TS_MAX)))}
+            return run
+
+        if W == N:
+            new_tab, new_pend, emit, t_abs, b_rows, seen = table_pass(N)()
+        else:
+            new_tab, new_pend, emit, t_abs, b_rows, seen = jax.lax.cond(
+                (n1 <= W) & (n2 <= W), table_pass(W), table_pass(N))
+
+        with phase("wf.join.close"):
+            pairs = {"key": c["key"], "t": t_abs, "u": t0 + c["rel"],
+                     "b": b_rows, "p": of(c["own"], rides, reads_p)}
+            n_old = held_rows["n"]
+            n_all = n_old + count(emit)
+            n_out = jnp.minimum(n_all, OC)
+            n_left = jnp.minimum(n_all - n_out, HC)
+            old = {k: v for k, v in held_rows.items() if k != "n"}
+
+            def front():
+                # every pair lies in the first OC lanes of the order
+                return (jax.tree.map(lambda a: a[:OC], pairs), emit[:OC],
+                        old)
+
+            def queued():
+                # the pairs held back first, then this step's, in order
+                flag = jnp.concatenate(
+                    [jnp.arange(HC, dtype=jnp.int32) < n_old, emit])
+                _, at = jax.lax.sort(
+                    ((~flag).astype(jnp.int32),
+                     jnp.arange(HC + N, dtype=jnp.int32)), num_keys=1)
+                both = jax.tree.map(lambda h, a: jnp.concatenate([h, a]),
+                                    old, pairs)
+                out_at, held_at = at[:OC], at[OC:OC + HC]
+                kept = jnp.arange(HC, dtype=jnp.int32) < n_left
+                return (jax.tree.map(lambda a: a[out_at], both),
+                        jnp.arange(OC, dtype=jnp.int32) < n_out,
+                        jax.tree.map(lambda a: jnp.where(
+                            _b(kept, a[held_at]), a[held_at], 0), both))
+
+            rows_out, fired, new_held = jax.lax.cond(
+                (n_old == 0) & (n0 + n1 <= OC), front, queued)
+            new_held["n"] = n_left
+        with phase("wf.fn"):
+            value = jax.vmap(join_fn)(whole(rows_out["b"], reads_b, OC),
+                                      whole(rows_out["p"], reads_p, OC),
+                                      rows_out["u"])
+        with phase("wf.join.close"):
+            blank = lambda a: jnp.where(_b(fired, a), a, 0)  # noqa: E731
+            out = jax.tree.map(blank, {
+                "key": rows_out["key"], "build_ts": rows_out["t"],
+                "probe_ts": rows_out["u"], "value": value})
+            out_ts = blank(jnp.maximum(rows_out["t"], rows_out["u"]))
+            counts = {
+                "n_late": wide(late), "n_built": wide(ok & builds),
+                "n_replaced": seen["n_replaced"],
+                "n_matched": wide(matched1) + seen["n_matched"],
+                "n_miss_build": seen["n_miss_build"],
+                "n_miss_interval": wide(before & ~inside)
+                + seen["n_miss_interval"],
+                "n_miss_pred": wide(inside & ~fits) + seen["n_miss_pred"],
+                "n_waited": seen["n_waited"],
+                "n_held": n_left.astype(jnp.int64),
+                "n_overflow": (n_all - n_out - n_left).astype(jnp.int64)
+                + jnp.maximum(seen["n_pend"] - P, 0),
+            }
+            new_state = {"tab": new_tab, "pend": new_pend, "held": new_held,
+                         "wm": wm_now,
+                         "pend_max": jnp.maximum(state["pend_max"],
+                                                 seen["n_pend"])}
+            new_state.update({k: state[k] + counts[k]
+                              for k in PAIR_COUNTERS})
+        return new_state, out, fired, out_ts, jnp.stack([
+            counts["n_held"], (n_all - n_out - n_left).astype(jnp.int64),
+            jnp.maximum(seen["n_pend"] - P, 0),
+            wide(valid & ~in_range), seen["oldest"]])
+
+    return step

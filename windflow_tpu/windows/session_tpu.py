@@ -138,6 +138,11 @@ class _RowsBoundedByDataTPU(Operator):
         """The held-back count of a step that is done (one device read)."""
         return int(held)
 
+    def _hand_on(self, wm: int, held) -> int:
+        """The watermark to hand on after a step that acted on ``wm``
+        and held nothing back (``held``: what that step said)."""
+        return wm
+
     def _last_held(self):
         """What the last step said of its held-back rows, read (it may
         raise) and kept as the step gave it; None before the first."""
@@ -206,7 +211,8 @@ class _RowsBoundedByDataTPU(Operator):
                 and self._held(self._prev_held) == 0 \
                 and self._prev_wm != TS_MIN:
             # the previous step emitted everything its watermark closed
-            self._out_wm = max(self._out_wm, self._prev_wm)
+            self._out_wm = max(self._out_wm, self._hand_on(
+                self._prev_wm, self._prev_held))
         self._prev_wm, self._prev_held = wm, held
         return DeviceBatch(out, out_ts, fired, watermark=self._out_wm,
                            size=None, trace=batch.trace)
