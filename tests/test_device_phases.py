@@ -261,11 +261,16 @@ def test_the_sketch_and_the_egress_pack_carry_their_phase():
     ok, leaves, treedef, cap = wbatch._egress_packable(b)
     assert ok
     wbatch._egress_pack(b, leaves, treedef, cap)
-    (pack,) = [v for k, v in wbatch._EGRESS_PACK_CACHE.items()
-               if k[2] == 8 and len(k[1]) == 2]
-    names = op_names(pack._jit.lower(leaves, b.ts, b.valid).compile()
-                     .as_text())
-    assert any("/wf.egress.pack/" in n for n in names)
+    # ... and so does the program that packs a batch's front only
+    wbatch._egress_pack(b, leaves, treedef, cap, front=4)
+    whole, front = [[v for k, v in wbatch._EGRESS_PACK_CACHE.items()
+                     if k[2] == 8 and len(k[1]) == 2 and k[3:] == rest]
+                    for rest in ((None,), (4,))]
+    for (pack,) in (whole, front):
+        names = op_names(pack._jit.lower(leaves, b.ts, b.valid).compile()
+                         .as_text())
+        assert any("/wf.egress.pack/" in n for n in names)
+        assert not any("wf.op." in n for n in names)
 
 
 def _sources():

@@ -38,8 +38,8 @@ class Gated(wfbatch.ColumnarEgress):
     made: list = []
     blocked_on: list = []       # seq of every batch waited for, in order
 
-    def __init__(self, b):
-        super().__init__(b)
+    def __init__(self, b, front=None):
+        super().__init__(b, front)
         self.ready = False if self._packed is not None else None
         Gated.made.append(self)
 
@@ -344,8 +344,13 @@ def test_counters_and_the_spans_waited_say_what_happened(monkeypatch, gated):
     assert row["Sink_pending_max"] == 3
     d2h = [a.counts for a in _Annotation.made if a.name == "wf.sink.d2h"]
     assert [c["waited"] for c in d2h] == [0, 1, 0, 1]
-    assert all(c["batches"] == 1 and c["lanes"] == 64 and c["bytes"] > 0
-               for c in d2h)
+    # four whole-batch copies (a batch this small is never copied by its
+    # front): ``lanes`` copied = the batch's ``cap``, ``bytes`` = its lanes
+    # (key 4 + value 4 + ts 8 + valid 1 a lane), summed in the counter
+    assert all(c["batches"] == 1 and c["lanes"] == c["cap"] == 64
+               and c["bytes"] == 64 * 17 for c in d2h)
+    assert st["Bytes_D2H_total"] == sum(c["bytes"] for c in d2h)
+    assert row["Sink_front_copies"] == row["Sink_front_overflows"] == 0
     assert [c["batch"] for c in d2h] == sorted(c["batch"] for c in d2h)
     rows = [a.counts["rows"] for a in _Annotation.made
             if a.name == "wf.sink.deliver"]
@@ -356,6 +361,9 @@ def test_counters_and_the_spans_waited_say_what_happened(monkeypatch, gated):
     assert by == {"ready": 2, "waited": 2}
     (_n, _l, most), = fams["wf_operator_sink_pending_max"]["samples"]
     assert most == 3
+    for name in ("front_copies", "front_overflows"):
+        (_n, _l, v), = fams[f"wf_operator_sink_{name}_total"]["samples"]
+        assert v == 0
 
 
 def test_a_record_sink_has_no_columnar_counters_and_is_not_polled():

@@ -488,8 +488,28 @@ def columns_to_device(cols, tss, capacity: int, watermark: int = WM_NONE,
 
 
 #: cached pack programs for single-transfer egress, keyed by the payload's
-#: (treedef, shape/dtype) signature
+#: (treedef, shape/dtype) signature and the lanes of a front copy (or None)
 _EGRESS_PACK_CACHE: dict = {}
+
+#: fewest leading lanes a front copy takes: a copy of a few hundred KB
+#: costs its latency, not its bytes (``device_to_columns``), so a shorter
+#: front saves nothing (the idiom of ``session_kernels.FRONT_MIN``)
+FRONT_MIN_LANES = 4096
+
+
+def front_lanes(cap: int, extent: int) -> Optional[int]:
+    """Leading lanes to copy of a ``cap``-lane batch whose rows are
+    expected to end by lane ``extent``: the smallest of ``cap / 2``,
+    ``cap / 4``, ... that is not under :data:`FRONT_MIN_LANES` and holds
+    twice the extent, or ``None`` where none does or the batch is too
+    small to be worth a second program (the whole batch then)."""
+    if cap <= 2 * FRONT_MIN_LANES:
+        return None
+    need = max(2 * extent, FRONT_MIN_LANES)
+    front, lanes = None, cap // 2
+    while lanes >= need:
+        front, lanes = lanes, lanes // 2
+    return front
 
 
 def device_to_columns(batch: DeviceBatch):
@@ -540,17 +560,22 @@ def _egress_packable(batch: DeviceBatch):
     return ok, leaves, treedef, cap
 
 
-def _egress_pack(batch: DeviceBatch, leaves, treedef, cap):
-    """Device program producing the batch's single uint32 egress buffer."""
+def _egress_pack(batch: DeviceBatch, leaves, treedef, cap,
+                 front: Optional[int] = None):
+    """Device program producing the batch's single uint32 egress buffer:
+    every lane, or with ``front`` the leading ``front`` lanes of each
+    (the same word layout at that capacity) followed by a two-word header,
+    the batch's valid lanes counted over ALL ``cap`` lanes and its extent
+    (1 + the index of the last valid lane, 0 for none)."""
     specs = tuple((str(np.dtype(l.dtype)), tuple(l.shape[1:]))
                   for l in leaves)
-    key = (treedef, specs, cap)
+    key = (treedef, specs, cap, front)
     pack = _EGRESS_PACK_CACHE.get(key)
     if pack is None:
         def to_words(l):
             # only 32-bit device bitcasts (see packing note above):
             # 64-bit lanes leave as arithmetic lo/hi uint32 pairs
-            l = l.reshape(-1)
+            l = (l if front is None else l[:front]).reshape(-1)
             if l.dtype == jnp.bool_:
                 return [l.astype(jnp.uint32)]
             if np.dtype(l.dtype).itemsize == 8:
@@ -566,28 +591,36 @@ def _egress_pack(batch: DeviceBatch, leaves, treedef, cap):
             for l in lvs:
                 parts.extend(to_words(l))
             parts.extend(to_words(ts))
-            parts.append(vld.astype(jnp.uint32))
+            parts.extend(to_words(vld))
+            if front is not None:
+                last = jnp.arange(1, cap + 1, dtype=jnp.int32)
+                parts.append(jnp.stack(
+                    [vld.sum(dtype=jnp.int32),
+                     jnp.max(jnp.where(vld, last, 0))]).astype(jnp.uint32))
             return jnp.concatenate(parts)
         pack = wf_jit(pack_fn, op_name="staging.egress_pack")
         _EGRESS_PACK_CACHE[key] = pack
     return pack(leaves, batch.ts, batch.valid), specs
 
 
-def _egress_unpack(raw, batch: DeviceBatch, treedef, specs, cap):
+def _egress_unpack(raw, treedef, specs, lanes, n):
+    """Re-type a packed buffer of ``lanes`` lanes a leaf (words after them
+    are not read) and select its valid lanes; ``n`` is their count where
+    it is known.  Returns ``(cols, tss, extent)``."""
     def take(off, dt, trail=()):
         d = np.dtype(dt)
-        n = cap * math.prod(trail)
+        w = lanes * math.prod(trail)
         if d == np.bool_:
-            col = raw[off:off + n].astype(np.bool_)
+            col = raw[off:off + w].astype(np.bool_)
         elif d.itemsize == 8:
-            lo = raw[off:off + n].astype(np.uint64)
-            hi = raw[off + n:off + 2 * n].astype(np.uint64)
+            lo = raw[off:off + w].astype(np.uint64)
+            hi = raw[off + w:off + 2 * w].astype(np.uint64)
             col = ((hi << np.uint64(32)) | lo).view(np.int64) \
                 .astype(d, copy=False)
-            off += n
+            off += w
         else:
-            col = raw[off:off + n].view(d)
-        return col.reshape((cap,) + trail), off + n
+            col = raw[off:off + w].view(d)
+        return col.reshape((lanes,) + trail), off + w
 
     off = 0
     cols_flat = []
@@ -595,14 +628,14 @@ def _egress_unpack(raw, batch: DeviceBatch, treedef, specs, cap):
         col, off = take(off, dt, trail)
         cols_flat.append(col)
     tss, off = take(off, "int64")
-    valid = raw[off:off + cap].astype(np.bool_)
-    n = batch.known_size
+    valid = raw[off:off + lanes].astype(np.bool_)
     if n is not None and bool(valid[:n].all()):
-        sel = slice(None, n)
+        sel, extent = slice(None, n), n
     else:
         sel = np.nonzero(valid)[0]
+        extent = int(sel[-1]) + 1 if len(sel) else 0
     cols = jax.tree.unflatten(treedef, [c[sel] for c in cols_flat])
-    return cols, tss[sel]
+    return cols, tss[sel], extent
 
 
 class ColumnarEgress:
@@ -614,18 +647,41 @@ class ColumnarEgress:
     :meth:`columns` blocks for what is still in flight and re-types the
     bytes.  A batch that cannot be packed (``_egress_packable``: numpy
     leaves, lanes this process does not hold whole) keeps its lanes and
-    takes ``_columns_fallback``; its readiness is its validity lane's."""
+    takes ``_columns_fallback``; its readiness is its validity lane's.
 
-    __slots__ = ("batch", "_packed")
+    With ``front`` lanes (the columnar sink's guess, from what the edge
+    delivered before: :func:`front_lanes`) only the leading ``front``
+    lanes of a packable batch held by one device are packed and copied,
+    behind a header that says where the batch's rows end.  The header
+    decides in :meth:`columns`: rows that end inside the front are all in
+    the buffer; rows beyond it (an overflow) send the whole batch through
+    the whole-batch pack and copy, waited for in place.  Only the size of
+    the first copy is a guess, never a row.  After :meth:`columns`,
+    ``lanes_copied`` is what crossed the link and ``extent`` where the
+    rows ended (``None`` on the fallback)."""
 
-    def __init__(self, batch: DeviceBatch) -> None:
+    __slots__ = ("batch", "_packed", "front", "lanes_copied", "extent")
+
+    def __init__(self, batch: DeviceBatch,
+                 front: Optional[int] = None) -> None:
         self.batch = batch
         ok, leaves, treedef, cap = _egress_packable(batch)
-        self._packed = None
+        self._packed = self.front = self.extent = None
+        self.lanes_copied = cap
         if ok:
-            buf, specs = _egress_pack(batch, leaves, treedef, cap)
+            if front is not None and front < cap and all(
+                    len(a.devices()) == 1
+                    for a in (*leaves, batch.ts, batch.valid)):
+                self.front = self.lanes_copied = front
+            buf, specs = _egress_pack(batch, leaves, treedef, cap,
+                                      self.front)
             buf.copy_to_host_async()
             self._packed = (buf, treedef, specs, cap)
+
+    @property
+    def overflowed(self) -> bool:
+        """Did the rows end beyond the front that was copied first?"""
+        return self.front is not None and self.lanes_copied > self.front
 
     def is_ready(self) -> bool:
         gate = self.batch.valid if self._packed is None else self._packed[0]
@@ -636,8 +692,20 @@ class ColumnarEgress:
         if self._packed is None:
             return _columns_fallback(self.batch)
         buf, treedef, specs, cap = self._packed
-        return _egress_unpack(np.asarray(buf), self.batch, treedef, specs,
-                              cap)
+        raw, lanes, n = np.asarray(buf), cap, self.batch.known_size
+        if self.front is not None:
+            n, extent = int(raw[-2]), int(raw[-1])
+            if extent <= self.front:
+                lanes = self.front
+            else:
+                whole, _ = _egress_pack(
+                    self.batch, jax.tree.leaves(self.batch.payload),
+                    treedef, cap)
+                raw = np.asarray(whole)
+                self.lanes_copied = self.front + cap
+        cols, tss, self.extent = _egress_unpack(raw, treedef, specs, lanes,
+                                                n)
+        return cols, tss
 
 
 def device_to_columns_multi(batches):

@@ -237,6 +237,13 @@ def _families(stats: dict,
     f_sp = fam("wf_operator_sink_pending_max", "gauge",
                "Most batches a columnar sink replica has held in flight "
                "at once")
+    f_sf = fam("wf_operator_sink_front_copies_total", "counter",
+               "Batches of which a columnar sink copied the leading lanes "
+               "only at first, sized from where the rows of its last "
+               "deliveries ended")
+    f_so = fam("wf_operator_sink_front_overflows_total", "counter",
+               "Front copies whose batch held rows beyond the lanes "
+               "copied, so that the whole batch was fetched after all")
     for op in ops:
         name = op.get("Operator_name") or op.get("Name") or "?"
         if "Sink_deliveries_ready" in op:
@@ -245,6 +252,8 @@ def _families(stats: dict,
                 f_sd.add(op.get("Sink_deliveries_" + outcome, 0),
                          dict(lab, outcome=outcome))
             f_sp.add(op.get("Sink_pending_max", 0), lab)
+            f_sf.add(op.get("Sink_front_copies", 0), lab)
+            f_so.add(op.get("Sink_front_overflows", 0), lab)
         if "Join_build_built" in op:
             # the pair form: the same two families, its own events
             lab = dict(base, operator=name)

@@ -437,7 +437,8 @@ PHASES: Dict[str, tuple] = {
         "the shard plane's key sketch (count-min rows, shard counts)"),
     "wf.egress.pack": (
         "egress / sink",
-        "packing an output batch into the one buffer the sink copies"),
+        "packing an output batch, or the leading lanes of it a columnar "
+        "sink asks for, into the one buffer the sink copies"),
 }
 #: ``wf.op.<operator>``: who, where a phase says what
 OP_SCOPE = "wf.op."

@@ -11,6 +11,7 @@ object construction entirely (the egress twin of the columnar ingest path,
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Callable, Optional
 
@@ -34,6 +35,11 @@ class SinkColumns:
         return len(self.tss)
 
 
+#: deliveries (of rows) a columnar sink replica looks back on to size the
+#: next copy
+FRONT_HISTORY = 8
+
+
 class SinkReplica(Replica):
     def __init__(self, op: "Sink", index: int) -> None:
         super().__init__(op, index)
@@ -45,6 +51,14 @@ class SinkReplica(Replica):
         self.deliveries_ready = 0   # delivered without a wait
         self.deliveries_waited = 0  # the bound or end of stream waited
         self.pending_max = 0
+        #: where the rows of the last packed deliveries that held any ended
+        #: (their ``extent``): what the next batch's front copy is sized
+        #: from.  An empty batch says nothing about where rows lie when
+        #: they come: a window that fires once in forty batches, all over
+        #: its output grid, must not pay a second copy for every firing
+        self._extents = collections.deque(maxlen=FRONT_HISTORY)
+        self.front_copies = 0       # batches whose front was copied first
+        self.front_overflows = 0    # ... of them, fetched whole after all
 
     def process_single(self, item, ts, wm):
         self._fn(item, self.context)
@@ -55,8 +69,6 @@ class SinkReplica(Replica):
         # one bulk copy, record sinks get per-tuple dicts.  The egress copy
         # moves the timestamp and validity lanes too, so the D2H counter
         # uses the shared whole-batch definition (batch.transfer_nbytes).
-        nbytes = wfbatch.transfer_nbytes(batch)
-        self.stats.d2h_bytes += nbytes
         if self.op.columnar:
             # The copy starts now: the batch's pack program is enqueued
             # behind the step that fills it and the host copy requested
@@ -67,18 +79,33 @@ class SinkReplica(Replica):
             # oldest, never for the step it was just handed (the reference
             # hides D2H behind per-batch CUDA streams the same way).  EOS
             # drains the queue.
-            self._pending.append(wfbatch.ColumnarEgress(batch))
+            self._pending.append(wfbatch.ColumnarEgress(
+                batch, self._front_lanes(batch.capacity)))
             self.pending_max = max(self.pending_max, len(self._pending))
             self.deliver(keep=self.op.columnar_defer)
             return
+        nbytes = wfbatch.transfer_nbytes(batch)
+        self.stats.d2h_bytes += nbytes
         with flightrec.span("wf.sink.d2h", batch=batch.seq, batches=1,
-                            bytes=nbytes, lanes=batch.capacity):
+                            bytes=nbytes, lanes=batch.capacity,
+                            cap=batch.capacity):
             hb = wfbatch.device_to_host(batch)
         with flightrec.span("wf.sink.deliver", batch=batch.seq,
                             rows=len(hb.items)):
             for item, ts in zip(hb.items, hb.tss):
                 self.context._set_context(ts, batch.watermark)
                 self._fn(item, self.context)
+
+    def _front_lanes(self, cap: int) -> Optional[int]:
+        """Leading lanes to copy of the next ``cap``-lane batch, from
+        where the rows of the last :data:`FRONT_HISTORY` deliveries that
+        held rows ended (``None``: the whole batch, as until that many
+        were seen).  A longer extent widens the next copy at once, and it
+        narrows only when every delivery looked back on fits the narrower
+        one, so a steady stream settles on one size."""
+        if len(self._extents) < FRONT_HISTORY:
+            return None
+        return wfbatch.front_lanes(cap, max(self._extents))
 
     def oldest_ready(self) -> bool:
         """Has the device finished the oldest batch in flight?  (Only
@@ -114,13 +141,22 @@ class SinkReplica(Replica):
         else:
             self.deliveries_ready += 1
         b = egress.batch
-        # the copy moves the whole batch, rows and padding alike;
         # ``waited`` says whether the driver blocked for the batch's step
-        # here or found it done
+        # here or found it done; ``lanes`` and ``bytes`` what crossed the
+        # link of the batch's ``cap`` lanes: the front, the front and then
+        # the whole batch (an overflow), or the whole batch
         with flightrec.span("wf.sink.d2h", batch=b.seq, batches=1,
-                            bytes=wfbatch.transfer_nbytes(b),
-                            lanes=b.capacity, waited=waited):
+                            cap=b.capacity, waited=waited) as sp:
             (cols, tss), = wfbatch.device_to_columns_multi([egress])
+            lanes = egress.lanes_copied
+            nbytes = wfbatch.transfer_nbytes(b) * lanes // b.capacity
+            sp.note(bytes=nbytes, lanes=lanes)
+        self.stats.d2h_bytes += nbytes
+        if egress.front is not None:
+            self.front_copies += 1
+            self.front_overflows += egress.overflowed
+        if egress.extent:
+            self._extents.append(egress.extent)
         if len(tss):
             self.context._set_context(int(tss[-1]), b.watermark)
             with flightrec.span("wf.sink.deliver", batch=b.seq,
@@ -163,4 +199,7 @@ class Sink(Operator):
                                                for r in reps)
             st["Sink_pending_max"] = max((r.pending_max for r in reps),
                                          default=0)
+            st["Sink_front_copies"] = sum(r.front_copies for r in reps)
+            st["Sink_front_overflows"] = sum(r.front_overflows
+                                             for r in reps)
         return st
