@@ -21,6 +21,12 @@ The contracts pinned here:
 - **WF608 preflight**: a forced ``WF_TPU_MEGASTEP=K`` graph whose edge
   cannot fold names the downgrade (the WF606/WF607 contract applied to
   the megastep plane); auto stays silent.
+- **The hold**: an edge queues a packet only while its group can still
+  fill before the next external drain, judged from the offer intervals
+  and the drain period it measured itself; a packet it will not hold
+  takes the per-batch ship at once, behind whatever was queued
+  (``unheld_batches``).  The tests stamp the offers and the drains on a
+  clock of their own.
 """
 
 import dataclasses
@@ -105,9 +111,10 @@ def _tail(family):
     raise ValueError(family)
 
 
-def _run(family, k, n=N, cap=CAP, **cfg_kw):
+def _run(family, k, n=N, cap=CAP, started=None, **cfg_kw):
     """One graph run at megastep_sweeps=k; returns (sunk records,
-    Megastep stats section, completed graph)."""
+    Megastep stats section, completed graph).  ``started(g)`` runs
+    between ``g.start()`` and the first sweep."""
     fired = []
     # dense kinds under default key_compaction attach a host-admission
     # compactor — a DIFFERENT (deliberate, WF608-named) downgrade; off
@@ -122,7 +129,10 @@ def _run(family, k, n=N, cap=CAP, **cfg_kw):
                         if r is not None else None).build())
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
-        g.run()
+        g.start()
+        if started is not None:
+            started(g)
+        g.wait_end()
     return fired, g.stats()["Megastep"], g
 
 
@@ -331,6 +341,215 @@ def test_epoch_cadence_keeps_logical_sweep_meaning(tmp_path):
     # the conversion guard: without ceil(eps/K) the folded run would
     # cover ~K x more stream per epoch and commit ~c1/K epochs
     assert c4 >= c1
+
+
+# ---------------------------------------------------------------------------
+# the hold: decided per offer from what the edge has observed
+# ---------------------------------------------------------------------------
+
+HOLD_N = 16384          # 64 batches of CAP lanes: room for the rule to learn
+PERIOD = 100_000        # usec between the external drains the tests stamp
+
+
+class _Drive:
+    """Stamps an edge's offers and external drains on a clock the test
+    owns: ``gap(self)`` usec pass before every offer, an external drain
+    follows the offer once ``PERIOD`` has passed since the last (at most
+    ``drains`` of them); real punctuations are off.  ``tail_log`` is
+    what reached the tail replica per batch, in order: (seq, watermark,
+    frontier, tuples)."""
+
+    def __init__(self, g, gap, drains=10 ** 9, rule=True):
+        self.g = g
+        self.now = 1_000_000
+        self.gap = gap
+        self.drains = drains
+        self.offers = 0
+        self.events = []    # (queued before, unheld by, queued after, took)
+        self.tail_log = []
+        self.tail = tail = g._source_replicas[0].emitter.dests[0][0]
+        receive = tail.receive
+
+        def logged(ch, msg):
+            if hasattr(msg, "seq"):
+                self.tail_log.append((msg.seq, msg.watermark,
+                                      msg.frontier, msg.known_size))
+            return receive(ch, msg)
+        tail.receive = logged
+        edges = g._megastep_plane.edges
+        self.edge = edge = edges[0] if edges else None
+        if edge is None:
+            return
+        edge._clock = lambda: self.now
+        if not rule:            # the parent: every warm packet is queued
+            edge._group_can_fill = lambda now: True
+        offer = edge.offer
+        self._last_drain = self.now
+
+        def stamped(pkt):
+            self.now += self.gap(self)
+            self.offers += 1
+            q0, u0 = len(edge._q), edge.unheld_batches
+            took = offer(pkt)
+            self.events.append((q0, edge.unheld_batches - u0,
+                                len(edge._q), took))
+            if self.now - self._last_drain >= PERIOD and self.drains > 0:
+                self.drains -= 1
+                self._last_drain = self.now
+                edge.external_drain()
+            return took
+        edge.offer = stamped
+
+
+def _run_driven(family, k, gap, cfg_kw=None, setup=None, **drive_kw):
+    """One driven run: (sunk records, the edge's summary, the drive).
+    ``setup(drive)`` runs once the graph has started."""
+    drives = []
+
+    def started(g):
+        drives.append(_Drive(g, gap, **drive_kw))
+        if setup is not None:
+            setup(drives[0])
+    fired, ms, _ = _run(family, k, n=HOLD_N, started=started,
+                        punctuation_interval_usec=10 ** 12,
+                        **(cfg_kw or {}))
+    return fired, (ms["edges"][0] if ms["edges"] else None), drives[0]
+
+
+def _slow(_d):
+    return PERIOD // 2          # two offers a drain: K = 4 never fills
+
+
+def _fast(_d):
+    return PERIOD // 16         # sixteen offers a drain: four groups of 4
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_hold_slow_offers_are_never_held(family):
+    """Offers slower than period / K: once the edge has seen two drains
+    and its ring of intervals, every packet reaches the tail before the
+    next offer, no scan ever runs, and what the tail and the sink see
+    is the K = 1 run's, stamp for stamp."""
+    base, _, d1 = _run_driven(family, 1, _slow)
+    fold, e, d4 = _run_driven(family, 4, _slow)
+    assert base and _norm(base) == _norm(fold)
+    assert d1.tail_log == d4.tail_log and len(d4.tail_log) == HOLD_N // CAP
+    assert e["megasteps"] == 0 and e["batches"] == 0
+    known = next(i for i, ev in enumerate(d4.events) if ev[1])
+    assert known <= e["warmup_batches"] + 9     # eight intervals, two drains
+    # the first judgement may find a packet queued before the edge knew
+    q0, unheld, q1, took = d4.events[known]
+    assert (unheld, q1, took) == (q0 + 1, 0, False) and q0 <= 1
+    for ev in d4.events[known + 1:]:
+        assert ev == (0, 1, 0, False)
+    assert e["unheld_batches"] == len(d4.events) - known + q0
+    # an unheld batch is a per-batch ship while warm
+    assert e["fallback_batches"] + e["warmup_batches"] == HOLD_N // CAP
+    assert e["fallback_batches"] >= e["unheld_batches"]
+
+
+def test_unheld_batches_in_stats_and_openmetrics():
+    """``g.stats()["Megastep"]["edges"][i]`` carries ``unheld_batches``
+    beside the three buckets it always had, and the exposition renders
+    the same numbers (strict parser round trip)."""
+    from windflow_tpu.monitoring.openmetrics import (parse_exposition,
+                                                     render_openmetrics)
+    _, e, d = _run_driven("window_cb", 4, _slow)
+    assert e["unheld_batches"] > 0
+    st = {"PipeGraph_name": "hold", "Megastep": {"k": 4, "edges": [e]}}
+    text = render_openmetrics(st)
+    parse_exposition(text)      # strict: raises on any violation
+    want = {'wf_megastep_dispatches_total{': e["megasteps"],
+            'wf_megastep_unheld_batches_total{': e["unheld_batches"],
+            'path="scanned"': e["batches"],
+            'path="fallback"': e["fallback_batches"],
+            'path="warmup"': e["warmup_batches"]}
+    for mark, value in want.items():
+        line = [ln for ln in text.splitlines()
+                if ln.startswith("wf_megastep") and mark in ln]
+        assert len(line) == 1, (mark, line)
+        assert float(line[0].rsplit(" ", 1)[1]) == float(value), mark
+        assert 'operator="w"' in line[0]
+    # a graph with no folded edge renders no megastep family
+    assert "wf_megastep" not in render_openmetrics(
+        {"PipeGraph_name": "k1", "Megastep": {"k": 1, "edges": []}})
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_hold_fast_offers_fill_groups_as_before(family):
+    """Offers faster than period / K: the edge holds and scans exactly
+    as it did before the rule (the same drive with the rule off)."""
+    then, e0, _ = _run_driven(family, 4, _fast, rule=False)
+    fold, e, d = _run_driven(family, 4, _fast)
+    assert fold and _norm(then) == _norm(fold)
+    assert e["unheld_batches"] == 0
+    assert e["megasteps"] >= 10
+    assert e == e0
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_hold_releases_the_queue_fifo_when_the_stream_slows(family):
+    """A stream that stalls with packets queued: the next offer finds
+    the drain overdue, ships what is queued ahead of the packet in
+    hand, and both count as unheld."""
+    slowed = []
+
+    def gap(d):
+        # fast until the edge knows both quantities and holds two
+        if slowed or (d.offers >= 40 and len(d.edge._q) == 2):
+            slowed.append(d.offers)
+            return 2 * PERIOD
+        return PERIOD // 16
+    base, _, _ = _run_driven(family, 1, gap)     # no edge: gap never asked
+    fold, e, d = _run_driven(family, 4, gap)
+    assert base and _norm(base) == _norm(fold)
+    released = [ev for ev in d.events if ev[1] > 1]
+    assert released == [(2, 3, 0, False)]
+    assert d.events.index(released[0]) == slowed[0]
+    # the two queued and then the packet in hand: per-batch arrivals at
+    # the tail stay in staging order, none twice
+    seqs = [row[0] for row in d.tail_log]
+    assert seqs == sorted(seqs) and len(set(seqs)) == len(seqs)
+    assert e["megasteps"] > 0 and e["unheld_batches"] >= 3
+    assert e["batches"] + e["warmup_batches"] + e["fallback_batches"] \
+        == HOLD_N // CAP
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_hold_waits_for_two_drains_before_it_judges(family):
+    """One external drain says nothing of a period: slow offers are
+    queued as they always were, and groups fill."""
+    then, e0, _ = _run_driven(family, 4, _slow, drains=1, rule=False)
+    fold, e, d = _run_driven(family, 4, _slow, drains=1)
+    assert fold and _norm(then) == _norm(fold)
+    assert e["unheld_batches"] == 0 and e["megasteps"] >= 10
+    assert e == e0
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_hold_leaves_nothing_parked_at_a_quiesce(family, tmp_path):
+    """A durability quiesce is an external drain: with the rule shipping
+    at once and with it holding, the barrier finds the edge's queue and
+    the tail's inbox empty, and epochs commit."""
+    # the quiesces are the only external drains here: one a driver sweep
+    # (a period of one offer: nothing can fill), or one in eight
+    for name, eps in (("slow", 4), ("fast", 32)):
+        seen = []
+
+        def setup(d):
+            def hook(site):
+                if site == "post_quiesce":
+                    seen.append((len(d.edge._q), len(d.tail.inbox)))
+            d.g._durability.chaos_hook = hook
+
+        _, e, d = _run_driven(
+            family, 4, _fast, drains=0, setup=setup,
+            cfg_kw={"durability": str(tmp_path / name),
+                    "durability_epoch_sweeps": eps})
+        assert len(seen) >= 3 and set(seen) == {(0, 0)}, (name, seen)
+        assert d.g.stats()["Durability"]["epochs_committed"] == len(seen)
+        assert (e["unheld_batches"] > 0) == (name == "slow"), (name, e)
+        assert (e["megasteps"] > 0) == (name == "fast"), (name, e)
 
 
 # ---------------------------------------------------------------------------

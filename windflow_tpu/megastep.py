@@ -23,6 +23,16 @@ Correctness stance — the per-batch path IS the reference semantics:
   flush (quiesce, EOS, punctuation cadence), and a non-empty tail inbox
   all fall back to the per-batch ship — record-identical by
   construction, so eligibility can be conservative without being wrong.
+* A group is only STARTED or CONTINUED while it can still fill before
+  the next external flush ships it partial anyway.  The edge measures
+  both sides itself on the host clock — the median of its last
+  ``HOLD_SAMPLES`` intervals between warm offers, and the distance
+  between the last two external drains — and a packet whose group
+  would complete after the next drain is due takes the same per-batch
+  ship at once (``MegastepEdge._group_can_fill``; counted as
+  ``unheld_batches``).  A partial group at a flush is therefore what is
+  left of a group that could still have filled when each of its
+  packets was queued.
 * Step REBUILDS (TB ring regrow, durability restore) are detected by
   wrapper object IDENTITY: the scan cache pins the wrapper it traced
   and recompiles when the operator swapped it.
@@ -68,10 +78,19 @@ from windflow_tpu.monitoring.jit_registry import wf_jit
 #: stays per-batch so the tier-1 suite exercises the verbatim cadence.
 #: Not 2: a group of two only breaks even, the K x L super-batch copy
 #: costing what the one saved dispatch gives, and the saving compounds
-#: from 4 up.  A group also puts a K x batch-span floor under latency
-#: (the first batch staged waits for the group to fill).  K=8 against
-#: K=1 has not been A/B'd on the chip (ROADMAP.md queue 1 item 8).
+#: from 4 up.  A group that fills puts a K x batch-span floor under
+#: latency (the first batch staged waits for the group to fill); a
+#: stream too slow to fill one before the next external flush pays no
+#: hold at all, since its edge never starts one
+#: (``MegastepEdge._group_can_fill``).  K=8 against K=1 has not been
+#: A/B'd on the chip (ROADMAP.md queue 1 item 2 (b)).
 AUTO_K = 8
+
+#: intervals between warm offers an edge keeps: their median is how fast
+#: the host finalizes batches there.  Eight, so that one compile, one
+#: ``run()`` or one stalled sweep inside an interval does not flip the
+#: hold for the offers after it; the rule waits until it has all eight.
+HOLD_SAMPLES = 8
 
 
 def resolve_megastep(config) -> int:
@@ -177,8 +196,9 @@ class MegastepEdge:
 
     The feeding ``DeviceStageEmitter`` offers every finalized packed
     batch here (``offer``); acceptance queues it and the K-th packet
-    runs the megastep.  Refusal (tail cold, signature change mid-group)
-    and ``drain_remainder`` (external flush: quiesce, EOS, punctuation)
+    runs the megastep.  Refusal (tail cold, a group that cannot fill
+    before the next external drain), a signature change mid-group and
+    ``external_drain`` (the emitter's flush: quiesce, EOS, punctuation)
     ship per-batch through the emitter's verbatim path — so durability
     epochs land on megastep boundaries and partial groups stay
     record-identical."""
@@ -202,6 +222,20 @@ class MegastepEdge:
         self.batches = 0            # logical batches served by scans
         self.fallback_batches = 0   # per-batch ships while warm
         self.warmup_batches = 0     # per-batch ships while cold
+        # of the fallback ones: shipped at once because their group
+        # could not have filled before the next external drain
+        self.unheld_batches = 0
+        # what the hold is decided from, all on the host clock (usec):
+        # a ring of the last intervals between warm offers with its
+        # sorting scratch, and when the last external drain came and how
+        # long after the one before it.  0 = not seen yet.
+        self._clock = current_time_usecs
+        self._last_offer = 0
+        self._gaps = [0] * HOLD_SAMPLES
+        self._gaps_sorted = [0] * HOLD_SAMPLES
+        self._n_gaps = 0
+        self._last_drain = 0
+        self._drain_period = 0
         # per-packet event-time span accumulation (ts_max - ts_min of the
         # staged lanes): the measured basis of the K x batch-span
         # freshness floor the latency ledger surfaces per edge
@@ -252,15 +286,54 @@ class MegastepEdge:
                 and a.capacity == b.capacity and a.fmt == b.fmt
                 and a.buf.shape[0] == b.buf.shape[0])
 
+    # -- the hold -----------------------------------------------------------
+    @hot_path
+    def _offer_interval(self, now: int) -> int:
+        """Note a warm offer at ``now``; the median interval between the
+        last ``HOLD_SAMPLES`` + 1 of them, 0 until there are that many."""
+        last, self._last_offer = self._last_offer, now
+        if last:
+            self._gaps[self._n_gaps % HOLD_SAMPLES] = now - last
+            self._n_gaps += 1
+        if self._n_gaps < HOLD_SAMPLES:
+            return 0
+        s = self._gaps_sorted
+        s[:] = self._gaps
+        s.sort()
+        return (s[HOLD_SAMPLES // 2 - 1] + s[HOLD_SAMPLES // 2]) // 2
+
+    @hot_path
+    def _group_can_fill(self, now: int) -> bool:
+        """Whether a group holding the packet offered at ``now`` can
+        still complete before the next external drain ships it partial:
+        it needs ``k - q - 1`` more packets, one a median interval, and
+        the drain is due a period after the last.  True as well while
+        either quantity is unknown (the first offers after warm-up,
+        fewer than two external drains seen): the edge then queues as it
+        always did, so a stream whose groups fill never loses its first
+        scan."""
+        interval = self._offer_interval(now)
+        if not interval or not self._drain_period:
+            return True
+        return now + (self.k - len(self._q) - 1) * interval \
+            <= self._last_drain + self._drain_period
+
     # -- emitter contract ----------------------------------------------------
     @hot_path
     def offer(self, pkt) -> bool:
         """Queue one finalized packed batch.  False → the caller ships
-        it per-batch (tail cold).  A signature change against the queued
-        group drains the group per-batch first — a megastep only ever
-        runs K same-shaped buffers."""
+        it per-batch (tail cold, or a group it would wait in cannot fill
+        before the next external drain: what is queued is shipped ahead
+        of it, FIFO).  A signature change against the queued group
+        drains the group per-batch first — a megastep only ever runs K
+        same-shaped buffers."""
         if not self._tail_warm(pkt.capacity):
             self.warmup_batches += 1
+            return False
+        if not self._group_can_fill(self._clock()):
+            self.unheld_batches += len(self._q) + 1
+            self.fallback_batches += 1      # the caller's ship
+            self.drain_remainder()
             return False
         if self._q and not self._sig_match(self._q[0], pkt):
             self.drain_remainder()
@@ -284,11 +357,25 @@ class MegastepEdge:
         return True
 
     @hot_path
+    def external_drain(self) -> None:
+        """The feeding emitter's flush (punctuation cadence, durability
+        quiesce, EOS): ship what is queued so that a checkpoint or a
+        watermark never overtakes it, and note when it came — the edge
+        measures the cadence of these itself, whatever drives them.  A
+        drain with no offer since the last (a quiesce's second round, an
+        idle stream's punctuation) moves the date and not the period."""
+        now = self._clock()
+        if self._last_offer > self._last_drain > 0:
+            self._drain_period = now - self._last_drain
+        self._last_drain = now
+        self.drain_remainder()
+
+    @hot_path
     def drain_remainder(self) -> None:
         """Ship every queued packet per-batch (FIFO) through the
-        feeding emitter's verbatim path — external flushes (quiesce,
-        EOS, punctuation cadence) call this so a checkpoint or a
-        watermark never overtakes queued data."""
+        feeding emitter's verbatim path: at an external drain, before a
+        packet the edge will not hold, on a signature change, and where
+        ``run()`` stands down."""
         q, self._q = self._q, []
         for pkt in q:
             self.fallback_batches += 1
@@ -557,6 +644,7 @@ class MegastepEdge:
             "batches": self.batches,
             "fallback_batches": self.fallback_batches,
             "warmup_batches": self.warmup_batches,
+            "unheld_batches": self.unheld_batches,
             "freshness_floor_usec": self.freshness_floor_usec(),
         }
 

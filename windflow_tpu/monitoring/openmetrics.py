@@ -552,6 +552,28 @@ def _families(stats: dict,
                 "Staged-to-sunk end-to-end latency (microseconds)")
     _hist_from_stats(f_e2e, lat.get("end_to_end_usec"), base)
 
+    # -- megastep plane (windflow_tpu/megastep.py) ---------------------------
+    edges = (stats.get("Megastep") or {}).get("edges") or []
+    if edges:
+        f_mega = fam("wf_megastep_dispatches_total", "counter",
+                     "K-group scan programs dispatched per folded edge")
+        f_path = fam("wf_megastep_batches_total", "counter",
+                     "Staged batches of a folded edge by the path they "
+                     "took: scanned, fallback (per-batch while warm), "
+                     "warmup (per-batch while cold)")
+        f_unheld = fam("wf_megastep_unheld_batches_total", "counter",
+                       "Fallback batches shipped at once because their "
+                       "K-group could not fill before the next external "
+                       "drain")
+        for e in edges:
+            lab = dict(base, operator=e.get("operator", ""))
+            f_mega.add(e.get("megasteps", 0), lab)
+            for path, key in (("scanned", "batches"),
+                              ("fallback", "fallback_batches"),
+                              ("warmup", "warmup_batches")):
+                f_path.add(e.get(key, 0), dict(lab, path=path))
+            f_unheld.add(e.get("unheld_batches", 0), lab)
+
     # -- latency plane (critical-path decomposition + SLO) -------------------
     lplane = stats.get("Latency_plane") or {}
     if lplane.get("enabled"):

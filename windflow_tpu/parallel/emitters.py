@@ -826,7 +826,7 @@ class DeviceStageEmitter(Emitter):
         self._flush_impl(wm)
         ms = self._megastep
         if ms is not None:
-            ms.drain_remainder()
+            ms.external_drain()
 
     def _flush_impl(self, wm):
         if self._builder is not None:
