@@ -1,15 +1,16 @@
 """The O(n) dense-key grouping permutation (windows/grouping.py) and its
-wiring into the FFAT steps (Config.ffat_grouping).
+wiring into the FFAT steps (their ``grouping=`` parameter).
 
-Three layers of evidence, mirroring how the argsort path earned trust:
+Two layers of evidence, mirroring how the argsort path earned trust:
 1. the permutation itself is bit-identical to ``jnp.argsort(stable=True)``
    across bucket widths (single-digit, radix), batch sizes (chunk-padding
    edges), and skews;
 2. the CB and TB FFAT steps produce bit-identical outputs AND state under
    both groupings — including a NON-commutative combiner, which fails if
-   arrival order within a key is ever perturbed;
-3. a whole graph run under ``ffat_grouping="rank_scatter"`` matches the
-   pure-Python oracle (the config plumbing, not just the kernel).
+   arrival order within a key is ever perturbed.
+
+Every FFAT graph test runs the ``rank_scatter`` steps: the operator builds
+no other.
 
 Reference anchor: the grouping the reference buys with
 ``thrust::sort_by_key`` (``keyby_emitter_gpu.hpp:519-583``).
@@ -22,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import windflow_tpu as wf
 from windflow_tpu.windows.ffat_kernels import (agg_spec_for, make_ffat_state,
                                                make_ffat_step,
                                                make_ffat_tb_state,
@@ -340,71 +340,3 @@ def test_tb_step_scatter_add_matches_grouped():
                 for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
                     np.testing.assert_array_equal(np.asarray(la),
                                                   np.asarray(lb))
-
-
-# -- graph-level: config plumbing + oracle ---------------------------------
-
-N_KEYS = 3
-LENGTH = 240
-
-
-def _stream():
-    return [{"key": i % N_KEYS, "value": i, "ts": i * 1000}
-            for i in range(LENGTH)]
-
-
-def _oracle_cb(win, slide):
-    per_key = {}
-    for t in _stream():
-        per_key.setdefault(t["key"], []).append(t["value"])
-    exp = {}
-    for k, vals in per_key.items():
-        w = 0
-        while w * slide < len(vals):
-            seg = vals[w * slide: w * slide + win]
-            if seg:
-                exp[(k, w)] = sum(seg)
-            w += 1
-    return exp
-
-
-@pytest.mark.parametrize("grouping", ["rank_scatter", "argsort"])
-def test_graph_ffat_grouping_config(grouping):
-    import dataclasses
-
-    got = {}
-    src = (wf.Source_Builder(lambda: iter(_stream()))
-           .withTimestampExtractor(lambda t: t["ts"])
-           .withOutputBatchSize(31).build())
-    op = (wf.Ffat_WindowsTPU_Builder(lambda t: t["value"],
-                                     lambda a, b: a + b)
-          .withKeyBy(lambda t: t["key"]).withMaxKeys(N_KEYS)
-          .withCBWindows(16, 4).build())
-    snk = wf.Sink_Builder(
-        lambda r: got.__setitem__((r["key"], r["wid"]), r["value"])
-        if r is not None else None).build()
-    cfg = dataclasses.replace(wf.default_config, ffat_grouping=grouping)
-    g = wf.PipeGraph("grouping_cfg", wf.ExecutionMode.DEFAULT,
-                     wf.TimePolicy.EVENT, config=cfg)
-    g.add_source(src).add(op).add_sink(snk)
-    g.run()
-    assert got == _oracle_cb(16, 4)
-
-
-def test_unknown_grouping_rejected():
-    import dataclasses
-
-    src = (wf.Source_Builder(lambda: iter(_stream()))
-           .withTimestampExtractor(lambda t: t["ts"])
-           .withOutputBatchSize(31).build())
-    op = (wf.Ffat_WindowsTPU_Builder(lambda t: t["value"],
-                                     lambda a, b: a + b)
-          .withKeyBy(lambda t: t["key"]).withMaxKeys(N_KEYS)
-          .withCBWindows(16, 4).build())
-    snk = wf.Sink_Builder(lambda r: None).build()
-    cfg = dataclasses.replace(wf.default_config, ffat_grouping="bogus")
-    g = wf.PipeGraph("grouping_bad", wf.ExecutionMode.DEFAULT,
-                     wf.TimePolicy.EVENT, config=cfg)
-    g.add_source(src).add(op).add_sink(snk)
-    with pytest.raises(wf.WindFlowError, match="ffat_grouping"):
-        g.run()

@@ -669,15 +669,6 @@ def test_a_mesh_graph_refuses_the_operator():
         wf.Config = keep
 
 
-def test_snapshot_kind_is_known_to_preflight_and_rebucket():
-    from windflow_tpu.analysis import preflight
-    from windflow_tpu.durability import rebucket
-    op = count_op(8)
-    assert not preflight._checkpoints_unrebucketable_state(op)
-    blob = {"kind": "session_tpu", "state": {}}
-    assert rebucket.rebucket_blob(op, blob, 1, 2, None, None) is blob
-
-
 def test_dispatch_span_says_out_cap(replayed, monkeypatch):
     """The operator's ``wf.dispatch`` notes ``out_cap`` although the
     output batch has the input's capacity, and the sink's ``wf.sink.d2h``

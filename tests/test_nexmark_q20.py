@@ -763,17 +763,11 @@ def test_a_mesh_more_replicas_and_mixed_forms_are_refused():
                   config=wf.Config(mesh=make_mesh(4)))
 
 
-def test_the_pair_form_is_known_to_preflight_rebucket_and_megastep():
-    from windflow_tpu.analysis import preflight
-    from windflow_tpu.durability import rebucket
-    from windflow_tpu.megastep import tail_kind
+def test_the_pair_form_names_its_program_key_space_and_phases():
+    # what it says to fusion, megastep, preflight and the re-bucketer:
+    # tests/test_operator_contract.py, case ``interval_join_pairs``
     from windflow_tpu.monitoring import recorder
     op = pair_op()
-    assert not preflight._checkpoints_unrebucketable_state(op)
-    blob = {"kind": "interval_join_pairs_tpu", "state": {}}
-    assert rebucket.rebucket_blob(op, blob, 1, 2, None, None) is blob
-    kind, why = tail_kind(op)
-    assert kind is None and "interval join" in why
     assert op.notes_out_cap and op.key_space() == 4096
     assert op.program_name == "step_join_pairs"
     assert recorder.PHASES["wf.join.table"][0] == "fused operator program"

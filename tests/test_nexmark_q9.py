@@ -698,19 +698,6 @@ def test_a_mesh_and_more_replicas_are_refused():
                   config=wf.Config(mesh=make_mesh(4)))
 
 
-def test_snapshot_kind_is_known_to_preflight_and_rebucket():
-    from windflow_tpu.analysis import preflight
-    from windflow_tpu.durability import rebucket
-    from windflow_tpu.megastep import tail_kind
-    op = join_op()
-    assert not preflight._checkpoints_unrebucketable_state(op)
-    blob = {"kind": "interval_join_tpu", "state": {}}
-    assert rebucket.rebucket_blob(op, blob, 1, 2, None, None) is blob
-    kind, why = tail_kind(op)
-    assert kind is None and "interval join" in why
-    assert op.notes_out_cap
-
-
 # ---------------------------------------------------------------------------
 # the program
 # ---------------------------------------------------------------------------

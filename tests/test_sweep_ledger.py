@@ -421,7 +421,7 @@ def test_kill_switch_off_path_budget(tmp_path):
     # dispatch counter belongs to the compile watcher).
     t0 = time.perf_counter()
     for _ in range(10_000):
-        g._sweep_section()
+        g._plane_section(g._ledger)
     per_call = (time.perf_counter() - t0) / 10_000
     assert per_call < 5e-6, \
         f"disabled sweep section costs {per_call * 1e6:.2f}us/call"

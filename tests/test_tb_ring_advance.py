@@ -426,6 +426,24 @@ def _mesh_cfg():
     return Config(mesh=make_mesh(8, data=2))
 
 
+def test_the_states_scalars_are_listed_once_where_the_state_is_made():
+    """``TB_SCALARS`` is the scalar-shaped leaves of a freshly made state,
+    and the mesh step and the re-bucketer read that one list: a counter
+    added to the state and not to it fails here, not at a restore."""
+    from windflow_tpu.durability import rebucket
+    from windflow_tpu.parallel import mesh
+    state = fk.make_ffat_tb_state(jax.ShapeDtypeStruct((), jnp.float32),
+                                  K, NP)
+    scalars = {k for k, v in state.items()
+               if k != "cells" and jnp.ndim(v) == 0}
+    assert set(fk.TB_SCALARS) == scalars
+    assert len(set(fk.TB_SCALARS)) == len(fk.TB_SCALARS)
+    assert set(fk.TB_ALIGNED) | {"max_seen"} | set(fk.TB_COUNTERS) == scalars
+    assert mesh.TB_SCALARS is fk.TB_SCALARS is rebucket.TB_SCALARS
+    assert rebucket.TB_COUNTERS is fk.TB_COUNTERS
+    assert rebucket.TB_ALIGNED is fk.TB_ALIGNED
+
+
 @pytest.mark.parametrize("blob_has_it", [True, False])
 def test_ring_advances_survive_snapshot_restore_and_rebucket(blob_has_it):
     """The counter rides the checkpoint blob, a lane a key shard on a

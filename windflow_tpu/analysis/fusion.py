@@ -16,9 +16,10 @@ permitting.
 
 Two link strengths:
 
-* ``chainable`` — both ends satisfy ``ops.chained.tpu_chainable`` and
-  the edge is FORWARD at equal parallelism: today's ``chain()`` could
-  already fuse them (a plan entry here is a missed call site).
+* ``chainable`` — both ends are stateless members (``Operator.
+  chain_role``) and the edge is FORWARD at equal parallelism: today's
+  ``chain()`` could already fuse them into one ``ChainedTPU``
+  (``ops.chained.fuse``; a plan entry here is a missed call site).
 * ``whole_chain`` — the edge needs the whole-chain-fusion refactor:
   a window/reduce/stateful tail, or a single-replica KEYBY edge whose
   key extraction already runs inside the compiled program (the keyby
@@ -40,11 +41,14 @@ def _chain_boundary(a, b, fanout: Dict[int, int],
                     fanin: Dict[int, int]) -> Optional[str]:
     """Why the edge ``a -> b`` cannot join one fused program; ``None``
     when it can (the link reasons :func:`fusible_chains` records)."""
-    from windflow_tpu.ops.source import Source
-    if not a.is_tpu or isinstance(a, Source):
+    if not a.is_tpu:
         return "upstream is not a TPU stage"
     if not b.is_tpu:
         return "downstream leaves the device (host stage / sink)"
+    for end, op in (("upstream", a), ("downstream", b)):
+        if op.chain_role is None:   # a device source; an operator that
+            # declares no part (``Operator.chain_role``)
+            return f"{end} takes no part in a fused chain"
     if fanout.get(id(a), 0) != 1:
         return "upstream fans out (split / multi-consumer)"
     if fanin.get(id(b), 0) != 1:
@@ -62,23 +66,10 @@ def _chain_boundary(a, b, fanout: Dict[int, int],
     return f"{b.routing.value} routing breaks the device chain"
 
 
-def _terminal(op) -> bool:
-    """Ops that end a fused chain even when linkable: their output is a
-    different stream (window results, reduced batches), so fusing PAST
-    them changes the program contract, not just its launch count."""
-    from windflow_tpu.ops.tpu import ReduceTPU
-    from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
-    from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-    from windflow_tpu.windows.session_tpu import _RowsBoundedByDataTPU
-    return isinstance(op, (ReduceTPU, FfatWindowsTPU,
-                           _RowsBoundedByDataTPU, _StatefulTPUBase))
-
-
 def fusible_chains(graph) -> List[dict]:
     """Maximal fusible chains over a composed (built or unbuilt)
     PipeGraph: ``[{"ops": [op, ...], "links": [kind, ...],
     "tail_boundary": why-the-chain-ends}, ...]``, length >= 2 only."""
-    from windflow_tpu.ops.chained import tpu_chainable
     edges = graph._edges()
     fanout: Dict[int, int] = {}
     fanin: Dict[int, int] = {}
@@ -98,8 +89,9 @@ def fusible_chains(graph) -> List[dict]:
     linked_in = set()
     for a, b in op_edges:
         boundary = _chain_boundary(a, b, fanout, fanin)
-        if boundary is None and not _terminal(a):
-            kind = ("chainable" if tpu_chainable(a) and tpu_chainable(b)
+        # only a member is fused PAST: a tail's output is another stream
+        if boundary is None and a.chain_role == "member":
+            kind = ("chainable" if b.chain_role == "member"
                     and b.routing == RoutingMode.FORWARD else "whole_chain")
             links[id(a)] = (b, kind)
             linked_in.add(id(b))
@@ -122,7 +114,7 @@ def fusible_chains(graph) -> List[dict]:
         for b2 in (b for x, b in op_edges if x is cur):
             tail = _chain_boundary(cur, b2, fanout, fanin) \
                 or ("chain tail is a window/reduce/stateful stage"
-                    if _terminal(cur) else None)
+                    if cur.chain_role != "member" else None)
         chains.append({"ops": ops, "links": kinds, "tail_boundary": tail})
     return chains
 

@@ -273,7 +273,6 @@ def _megastep_pass(graph, ops, edges, upstreams, diags) -> None:
 
     ``auto`` mode picks per backend silently and never warns; every
     case above runs correctly at the per-batch (K=1) cadence."""
-    from windflow_tpu.fusion.executor import _is_stateless
     from windflow_tpu.io.device_source import DeviceSource
     from windflow_tpu.megastep import megastep_forced, tail_kind
     from windflow_tpu.ops.sink import Sink
@@ -339,8 +338,7 @@ def _megastep_pass(graph, ops, edges, upstreams, diags) -> None:
                 tail = None
                 break
             tail = dests[0]
-            if not (_is_stateless(tail) and getattr(tail, "is_tpu",
-                                                    False)):
+            if tail.chain_role != "member":
                 break
         if tail is None or isinstance(tail, Sink):
             # an all-stateless run ending at the sink has no stateful
@@ -573,25 +571,23 @@ def _durability_pass(graph, ops, diags) -> None:
 
 def _checkpoints_unrebucketable_state(op) -> bool:
     """True when the operator overrides ``snapshot_state`` (it
-    checkpoints something) but is none of the kinds
-    ``durability/rebucket.py`` knows how to re-bucket."""
+    checkpoints something) but states no ``snapshot_kind`` that
+    ``durability/rebucket.py`` can re-bucket."""
+    from windflow_tpu.durability.rebucket import _has_rule
     from windflow_tpu.ops.base import Operator
-    impl = type(op).snapshot_state
-    if impl is Operator.snapshot_state:
+    cls = type(op)
+    if cls.snapshot_state is Operator.snapshot_state:
         return False    # stateless: nothing to re-bucket
-    from windflow_tpu.ops.reduce_op import Reduce
-    from windflow_tpu.ops.tpu import ReduceTPU
-    from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
-    from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-    from windflow_tpu.windows.session_tpu import _RowsBoundedByDataTPU
-    # identity on the IMPLEMENTATION, not the class: a subclass that
-    # overrides snapshot_state checkpoints a kind the re-bucketer has
-    # never seen, however familiar its base class is
-    known = {Reduce.snapshot_state, ReduceTPU.snapshot_state,
-             FfatWindowsTPU.snapshot_state,
-             _RowsBoundedByDataTPU.snapshot_state,   # sessions, the join
-             _StatefulTPUBase.snapshot_state}
-    return impl not in known
+    if not _has_rule(op, cls.snapshot_kind):
+        return True
+    # identity on the IMPLEMENTATION, not the class: a kind is stated
+    # for the ``snapshot_state`` it was written beside (or above: the
+    # sessions and the joins share one).  A subclass that overrides
+    # snapshot_state BELOW that statement checkpoints a kind the
+    # re-bucketer has never seen, however familiar its base class is
+    stated = next(c for c in cls.__mro__ if "snapshot_kind" in vars(c))
+    written = next(c for c in cls.__mro__ if "snapshot_state" in vars(c))
+    return not issubclass(stated, written)
 
 
 def manifest_conflicts(graph, manifest,

@@ -171,16 +171,6 @@ class Config:
     # 0 disables (sources tick once per sweep, pre-r6 behavior).
     stage_prefetch_depth: int = int(os.environ.get("WF_TPU_STAGE_PREFETCH",
                                                    "1"))
-    # FFAT batch-grouping algorithm: "rank_scatter" (default) groups each
-    # batch by key with the O(n) dense-key counting permutation
-    # (windows/grouping.py — no comparison sort; the reference pays
-    # thrust::sort_by_key for the same grouping); "argsort" keeps the
-    # stable-comparison-sort baseline (bit-identical results, both order
-    # by (key, arrival)).  Time-based steps whose (key, pane) id space
-    # exceeds int32 (max_keys * pane_capacity >= 2^31) fall back to
-    # argsort regardless — the counting ids are int32.
-    ffat_grouping: str = os.environ.get("WF_TPU_FFAT_GROUPING",
-                                        "rank_scatter")
     # Profiler bridge (monitoring/device_metrics, docs/OBSERVABILITY.md):
     # directory PipeGraph.profile(duration_ms) writes its jax.profiler
     # capture into ("" = "{log_dir}/{name}_xprof").  With the flight

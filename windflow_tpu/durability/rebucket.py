@@ -39,19 +39,9 @@ from typing import Dict, Optional
 import numpy as np
 
 from windflow_tpu.basic import WindFlowError, int32_key, stable_hash
+from windflow_tpu.windows.ffat_kernels import (TB_ALIGNED, TB_COUNTERS,
+                                               TB_SCALARS)
 
-#: the TB scalar-clock lanes (mesh: one per key shard; single chip /
-#: per-replica states: shape ()) — mirror of parallel/mesh._TB_SCALARS,
-#: duplicated so this module never imports jax at module scope
-TB_SCALARS = ("base", "win_next", "max_seen", "n_late", "n_evicted",
-              "n_win_dropped", "n_wide", "n_ring_advances")
-#: TB clock lanes that must AGREE across merged shards (the ring
-#: alignment invariants); the remaining scalars merge (max / sum)
-TB_ALIGNED = ("base", "win_next")
-#: ... the ones that sum (a blob from before ``n_wide`` or
-#: ``n_ring_advances`` lacks it)
-TB_COUNTERS = ("n_late", "n_evicted", "n_win_dropped", "n_wide",
-               "n_ring_advances")
 #: the one shard-shaped lane of a count-based state, on a mesh alone: its
 #: many-round steps, one lane a key shard (parallel/mesh.CB_WIDE_STEPS;
 #: a blob from before it, or from one chip, lacks it and restores as 0)
@@ -123,8 +113,8 @@ def _slot_override(blob: dict, override: Optional[dict]
 # per-kind re-bucketing
 # ---------------------------------------------------------------------------
 
-def _rebucket_reduce_host(op, blob, new_p: int,
-                          override: Optional[dict]) -> dict:
+def _rebucket_reduce_host(op, blob, old_p, new_p, old_kk, new_kk,
+                          override: Optional[dict], on_mesh) -> dict:
     """Host Reduce per-replica per-key dicts: merge, re-split by the
     host keyby placement (``stable_hash(key) % n`` with overrides
     first) — each key's rolling state lands on the replica its tuples
@@ -311,7 +301,8 @@ def _gather_rows(live, o_old, rows_j, name, leaf, template):
     return acc
 
 
-def _rebucket_stateful(op, blob, new_kk: int) -> dict:
+def _rebucket_stateful(op, blob, old_p, new_p, old_kk, new_kk: int,
+                       override, on_mesh) -> dict:
     """Dense/interned stateful tables are ONE shared table across
     replicas (per-key arrival order comes from keyed routing, not state
     ownership) — shape-independent; only mesh divisibility can block."""
@@ -321,6 +312,29 @@ def _rebucket_stateful(op, blob, new_kk: int) -> dict:
             op.name, f"num_key_slots {S} not divisible by the new mesh "
                      f"key axis {new_kk}")
     return blob
+
+
+#: the kinds whose state HAS a shard shape, each with its rule
+#: ``(op, blob, old_p, new_p, old_kk, new_kk, override, on_mesh)``: the
+#: module's own knowledge of three state layouts, keyed by the string the
+#: operator states as ``snapshot_kind``.  A kind without one re-buckets
+#: only where the operator declares ``snapshot_shapeless``.
+_RULES = {
+    "reduce_host": _rebucket_reduce_host,
+    "ffat_tpu": _rebucket_ffat,
+    "stateful_tpu": _rebucket_stateful,
+}
+
+
+def _has_rule(op, kind) -> bool:
+    """True where a blob of ``kind``, checkpointed by ``op``, can move
+    onto another shard shape: the kind has a rule here, or it is the
+    operator's own and its state has no shard shape."""
+    if kind is None:
+        return False
+    return kind in _RULES or (
+        kind == getattr(op, "snapshot_kind", None)
+        and getattr(op, "snapshot_shapeless", False))
 
 
 # ---------------------------------------------------------------------------
@@ -342,20 +356,12 @@ def rebucket_blob(op, blob: dict, old_p: int, new_p: int,
     if unchanged:
         return blob
     kind = blob.get("kind") if isinstance(blob, dict) else None
-    if kind == "reduce_host":
-        return _rebucket_reduce_host(op, blob, new_p, override)
-    if kind == "ffat_tpu":
-        return _rebucket_ffat(op, blob, old_p, new_p, old_kk, new_kk,
-                              override, new_mesh is not None)
-    if kind == "stateful_tpu":
-        return _rebucket_stateful(op, blob, new_kk)
-    if kind == "reduce_tpu":
-        return blob     # drop counters + remap: shard-shape independent
-    if kind in ("session_tpu", "interval_join_tpu",
-                "interval_join_pairs_tpu"):
-        # one replica, no mesh (the operators refuse both at build):
-        # their state has no shard shape to change
-        return blob
+    rule = _RULES.get(kind)
+    if rule is not None:
+        return rule(op, blob, old_p, new_p, old_kk, new_kk, override,
+                    new_mesh is not None)
+    if _has_rule(op, kind):
+        return blob     # the operator says: no shard shape to change
     raise RescaleError(
         op.name,
         f"state of kind {kind!r} has no re-bucketing rule (the operator "

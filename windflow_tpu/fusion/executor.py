@@ -68,42 +68,6 @@ def fused_name(members) -> str:
     return "|".join(op.name for op in members)
 
 
-def _is_stateless(op) -> bool:
-    from windflow_tpu.ops.chained import ChainedTPU
-    from windflow_tpu.ops.tpu import FilterTPU, MapTPU
-    return isinstance(op, (MapTPU, FilterTPU, ChainedTPU))
-
-
-def _tail_supported(op) -> bool:
-    """Stateful chain tails the executor can extend with a prelude.
-    Host-interning stateful ops are excluded: their key intern reads
-    distinct keys back to host BEFORE the step, which would need the
-    prelude's output mid-chain — a second dispatch, defeating fusion."""
-    from windflow_tpu.ops.tpu import ReduceTPU
-    from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
-    from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-    from windflow_tpu.windows.session_tpu import _RowsBoundedByDataTPU
-    if isinstance(op, _RowsBoundedByDataTPU):
-        # the step inlines the prelude ahead of its sort (the bid filter
-        # of NEXmark Q11 rides in jit_step_session, the person filter
-        # of Q9 in jit_step_join)
-        return True
-    if isinstance(op, FfatWindowsTPU):
-        # compacted key spaces (withCompactedKeys, max_keys None) stay
-        # un-fused: their remap admits keys at the HOST staging boundary
-        # (parallel/compaction.py), and a prelude would move key
-        # extraction behind the chain where no host admission path can
-        # see it — a pinned table that never fills.  Compacted REDUCE
-        # tails fuse fine: their cold tail is the in-program sorted
-        # lane, so a slow-to-seed table costs speed, never records.
-        return op.max_keys is not None
-    if isinstance(op, ReduceTPU):
-        return True
-    if isinstance(op, _StatefulTPUBase):
-        return bool(op.dense_keys)
-    return False
-
-
 def build_prelude(members):
     """One traced ``(payload, valid) -> (payload, valid)`` body applying
     every stateless member's record transform in chain order — the
@@ -319,10 +283,10 @@ def plan_segments(graph) -> List[dict]:
     for chain in fusible_chains(graph):
         run = []
         for op in chain["ops"]:
-            if _is_stateless(op):
+            if op.chain_role == "member":
                 run.append(op)
                 continue
-            if run and _tail_supported(op):
+            if run and op.inlines_prelude():
                 run.append(op)
             break
         if len(run) < 2:
@@ -405,7 +369,7 @@ def apply_fusion(graph) -> List[dict]:
         for m in members[:-1]:
             m._fused_into = seg["name"]
         host._fused_name = seg["name"]
-        if _is_stateless(host):
+        if host.chain_role == "member":
             host._fusion_exec = FusedStatelessExec(
                 seg["name"], members, donate_inputs=donate)
         else:

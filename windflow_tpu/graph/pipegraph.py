@@ -56,6 +56,11 @@ def _staging_pool_stats() -> dict:
     return staging.default_pool().stats()
 
 
+def _section_error(e: Exception) -> dict:
+    """What a plane's section reads as when its read raised."""
+    return {"enabled": True, "error": f"{type(e).__name__}: {e}"[:200]}
+
+
 def _calibration_summary() -> dict:
     """Provenance frame of every modeled constant (monitoring/
     calibration.py), for dump_trace metadata and the postmortem's
@@ -533,15 +538,12 @@ class PipeGraph:
         if cfg.latency_ledger \
                 and self._recorder is not None:
             from windflow_tpu.monitoring.latency_ledger import LatencyLedger
-            from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-            from windflow_tpu.windows.session_tpu import \
-                _RowsBoundedByDataTPU
             self._latency = LatencyLedger(
                 self._recorder,
                 slo_ms=cfg.latency_slo_ms or 0.0)
             self._latency.megastep_plane = self._megastep_plane
             for op in self._operators:
-                if isinstance(op, (FfatWindowsTPU, _RowsBoundedByDataTPU)):
+                if op.reports_fire_freshness:
                     for rep in op.replicas:
                         rep.latency = self._latency
             if self._health is not None:
@@ -1057,112 +1059,31 @@ class PipeGraph:
         if self._health is not None:
             self._health.sample()
 
-    def _health_section(self) -> dict:
-        if self._health is None:
+    def _plane_section(self, plane, before: Optional[str] = None) -> dict:
+        """The ``section()`` of one plane object (health, latency, tenant,
+        roofline, durability, reshard, sweep, shard), guarded: a telemetry
+        read must never take the pipeline or a stats dump down.  With the
+        plane off this is the whole cost: one check.  ``before`` names
+        what the plane runs first so that a headless ``stats()`` call
+        sees current numbers without a monitor thread (the latency
+        ledger's ``harvest``, the roofline's ``tick``)."""
+        if plane is None:
             return {"enabled": False}
         try:
-            return self._health.section()
-        except Exception as e:  # lint: broad-except-ok (same stance as
-            # the device section: a watchdog read must never take the
-            # pipeline or a stats dump down)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
-
-    def _latency_plane_section(self) -> dict:
-        """Guarded like the health/device sections; with
-        ``Config.latency_ledger`` off this is the whole cost: one
-        check.  Harvests before reading so a headless ``stats()`` call
-        sees completed traces without a monitor thread."""
-        if self._latency is None:
-            return {"enabled": False}
-        try:
-            self._latency.harvest()
-            return self._latency.section()
-        except Exception as e:  # lint: broad-except-ok (a decomposition
-            # read must never take the pipeline or a stats dump down —
-            # same stance as every other plane section)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
-
-    def _tenant_section(self) -> dict:
-        """Guarded like the health/latency sections; with
-        ``Config.tenant_ledger`` off this is the whole cost: one
-        check.  Reports the WHOLE process tenant table (every
-        co-resident graph), focused on this graph's row/tenant — one
-        tenant's stats dump is enough for the advisor to plan across
-        tenants."""
-        if self._tenant is None:
-            return {"enabled": False}
-        try:
-            return self._tenant.section()
-        except Exception as e:  # lint: broad-except-ok (an attribution
-            # read must never take the pipeline or a stats dump down —
-            # same stance as every other plane section)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
-
-    def _roofline_section(self) -> dict:
-        """Guarded like the health/latency/tenant sections; with
-        ``Config.roofline_plane`` off this is the whole cost: one
-        check.  Ticks once before reading so a headless ``stats()``
-        call sees current rates without a monitor thread."""
-        if self._roofline is None:
-            return {"enabled": False}
-        try:
-            self._roofline.tick()
-            return self._roofline.section()
-        except Exception as e:  # lint: broad-except-ok (a rate/ratio
-            # read must never take the pipeline or a stats dump down —
-            # same stance as every other plane section)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
-
-    def _durability_section(self) -> dict:
-        """Guarded like the health/device/sweep sections; with
-        ``Config.durability`` unset this is the whole cost: one check."""
-        if self._durability is None:
-            return {"enabled": False}
-        try:
-            return self._durability.section()
-        except Exception as e:  # lint: broad-except-ok (a checkpoint
-            # telemetry read must never take the pipeline or a stats
-            # dump down — same stance as every other plane section)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
-
-    def _reshard_section(self) -> dict:
-        """Guarded like the health/durability sections; with
-        ``Config.reshard_executor`` off this is the whole cost: one
-        check."""
-        if self._reshard is None:
-            return {"enabled": False}
-        try:
-            return self._reshard.section()
-        except Exception as e:  # lint: broad-except-ok (an executor
-            # telemetry read must never take the pipeline or a stats
-            # dump down — same stance as every other plane section)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
-
-    def _sweep_section(self) -> dict:
-        """Guarded like the health/device sections: a ledger read must
-        never take the pipeline or a stats dump down.  With
-        ``Config.sweep_ledger`` off this is the whole cost: one check."""
-        if self._ledger is None:
-            return {"enabled": False}
-        try:
-            return self._ledger.section()
-        except Exception as e:  # lint: broad-except-ok (the ledger walks
-            # registry snapshots and abstract specs at stats cadence —
-            # telemetry degrades, the report still ships)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
+            if before is not None:
+                getattr(plane, before)()
+            return plane.section()
+        except Exception as e:  # lint: broad-except-ok (the planes walk
+            # registry snapshots, device sketch states and abstract specs
+            # at stats cadence — telemetry degrades, the report still
+            # ships)
+            return _section_error(e)
 
     def _ir_audit_section(self) -> dict:
         """wfir (analysis/ir_audit.py): WF9xx findings over the lowered
         StableHLO of this graph's compiled programs.  Re-audits the
         compile watcher's program store at read cadence (cold path, no
-        compiles); guarded like every other plane section.  With
+        compiles); guarded like ``_plane_section``.  With
         ``Config.ir_audit`` off (or ``WF_TPU_IR_AUDIT=0``) this is the
         whole cost: one check."""
         try:
@@ -1177,22 +1098,7 @@ class PipeGraph:
         except Exception as e:  # lint: broad-except-ok (the auditor
             # parses backend-emitted IR text at stats cadence —
             # telemetry degrades, the report still ships)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
-
-    def _shard_section(self) -> dict:
-        """Guarded like the health/device/sweep sections: a shard-plane
-        read must never take the pipeline or a stats dump down.  With
-        ``Config.shard_ledger`` off this is the whole cost: one check."""
-        if self._shard is None:
-            return {"enabled": False}
-        try:
-            return self._shard.section()
-        except Exception as e:  # lint: broad-except-ok (the ledger
-            # merges device sketch states and walks abstract specs at
-            # stats cadence — telemetry degrades, the report still ships)
-            return {"enabled": True, "error": f"{type(e).__name__}: "
-                                              f"{e}"[:200]}
+            return _section_error(e)
 
     def _rolling_rate(self, window_s: float) -> float:
         """Sunk-tuples/sec over (at least) the trailing ``window_s``: the
@@ -1322,13 +1228,13 @@ class PipeGraph:
             "layers": self._recorder.layers(),
             # sweep-ledger cross-reference: per-hop dispatch counts and
             # attributed HBM bytes for the spans in this trace
-            "sweep": self._sweep_section(),
+            "sweep": self._plane_section(self._ledger),
             # shard-plane cross-reference: per-shard load + hot keys for
             # the operators whose spans this trace carries
-            "shard": self._shard_section(),
+            "shard": self._plane_section(self._shard),
             # tenant-plane cross-reference: which tenant this graph's
             # spans bill to, and the process tenant roll-up at dump time
-            "tenant": self._tenant_section(),
+            "tenant": self._plane_section(self._tenant),
             # calibration cross-reference: where every modeled constant
             # behind the trace's derived numbers currently comes from
             # (measured/modeled/calibrated provenance + store age)
@@ -1418,22 +1324,22 @@ class PipeGraph:
             # latency ledger (monitoring/latency_ledger.py): per-batch
             # critical-path segment decomposition, window freshness,
             # and the SLO verdict
-            "Latency_plane": self._latency_plane_section(),
+            "Latency_plane": self._plane_section(self._latency, "harvest"),
             # tenant plane (monitoring/tenant_ledger.py): per-tenant
             # HBM/ICI/dispatch attribution + budget verdicts across
             # every PipeGraph in the process — what the tenant advisor
             # (analysis/tenancy.py, tools/wf_tenant.py) plans against
-            "Tenant": self._tenant_section(),
+            "Tenant": self._plane_section(self._tenant),
             # roofline plane (monitoring/calibration.RooflineLedger):
             # per-hop achieved tup/s vs the calibrated bandwidth
             # ceiling, with measured/modeled/calibrated provenance on
             # every column and the latched ROOFLINE_DEGRADED verdict —
             # docs/OBSERVABILITY.md "Calibration plane"
-            "Roofline": self._roofline_section(),
+            "Roofline": self._plane_section(self._roofline, "tick"),
             "Gauges": self.gauges(),
             # health plane (monitoring/health.py): per-operator watchdog
             # verdicts, stall counters + attribution, verdict timeline
-            "Health": self._health_section(),
+            "Health": self._plane_section(self._health),
             # device plane (monitoring/device_metrics.py): compile-watcher
             # per-op table, HBM/live-buffer gauges, staging-attributed
             # device bytes — the ``"Device"`` half of the telemetry story
@@ -1442,12 +1348,12 @@ class PipeGraph:
             # dispatches + XLA-cost HBM bytes per staged batch, donation
             # misses, hop-boundary residency — the attribution layer the
             # fusion advisor (tools/wf_advisor.py) plans against
-            "Sweep": self._sweep_section(),
+            "Sweep": self._plane_section(self._ledger),
             # shard plane (monitoring/shard_ledger.py): per-shard queue/
             # lag/latency/HBM attribution, key-skew sketches on keyed
             # edges, mesh ICI model — the measurement layer the reshard
             # advisor (tools/wf_shard.py) plans against
-            "Shard": self._shard_section(),
+            "Shard": self._plane_section(self._shard),
             # wfir (analysis/ir_audit.py): WF9xx audit of the lowered
             # StableHLO of this graph's compiled programs — collectives,
             # callbacks, donation aliasing, Pallas lowering proven on
@@ -1462,11 +1368,11 @@ class PipeGraph:
             # durability plane (windflow_tpu/durability): epochs
             # committed, checkpoint/restore wall cost + bytes, sink
             # fence dedupe hits — docs/DURABILITY.md
-            "Durability": self._durability_section(),
+            "Durability": self._plane_section(self._durability),
             # reshard executor (windflow_tpu/serving): plans applied,
             # keys moved, quiesce/recovery wall cost, admission factor,
             # action timeline — docs/OBSERVABILITY.md
-            "Reshard": self._reshard_section(),
+            "Reshard": self._plane_section(self._reshard),
             "Operators": [op.dump_stats() for op in self._operators],
         }
 
@@ -1555,9 +1461,9 @@ class PipeGraph:
         files: List[str] = []
         errors: dict = {}
 
-        def write(name: str, build) -> None:
+        def write(name: str, build, *args) -> None:
             try:
-                obj = build()
+                obj = build(*args)
                 with open(os.path.join(d, name), "w") as f:
                     json.dump(obj, f, indent=1, default=str)
                 files.append(name)
@@ -1581,15 +1487,17 @@ class PipeGraph:
             reg = default_registry()
             return {"jit": reg.snapshot(), "totals": reg.totals()}
         write("jit.json", jit_tables)
-        write("sweep.json", self._sweep_section)
-        write("shard.json", self._shard_section)
+        write("sweep.json", self._plane_section, self._ledger)
+        write("shard.json", self._plane_section, self._shard)
         write("ir_audit.json", self._ir_audit_section)
-        write("latency.json", self._latency_plane_section)
-        write("tenant.json", self._tenant_section)
-        write("roofline.json", self._roofline_section)
+        write("latency.json", self._plane_section, self._latency,
+              "harvest")
+        write("tenant.json", self._plane_section, self._tenant)
+        write("roofline.json", self._plane_section, self._roofline,
+              "tick")
         write("calibration.json", _calibration_summary)
-        write("durability.json", self._durability_section)
-        write("reshard.json", self._reshard_section)
+        write("durability.json", self._plane_section, self._durability)
+        write("reshard.json", self._plane_section, self._reshard)
         write("preflight.json", lambda: {
             "mode": self.config.preflight,
             "check_ms": self._preflight_ms,

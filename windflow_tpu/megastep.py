@@ -141,27 +141,8 @@ def tail_kind(op):
     if getattr(op, "_fusion_exec", None) is not None:
         return None, ("all-stateless fused segment (no stateful tail "
                       "step to carry)")
-    from windflow_tpu.ops.tpu import ReduceTPU
-    from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
-    from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
-    from windflow_tpu.windows.session_tpu import _RowsBoundedByDataTPU
-    if isinstance(op, _RowsBoundedByDataTPU):
-        # session windows, the interval join
-        return None, op.per_batch_reason
-    if isinstance(op, FfatWindowsTPU):
-        if op.parallelism != 1:
-            return None, "parallel window state (per-replica rings)"
-        return ("ffat_tb" if op.is_tb else "ffat_cb"), None
-    if isinstance(op, ReduceTPU):
-        if op.monoid is not None and op.max_keys is not None:
-            return "reduce_dense", None
-        return "reduce_sorted", None
-    if isinstance(op, _StatefulTPUBase):
-        if not op.dense_keys:
-            return None, ("host-interning stateful (per-batch D2H "
-                          "intern sync; declare withDenseKeys)")
-        return "stateful", None
-    return None, f"unsupported tail operator {type(op).__name__}"
+    # what is left is about the operator: its own answer
+    return op.megastep_tail()
 
 
 def _raw_fn(wrapper):

@@ -301,6 +301,32 @@ class Operator:
     #: host op carries a FusedStatelessExec instead, dispatched through
     #: _TPUReplica._op_step (one attribute check per batch).
     _fusion_exec = None
+    #: what it is to a fused chain (analysis/fusion.py links, fusion/
+    #: executor.py trims): ``"member"`` is a stateless record transform
+    #: the chain inlines (its specs: ``ops/chained._tpu_specs``);
+    #: ``"tail"`` can be a chain's LAST operator and never an inner one:
+    #: its output is a different stream (window results, reduced
+    #: batches), so fusing PAST it changes the program contract, not
+    #: just its launch count.  None takes no part in a chain at all.
+    chain_role = None
+    #: True on device operators whose replicas fire windows: the graph
+    #: binds the latency ledger to them for the fire-freshness gauge at
+    #: their sampled-sync site (``_TPUReplica``); the rest keep
+    #: ``latency = None`` (one check)
+    reports_fire_freshness = False
+    #: the ``kind`` of the blob ``snapshot_state`` writes (checkpoints on
+    #: disk carry the string), stated by the class that implements
+    #: ``snapshot_state`` or by the subclasses that share one
+    #: implementation.  None, or a ``snapshot_state`` overridden BELOW
+    #: the class that states the kind: a blob nobody can re-bucket —
+    #: preflight says WF604 on a mesh, a rescaling restore refuses with
+    #: WF605 (durability/rebucket.py, which holds a rule for each kind
+    #: whose state has a shard shape)
+    snapshot_kind = None
+    #: True where the checkpointed state has no shard shape to change
+    #: (one shared table, or one replica on one chip): a restore onto
+    #: another shape takes the blob as it is, and the kind needs no rule
+    snapshot_shapeless = False
     #: device-side key compaction (parallel/compaction.py): non-None on
     #: keyed consumers the graph build attached a KeyCompactor to —
     #: their step resolves arbitrary int32 keys to dense slots through
@@ -378,6 +404,21 @@ class Operator:
         from the ranges each chip owns); unbounded spaces fall back to
         the count-min sketch."""
         return None
+
+    def inlines_prelude(self) -> bool:
+        """True where a fused chain's stateless members can ride INSIDE
+        this operator's step program (``_fused_prelude``, built by
+        fusion/executor.py): the chain is then one dispatch a batch.
+        False keeps the operator's own program and dispatch."""
+        return False
+
+    def megastep_tail(self):
+        """``(kind, None)`` where this operator's step can be the body
+        of a megastep scan (``kind`` names the scan-body adapter in
+        megastep.py that knows its carry layout), else ``(None,
+        reason)``.  ``megastep.tail_kind`` asks after the conditions
+        that are about the graph; the reason feeds preflight's WF608."""
+        return None, f"unsupported tail operator {type(self).__name__}"
 
     def num_dropped_tuples(self) -> int:
         """Tuples this operator dropped beyond collector-level drops (e.g.

@@ -136,6 +136,7 @@ class ChainedTPUReplica(_TPUReplica):
 
 class ChainedTPU(Operator):
     replica_class = ChainedTPUReplica
+    chain_role = "member"
 
     def __init__(self, specs, name, parallelism, routing, key_extractor):
         super().__init__(name, parallelism, routing=routing, is_tpu=True,
@@ -175,17 +176,6 @@ class ChainedTPU(Operator):
 
     def _step(self, batch: DeviceBatch) -> DeviceBatch:
         return self._chain.step(batch)
-
-
-def tpu_chainable(op: Operator) -> bool:
-    """True when :func:`fuse` can provably fold ``op`` into a single-XLA-
-    program :class:`ChainedTPU` stage TODAY (the pairwise fusion
-    ``MultiPipe.chain`` applies).  The fusion advisor
-    (windflow_tpu/analysis/fusion.py) generalizes from this predicate:
-    chains of ``tpu_chainable`` ops are "provable now", while window /
-    reduce / stateful tails need the whole-chain-fusion refactor the
-    advisor's plan is sized for."""
-    return isinstance(op, (MapTPU, FilterTPU, ChainedTPU))
 
 
 def fuse(a: Operator, b: Operator) -> Operator:

@@ -42,6 +42,8 @@ class ReduceReplica(Replica):
 
 class Reduce(Operator):
     replica_class = ReduceReplica
+    #: its re-bucket rule: ``durability/rebucket._rebucket_reduce_host``
+    snapshot_kind = "reduce_host"
 
     # -- durable state (windflow_tpu/durability) -----------------------------
     def snapshot_state(self):
@@ -50,7 +52,7 @@ class Reduce(Operator):
         serializer defaults)."""
         if not self.replicas:
             return None
-        return {"kind": "reduce_host",
+        return {"kind": self.snapshot_kind,
                 "replicas": [dict(r._states) for r in self.replicas]}
 
     def restore_state(self, blob):

@@ -125,6 +125,7 @@ class MapTPU(Operator):
     always per-item)."""
 
     replica_class = MapTPUReplica
+    chain_role = "member"
 
     def __init__(self, fn: Callable, name: str = "map_tpu",
                  parallelism: int = 1, batch_fn: bool = False,
@@ -165,6 +166,7 @@ class FilterTPU(Operator):
     copy the reference pays on the GPU is avoided entirely."""
 
     replica_class = FilterTPUReplica
+    chain_role = "member"
 
     def __init__(self, fn: Callable, name: str = "filter_tpu",
                  parallelism: int = 1,
@@ -268,6 +270,10 @@ class ReduceTPU(Operator):
     TPU→TPU edge."""
 
     replica_class = ReduceTPUReplica
+    chain_role = "tail"
+    snapshot_kind = "reduce_tpu"
+    #: drop counters + remap: shard-shape independent
+    snapshot_shapeless = True
 
     def __init__(self, comb: Callable[[Any, Any], Any],
                  name: str = "reduce_tpu", parallelism: int = 1,
@@ -540,6 +546,16 @@ class ReduceTPU(Operator):
         # contract bounds the key space exactly where routing/state do
         return self.max_keys if self.key_extractor is not None else None
 
+    def inlines_prelude(self) -> bool:
+        # compacted tails too: their cold tail is the in-program sorted
+        # lane, so a slow-to-seed table costs speed, never records
+        return True
+
+    def megastep_tail(self):
+        if self.monoid is not None and self.max_keys is not None:
+            return "reduce_dense", None
+        return "reduce_sorted", None
+
     def num_dropped_tuples(self) -> int:
         if self._mesh_dropped is None:
             return 0
@@ -551,7 +567,7 @@ class ReduceTPU(Operator):
     # only state worth a checkpoint is the accumulated drop counter the
     # stats layer reports.
     def snapshot_state(self):
-        blob = {"kind": "reduce_tpu"}
+        blob = {"kind": self.snapshot_kind}
         if self._mesh_dropped is not None:
             blob["dropped"] = int(self._mesh_dropped)
         if self._compactor is not None:

@@ -211,6 +211,9 @@ class _StatefulTPUBase(Operator):
     all replicas — reference shares one tbb map across replicas too)."""
 
     _is_filter = False
+    chain_role = "tail"
+    #: its re-bucket rule: ``durability/rebucket._rebucket_stateful``
+    snapshot_kind = "stateful_tpu"
 
     @property
     def fixed_capacity_label(self):
@@ -323,9 +326,7 @@ class _StatefulTPUBase(Operator):
             prelude = self._fused_prelude
             if prelude is not None and not self.dense_keys:
                 # the fusion planner only selects dense-key tails
-                # (fusion/executor._tail_supported): interning reads
-                # distinct keys to host BEFORE the step, which a fused
-                # program cannot serve mid-chain
+                # (``inlines_prelude``)
                 raise WindFlowError(
                     f"stateful operator '{self.name}': whole-chain "
                     "fusion requires withDenseKeys")
@@ -393,6 +394,19 @@ class _StatefulTPUBase(Operator):
             self._steps[("compact", capacity)] = step
         return step
 
+    def inlines_prelude(self) -> bool:
+        # host-interning tables are excluded: their key intern reads
+        # distinct keys back to host BEFORE the step, which would need
+        # the prelude's output mid-chain — a second dispatch, defeating
+        # fusion
+        return bool(self.dense_keys)
+
+    def megastep_tail(self):
+        if not self.dense_keys:
+            return None, ("host-interning stateful (per-batch D2H "
+                          "intern sync; declare withDenseKeys)")
+        return "stateful", None
+
     def key_space(self):
         # keys-lane plumbing for the shard ledger: dense extractors are
         # bounded by the slot table; interned key spaces are unbounded
@@ -408,7 +422,7 @@ class _StatefulTPUBase(Operator):
         construction, so this snapshots even before the first batch —
         restore then simply re-seeds the same initial table."""
         return {
-            "kind": "stateful_tpu",
+            "kind": self.snapshot_kind,
             "state": jax.tree.map(np.asarray, self._state),
             "interner": dict(self._interner._ids),
             # compacted runs: the remap IS the key→slot half of per-key

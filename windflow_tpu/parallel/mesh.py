@@ -45,7 +45,8 @@ from windflow_tpu.basic import WindFlowError
 from windflow_tpu.batch import DeviceBatch, HostBatch, host_to_device
 from windflow_tpu.monitoring.jit_registry import wf_jit
 from windflow_tpu.monitoring.recorder import operator_scope, phase
-from windflow_tpu.windows.ffat_kernels import (_b, _masked_reduce_last,
+from windflow_tpu.windows.ffat_kernels import (TB_SCALARS, _b,
+                                           _masked_reduce_last,
                                            _monoid_identity, _seg_scan,
                                            make_ffat_flush,
                                            make_ffat_state, make_ffat_step,
@@ -117,7 +118,9 @@ def _aligned_slot_bound(op) -> Optional[int]:
       all_gather AND the psum lane merge both vanish.
 
     Compacted key spaces stay unaligned (admission runs at the keyed
-    staging boundary of a replica-sharded consumer)."""
+    staging boundary of a replica-sharded consumer).  Not
+    ``op.key_space()``: sessions and the pair join declare one and have
+    no key-sharded step to align (they refuse a mesh only at build)."""
     from windflow_tpu.ops.tpu import ReduceTPU
     from windflow_tpu.ops.tpu_stateful import _StatefulTPUBase
     from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
@@ -950,10 +953,8 @@ def make_sharded_stateful_step(mesh: Mesh, capacity: int, S: int,
 # Time-based FFAT on the mesh.  The single-chip TB state keeps scalar pane
 # clocks shared by all keys (ffat_kernels.make_ffat_tb_state); sharded along
 # ``key`` each shard's ring evolves independently — its capacity roll depends
-# on the panes of the keys it owns — so the scalars become one lane per key
-# shard, sharded the same way as the ``[K, NP]`` cells.
-_TB_SCALARS = ("base", "win_next", "max_seen", "n_late", "n_evicted",
-               "n_win_dropped", "n_wide", "n_ring_advances")
+# on the panes of the keys it owns — so the scalars (``TB_SCALARS``) become
+# one lane per key shard, sharded the same way as the ``[K, NP]`` cells.
 
 
 def make_sharded_ffat_tb_state(agg_spec, K: int, NP: int, mesh: Mesh):
@@ -961,7 +962,7 @@ def make_sharded_ffat_tb_state(agg_spec, K: int, NP: int, mesh: Mesh):
     by key rows, one scalar-clock lane per key shard."""
     kk = mesh.shape[KEY_AXIS]
     state = make_ffat_tb_state(agg_spec, K, NP)
-    for name in _TB_SCALARS:
+    for name in TB_SCALARS:
         state[name] = jnp.broadcast_to(state[name], (kk,))
     sh = state_sharding(mesh)
     return jax.tree.map(lambda a: jax.device_put(a, sh), state)
@@ -998,11 +999,11 @@ def make_sharded_ffat_tb_step(mesh: Mesh, capacity: int, K: int, P_usec: int,
     @operator_scope(owner or op_name)
     def local(state, payload, ts, valid, wm_pane):
         payload, ts, valid = gather(payload, ts, valid)
-        sstate = {k: (v[0] if k in _TB_SCALARS else v)
+        sstate = {k: (v[0] if k in TB_SCALARS else v)
                   for k, v in state.items()}
         new_state, out, fired, out_ts, n_adv = step_local(
             sstate, payload, ts, valid, wm_pane)
-        new_state = {k: (v[None] if k in _TB_SCALARS else v)
+        new_state = {k: (v[None] if k in TB_SCALARS else v)
                      for k, v in new_state.items()}
         # Total window advance across key shards (drivers loop flushes on
         # it).  Along ``data`` the value is already replicated — every data
@@ -1013,7 +1014,7 @@ def make_sharded_ffat_tb_step(mesh: Mesh, capacity: int, K: int, P_usec: int,
         return new_state, out, fired, out_ts, n_adv
 
     sspec = {k: P(KEY_AXIS) for k in
-             ("cells", "cell_valid", "horizon") + _TB_SCALARS}
+             ("cells", "cell_valid", "horizon") + TB_SCALARS}
     fn = shard_map(
         local, mesh=mesh,
         in_specs=(sspec, bspec, bspec, bspec, P()),

@@ -771,6 +771,22 @@ def tb_out_capacity(capacity: int, K: int, R: int, D: int, NP: int) -> int:
     return min(K * 3 * (NP // D + 2), K + capacity * (-(-R // D)))
 
 
+#: the scalar leaves of the time-based state made below (shape () on one
+#: chip and in per-replica states; on a mesh one lane a key shard,
+#: parallel/mesh.py), which a restore onto another shard shape re-lanes
+#: (durability/rebucket.py).  The one list: a counter added to the state
+#: and not here fails tests/test_tb_ring_advance.py at once.
+TB_SCALARS = ("base", "win_next", "max_seen", "n_late", "n_evicted",
+              "n_win_dropped", "n_wide", "n_ring_advances")
+#: of them, the clocks that must AGREE across merged shards (the ring
+#: alignment invariants) ...
+TB_ALIGNED = ("base", "win_next")
+#: ... and the counters, which sum (``max_seen`` merges by max; a blob
+#: from before ``n_wide`` or ``n_ring_advances`` lacks it)
+TB_COUNTERS = ("n_late", "n_evicted", "n_win_dropped", "n_wide",
+               "n_ring_advances")
+
+
 def make_ffat_tb_state(agg_spec, K: int, NP: int):
     """Dense pane-ring state for time-based FFAT: column ``i`` of ``cells``
     holds the aggregate of time pane ``base + i`` (pane = ts // P_usec) for

@@ -123,6 +123,10 @@ class FfatWindowsTPU(Operator):
 
     replica_class = FfatTPUReplica
     fixed_capacity_label = "FfatWindowsTPU"
+    chain_role = "tail"
+    reports_fire_freshness = True
+    #: its re-bucket rule: ``durability/rebucket._rebucket_ffat``
+    snapshot_kind = "ffat_tpu"
 
     #: 1 for the first window operator of a pipeline, n + 1 for one fed
     #: (through whatever operators) by a stage-n window's rows; set by
@@ -343,7 +347,7 @@ class FfatWindowsTPU(Operator):
                     self.D, self.NP, self.lift, self.comb,
                     self.key_extractor,
                     drop_tainted=self.overflow_policy == "drop",
-                    grouping=self._grouping(), ingest=ingest,
+                    ingest=ingest,
                     monoid=self.monoid, op_name=f"{self.name}.mesh",
                     owner=self.name))
             # lanes a key shard's step runs over (its share of the batch:
@@ -352,7 +356,7 @@ class FfatWindowsTPU(Operator):
             return make_sharded_ffat_step(
                 self.mesh, capacity, self.max_keys, self.P, self.R, self.D,
                 self.lift, self.comb, self.key_extractor,
-                monoid=self.monoid, grouping=self._grouping(),
+                monoid=self.monoid,
                 ingest=ingest, op_name=f"{self.name}.mesh", owner=self.name)
         # Pallas kernel selection (windflow_tpu/kernels): resolved once
         # per program build against Config.pallas_kernels + the runtime
@@ -378,14 +382,12 @@ class FfatWindowsTPU(Operator):
                                      key_fn,
                                      drop_tainted=self.overflow_policy
                                      == "drop",
-                                     grouping=self._grouping(),
                                      monoid=self.monoid, pallas=pallas)
         else:
             step = make_ffat_step(capacity, self.max_keys, self.P, self.R,
                                   self.D, lift, self.comb,
                                   key_fn,
                                   monoid=self.monoid,
-                                  grouping=self._grouping(),
                                   pallas=pallas)
         if comp is not None:
             from windflow_tpu.parallel import compaction
@@ -446,15 +448,6 @@ class FfatWindowsTPU(Operator):
         (windflow_tpu/kernels; None = lax path)."""
         from windflow_tpu.kernels import resolve_pallas_for
         return resolve_pallas_for(self)
-
-    def _grouping(self) -> str:
-        """Batch-grouping algorithm from the graph config (rank_scatter |
-        argsort — Config.ffat_grouping), validated at step-build time."""
-        mode = getattr(self.config, "ffat_grouping", "rank_scatter")
-        if mode not in ("rank_scatter", "argsort"):
-            raise WindFlowError(
-                f"unknown ffat_grouping '{mode}' (rank_scatter | argsort)")
-        return mode
 
     # -- operator plumbing ---------------------------------------------------
     @property
@@ -876,7 +869,7 @@ class FfatWindowsTPU(Operator):
         if not self._states:
             return None     # never stepped: nothing to restore
         return {
-            "kind": "ffat_tpu",
+            "kind": self.snapshot_kind,
             "states": {k: jax.tree.map(np.asarray, st)
                        for k, st in self._states.items()},
             "capacity": self._capacity,
@@ -991,6 +984,19 @@ class FfatWindowsTPU(Operator):
         # one device sync at read time, never on the step path; summed over
         # replica states (and over key-shard lanes on a mesh)
         return sum(int(jnp.sum(st[name])) for st in self._states.values())
+
+    def inlines_prelude(self) -> bool:
+        # compacted key spaces (withCompactedKeys, max_keys None) stay
+        # un-fused: their remap admits keys at the HOST staging boundary
+        # (parallel/compaction.py), and a prelude would move key
+        # extraction behind the chain where no host admission path can
+        # see it — a pinned table that never fills
+        return self.max_keys is not None
+
+    def megastep_tail(self):
+        if self.parallelism != 1:
+            return None, "parallel window state (per-replica rings)"
+        return ("ffat_tb" if self.is_tb else "ffat_cb"), None
 
     def key_space(self):
         # keys-lane plumbing for the shard ledger: the dense pane state

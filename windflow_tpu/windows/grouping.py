@@ -26,9 +26,10 @@ radix over base-``DIGIT`` digits, each pass a stable single-digit
 counting sort.
 
 The permutation is bit-identical to ``jnp.argsort(ids, stable=True)``:
-both order by (id, arrival position).  ``ffat_kernels`` keeps the argsort
-path selectable (``Config.ffat_grouping``) so the equivalence is testable
-on every platform.
+both order by (id, arrival position).  The step makers of ``ffat_kernels``
+keep the argsort path as their ``grouping="argsort"`` (the reference of
+tests/test_grouping.py, and what a step falls back to by itself where the
+counting ids would pass int32).
 """
 
 from __future__ import annotations

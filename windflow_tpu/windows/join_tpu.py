@@ -146,12 +146,14 @@ class _IntervalJoin(_RowsBoundedByDataTPU):
     predicate and the output batch."""
 
     fixed_capacity_label = "IntervalJoinTPU"
-    per_batch_reason = (
-        "interval join (each step's hand-on watermark waits for the "
-        "previous step's held-back and overflow counts: per-batch "
-        "dispatch, no scan body)")
     #: ``g.stats()`` name -> the state's counter
     counters = ()
+
+    def megastep_tail(self):
+        return None, (
+            "interval join (each step's hand-on watermark waits for the "
+            "previous step's held-back and overflow counts: per-batch "
+            "dispatch, no scan body)")
 
     def __init__(self, *, build_side: Callable, key_extractor: Callable,
                  match: Optional[Callable], out_capacity: Optional[int],

@@ -95,10 +95,12 @@ class _RowsBoundedByDataTPU(Operator):
     notes_out_cap = True
     #: the program's name in a device trace, less the ``jit_``
     program_name = None
-    #: ``kind`` of the checkpoint's blob
-    snapshot_kind = None
-    #: why a megastep tail keeps per-batch dispatch (``megastep.tail_kind``)
-    per_batch_reason = None
+    chain_role = "tail"
+    reports_fire_freshness = True
+    #: one replica, no mesh (refused at build): the state has no shard
+    #: shape to change.  A subclass states its ``snapshot_kind``, and in
+    #: ``megastep_tail`` why its step is no scan body
+    snapshot_shapeless = True
 
     def __init__(self, name: str, parallelism: int,
                  key_extractor: Optional[Callable], lateness: int) -> None:
@@ -125,6 +127,12 @@ class _RowsBoundedByDataTPU(Operator):
         self._out_wm = WM_NONE
         self._prev_wm = WM_NONE
         self._prev_held = None
+
+    def inlines_prelude(self) -> bool:
+        # the step inlines the prelude ahead of its sort (the bid filter
+        # of NEXmark Q11 rides in jit_step_session, the person filter
+        # of Q9 in jit_step_join)
+        return True
 
     def _make_step(self, capacity: int) -> Callable:
         raise NotImplementedError
@@ -289,10 +297,12 @@ class SessionWindowsTPU(_RowsBoundedByDataTPU):
     fixed_capacity_label = "SessionWindowsTPU"
     program_name = PROGRAM_NAME         # jit_step_session
     snapshot_kind = "session_tpu"
-    per_batch_reason = (
-        "session windows (each step's hand-on watermark waits for the "
-        "previous step's held-back count: per-batch dispatch, no scan "
-        "body)")
+
+    def megastep_tail(self):
+        return None, (
+            "session windows (each step's hand-on watermark waits for "
+            "the previous step's held-back count: per-batch dispatch, "
+            "no scan body)")
 
     def __init__(self, lift: Callable, comb: Callable, gap_usec: int, *,
                  max_keys: int, name: str = "session_windows_tpu",
