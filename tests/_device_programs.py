@@ -54,6 +54,19 @@ def session():
     return jax.jit(step), (state, *_batch(keys=64), jnp.int64(2500))
 
 
+def count_ordered():
+    """The count window in event-time order: a sort, then the released
+    rows a chunk at a time."""
+    from windflow_tpu.windows import count_ordered_kernels as ck
+    step = ck.make_count_ordered_step(B, 64, 4, 1, lambda e: e["v"], ADD,
+                                      lambda e: e["k"], None, True)
+    payload, ts, valid = _batch(keys=64)
+    one = {"k": jnp.zeros((), jnp.int32), "v": jnp.zeros((), jnp.float32)}
+    state = ck.make_count_ordered_state(one, jnp.zeros((), jnp.float32),
+                                        64, 4, B)
+    return jax.jit(step), (state, payload, ts, valid, jnp.int64(2500))
+
+
 def mesh_cb():
     """The key shard's count-window step on a host mesh of four."""
     mesh = M.make_mesh(4)
@@ -108,6 +121,7 @@ FAMILIES = {
     "tb_scatter": tb_scatter,
     "tb_generic": lambda: tb(None),
     "session": session,
+    "count_ordered": count_ordered,
     "mesh_cb": mesh_cb,
     "unpack": unpack,
     "chain_cb": chain,

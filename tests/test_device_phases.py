@@ -54,6 +54,9 @@ EXPECT = {
                    set(), 1),
     "session": ({"wf.session.sort", "wf.session.scan",
                  "wf.session.carry", "wf.session.close"}, set(), 1),
+    # the loop over the chunks of released rows holds place / fire / ring
+    "count_ordered": ({"wf.order", "wf.place", "wf.fire", "wf.ring"},
+                      set(), 1),
     # the rounds' loop holds the owned-lane step and all its phases
     "mesh_cb": ({"wf.mesh.own", "wf.group", "wf.place", "wf.fire",
                  "wf.ring"}, {"mesh.ffat_step"}, 1),

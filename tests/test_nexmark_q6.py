@@ -1,0 +1,802 @@
+"""The count window in event-time order (``windows/count_ordered_tpu.py``)
+and NEXmark Q6 (average selling price by seller) at small sizes on the CPU
+backend: the interval join feeding the count window on the device through
+``PipeGraph`` and the public builders against a per-row oracle in plain
+Python, in every case the operator's contract names; the count window
+alone against ``benchmark/reference.py``; what the options leave as it
+was; and the benchmark's graph and its reference against the same
+oracle."""
+
+import dataclasses
+import hashlib
+import json
+import os
+import pickle
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import windflow_tpu as wf  # noqa: E402
+from benchmark import harness  # noqa: E402
+from benchmark import reference as ref  # noqa: E402
+from test_nexmark_q9 import (DTYPES, LANES, auctions, cut,  # noqa: E402
+                             higher, oracle_rows)
+from windflow_tpu.batch import DeviceBatch  # noqa: E402
+from windflow_tpu.windows import count_ordered_kernels as ck  # noqa: E402
+from windflow_tpu.windows import ffat_kernels as fk  # noqa: E402
+from windflow_tpu.windows import join_kernels as jk  # noqa: E402
+
+q6 = harness.load_module("configs", "nexmark_q6")
+
+W = 10
+SELLERS = 7
+
+
+# ---------------------------------------------------------------------------
+# the per-row oracle: a seller's rows one at a time, in closing order
+# ---------------------------------------------------------------------------
+
+def with_sellers(rng, ev, n_sellers=SELLERS):
+    """The stream ``ev`` with a seller on the ``w`` lane of every build
+    row (a probe's ``w`` stays its bidder)."""
+    ev = dict(ev)
+    ev["w"] = np.where(ev["b"] == 1,
+                       rng.integers(0, n_sellers, len(ev["t"])),
+                       ev["w"]).astype(np.int32)
+    return ev
+
+
+def seller_of(ev):
+    """(auction key, start) -> seller."""
+    b = ev["b"] == 1
+    return dict(zip(zip(ev["k"][b].tolist(), ev["t"][b].tolist()),
+                    ev["w"][b].tolist()))
+
+
+def moving(rows, window=W, slide=1, leading=True, flush=False):
+    """``rows``: ``(seller, end, start, price, count)`` in any order.
+    Per seller in the order (end, start): a row ``(seller, start, sum,
+    n, price, end, count)`` for each row that ends a window; with
+    ``flush`` the windows left incomplete (``start`` 0, as the operator
+    says them: no record ended them)."""
+    out, by = [], {}
+    for r in sorted(rows, key=lambda r: (r[0], r[1], r[2])):
+        by.setdefault(r[0], []).append(r)
+    for s, rs in by.items():
+        for i, r in enumerate(rs):
+            c = i + 1
+            if (c - window) % slide == 0 and (leading or c >= window):
+                last = rs[max(0, c - window):c]
+                out.append((s, r[2], sum(x[3] for x in last), len(last),
+                            r[3], r[1], r[4]))
+        if flush:
+            e = max(len(rs) + 1, window)
+            e += -(e - window) % slide
+            while e - window < len(rs):
+                last = rs[e - window:]
+                out.append((s, 0, sum(x[3] for x in last), len(last), 0, 0,
+                            0))
+                e += slide
+    return sorted(out)
+
+
+def q6_oracle(ev, **kw):
+    """The winning bids of ``ev`` by the join's per-tuple oracle, then
+    each seller's moving sums."""
+    wins, _ = oracle_rows(ev)
+    who = seller_of(ev)
+    return moving([(who[(k, t)], end, t, int(price), n)
+                   for k, t, end, price, _at, _w, n in wins], **kw)
+
+
+# ---------------------------------------------------------------------------
+# the graph: source -> filter -> join -> count window -> sink
+# ---------------------------------------------------------------------------
+
+def lift_bid(auction, bid, ts):
+    return {"price": bid["v"], "at": ts, "who": bid["w"],
+            "seller": auction["w"]}
+
+
+def window_builder(window=W, slide=1, keys=SELLERS, ordered=True,
+                   leading=True):
+    b = (wf.Ffat_WindowsTPU_Builder(
+        lambda r: {"sum": r["value"]["price"].astype(jnp.int64),
+                   "n": jnp.int64(1)},
+        lambda a, b: {"sum": a["sum"] + b["sum"], "n": a["n"] + b["n"]})
+        .withName("mean").withCBWindows(window, slide)
+        .withKeyBy(lambda r: r["value"]["seller"]).withMaxKeys(keys))
+    if ordered:
+        b = b.withEventTimeOrder(
+            lambda r: (r["start"] - r["end"]).astype(jnp.int32))
+    if ordered and leading:
+        b = b.withLeadingPartialWindows()
+    return b
+
+
+def run_graph(ev, batch, out=None, C=128, config=None, order=None, **kw):
+    n = len(ev["t"])
+    order = np.arange(n) if order is None else order
+
+    def gen():
+        for i in order:
+            yield {"t": int(ev["t"][i]),
+                   **{x: DTYPES[x](ev[x][i]) for x in LANES}}
+
+    got = []
+    src = (wf.Source_Builder(gen).withTimestampExtractor(lambda e: e["t"])
+           .withOutputBatchSize(batch).build())
+    keep = wf.FilterTPU_Builder(lambda e: e["w"] >= 0).build()
+    jb = (wf.Interval_JoinTPU_Builder(lift_bid, higher).withName("join")
+          .withBuildSide(lambda e: e["b"] == 1)
+          .withIntervalLength(lambda e: e["len"])
+          .withMatch(lambda b, p: p["v"] >= b["v"])
+          .withKeyBy(lambda e: e["k"]).withBuildCapacity(C))
+    if out is not None:
+        jb = jb.withOutputCapacity(out)
+    mean = window_builder(**kw).build()
+
+    def sink(r):
+        if r is not None:
+            last = r.get("last")
+            got.append((int(r["key"]), int(last["start"]),
+                        int(r["value"]["sum"]), int(r["value"]["n"]),
+                        int(last["value"]["price"]), int(last["end"]),
+                        int(last["count"])) if last is not None else
+                       (int(r["key"]), int(r["wid"]),
+                        int(r["value"]["sum"]), int(r["value"]["n"])))
+    g = wf.PipeGraph("q6_graph", wf.ExecutionMode.DEFAULT,
+                     wf.TimePolicy.EVENT, config=config or wf.Config())
+    pipe = g.add_source(src)
+    pipe.add(keep)
+    pipe.add(jb.build()).add(mean).add_sink(wf.Sink_Builder(sink).build())
+    g.run()
+    return got, g, mean
+
+
+def ops_of(g):
+    return {o["Operator_name"]: o for o in g.stats()["Operators"]}
+
+
+@pytest.mark.parametrize("out", [None, 64])
+@pytest.mark.parametrize("batch", [96, 500])
+@pytest.mark.parametrize("seed", [41, 42])
+def test_the_graph_against_the_oracle(seed, batch, out):
+    """Seeds x batch sizes x output capacities of the join: one row a
+    winning bid, each seller's in the order its auctions close."""
+    rng = np.random.default_rng(seed)
+    ev = with_sellers(rng, auctions(rng, 3000))
+    got, g, mean = run_graph(ev, batch, out=out)
+    exp = q6_oracle(ev)
+    assert sorted(got) == exp and len(exp) > 100
+    # a seller's rows leave in the order its auctions close
+    for s in range(SELLERS):
+        ends = [(r[5], r[1]) for r in got if r[0] == s]
+        assert ends == sorted(ends)
+    ops = ops_of(g)
+    m, j = ops["mean"], ops["join"]
+    assert m["Operator_type"] == "OrderedCountWindowsTPU"
+    assert m["CB_order"] == "event_time"
+    assert m["CB_rows_out_of_order"] == 0 == m["CB_rows_waiting"]
+    assert m["CB_windows_fired"] == len(exp)
+    assert m["CB_partial_windows"] == sum(r[3] < W for r in exp) > 0
+    assert m["CB_out_capacity"] == 4 * j["Join_out_capacity"]
+    assert g.stats()["Dropped_tuples"] == 0
+    # the window behind the join is the second stage of a batch
+    assert mean.window_stage == 2 and mean.program_name == "step_w2"
+    # the counters have their families in the exposition
+    from windflow_tpu.monitoring.openmetrics import (parse_exposition,
+                                                     render_openmetrics)
+    fams = parse_exposition(render_openmetrics(g.stats()))
+    fired = {labels["kind"]: value for _n, labels, value
+             in fams["wf_operator_cb_windows_fired_total"]["samples"]}
+    assert fired == {"full": len(exp) - m["CB_partial_windows"],
+                     "partial": m["CB_partial_windows"]}
+    assert [v for _n, _l, v in fams[
+        "wf_operator_cb_rows_out_of_order_total"]["samples"]] == [0]
+    assert "wf_operator_cb_rows_waiting" in fams
+
+
+def test_a_sellers_rows_of_one_step_arrive_out_of_time_order():
+    """What the count window in ARRIVAL order gets wrong: the join hands
+    a step's rows over in the order of its own sort, by auction, and a
+    seller's auctions do not close in the order of their ids."""
+    rng = np.random.default_rng(43)
+    ev = with_sellers(rng, auctions(rng, 3000))
+    exp = q6_oracle(ev, leading=False)
+    got, g, _ = run_graph(ev, 96, leading=False)
+    full = [r for r in got if r[1] != 0 or r[5] != 0]   # not the flush's
+    assert sorted(full) == exp
+    # the parent's window over the same join: windows over the order in
+    # which the rows reach it
+    arrival, _, op = run_graph(ev, 96, ordered=False)
+    assert type(op).__name__ == "FfatWindowsTPU" and op.window_stage == 2
+    sums = lambda rows: sorted((r[0], r[2]) for r in rows   # noqa: E731
+                               if r[3] == W)
+    assert len(sums(arrival)) == len(sums(exp))
+    assert sums(arrival) != sums(exp)
+
+
+def test_rows_held_back_by_the_join_are_counted_in_their_place():
+    """Auctions that close in bursts (every end on a multiple of 1 024
+    usec) into an output of 16 lanes: the join holds closed rows back
+    and hands them over a step later, behind rows of the same seller
+    that closed after them; the window waits for the watermark the join
+    holds back with them."""
+    rng = np.random.default_rng(44)
+    ev = with_sellers(rng, auctions(rng, 2500))
+    end = (ev["t"] + ev["len"] + 1023) // 1024 * 1024
+    ev["len"] = np.where(ev["b"] == 1, end - ev["t"],
+                         ev["len"]).astype(np.int32)
+    got, g, _ = run_graph(ev, 96, out=16, C=256)
+    ops = ops_of(g)
+    assert ops["join"]["Join_rows_held_back"] > 50
+    assert sorted(got) == q6_oracle(ev)
+    assert ops["mean"]["CB_rows_out_of_order"] == 0
+
+
+def test_a_seller_with_fewer_rows_than_a_window_has_only_partials():
+    rng = np.random.default_rng(45)
+    ev = with_sellers(rng, auctions(rng, 3000))
+    b = np.flatnonzero(ev["b"] == 1)
+    ev["w"][b[:4]] = SELLERS          # a seller of four auctions
+    ev["w"][b[4:]] %= SELLERS
+    got, g, _ = run_graph(ev, 96, keys=SELLERS + 1)
+    exp = q6_oracle(ev)
+    assert sorted(got) == exp
+    few = [r for r in got if r[0] == SELLERS]
+    assert 1 <= len(few) <= 4 and [r[3] for r in few] \
+        == list(range(1, len(few) + 1))
+    # ... and the end of the stream adds no row: one a winning bid
+    assert len(got) == len(oracle_rows(ev)[0])
+
+
+def test_ties_on_expires_go_by_the_auctions_datetime():
+    """Auctions of one seller that close in the same microsecond are
+    counted in the order they opened."""
+    rng = np.random.default_rng(46)
+    ev = with_sellers(rng, auctions(rng, 2000, life=40), n_sellers=2)
+    b = ev["b"] == 1
+    # every auction ends on a multiple of 64: many ties a seller
+    end = (ev["t"] + ev["len"] + 63) // 64 * 64
+    ev["len"] = np.where(b, end - ev["t"], ev["len"]).astype(np.int32)
+    exp = q6_oracle(ev)
+    by = {}
+    for r in exp:
+        by.setdefault((r[0], r[5]), []).append(r)
+    assert sum(len(v) > 1 for v in by.values()) > 10
+    got, _, _ = run_graph(ev, 64, keys=2)
+    assert sorted(got) == exp
+
+
+@pytest.mark.parametrize("megastep", [True, False])
+def test_the_same_rows_with_the_megastep_edge_forced_and_refused(megastep):
+    rng = np.random.default_rng(47)
+    ev = with_sellers(rng, auctions(rng, 2000))
+    cfg = dataclasses.replace(wf.Config(), megastep_sweeps=megastep)
+    got, g, _ = run_graph(ev, 96, config=cfg)
+    assert sorted(got) == q6_oracle(ev)
+    # neither tail is a scan body: per-batch dispatch, and both say why
+    assert all(e["batches"] == 0 for e in g.stats()["Megastep"]["edges"])
+
+
+# ---------------------------------------------------------------------------
+# the operator, a batch at a time
+# ---------------------------------------------------------------------------
+
+def count_op(window, slide, leading, keys=5, tie=True):
+    b = (wf.Ffat_WindowsTPU_Builder(lambda r: r["v"], lambda a, b: a + b)
+         .withCBWindows(window, slide).withKeyBy(lambda r: r["key"])
+         .withMaxKeys(keys)
+         .withEventTimeOrder((lambda r: r["tie"]) if tie else None))
+    return (b.withLeadingPartialWindows() if leading else b).build()
+
+
+def batch_of(B, keys, vals, ties, tss, wm):
+    n = len(keys)
+    pad = lambda a, dt: jnp.asarray(  # noqa: E731
+        np.r_[np.asarray(a, dt), np.zeros(B - n, dt)])
+    return DeviceBatch({"key": pad(keys, np.int32), "v": pad(vals, np.int64),
+                        "tie": pad(ties, np.int64)}, pad(tss, np.int64),
+                       jnp.asarray(np.arange(B) < n), watermark=wm)
+
+
+def rows_of(out):
+    ok = np.asarray(out.valid)
+    p = jax.tree.map(lambda a: np.asarray(a)[ok], out.payload)
+    return list(zip(p["key"].tolist(), p["wid"].tolist(),
+                    p["value"].tolist()))
+
+
+def drive(make, B, keys, vals, ties, tss, shuffle=None, split=None):
+    """The stream through ``make()`` in batches of ``B`` rows, each under
+    the watermark of its own oldest row (so its rows wait a step),
+    shuffled inside the batch; ``split``: snapshot after that many
+    batches and go on in a new operator."""
+    rows, n, op = [], len(keys), make()
+    for i, lo in enumerate(range(0, n, B)):
+        if split is not None and i == split:
+            assert op.dump_stats()["CB_rows_waiting"] > 0
+            blob = pickle.loads(pickle.dumps(op.snapshot_state()))
+            assert blob["kind"] == "count_ordered_tpu"
+            assert all(isinstance(a, np.ndarray)
+                       for a in jax.tree.leaves(blob["state"]))
+            op = make()
+            assert op.snapshot_state() is None          # never stepped
+            op.restore_state(blob)
+        s = np.arange(lo, min(lo + B, n))
+        if shuffle is not None:
+            s = shuffle.permutation(s)
+        rows += rows_of(op._step(batch_of(
+            B, keys[s], vals[s], ties[s], tss[s], int(tss[s].min()))))
+    for out in op._flush():
+        rows += rows_of(out)
+    return rows, op
+
+
+def a_stream(seed, n=900, keys=5):
+    rng = np.random.default_rng(seed)
+    return (rng.integers(0, keys, n).astype(np.int32),
+            rng.integers(1, 1000, n).astype(np.int64),
+            rng.permutation(n).astype(np.int64),
+            np.sort(rng.integers(0, 3 * n, n)).astype(np.int64), rng)
+
+
+@pytest.mark.parametrize("window,slide", [(10, 1), (8, 4), (5, 3), (3, 5),
+                                          (1, 1)])
+@pytest.mark.parametrize("seed", [51, 52])
+def test_the_count_window_alone_against_the_reference(seed, window, slide):
+    """No join: rows shuffled inside each batch, many of one timestamp,
+    against ``reference.oracle_cb_windows`` over the stream sorted by
+    (timestamp, tie): the upstream windows, the incomplete ones flushed
+    at the end of the stream."""
+    keys, vals, ties, tss, rng = a_stream(seed)
+    got, op = drive(lambda: count_op(window, slide, False), 64, keys, vals,
+                    ties, tss, shuffle=rng)
+    o = np.lexsort((ties, tss))
+    ek, ew, ev = ref.oracle_cb_windows(keys[o], vals[o], window, slide)
+    assert sorted(got) == sorted(zip(ek.tolist(), ew.tolist(),
+                                     ev.astype(np.int64).tolist()))
+    st = op.dump_stats()
+    assert st["CB_rows_out_of_order"] == 0 == st["CB_partial_windows"]
+    # the windows the stream filled; the flush's are not counted
+    assert st["CB_windows_fired"] == sum(
+        (c - window) // slide + 1 for c in np.bincount(keys).tolist()
+        if c >= window)
+
+
+@pytest.mark.parametrize("window,slide", [(10, 1), (8, 4), (5, 3)])
+def test_leading_partial_windows_alone(window, slide):
+    keys, vals, ties, tss, rng = a_stream(53)
+    got, op = drive(lambda: count_op(window, slide, True), 64, keys, vals,
+                    ties, tss, shuffle=rng)
+    exp = []
+    for k in range(5):
+        ix = np.flatnonzero(keys == k)
+        v = vals[ix[np.lexsort((ties[ix], tss[ix]))]]
+        ends = [c for c in range(1, len(v) + 1) if (c - window) % slide == 0]
+        exp += [(k, i, int(v[max(0, c - window):c].sum()))
+                for i, c in enumerate(ends)]
+    assert sorted(got) == sorted(exp)
+    st = op.dump_stats()
+    assert st["CB_windows_fired"] == len(exp)
+    assert st["CB_partial_windows"] == 5 * len(
+        [c for c in range(1, window) if (c - window) % slide == 0])
+
+
+def test_the_released_rows_are_taken_a_chunk_at_a_time(monkeypatch):
+    """Chunks of 12 lanes over 192: a key cut by a chunk's end goes on
+    from the state the chunk before left."""
+    monkeypatch.setattr(ck, "CHUNK_LANES", 16)
+    monkeypatch.setattr(ck, "CHUNK_MIN", 8)
+    assert ck.chunk_lanes(192) == 12
+    keys, vals, ties, tss, rng = a_stream(54)
+    make = lambda: count_op(10, 1, True)   # noqa: E731
+    got, _ = drive(make, 64, keys, vals, ties, tss, shuffle=rng)
+    monkeypatch.undo()
+    assert ck.chunk_lanes(192) == 192 and ck.chunk_lanes(4 * 32768) == 16384
+    whole, _ = drive(make, 64, keys, vals, ties, tss, shuffle=rng)
+    assert sorted(got) == sorted(whole) and len(got) == len(keys)
+
+
+def test_snapshot_and_restore_between_two_steps():
+    keys, vals, ties, tss, rng = a_stream(55)
+    make = lambda: count_op(10, 1, True)   # noqa: E731
+    whole, _ = drive(make, 64, keys, vals, ties, tss)
+    again, op = drive(make, 64, keys, vals, ties, tss, split=6)
+    assert again == whole and len(whole) == len(keys)
+    assert op.dump_stats()["CB_windows_fired"] == len(keys)
+
+
+def test_the_join_and_the_window_snapshot_and_restore_together():
+    """Both device stages a batch at a time: snapshot the join's carry
+    and the window's rows (those that wait included) between two steps,
+    restore both into fresh operators, go on: the same rows."""
+    from test_nexmark_q9 import batch_of as events_batch
+    rng = np.random.default_rng(56)
+    ev = with_sellers(rng, auctions(rng, 1800))
+    B = 128
+
+    def stages():
+        join = (wf.Interval_JoinTPU_Builder(lift_bid, higher)
+                .withBuildSide(lambda e: e["b"] == 1)
+                .withIntervalLength(lambda e: e["len"])
+                .withMatch(lambda b, p: p["v"] >= b["v"])
+                .withKeyBy(lambda e: e["k"]).withBuildCapacity(128)
+                .withOutputCapacity(64).build())
+        return join, window_builder().build()
+
+    def rows(out):
+        ok = np.asarray(out.valid)
+        p = jax.tree.map(lambda a: np.asarray(a)[ok], out.payload)
+        last = p["last"]
+        return list(zip(p["key"].tolist(), last["start"].tolist(),
+                        p["value"]["sum"].tolist(), p["value"]["n"].tolist(),
+                        last["value"]["price"].astype(int).tolist(),
+                        last["end"].tolist(), last["count"].tolist()))
+
+    def run(split=None):
+        join, mean = stages()
+        got = []
+        for i, lo in enumerate(range(0, len(ev["t"]), B)):
+            if i == split:
+                blobs = pickle.loads(pickle.dumps(
+                    [join.snapshot_state(), mean.snapshot_state()]))
+                assert [b["kind"] for b in blobs] \
+                    == ["interval_join_tpu", "count_ordered_tpu"]
+                assert join.dump_stats()["Join_build_open"] > 0
+                assert mean.dump_stats()["CB_rows_waiting"] > 0
+                join, mean = stages()
+                join.restore_state(blobs[0])
+                mean.restore_state(blobs[1])
+            part = cut(ev, slice(lo, lo + B))
+            got += rows(mean._step(join._step(events_batch(B, part))))
+        for out in join._flush():
+            got += rows(mean._step(out))
+        for out in mean._flush():
+            got += rows(out)
+        return got
+
+    whole = run()
+    assert run(split=7) == whole
+    assert sorted(whole) == q6_oracle(ev)
+
+
+def test_a_row_older_than_a_watermark_acted_on_is_counted_not_dropped():
+    op = count_op(3, 1, True, keys=1, tie=False)
+    z = np.zeros
+    feed = lambda tss, wm: rows_of(op._step(batch_of(   # noqa: E731
+        8, z(len(tss), np.int32), np.asarray(tss) * 10, z(len(tss)),
+        np.asarray(tss), wm)))
+    assert feed([1, 2, 3], 3) == [(0, 0, 10), (0, 1, 30)]
+    # 2 arrives again, under a watermark that had passed it: counted,
+    # and in its place among the rows released with it (3 waited)
+    assert feed([2, 5], 5) == [(0, 2, 50), (0, 3, 70)]
+    assert op.dump_stats()["CB_rows_out_of_order"] == 1
+    assert [r for o in op._flush() for r in rows_of(o)] == [(0, 4, 100)]
+    assert op.dump_stats()["CB_windows_fired"] == 5
+    assert op.num_dropped_tuples() == 0
+
+
+def test_more_rows_waiting_than_the_state_holds_stop_the_graph():
+    op = count_op(3, 1, True, keys=1, tie=False)
+    z = np.zeros
+    for i in range(4):          # no watermark ever passes a row
+        op._step(batch_of(8, z(8, np.int32), z(8), z(8),
+                          np.arange(8) + 8 * i, 0))
+    with pytest.raises(wf.WindFlowError, match="waiting for the watermark"):
+        op._step(batch_of(8, z(8, np.int32), z(8), z(8), np.arange(8), 0))
+
+
+def _unstarted(mean, tb=False):
+    """``source -> join -> <mean> -> sink``, built and not run."""
+    src = (wf.Source_Builder(lambda: iter(()))
+           .withTimestampExtractor(lambda e: e["t"])
+           .withOutputBatchSize(64).build())
+    join = (wf.Interval_JoinTPU_Builder(lift_bid, higher).withName("join")
+            .withBuildSide(lambda e: e["b"] == 1)
+            .withIntervalLength(lambda e: e["len"])
+            .withKeyBy(lambda e: e["k"]).withBuildCapacity(64).build())
+    g = wf.PipeGraph("q6_check", wf.ExecutionMode.DEFAULT,
+                     wf.TimePolicy.EVENT)
+    g.add_source(src).add(join).add(mean).add_sink(
+        wf.Sink_Builder(lambda r: None).build())
+    return g
+
+
+def test_preflight_names_a_count_window_in_arrival_order_behind_a_join():
+    """WF609: the count window says which order it counts in, where a
+    device operator whose rows follow the data feeds it."""
+    found = [d for d in _unstarted(window_builder(ordered=False).build())
+             .check() if d.code == "WF609"]
+    assert len(found) == 1 and found[0].severity == "warning"
+    assert "'mean'" in found[0].message and "'join'" in found[0].message
+    assert "IntervalJoinTPU" in found[0].message
+    assert "withEventTimeOrder" in found[0].hint
+    # in event-time order, or a time window: nothing to say
+    assert not [d for d in _unstarted(window_builder().build()).check()
+                if d.code == "WF609"]
+    tb = (wf.Ffat_WindowsTPU_Builder(
+        lambda r: r["value"]["price"], lambda a, b: a + b)
+        .withTBWindows(1000, 500).withKeyBy(lambda r: r["value"]["seller"])
+        .withMaxKeys(SELLERS).build())
+    assert not [d for d in _unstarted(tb).check() if d.code == "WF609"]
+    # a count window fed by a host source alone counts in the order of
+    # the stream
+    g = wf.PipeGraph("q6_host_fed")
+    g.add_source(wf.Source_Builder(lambda: iter(())).withOutputBatchSize(64)
+                 .build()).add(count_op(8, 4, False)).add_sink(
+        wf.Sink_Builder(lambda r: None).build())
+    assert not [d for d in g.check() if d.code == "WF609"]
+    plain = wf.Ffat_WindowsTPU_Builder(lambda r: r["v"], lambda a, b: a + b) \
+        .withCBWindows(8, 4).withKeyBy(lambda r: r["key"]).withMaxKeys(5)
+    assert plain.build().count_order == "arrival"
+    assert count_op(8, 4, False).count_order == "event_time"
+
+
+# ---------------------------------------------------------------------------
+# what the options leave as it was
+# ---------------------------------------------------------------------------
+
+def test_the_options_off_build_the_parents_count_window():
+    b = wf.Ffat_WindowsTPU_Builder(lambda r: r["v"], lambda a, b: a + b) \
+        .withCBWindows(8, 4).withKeyBy(lambda r: r["key"]).withMaxKeys(4)
+    assert type(b.build()).__name__ == "FfatWindowsTPU"
+    with pytest.raises(wf.WindFlowError, match="withEventTimeOrder"):
+        b.withLeadingPartialWindows().build()
+    with pytest.raises(wf.WindFlowError, match="COUNT window"):
+        (wf.Ffat_WindowsTPU_Builder(lambda r: r["v"], lambda a, b: a + b)
+         .withTBWindows(8, 4).withMaxKeys(4).withEventTimeOrder().build())
+    with pytest.raises(wf.WindFlowError, match="at most 256"):
+        (wf.Ffat_WindowsTPU_Builder(lambda r: r["v"], lambda a, b: a + b)
+         .withCBWindows(1024, 128).withMaxKeys(4).withEventTimeOrder()
+         .build())
+
+
+def _lowered_sha(step, *args):
+    text = jax.jit(step).lower(*args).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_the_count_window_and_join_steps_are_the_parents():
+    """The default paths lower to the text they had at the parent commit
+    (this backend, small sizes): the pane form of the count window, both
+    combiners, and the join's fold form."""
+    S = jax.ShapeDtypeStruct
+    B, K = 1024, 64
+    payload = {"key": S((B,), np.int32), "v": S((B,), np.float32)}
+    shas = []
+    for monoid in (None, "sum"):
+        step = fk.make_ffat_step(B, K, 8, 4, 1, lambda r: r["v"],
+                                 lambda a, b: a + b, lambda r: r["key"],
+                                 monoid=monoid)
+        state = jax.eval_shape(lambda: fk.make_ffat_state(
+            jnp.zeros((), jnp.float32), K, 4))
+        shas.append(_lowered_sha(step, state, payload, S((B,), np.int64),
+                                 S((B,), np.bool_)))
+    one = {x: S((), DTYPES[x]) for x in LANES}
+    step = jk.make_join_step(
+        B, 64, lambda e: e["k"], lambda e: e["b"] == 1, lambda e: e["len"],
+        lambda b, p: p["v"] >= b["v"], lift_bid, higher, 128)
+    state = jax.eval_shape(lambda: jk.make_join_state(
+        one, jax.eval_shape(lift_bid, one, one, S((), np.int64)), 64))
+    shas.append(_lowered_sha(
+        step, state, {x: S((B,), DTYPES[x]) for x in LANES},
+        S((B,), np.int64), S((B,), np.bool_), S((), np.int64)))
+    assert shas == PARENT_SHAS
+
+
+PARENT_SHAS = [
+    "30c19025c947e229c13a5218fe155da03fa7fa633f28a7a931de09a828bd6b5c",
+    "ece7d84adc98d856d5b8a2d38efa572179ce9d4d026ad2e6aaa0d8a556108b9f",
+    "d68b372b15a72bb281824c4313179ff06c0405e1ac8478787a9fe848688cc3e5"]
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's graph: public builders, default Config()
+# ---------------------------------------------------------------------------
+
+def tiny_cfg(**graph):
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "nexmark_q6.json")) as f:
+        cfg = json.load(f)
+    cfg["graph"].update(dict(batch=512, build_capacity=512,
+                             out_capacity=128, max_keys=128), **graph)
+    # 100 000 events a second: an auction lives 1-33 340 usec, a pass
+    # spans 40.96 ms; 4 active people, the hot one three times in four
+    cfg["stream"].update(ring_batches=8, active_people=4,
+                         hot_bidder_stride=8, event_rate=100_000)
+    return cfg
+
+
+def run_q6(rec, cfg, ring=None, chunk=300):
+    got = []
+
+    def chunks():
+        for lo in range(0, len(rec), chunk):
+            yield rec[lo:lo + chunk].tobytes()
+
+    def sink(c):
+        if c is not None:
+            got.append({k: np.asarray(v) for k, v in c.cols.items()})
+
+    g = q6.build_graph(cfg, ring, chunks, sink)
+    g.run()
+    cat = lambda n: np.concatenate([b[n] for b in got])  # noqa: E731
+    return {n: cat(n) for n in ("key", "wid", "value")}, g
+
+
+def oracle_of(rec):
+    """The per-row oracle over the benchmark's records."""
+    kind = rec[q6.KIND]
+    ev = {"k": rec["k"].astype(np.int64), "t": rec["t"].astype(np.int64),
+          "b": (kind == q6.AUCTION).astype(np.int32),
+          "len": rec[q6.LENGTH].astype(np.int64),
+          "v": rec[q6.PRICE].astype(np.float64),
+          "w": rec[q6.BIDDER].astype(np.int64)}
+    return q6_oracle(cut(ev, kind != q6.PERSON))
+
+
+@pytest.fixture(scope="module")
+def replayed():
+    """The generator's own stream, two and a third passes of a ring,
+    through the benchmark's graph."""
+    cfg = tiny_cfg()
+    ring = q6.make_ring(2**31 + 6, cfg)
+    n = len(ring["rec"]) * 7 // 3
+    rec = ring["rec"][np.arange(n) % len(ring["rec"])].copy()
+    rec["t"] = np.arange(n) * 10                # 100 000 events a second
+    got, g = run_q6(rec, cfg, ring)
+    return cfg, ring, rec, got, g
+
+
+def as_rows(key, wid, value):
+    v = np.asarray(value).reshape(-1, 5)
+    return sorted(zip(np.asarray(key).tolist(), np.asarray(wid).tolist(),
+                      *(v[:, i].tolist() for i in range(5))))
+
+
+def test_the_benchmarks_graph_agrees_with_the_oracle(replayed):
+    _cfg, _ring, rec, got, _g = replayed
+    exp = oracle_of(rec)
+    assert as_rows(got["key"], got["wid"], got["value"]) == exp
+    assert len(exp) > 300
+    # sellers come back pass after pass: windows reach over the passes
+    assert sum(r[3] == W for r in exp) > len(exp) // 2
+
+
+def test_the_closed_form_agrees_with_the_oracle(replayed):
+    cfg, ring, rec, got, g = replayed
+    exp = q6.selling_prices(ring["rec"], len(rec), cfg, run=ring["run"])
+    assert as_rows(exp.key, exp.wid, exp.value) == oracle_of(rec)
+    checks = q6.compare(cfg, got, exp)
+    assert all(c["ok"] for c in checks), checks
+    assert {c["name"] for c in checks} >= {"rows_out_of_order",
+                                           "counter_mismatches",
+                                           "count_mismatches"}
+    assert exp.partial == sum(exp.value[:, 1] < W) > 0
+
+
+def test_the_graph_is_two_programs_a_batch(replayed):
+    cfg, ring, rec, _got, g = replayed
+    st = g.stats()
+    ops = {o["Operator_name"]: o for o in st["Operators"]}
+    assert ops["winning_bids"]["Operator_type"] == "IntervalJoinTPU"
+    assert ops["selling_price"]["Operator_type"] == "OrderedCountWindowsTPU"
+    assert ops["filter_tpu"]["Fused_into"] == "filter_tpu|winning_bids"
+    assert ops["winning_bids"]["Join_rows_held_back"] == 0
+    from windflow_tpu.monitoring.jit_registry import default_registry
+    names = set(default_registry().snapshot())
+    assert {"filter_tpu|winning_bids", "selling_price"} <= names
+    # leading partial windows: nothing is flushed, nothing compiles then
+    assert not [n for n in names if "flush" in n and "selling" in n]
+    assert all(e["batches"] == 0 for e in st["Megastep"]["edges"])
+
+
+@pytest.mark.parametrize("fault", ["control", "no_partials", "counter"])
+def test_a_wrong_answer_fails_a_check(replayed, fault):
+    cfg, ring, rec, got, _g = replayed
+    exp = q6.selling_prices(ring["rec"], len(rec), cfg)
+    if fault == "control":
+        # a seller's auctions counted in the order they open
+        k, w, v = q6.control(cfg, ring, len(rec), {"event_rate": 100_000})
+        bad = q6.compare(cfg, {"key": k, "wid": w, "value": v}, exp)
+        assert [c["name"] for c in bad if not c["ok"]] \
+            == ["count_mismatches"]
+    elif fault == "no_partials":
+        full = got["value"].reshape(-1, 5)[:, 1] == W
+        bad = q6.compare(cfg, {"key": got["key"][full],
+                               "wid": got["wid"][full],
+                               "value": got["value"].reshape(-1, 5)[full]},
+                         exp)
+        assert not all(c["ok"] for c in bad)
+    else:
+        class Broken(q6.Run):
+            def window_counters(self):
+                return {"CB_rows_out_of_order": 3,
+                        "CB_windows_fired": len(exp.key),
+                        "CB_partial_windows": exp.partial,
+                        "CB_rows_waiting": 0}
+        run = Broken()
+        run.graph = object()
+        bad = q6.compare(cfg, got, exp._replace(run=run))
+        assert sorted(c["name"] for c in bad if not c["ok"]) \
+            == ["counter_mismatches", "rows_out_of_order"]
+
+
+def test_a_program_without_the_options_is_refused_at_once(monkeypatch):
+    monkeypatch.delattr(wf.Ffat_WindowsTPU_Builder, "withEventTimeOrder")
+    with pytest.raises(RuntimeError, match="order they arrive"):
+        q6.make_ring(1, tiny_cfg())
+    with pytest.raises(RuntimeError, match="nexmark_q6"):
+        q6.build_graph(tiny_cfg(), None, lambda: iter(()), lambda c: None)
+
+
+def test_the_stream_has_the_sources_shapes():
+    """The hot seller three auctions in four, on one person in a hundred
+    of the active ones; a seller outside the key space is refused."""
+    cfg = tiny_cfg(batch=4096)
+    cfg["stream"].update(active_people=1000, hot_bidder_stride=100)
+    cfg["graph"]["max_keys"] = 212992
+    rec = q6.make_ring(7, cfg)["rec"]
+    a = rec[q6.KIND] == q6.AUCTION
+    sellers = rec[q6.SELLER][a].astype(np.int64) - q6.FIRST_PERSON_ID
+    assert 0.7 < np.mean(sellers % 100 == 1) < 0.8
+    cfg["graph"]["max_keys"] = 8
+    with pytest.raises(ValueError, match="a seller outside"):
+        q6.make_ring(7, cfg)
+
+
+def test_the_second_stages_dispatch_span_says_so(replayed, monkeypatch):
+    """The window's ``wf.dispatch`` notes ``stage=2``, ``out_cap`` (four
+    times the join's output lanes) and ``rows_in``: the rows the join
+    handed over a step earlier, read when the read costs no wait."""
+    from windflow_tpu.monitoring import recorder
+    seen = []
+    real = recorder.span
+
+    class Spy:
+        def __init__(self, name, kw):
+            self.name, self.kw, self.inner = name, dict(kw), real(name, **kw)
+
+        def __enter__(self):
+            self.sp = self.inner.__enter__()
+            return self
+
+        def note(self, **kw):
+            self.kw.update(kw)
+            return self.sp.note(**kw)
+
+        def __exit__(self, *a):
+            seen.append((self.name, self.kw))
+            return self.inner.__exit__(*a)
+
+    import windflow_tpu.ops.tpu as tpu_mod
+    monkeypatch.setattr(tpu_mod.flightrec, "span",
+                        lambda name, **kw: Spy(name, kw))
+    cfg, _ring, rec, _got, _g = replayed
+    got, g = run_q6(rec[:4096], cfg)
+    mine = [kw for n, kw in seen
+            if n == "wf.dispatch" and kw.get("op") == "selling_price"]
+    assert mine and {kw["stage"] for kw in mine} == {2}
+    assert {kw["out_cap"] for kw in mine} == {4 * 128}
+    said = [kw["rows_in"] for kw in mine if "rows_in" in kw]
+    assert len(said) >= len(mine) - 2       # all but the first
+    # every row the join closed in the steps before a dispatch
+    ops = {o["Operator_name"]: o for o in g.stats()["Operators"]}
+    closed = ops["winning_bids"]["Join_build_closed"] \
+        - ops["winning_bids"]["Join_build_unmatched"]
+    assert 0 < sum(said) <= closed == len(got["key"])
+    # the join, the first stage, says no stage; the map behind neither
+    first = [kw for n, kw in seen if n == "wf.dispatch"
+             and kw.get("op") == "winning_bids"]
+    assert first and not any("stage" in kw for kw in first)
+    assert not any("stage" in kw for n, kw in seen
+                   if kw.get("op") == "selling_price_row")

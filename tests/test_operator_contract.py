@@ -118,6 +118,9 @@ KINDS = {
     .withMaxKeys(KEYS).build(),
     "interval_join": lambda: _join(False),
     "interval_join_pairs": lambda: _join(True),
+    "count_ordered": lambda: wf.Ffat_WindowsTPU_Builder(
+        lambda t: t["v"], lambda a, b: a + b).withCBWindows(8, 4)
+    .withKeyBy(key).withMaxKeys(KEYS).withEventTimeOrder().build(),
 }
 
 #: kind -> the parent's answers, a column a question (``QUESTIONS``).
@@ -155,6 +158,9 @@ _ROWS = {
                       "interval_join_tpu"),
     "interval_join_pairs": ("tail", True, "interval join", True,
                             "interval_join_pairs_tpu"),
+    # PR 44: the count window in event-time order, on the same shell
+    "count_ordered": ("tail", True, "count windows in event-time order",
+                      True, "count_ordered_tpu"),
 }
 EXPECTED = {k: dict(zip(COLUMNS, row), unknown_state=False)
             for k, row in _ROWS.items()}
@@ -251,7 +257,7 @@ def test_the_answer_is_the_parents(kind, question):
 
 STATEFUL = sorted(k for k, e in EXPECTED.items() if e["snapshot"])
 SHAPELESS = {"reduce_tpu", "session_tpu", "interval_join_tpu",
-             "interval_join_pairs_tpu"}
+             "interval_join_pairs_tpu", "count_ordered_tpu"}
 
 
 @pytest.mark.parametrize("kind", STATEFUL)

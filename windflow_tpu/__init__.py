@@ -58,6 +58,7 @@ from windflow_tpu.windows.flatfat import FlatFAT
 from windflow_tpu.windows.join_tpu import (IntervalJoinPairsTPU,
                                            IntervalJoinTPU)
 from windflow_tpu.windows.session_tpu import SessionWindowsTPU
+from windflow_tpu.windows.count_ordered_tpu import OrderedCountWindowsTPU
 from windflow_tpu.windows.ops import (KeyedWindows, MapReduceWindows,
                                       PanedWindows, ParallelWindows,
                                       WindowResult)
@@ -94,6 +95,7 @@ __all__ = [
     "Paned_Windows_Builder", "MapReduce_Windows_Builder",
     "Ffat_Windows_Builder", "Ffat_WindowsTPU_Builder",
     "SessionWindowsTPU", "Session_WindowsTPU_Builder",
+    "OrderedCountWindowsTPU",
     "IntervalJoinTPU", "IntervalJoinPairsTPU", "Interval_JoinTPU_Builder",
     "DBHandle", "LogKV", "PMap", "PFilter", "PFlatMap", "PReduce", "PSink",
     "PKeyedWindows", "P_Map_Builder", "P_Filter_Builder",

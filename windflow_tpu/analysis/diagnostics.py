@@ -127,6 +127,17 @@ CODES = {
                          "to per-batch dispatch (host operator, mesh "
                          "or host-interning tail, compacted key "
                          "space, or spec-less source)"),
+    # A count window counts a key's rows in the order they ARRIVE unless
+    # it is built with withEventTimeOrder.  Behind a device operator
+    # whose rows close where the data says (interval join, session
+    # window) arrival order is the order of that operator's own sort, by
+    # key, with held-back rows a step later: windows over it are not the
+    # windows over event time, and nothing else says so.
+    "WF609": ("warning", "count window in arrival order fed by a "
+                         "device operator whose rows follow the data "
+                         "(interval join / session window): its "
+                         "windows are over that operator's hand-over "
+                         "order, not event time"),
     # -- determinism for replay (WF61x, wfverify — analysis/tracecheck.py):
     #    kernels and callbacks of a durability-enabled graph must
     #    regenerate the committed prefix identically on replay

@@ -186,6 +186,7 @@ def check_graph(graph) -> List[Diagnostic]:
 
     _structural_pass(graph, ops, edges, diags)
     _window_spec_pass(ops, diags)
+    _count_order_pass(ops, upstreams, diags)
     _capacity_pass(graph, upstreams, diags)
     _mesh_pass(graph, ops, edges, diags)
     _compaction_pass(graph, ops, diags)
@@ -722,6 +723,50 @@ def _structural_pass(graph, ops, edges, diags) -> None:
                 f"operator '{op.name}' uses KEYBY routing but declares no "
                 "key extractor",
                 node=op.name, hint="pass withKeyBy(fn) on the builder"))
+
+
+def _count_order_pass(ops, upstreams, diags) -> None:
+    """WF609: a count window that counts in ARRIVAL order
+    (``Operator.count_order``) and is fed, through whatever operators,
+    by a device operator whose rows follow the data
+    (``Operator.rows_follow_data``: the interval join, the session
+    window).  Such an operator hands a step's closed rows over compacted
+    in the order of its own sort, by key, and holds some back a step:
+    the count window's windows are then over that order, not over event
+    time, and no counter says so.  ``withEventTimeOrder`` is the cure;
+    a count window built with it is named in the producer's favour by
+    nothing (its ``CB_order`` stat says ``event_time``)."""
+    def producer(op, seen):
+        for up in upstreams.get(id(op), (None, []))[1]:
+            if id(up) in seen:
+                continue
+            seen.add(id(up))
+            if up.rows_follow_data:
+                return up
+            if up.count_order == "event_time":
+                continue        # hands its rows on in time order by key
+            found = producer(up, seen)
+            if found is not None:
+                return found
+        return None
+
+    for op in ops:
+        if op.count_order != "arrival":
+            continue
+        up = producer(op, set())
+        if up is not None:
+            diags.append(Diagnostic(
+                "WF609",
+                f"count window '{op.name}' counts a key's rows in the "
+                f"order they ARRIVE, and '{up.name}' "
+                f"({type(up).__name__}) hands a step's rows over in the "
+                "order of its own sort, by key, some a step later: its "
+                "windows are over that order, not over event time",
+                node=op.name,
+                hint="build the window with withEventTimeOrder(tie) "
+                     "(rows wait for the watermark and are counted by "
+                     "event time), or use a time window, which places "
+                     "a row by its timestamp"))
 
 
 def _window_spec_pass(ops, diags) -> None:

@@ -649,6 +649,42 @@ class Ffat_WindowsTPU_Builder(_WindowBuilderBase):
         self._pane_capacity = None
         self._overflow_policy = "drop"
         self._monoid = None
+        self._event_time_order = False
+        self._tie = None
+        self._leading_partials = False
+
+    def withEventTimeOrder(self, tie=None):
+        """Count windows only: count a key's rows in the order of their
+        EVENT TIME (then ``tie(record)``, an int, for rows of one
+        timestamp), not in the order they arrive.  A count window fed by
+        a device operator whose rows close where the data says (the
+        interval join, the session window) needs it: such an operator
+        hands a step's rows over in the order of its own sort and holds
+        some back a step.  Rows wait in the state until the watermark
+        has passed them; one that arrives older than a watermark already
+        acted on is counted (``CB_rows_out_of_order``).  Builds
+        :class:`~windflow_tpu.windows.count_ordered_tpu.
+        OrderedCountWindowsTPU` (the semantics, whole): a window's row
+        also carries the record that ended it (``last``), the state
+        keeps a key's last ``win_len - 1`` rows (``win_len`` at most
+        256) over ``withMaxKeys(n)``, and the declared-monoid, compacted
+        key space, pane capacity and overflow policy options belong to
+        the count window in arrival order."""
+        self._event_time_order = True
+        self._tie = tie
+        return self
+
+    def withLeadingPartialWindows(self):
+        """With ``withEventTimeOrder``: cut the windows at a key's START
+        instead of its end.  A key's first windows fire over the rows
+        there are (``withCBWindows(10, 1)``: the fold of its first 1, 2,
+        .. 9 rows, then of the last 10: SQL's ``ROWS BETWEEN 9 PRECEDING
+        AND CURRENT ROW``), and no incomplete window is flushed at end
+        of stream.  Off, the upstream rule stands: the first window
+        fires at the ``win_len``-th row and the windows left incomplete
+        fire at end of stream."""
+        self._leading_partials = True
+        return self
 
     def withMaxKeys(self, n: int):
         """Size of the dense device key space [0, n)."""
@@ -720,7 +756,30 @@ class Ffat_WindowsTPU_Builder(_WindowBuilderBase):
         self._overflow_policy = policy
         return self
 
-    def build(self) -> FfatWindowsTPU:
+    def build(self):
+        if self._event_time_order:
+            from windflow_tpu.windows.count_ordered_tpu import \
+                OrderedCountWindowsTPU
+            if self._pane_capacity is not None \
+                    or self._overflow_policy != "drop":
+                raise WindFlowError(
+                    f"'{self._name}': withPaneCapacity / "
+                    "withOverflowPolicy size a time window's pane ring; "
+                    "withEventTimeOrder builds a count window")
+            # a declared monoid is a licence to reorder the fold, which
+            # this form never needs: it folds a window's rows in order
+            return OrderedCountWindowsTPU(
+                self._lift, self._comb, self._spec(),
+                max_keys=self._max_keys, name=self._name,
+                parallelism=self._parallelism,
+                key_extractor=self._key_extractor, tie=self._tie,
+                leading_partials=self._leading_partials)
+        if self._leading_partials:
+            raise WindFlowError(
+                f"'{self._name}': withLeadingPartialWindows belongs to "
+                "the count window in event-time order "
+                "(withEventTimeOrder): the pane form in arrival order "
+                "fires a window once it is full")
         return FfatWindowsTPU(
             self._lift, self._comb, self._spec(), max_keys=self._max_keys,
             name=self._name,

@@ -219,6 +219,17 @@ def _families(stats: dict,
                "Closed rows (the pair form: completed pairs) a full "
                "output batch left in an interval join's state, summed "
                "over the steps that left them")
+    f_co = fam("wf_operator_cb_rows_out_of_order_total", "counter",
+               "Rows that reached a count window in event-time order "
+               "older than a watermark an earlier step had acted on "
+               "(the producer's word broken): counted, not dropped")
+    f_cf = fam("wf_operator_cb_windows_fired_total", "counter",
+               "Windows a count window in event-time order fired in its "
+               "steps: full, or partial (cut at the key's start, "
+               "withLeadingPartialWindows)")
+    f_cw = fam("wf_operator_cb_rows_waiting", "gauge",
+               "Rows a count window in event-time order holds now "
+               "because no watermark has passed them yet")
     f_jr = fam("wf_operator_join_build_retained", "gauge",
                "Build rows the pair form of an interval join retains in "
                "its keyed table now (written, and neither replaced nor "
@@ -280,6 +291,14 @@ def _families(stats: dict,
                          dict(lab, outcome=outcome))
             f_jo.add(op.get("Join_build_open", 0), lab)
             f_jh.add(op.get("Join_rows_held_back", 0), lab)
+        if "CB_rows_out_of_order" in op:
+            lab = dict(base, operator=name)
+            f_co.add(op["CB_rows_out_of_order"], lab)
+            part = op.get("CB_partial_windows", 0)
+            f_cf.add(op.get("CB_windows_fired", 0) - part,
+                     dict(lab, kind="full"))
+            f_cf.add(part, dict(lab, kind="partial"))
+            f_cw.add(op.get("CB_rows_waiting", 0), lab)
         if "CB_step_lanes" in op:
             f_lanes.add(op["CB_step_lanes"], dict(base, operator=name))
             f_whole.add(op.get("CB_wide_steps", 0),

@@ -362,6 +362,11 @@ PHASES: Dict[str, tuple] = {
         "fused operator program",
         "bringing a batch into key order: the grouping permutation "
         "(counting sort, sort or the Pallas kernel) and the gathers by it"),
+    "wf.order": (
+        "fused operator program",
+        "a count window in event-time order: the sort of the rows that "
+        "waited and the batch's by (released or waiting, key, event "
+        "time, tie)"),
     "wf.place": (
         "fused operator program",
         "folding a batch into pane cells and merging them into the "

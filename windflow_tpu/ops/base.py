@@ -281,6 +281,23 @@ class Operator:
     #: step compacts the batch to the lanes a shard owns (set when the
     #: step is built): ``step_cap`` on the ``wf.dispatch`` span
     step_cap = None
+    #: on a windowing device operator (the count / time window, the
+    #: count window in event-time order, the session window, the interval
+    #: join), which window stage of its pipeline it is: 1 for the first,
+    #: n + 1 where it is fed, through whatever operators, by a stage-n
+    #: one's rows (set by the graph build, ``windows/ffat_tpu.
+    #: number_window_stages``); None on every other operator.  A stage
+    #: past the first says so on its ``wf.dispatch`` span (``stage``,
+    #: and ``_stage_notes``)
+    window_stage = None
+    #: ``"arrival"`` / ``"event_time"`` on a count window: the order in
+    #: which it counts a key's rows.  ``rows_follow_data``: True on a
+    #: device operator whose rows close where the DATA says and leave a
+    #: step compacted in the order of its own sort (by key), some held
+    #: back a step: not the order of their timestamps.  Preflight WF609
+    #: names a count window in arrival order fed by one
+    count_order = None
+    rows_follow_data = False
     #: whole-chain fusion (windflow_tpu/fusion): non-None on the MEMBER
     #: operators of a fused segment — the name of the fused hop their
     #: execution folded into.  Member replicas are inert (wired with no
@@ -404,6 +421,11 @@ class Operator:
         from the ranges each chip owns); unbounded spaces fall back to
         the count-min sketch."""
         return None
+
+    def _stage_notes(self) -> dict:
+        """What a window stage past the first adds to its ``wf.dispatch``
+        span beside ``stage`` (``rows_in``, where it knows)."""
+        return {}
 
     def inlines_prelude(self) -> bool:
         """True where a fused chain's stateless members can ride INSIDE

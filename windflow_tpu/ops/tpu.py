@@ -73,6 +73,11 @@ class _TPUReplica(Replica):
                 sp.note(out_cap=out.capacity)
             if self.op.step_cap is not None:
                 sp.note(step_cap=self.op.step_cap)
+            if (self.op.window_stage or 1) > 1:
+                # the second device stage of a batch: two programs a
+                # batch, told apart here and by the program's name
+                sp.note(stage=self.op.window_stage,
+                        **self.op._stage_notes())
         self.stats.device_programs_launched += 1
         if self.ring is not None and batch.trace is not None:
             # `dispatched` stamps the ASYNC enqueue (the host is already

@@ -57,23 +57,25 @@ from windflow_tpu.windows.ffat_kernels import (agg_spec_for,
 
 
 def number_window_stages(operators, upstreams: dict) -> None:
-    """Set ``window_stage`` on every :class:`FfatWindowsTPU` of a graph
-    from its edges (``upstreams``: ``id(op) -> [(upstream op, _)]``, as
+    """Set ``window_stage`` on every windowing device operator of a graph
+    (those whose ``Operator.window_stage`` is not None: the count / time
+    window, the count window in event-time order, the session window,
+    the interval join) from its edges (``upstreams``: ``id(op) -> [(upstream op, _)]``, as
     ``fusion.executor._upstream_edges`` gives them): one more than the
-    deepest window operator anywhere upstream of it."""
+    deepest windowing operator anywhere upstream of it."""
     depth: dict = {}
 
     def above(op) -> int:
-        """Window operators on the deepest path into ``op``."""
+        """Windowing operators on the deepest path into ``op``."""
         if id(op) not in depth:
             depth[id(op)] = 0           # a cycle cannot be built; be safe
             depth[id(op)] = max(
-                (above(up) + isinstance(up, FfatWindowsTPU)
+                (above(up) + (up.window_stage is not None)
                  for up, _ in upstreams.get(id(op), ())), default=0)
         return depth[id(op)]
 
     for op in operators:
-        if isinstance(op, FfatWindowsTPU):
+        if op.window_stage is not None:
             op.window_stage = above(op) + 1
 
 
@@ -168,6 +170,8 @@ class FfatWindowsTPU(Operator):
         self.R = spec.win_len // self.P
         self.D = spec.slide // self.P
         self.is_tb = spec.win_type == WinType.TB
+        if not self.is_tb:
+            self.count_order = "arrival"
         # TB pane ring contract: the ring must cover the window span, plus
         # the time spread of any single batch (including idle gaps *inside*
         # a batch — gaps between batches cost nothing, pre-gap windows fire

@@ -96,6 +96,10 @@ class _RowsBoundedByDataTPU(Operator):
     #: the program's name in a device trace, less the ``jit_``
     program_name = None
     chain_role = "tail"
+    #: its rows are a window stage to the operators it feeds: a window
+    #: behind it is stage 2 (``jit_step_w2``)
+    window_stage = 1
+    rows_follow_data = True
     reports_fire_freshness = True
     #: one replica, no mesh (refused at build): the state has no shard
     #: shape to change.  A subclass states its ``snapshot_kind``, and in
