@@ -209,6 +209,39 @@ def test_fill_is_counted_where_the_batch_is_cut(ran):
     assert sum(a.counts["n"] for a in made if a.name == "wf.pack") == 5000
 
 
+def test_one_pass_edge_keeps_its_parse_and_pack_spans(ran):
+    """The packed edge takes its rows in place (the native parse writes
+    the staging buffer): every chunk still shows as ``wf.parse``, marked
+    ``direct=1``, beside the ``wf.pack`` of the rows it wrote, a pair a
+    slice (a chunk that splits a batch is two), and the edge counts
+    every tuple as parsed in place."""
+    g, made = ran
+    parsed = [a.counts for a in made if a.name == "wf.parse"]
+    packed = [a.counts for a in made if a.name == "wf.pack"]
+    assert all(c.get("direct") == 1 for c in parsed)
+    # 8 chunks of 700 records; 4 batch cuts fall inside a chunk
+    assert len(parsed) == len(packed) == 8 + 4
+    assert [c["n"] for c in parsed] == [c["n"] for c in packed]
+    assert all(c["bytes"] == 24 * c["n"] for c in parsed)
+    stg = g.stats()["Staging"]
+    assert stg["parsed_in_place_tuples"] == stg["tuples"] == 5000
+
+
+def test_two_pass_edge_marks_no_parse_direct(annotations, monkeypatch):
+    """Without the native library the same edge takes columns: no span
+    says ``direct`` and no tuple counts as parsed in place."""
+    from windflow_tpu import native
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_attempted", True)
+    g, got = _frame_graph("spans_two_pass", trace_sample_every=2)
+    g.run()
+    parsed = [a.counts for a in annotations if a.name == "wf.parse"]
+    assert len(parsed) == 8 and not any("direct" in c for c in parsed)
+    assert sum(c["n"] for c in parsed) == 5000
+    stg = g.stats()["Staging"]
+    assert stg["parsed_in_place_tuples"] == 0 and stg["tuples"] == 5000
+
+
 def test_recorder_off_constructs_nothing(annotations):
     g, got = _frame_graph("spans_off", flight_recorder=False)
     g.run()

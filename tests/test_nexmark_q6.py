@@ -230,12 +230,43 @@ def test_rows_held_back_by_the_join_are_counted_in_their_place():
     and hands them over a step later, behind rows of the same seller
     that closed after them; the window waits for the watermark the join
     holds back with them."""
+    ev = bursts_of_closing_auctions()
+    # no punctuation inside the run: the source is a Python generator, so
+    # at the default 100 ms interval a loaded machine sends one and an
+    # idle one does not, and a punctuation overtakes the rows the join
+    # holds back (the test below)
+    cfg = dataclasses.replace(wf.Config(),
+                              punctuation_interval_usec=10 ** 12)
+    got, g, _ = run_graph(ev, 96, out=16, C=256, config=cfg)
+    ops = ops_of(g)
+    assert ops["join"]["Join_rows_held_back"] > 50
+    assert sorted(got) == q6_oracle(ev)
+    assert ops["mean"]["CB_rows_out_of_order"] == 0
+
+
+def bursts_of_closing_auctions():
     rng = np.random.default_rng(44)
     ev = with_sellers(rng, auctions(rng, 2500))
     end = (ev["t"] + ev["len"] + 1023) // 1024 * 1024
     ev["len"] = np.where(ev["b"] == 1, end - ev["t"],
                          ev["len"]).astype(np.int32)
-    got, g, _ = run_graph(ev, 96, out=16, C=256)
+    return ev
+
+
+@pytest.mark.xfail(strict=False, reason=(
+    "a Punctuation passes the join at the replica's INPUT watermark "
+    "(Replica._dispatch_impl forwards current_wm) while the join stamps "
+    "its batches with the watermark it holds back with its rows: "
+    "ROADMAP.md queue 3 item 1"))
+def test_a_punctuation_does_not_overtake_the_rows_the_join_holds_back():
+    """The same bursts with a punctuation every 1 ms of wall clock, a
+    sweep or so (the default is 100 ms, which a loaded machine reaches
+    inside this run and an idle one does not).  The rows are still the
+    oracle's; the window counts 7 that reached it older than a watermark
+    it had acted on (read at 20, 5, 1, 0.2 and 0.05 ms alike)."""
+    ev = bursts_of_closing_auctions()
+    cfg = dataclasses.replace(wf.Config(), punctuation_interval_usec=1_000)
+    got, g, _ = run_graph(ev, 96, out=16, C=256, config=cfg)
     ops = ops_of(g)
     assert ops["join"]["Join_rows_held_back"] > 50
     assert sorted(got) == q6_oracle(ev)

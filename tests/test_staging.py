@@ -176,6 +176,67 @@ def test_packed_builder_streams_across_appends():
     np.testing.assert_array_equal(three.finish(), whole)
 
 
+@pytest.mark.parametrize("ts_fixed", [None, 1_700_000_000_000_123],
+                         ids=["event_ts", "one_arrival_stamp"])
+@pytest.mark.parametrize("val", ["float32", "int32", "int64"])
+@pytest.mark.parametrize("key", ["int32", "int64"])
+@pytest.mark.parametrize("nv", [1, 5])
+def test_in_place_writer_writes_what_append_writes(nv, key, val, ts_fixed):
+    """A producer that writes the packed words itself — the native frame
+    parse, given the builder's ``buf``, ``lane_layout`` and ``n``, and
+    reporting its rows with ``advance`` — leaves the buffer ``append``
+    leaves for the same rows as columns, word for word, in three slices
+    that start mid-buffer, in a lane order that is not the wire's."""
+    from windflow_tpu import native
+    if not native.is_available():
+        pytest.skip("no native library")
+    cap, n = 64, 50
+    rng = np.random.default_rng(nv)
+    rec = np.zeros(n, dtype=[("k", "<i8"), ("t", "<i8"),
+                             ("v", "<f8", (nv,))])
+    rec["k"] = rng.integers(-90, 90, n) if key == "int32" \
+        else rng.integers(-(1 << 50), 1 << 50, n)
+    rec["t"] = rng.integers(-5, 1 << 45, n)
+    rec["v"] = rng.normal(size=(n, nv)) * 1e6     # rounds in f32, truncates
+    blob = rec.tobytes() + b"\x07" * 9            # and a piece of a record
+    # lanes as emit_columns orders them: the values, then the key
+    dtypes = (val,) * nv + (key,)
+    pool = StagingPool()
+    tss = np.full(n, ts_fixed, np.int64) if ts_fixed is not None \
+        else rec["t"]
+    want = PackedBatchBuilder(dtypes, cap, pool=pool)
+    want.buf[:] = 0xFFFFFFFF
+    want.append([rec["v"][:, i].astype(val) for i in range(nv)]
+                + [rec["k"].astype(key)], tss)
+    got = PackedBatchBuilder(dtypes, cap, pool=pool)
+    got.buf[:] = 0xFFFFFFFF
+    offs = PackedBatchBuilder.lane_layout(dtypes, cap)
+    assert offs == got._offsets
+    # the writer's order: key, values in wire order, ts
+    lane_off = np.array([offs[nv]] + offs[:nv] + [offs[-1]], np.int64)
+    at, extrema = 0, []
+    for room in (7, 1, cap):
+        m, lo, hi, k_lo, k_hi = native.parse_frames_packed(
+            blob, at * rec.dtype.itemsize, nv, got.buf, lane_off,
+            2 if key == "int64" else 1,
+            native.PACKED_VALUE_KINDS[np.dtype(val)], got.n,
+            min(room, got.room), ts_fixed)
+        assert m == min(room, n - at)
+        extrema.append((lo, hi))
+        assert (lo, hi) == (int(tss[at:at + m].min()),
+                            int(tss[at:at + m].max()))
+        assert (k_lo, k_hi) == (int(rec["k"][at:at + m].min()),
+                                int(rec["k"][at:at + m].max()))
+        got.advance(m)
+        at += m
+    assert got.n == n and got.room == cap - n
+    np.testing.assert_array_equal(got.finish(), want.finish())
+    with pytest.raises(AssertionError):
+        got.advance(cap - n + 1)
+    assert native.frames_key_range(blob, nv) == (int(rec["k"].min()),
+                                                    int(rec["k"].max()))
+
+
 def test_builder_rejects_unpackable_dtypes():
     with pytest.raises(ValueError, match="unpackable"):
         PackedBatchBuilder(("float64",), 8, pool=StagingPool())

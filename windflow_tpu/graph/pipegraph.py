@@ -1380,12 +1380,17 @@ class PipeGraph:
         """What the staging edges shipped, counted where each batch is
         cut: ``tuples / capacity`` is the fill share, ``partial_batches``
         the ones a punctuation, a lane change or the end of stream
-        flushed short."""
+        flushed short.  ``parsed_in_place_tuples`` are the rows a source
+        wrote into the staging buffer itself (the one-pass frame parse),
+        counted where they are written: against ``tuples`` it says how
+        often that route engaged."""
         from windflow_tpu.wire import iter_stage_emitters
         ems = [em for _src, _route, em in iter_stage_emitters(self)]
         return {"batches": sum(e.staged_batches for e in ems),
                 "partial_batches": sum(e.partial_batches for e in ems),
                 "tuples": sum(e.staged_tuples for e in ems),
+                "parsed_in_place_tuples": sum(e.parsed_in_place_tuples
+                                              for e in ems),
                 "capacity": sum(e.staged_batches * e._local_cap
                                 for e in ems)}
 

@@ -9,6 +9,13 @@ columns to the staging emitter — so a batch travels from bytes to TPU HBM
 without any per-tuple Python work.  Falls back to numpy parsing when the
 native library is unavailable.
 
+Binary frames bound for a packed one-chip staging edge skip the columns:
+the native parse writes each field once, at the width and the offset the
+staged batch holds it, straight into the edge's pooled staging buffer
+(``FrameSourceReplica._ingest_in_place``, wf_parse_frames_packed; the
+edge answers ``Emitter.packed_destination``).  The buffer that ships is
+word for word the one the columns would have given.
+
 Record wire format (``fmt="frames"``): little-endian ``int64 key, int64 ts,
 nv × float64 values``.  CSV (``fmt="csv"``): ``key,ts,v0[,v1...]`` lines.
 """
@@ -28,11 +35,30 @@ from windflow_tpu.ops.base import Operator
 from windflow_tpu.ops.source import BaseSourceReplica, Source
 
 
+def _fits_int32(lo: int, hi: int) -> bool:
+    """The key lane's width rule: int32 when every key of a chunk (its
+    min ``lo`` and max ``hi``) fits and the chunk's keys are of one sign;
+    a chunk that straddles zero keeps the wire's int64, as it always has."""
+    return -(1 << 31) <= lo and hi < (1 << 31) and (lo < 0) == (hi < 0)
+
+
 class FrameSourceReplica(BaseSourceReplica):
     def __init__(self, op: "FrameSource", index: int) -> None:
         super().__init__(op, index)
         self._chunks = None
         self._carry = b""
+        # the one-pass route (_ingest_in_place) is taken for binary frames
+        # with the native library loaded and a value dtype it writes; the
+        # emitter's answer decides the rest, once
+        self._in_place_kind = native.PACKED_VALUE_KINDS.get(op.value_dtype)
+        self._in_place = op.fmt == "frames" and native.is_available() \
+            and self._in_place_kind is not None
+        self._in_place_names = ("key",) + tuple(op.fields)
+        # the columns' dtypes with a narrow (False) and a wide (True) key
+        self._in_place_dtypes = {
+            wide: (key,) + (op.value_dtype.name,) * op.nv
+            for wide, key in ((False, "int32"), (True, "int64"))}
+        self._in_place_wide = False     # the last chunk's key width
 
     def start(self) -> None:
         self._chunks = iter(self.op.chunks_fn(self.context))
@@ -61,6 +87,8 @@ class FrameSourceReplica(BaseSourceReplica):
             self._ingest(self._carry, final=True)
 
     def _ingest(self, buf: bytes, final: bool = False) -> None:
+        if self._in_place and self._ingest_in_place(buf, final):
+            return
         with flightrec.span("wf.parse", bytes=len(buf)) as sp:
             parsed = self._parse(buf, final)
             sp.note(n=0 if parsed is None else len(parsed[1]))
@@ -70,6 +98,69 @@ class FrameSourceReplica(BaseSourceReplica):
         self.emitter.emit_columns(cols, tss, self.current_wm,
                                   row_wms=row_wms)
         self._count_toward_punctuation(len(tss))
+
+    def _ingest_in_place(self, buf: bytes, final: bool) -> bool:
+        """The one-pass route: the native parse writes each field of a
+        frame once, at its staged width, into the staging buffer the
+        emitter's open batch ships — same words, same stamps and the same
+        cuts as ``_parse`` + ``emit_columns``, with no column in between.
+        False (and the two-pass route from then on) when the emitter has
+        no such destination: a mesh or keyed staging edge, a host edge."""
+        nv = self.op.nv
+        rec = native.frame_record_bytes(nv)
+        n = len(buf) // rec
+        em, names, dtypes = self.emitter, self._in_place_names, \
+            self._in_place_dtypes
+        # the chunk's key width, by _parse's rule.  Guessed from the chunk
+        # before and checked against the keys the parse reads, before its
+        # rows are committed: a chunk written at the wrong width is
+        # written again.  A chunk that will split a batch ships rows
+        # before its last key is read, so its keys are scanned first.
+        wide, checked = self._in_place_wide, False
+        # ingress time: one arrival stamp a chunk, as _parse gives its rows
+        ts_fixed = max(current_time_usecs(), self._last_ts) \
+            if self.time_policy == TimePolicy.INGRESS else None
+        ts_top = self._last_ts
+        pos = 0
+        while pos < n:
+            with flightrec.span("wf.parse", direct=1) as sp:
+                dest = em.packed_destination(names, dtypes[wide])
+                if dest is None:
+                    # asked before the chunk's first row: nothing is
+                    # written, and an edge's answer stands
+                    sp.note(n=0, bytes=0)
+                    self._in_place = False
+                    return False
+                bld, lane_off = dest
+                if not checked and n > bld.room:
+                    checked = True
+                    if wide == _fits_int32(*native.frames_key_range(buf, nv)):
+                        wide = not wide
+                        sp.note(n=0, bytes=0)
+                        continue
+                m, ts_lo, ts_hi, k_lo, k_hi = native.parse_frames_packed(
+                    buf, pos * rec, nv, bld.buf, lane_off, 2 if wide else 1,
+                    self._in_place_kind, bld.n, min(bld.room, n - pos),
+                    ts_fixed)
+                if not checked:
+                    checked = True      # m == n: the whole chunk was read
+                    if wide == _fits_int32(k_lo, k_hi):
+                        wide = not wide
+                        sp.note(n=0, bytes=0)
+                        continue
+                sp.note(n=m, bytes=m * rec)
+            # the row frontier after the slice's last row: the running
+            # max of event time (_parse's row_wms, read at that row)
+            ts_top = max(ts_top, ts_hi)
+            em.commit_packed(m, ts_lo, ts_hi, max(ts_top, 0))
+            pos += m
+        self._in_place_wide = wide
+        self._carry = b"" if final else buf[n * rec:]
+        self._last_ts = ts_top
+        self._advance_wm(ts_top)
+        self.stats.outputs_sent += n
+        self._count_toward_punctuation(n)
+        return True
 
     def _parse(self, buf: bytes, final: bool):
         """Bytes to the columns the emitter takes: the native parse and
@@ -105,7 +196,7 @@ class FrameSourceReplica(BaseSourceReplica):
         # extra key space — but keys outside int32 (e.g. 64-bit hash ids)
         # keep their width so host-side consumers never see collisions
         keys = keys.astype(np.int64)
-        if len(keys) and np.int32(keys.max() >> 31) == (keys.min() >> 31)                 and -(1 << 31) <= keys.min() and keys.max() < (1 << 31):
+        if _fits_int32(int(keys.min()), int(keys.max())):
             keys = keys.astype(np.int32)
         cols = {"key": keys}
         vd = self.op.value_dtype

@@ -117,6 +117,11 @@ def lib() -> Optional[ctypes.CDLL]:
     L.wf_frame_record_bytes.argtypes = [i4]
     L.wf_parse_frames.restype = i8
     L.wf_parse_frames.argtypes = [p, i8, i4, p, p, p, i8]
+    L.wf_frames_key_range.restype = i8
+    L.wf_frames_key_range.argtypes = [p, i8, i4, p]
+    L.wf_parse_frames_packed.restype = i8
+    L.wf_parse_frames_packed.argtypes = [p, i8, i4, p, p, i4, i4, i8, i8,
+                                         p, p]
     L.wf_parse_csv.restype = i8
     L.wf_parse_csv.argtypes = [p, i8, i4, p, p, p, i8, p]
     L.wf_min_watermark.restype = i8
@@ -217,6 +222,44 @@ def parse_frames(buf: bytes, nv: int, max_records: int = 2 ** 62):
     tss = arr[:, 8:16].copy().view(np.int64).reshape(n)
     vals = arr[:, 16:].copy().view(np.float64).reshape(n, nv)
     return keys, tss, vals, n * rec
+
+
+#: value-lane dtypes ``parse_frames_packed`` writes, by the kind code of
+#: ``wf_parse_frames_packed``; any other value dtype parses to columns
+PACKED_VALUE_KINDS = {np.dtype(np.float32): 0, np.dtype(np.int32): 1,
+                      np.dtype(np.int64): 2}
+
+
+def frames_key_range(buf, nv: int):
+    """(min, max) of the keys of the whole records in ``buf``: the
+    pre-scan that chooses the key width of a chunk that will split a
+    batch, before its first rows ship.  Native library only (``lib()``
+    is not None)."""
+    out = np.empty(2, np.int64)
+    raw = np.frombuffer(buf, np.uint8)
+    lib().wf_frames_key_range(_ptr(raw), len(raw), nv, _ptr(out))
+    return int(out[0]), int(out[1])
+
+
+def parse_frames_packed(buf, start: int, nv: int, dst: np.ndarray,
+                        lane_off: np.ndarray, key_words: int, val_kind: int,
+                        row: int, room: int, ts_fixed: Optional[int] = None):
+    """Parse up to ``room`` whole records of ``buf[start:]`` straight into the
+    packed staging buffer ``dst`` (``staging.PackedBatchBuilder`` layout)
+    from row ``row`` on; ``lane_off`` is int64[nv + 2]: the word offsets
+    of the key lane, the value lanes in wire order and the ts lane.
+    ``ts_fixed`` stamps every row with one timestamp instead of the
+    record's own.  Returns (rows written, ts min, ts max, key min, key
+    max).  Native library only: the numpy twins parse to columns."""
+    raw = np.frombuffer(buf, np.uint8, offset=start)
+    ranges = np.empty(4, np.int64)
+    fixed = None if ts_fixed is None else \
+        ctypes.byref(ctypes.c_int64(ts_fixed))
+    m = lib().wf_parse_frames_packed(
+        _ptr(raw), len(raw), nv, _ptr(dst), _ptr(lane_off), key_words,
+        val_kind, row, room, fixed, _ptr(ranges))
+    assert m >= 0, (key_words, val_kind)
+    return (m, *ranges.tolist())
 
 
 def parse_csv(buf: bytes, nv: int, max_records: int = 2 ** 62):

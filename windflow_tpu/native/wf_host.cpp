@@ -72,6 +72,118 @@ int64_t wf_parse_frames(const uint8_t* buf, int64_t nbytes, int32_t nv,
   return n;
 }
 
+// Min and max key of the whole records in buf (out[0], out[1]): the
+// pre-scan of the one-pass route for a chunk that will split a batch, whose
+// key width (FrameSourceReplica: int32 when every key fits) has to be known
+// before its first rows ship.  Returns #records scanned.
+int64_t wf_frames_key_range(const uint8_t* buf, int64_t nbytes, int32_t nv,
+                            int64_t* out) {
+  const int64_t rec = wf_frame_record_bytes(nv);
+  const int64_t n = nbytes / rec;
+  int64_t lo = INT64_MAX, hi = INT64_MIN;
+  for (int64_t i = 0; i < n; ++i) {
+    int64_t k;
+    memcpy(&k, buf + i * rec, 8);
+    lo = k < lo ? k : lo;
+    hi = k > hi ? k : hi;
+  }
+  out[0] = lo;
+  out[1] = hi;
+  return n;
+}
+
+}  // extern "C"
+
+// The one-pass route: each frame is read once and each field written once,
+// at the width and the word offset the staged batch holds it
+// (windflow_tpu/staging.py: [lane0 | lane1 | ... | ts lo/hi | n], a 4-byte
+// lane one word a row, an int64 lane two, little-endian lo/hi).  KW is the
+// key lane's words a row; V the value lanes' type: float(double) rounds as
+// numpy's astype(float32), the integer casts truncate as its astype does.
+namespace {
+
+template <int KW, typename V>
+void frames_into_packed(const uint8_t* buf, int64_t m, int32_t nv,
+                        uint32_t* dst, const int64_t* lane_off, int64_t row,
+                        const int64_t* ts_fixed, int64_t* ranges) {
+  constexpr int VW = sizeof(V) / 4;
+  const int64_t rec = wf_frame_record_bytes(nv);
+  uint32_t* kd = dst + lane_off[0] + KW * row;
+  uint32_t* td = dst + lane_off[nv + 1] + 2 * row;
+  int64_t lo = INT64_MAX, hi = INT64_MIN;
+  int64_t klo = INT64_MAX, khi = INT64_MIN;
+  for (int64_t i = 0; i < m; ++i) {
+    const uint8_t* p = buf + i * rec;
+    int64_t k, t;
+    memcpy(&k, p, 8);
+    klo = k < klo ? k : klo;
+    khi = k > khi ? k : khi;
+    if (KW == 1) {
+      kd[i] = (uint32_t)k;
+    } else {
+      memcpy(kd + 2 * i, &k, 8);
+    }
+    if (ts_fixed) {
+      t = *ts_fixed;
+    } else {
+      memcpy(&t, p + 8, 8);
+    }
+    lo = t < lo ? t : lo;
+    hi = t > hi ? t : hi;
+    memcpy(td + 2 * i, &t, 8);
+    for (int32_t v = 0; v < nv; ++v) {
+      double d;
+      memcpy(&d, p + 16 + 8 * v, 8);
+      const V x = (V)d;
+      memcpy(dst + lane_off[1 + v] + VW * (row + i), &x, sizeof(V));
+    }
+  }
+  ranges[0] = lo;
+  ranges[1] = hi;
+  ranges[2] = klo;
+  ranges[3] = khi;
+}
+
+}  // namespace
+
+extern "C" {
+
+// Value-lane kinds of wf_parse_frames_packed.
+enum { WF_VAL_F32 = 0, WF_VAL_I32 = 1, WF_VAL_I64 = 2 };
+
+// Parse up to `room` whole frames of buf straight into the packed staging
+// buffer `dst`, from row `row` on.  lane_off holds nv + 2 word offsets of
+// row 0: the key lane's, the nv value lanes' in wire order, the ts lane's.
+// key_words 1 writes the key's low word, 2 the int64 as lo/hi.  ts_fixed,
+// when not null, stamps every row with *ts_fixed instead of the frame's own
+// ts (ingress time: one arrival stamp a chunk).  ranges[0..3] = min / max of
+// the timestamps written, min / max of the keys read: a caller that wrote
+// low words learns here whether every key fit, before it commits the rows.
+// Returns #rows written, -1 for an unknown key_words / val_kind; the caller
+// carries what was not consumed.
+int64_t wf_parse_frames_packed(const uint8_t* buf, int64_t nbytes, int32_t nv,
+                               uint32_t* dst, const int64_t* lane_off,
+                               int32_t key_words, int32_t val_kind,
+                               int64_t row, int64_t room,
+                               const int64_t* ts_fixed, int64_t* ranges) {
+  int64_t m = nbytes / wf_frame_record_bytes(nv);
+  if (m > room) m = room;
+#define WF_INTO(KW, V)                                                      \
+  frames_into_packed<KW, V>(buf, m, nv, dst, lane_off, row, ts_fixed,      \
+                            ranges)
+  switch (key_words * 4 + val_kind) {
+    case 4 + WF_VAL_F32: WF_INTO(1, float); break;
+    case 4 + WF_VAL_I32: WF_INTO(1, int32_t); break;
+    case 4 + WF_VAL_I64: WF_INTO(1, int64_t); break;
+    case 8 + WF_VAL_F32: WF_INTO(2, float); break;
+    case 8 + WF_VAL_I32: WF_INTO(2, int32_t); break;
+    case 8 + WF_VAL_I64: WF_INTO(2, int64_t); break;
+    default: return -1;
+  }
+#undef WF_INTO
+  return m;
+}
+
 // CSV lines "key,ts,v0[,v1...]\n".  Returns #records; stops at max_records
 // or at the last complete line; *consumed_out = bytes consumed.
 int64_t wf_parse_csv(const char* buf, int64_t nbytes, int32_t nv,

@@ -197,6 +197,20 @@ class Emitter:
             self.emit(item, int(tss[i]),
                       int(row_wms[i]) if row_wms is not None else wm)
 
+    # -- in-place interface (a producer that writes packed words itself) ----
+    def packed_destination(self, names, dtypes):
+        """Where a producer of columns ``names`` (a tuple) of ``dtypes``
+        (numpy dtype names, in the same order) writes its next rows in
+        place: ``(builder, lane_off)``, the open
+        ``staging.PackedBatchBuilder`` and the int64 word offsets of the
+        columns' lanes in ``names`` order followed by the ts lane's.  The
+        producer writes at most ``builder.room`` rows from row
+        ``builder.n`` on and reports them with :meth:`commit_packed`.
+        ``None``: this edge has no such destination and takes columns
+        (:meth:`emit_columns`), as every edge but the packed one-chip
+        staging edge does."""
+        return None
+
     def propagate_punctuation(self, wm: int) -> None:
         """Flush open batches, then multicast a watermark punctuation
         (reference ``forward_emitter.hpp:226-262``)."""
@@ -514,6 +528,14 @@ class DeviceStageEmitter(Emitter):
         self.staged_batches = 0
         self.partial_batches = 0
         self.staged_tuples = 0
+        # rows a producer wrote into the open builder itself
+        # (packed_destination / commit_packed), counted where they are
+        # written; every other row came through emit_columns or emit
+        self.parsed_in_place_tuples = 0
+        # (names, dtypes) of an in-place producer's columns -> (treedef,
+        # dtypes in lane order, lane_off); None for lanes that cannot ride
+        # the packed buffer
+        self._in_place_layouts = {}
         # shard-plane key probe (monitoring/shard_ledger.HostKeyProbe):
         # attached by the ledger when this non-keyed staging edge feeds
         # a keyed device consumer whose key extraction runs in-program
@@ -672,38 +694,92 @@ class DeviceStageEmitter(Emitter):
         is applied only once the chunk's last row is packed."""
         tss = np.ascontiguousarray(tss, np.int64)
         dtypes = tuple(str(l.dtype) for l in leaves)
-        if self._builder is not None and (treedef != self._b_treedef
-                                          or dtypes != self._b_dtypes):
-            self._finalize_builder()    # lane structure changed mid-stream
         m = len(tss)
         pos = 0
         while pos < m:
-            if self._builder is None:
-                self._b_treedef = treedef
-                self._b_dtypes = dtypes
-                self._builder = staging.PackedBatchBuilder(
-                    dtypes, self.output_batch_size)
-                self._b_ts_min = None
-                self._b_ts_max = None
-            take = min(self._builder.room, m - pos)
+            b = self._builder_for(treedef, dtypes)
+            take = min(b.room, m - pos)
             sl = slice(pos, pos + take)
             tsl = tss[sl]
-            self._builder.append([l[sl] for l in leaves], tsl)
-            lo, hi = int(tsl.min()), int(tsl.max())
-            if self._b_ts_min is None or lo < self._b_ts_min:
-                self._b_ts_min = lo
-            if self._b_ts_max is None or hi > self._b_ts_max:
-                self._b_ts_max = hi
+            b.append([l[sl] for l in leaves], tsl)
+            pos += take
             if row_wms is not None:
                 w = int(np.max(row_wms[sl]))
-                if w != WM_NONE and w > self._b_wm:
-                    self._b_wm = w
-            elif pos + take == m and wm != WM_NONE and wm > self._b_wm:
+            else:
                 # a chunk-level wm is valid only after the chunk's LAST row
-                self._b_wm = wm
-            pos += take
-            if self._builder.room == 0:
-                self._finalize_builder()
+                w = wm if pos == m else WM_NONE
+            self._note_packed_rows(int(tsl.min()), int(tsl.max()), w)
+
+    def _builder_for(self, treedef, dtypes):
+        """The open packed builder for lanes of this structure; a change
+        of structure mid-stream ships the open rows first."""
+        if self._builder is not None and (treedef != self._b_treedef
+                                          or dtypes != self._b_dtypes):
+            self._finalize_builder()
+        if self._builder is None:
+            self._b_treedef = treedef
+            self._b_dtypes = dtypes
+            self._builder = staging.PackedBatchBuilder(
+                dtypes, self.output_batch_size)
+            self._b_ts_min = None
+            self._b_ts_max = None
+        return self._builder
+
+    def _note_packed_rows(self, ts_lo: int, ts_hi: int, w: int) -> None:
+        """Rows joined the open builder: fold their data-ts extrema and
+        the row frontier after the last of them (``w``) into the open
+        batch's stamps, and ship the batch once it is full."""
+        if self._b_ts_min is None or ts_lo < self._b_ts_min:
+            self._b_ts_min = ts_lo
+        if self._b_ts_max is None or ts_hi > self._b_ts_max:
+            self._b_ts_max = ts_hi
+        if w != WM_NONE and w > self._b_wm:
+            self._b_wm = w
+        if self._builder.room == 0:
+            self._finalize_builder()
+
+    def packed_destination(self, names, dtypes):
+        """The in-place route (see :meth:`Emitter.packed_destination`),
+        offered exactly where ``emit_columns`` takes the streaming packed
+        route: one chip, no chunk-accumulated rows open, packable
+        lanes."""
+        if self._stage_target is not None or self._col_chunks:
+            return None
+        try:
+            lay = self._in_place_layouts[names, dtypes]
+        except KeyError:
+            lay = None
+            if all(staging.packable_dtype(d) for d in dtypes):
+                # the lane order emit_columns gives the same columns
+                order, treedef = jax.tree.flatten(
+                    {nm: i for i, nm in enumerate(names)})
+                lane_dtypes = tuple(dtypes[i] for i in order)
+                offs = staging.PackedBatchBuilder.lane_layout(
+                    lane_dtypes, self.output_batch_size)
+                lay = (treedef, lane_dtypes, np.array(
+                    [offs[order.index(i)] for i in range(len(names))]
+                    + [offs[-1]], np.int64))
+            self._in_place_layouts[names, dtypes] = lay
+        if lay is None:
+            return None
+        treedef, lane_dtypes, lane_off = lay
+        return self._builder_for(treedef, lane_dtypes), lane_off
+
+    def commit_packed(self, m: int, ts_lo: int, ts_hi: int, w: int) -> None:
+        """``m`` rows were written in place into the builder
+        :meth:`packed_destination` handed out; ``ts_lo`` / ``ts_hi`` are
+        their data-ts extrema and ``w`` the row frontier after the last
+        of them — the batch bookkeeping ``_emit_columns_packed`` reads
+        off its columns, given by the writer instead.  A shard-plane key
+        probe reads the rows back as views of the staging buffer."""
+        if self._shard_probe is not None:
+            b = self._builder
+            self._shard_probe.columns(jax.tree.unflatten(
+                self._b_treedef, b.rows_view(b.n, m)), m)
+        with flightrec.span("wf.pack", n=m):
+            self._builder.advance(m)
+            self.parsed_in_place_tuples += m
+            self._note_packed_rows(ts_lo, ts_hi, w)
 
     def _finalize_builder(self, fallback_wm: int = WM_NONE) -> None:
         """Ship the open packed batch (padding derived on device from the

@@ -22,7 +22,14 @@ H2D copies with kernel execution):
   buffer and ONE host→device transfer per batch (``batch.py`` unpacks it
   on device with a cached program).  No intermediate concatenate, no
   per-lane ``device_put`` — host↔device links are dominated by
-  per-transfer latency, not bandwidth.
+  per-transfer latency, not bandwidth.  Beside ``append`` (columns in,
+  one copy a lane) stands the in-place writer: a producer that can write
+  the packed words itself takes the builder's ``buf``, ``lane_layout``
+  and ``n``, writes rows ``n .. n + m`` of every lane where they belong
+  and hands the count back with ``advance`` — the native frame parse
+  (``native.parse_frames_packed``, ``io/frames.py``) does, so a frame is
+  read once and no column stands between the bytes and the staged batch;
+  ``rows_view`` shows such rows as columns without a copy.
 
 * Double-buffered prefetch lives in the run loop
   (``graph/pipegraph.py``, ``Config.stage_prefetch_depth``): with a
@@ -345,6 +352,9 @@ class PackedBatchBuilder:
     word are implicit.  ``append`` writes each chunk slice at its final
     packed offset — the zero-copy-beyond-one-memcpy streaming form of the
     reference's pinned-buffer fill loop (``forward_emitter_gpu.hpp``).
+    A producer that can write the packed words itself (the native frame
+    parse) takes ``buf``, ``lane_layout`` and ``n`` instead and reports
+    its rows with ``advance``: no column stands in between.
     """
 
     __slots__ = ("capacity", "dtypes", "_words", "_offsets", "total",
@@ -360,12 +370,9 @@ class PackedBatchBuilder:
         # @hot_path append builds nothing per call
         self._lane_dtypes = self.dtypes + (np.dtype(np.int64),)
         self._words = [lane_words(d) for d in self.dtypes] + [2]  # + ts
-        self._offsets = []
-        off = 0
-        for w in self._words:
-            self._offsets.append(off)
-            off += w * capacity
-        self.total = off + 1            # + fill-count word
+        self._offsets = self.lane_layout(self.dtypes, capacity)
+        # + the ts lane's words + the fill-count word
+        self.total = self._offsets[-1] + 2 * capacity + 1
         self.capacity = capacity
         self.buf = self.pool.acquire(self.total)
         self.n = 0
@@ -398,6 +405,36 @@ class PackedBatchBuilder:
             src = np.ascontiguousarray(lane, dt).view(np.uint32)
             lo = off + w * self.n
             self.buf[lo:lo + w * m] = src
+        self.n += m
+
+    @staticmethod
+    def lane_layout(dtypes: Sequence, capacity: int) -> list:
+        """Word offset of row 0 of each payload lane (``dtypes`` order),
+        then of the ts lane, in a builder of ``capacity`` rows.  With
+        ``buf`` and ``n`` it is what an in-place writer needs: it writes
+        rows ``n .. n + m`` of every lane at ``offset + words * row``
+        itself (``native.parse_frames_packed``) and reports them with
+        :meth:`advance`."""
+        offsets, off = [], 0
+        for d in dtypes:
+            offsets.append(off)
+            off += lane_words(d) * capacity
+        return offsets + [off]
+
+    def rows_view(self, lo: int, m: int) -> list:
+        """Rows ``lo .. lo + m`` of each payload lane (``dtypes`` order)
+        as typed views of the buffer: what an in-place writer's rows look
+        like as columns, without a copy."""
+        return [self.buf[off + w * lo:off + w * (lo + m)].view(dt)
+                for off, w, dt in zip(self._offsets, self._words,
+                                      self.dtypes)]
+
+    @hot_path
+    def advance(self, m: int) -> None:
+        """Take back the count an in-place writer wrote (see
+        :meth:`lane_layout`): ``m`` rows, at most ``room``, in every
+        lane and the ts lane."""
+        assert 0 <= m <= self.capacity - self.n, (m, self.n, self.capacity)
         self.n += m
 
     @hot_path
