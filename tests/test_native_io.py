@@ -523,18 +523,26 @@ def test_one_pass_is_not_taken_where_the_edge_or_the_input_cannot(
     assert sorted(rows) == sorted(exp)
 
 
+@pytest.mark.parametrize("keys", ["narrow", "leave_int32"])
 @pytest.mark.parametrize("one_pass", [True, False],
                          ids=["one_pass", "two_pass"])
-def test_shard_probe_reads_the_rows_either_way(monkeypatch, one_pass):
+def test_shard_probe_reads_the_rows_either_way(monkeypatch, one_pass, keys):
     """A staging edge that feeds a keyed device operator carries the shard
-    plane's key probe.  On the one-pass route it reads the rows back as
-    views of the staging buffer: the sketch holds what it holds when the
-    probe is handed columns, and the edge still parses in place."""
+    plane's key probe.  On the one-pass route it reads the rows back
+    from the staging buffer (``rows_view``): the sketch holds what it
+    holds when the probe is handed columns, and the edge still parses in
+    place.  A chunk of 64-bit keys lies in the buffer as two word planes:
+    the probe is handed its keys whole."""
     from windflow_tpu.io import frames
+    from windflow_tpu.monitoring.shard_ledger import HostKeyProbe
     if not one_pass:
         monkeypatch.setattr(frames.FrameSourceReplica, "_ingest_in_place",
                             lambda self, buf, final: False)
-    rec = _stream(3000, 2, "narrow")
+    handed, columns = [], HostKeyProbe.columns
+    monkeypatch.setattr(
+        HostKeyProbe, "columns", lambda self, cols, n:
+        handed.append(np.array(cols["key"][:n])) or columns(self, cols, n))
+    rec = _stream(3000, 2, keys)
     blob = rec.tobytes()
     sums = {}
     src = FrameSource(lambda: (blob[i:i + 997]
@@ -554,6 +562,9 @@ def test_shard_probe_reads_the_rows_either_way(monkeypatch, one_pass):
     probe = em._shard_probe
     assert probe is not None and not probe.dead
     assert probe.sketch.total == 3000
+    assert np.concatenate(handed).tolist() == rec["k"].tolist()
+    if keys == "leave_int32":
+        assert {h.dtype.name for h in handed} == {"int32", "int64"}
     keys, counts = np.unique(rec["k"], return_counts=True)
     if probe.sketch.hist is not None:
         assert probe.sketch.hist[keys].tolist() == counts.tolist()

@@ -111,6 +111,145 @@ def test_pool_gate_blocks_until_device_done():
 # fused packed transfer
 # ---------------------------------------------------------------------------
 
+def _layout_words(lanes, tss, cap):
+    """The staged buffer of these columns, built from the layout's own
+    statement and nothing of the package: a 4-byte lane is ``cap`` words,
+    an 8-byte lane two planes of ``cap`` (the rows' low words, then their
+    high words), the int64 ts lane last, unwritten rows zero, then n."""
+    planes = []
+    for col in list(lanes) + [np.asarray(tss, np.int64)]:
+        if col.dtype.itemsize == 8:
+            u = col.astype(np.uint64)
+            planes += [u & np.uint64(0xFFFFFFFF), u >> np.uint64(32)]
+        else:
+            planes.append(col.view(np.uint32))
+    out = np.zeros(len(planes) * cap + 1, np.uint32)
+    for i, p in enumerate(planes):
+        out[i * cap:i * cap + len(p)] = p
+    out[-1] = len(tss)
+    return out
+
+
+def _lane_of(dt, n, rng):
+    """``n`` values of ``dt`` that fill the width: negative and beyond
+    2**32 where the dtype has them."""
+    dt = np.dtype(dt)
+    if dt == np.float32:
+        return (rng.normal(size=n) * 1e6).astype(dt)
+    if dt == np.uint64:
+        return rng.integers(0, 1 << 63, n).astype(dt) * np.uint64(2) \
+            + np.uint64(1)
+    info = np.iinfo(dt)
+    return rng.integers(info.min, info.max, n, dtype=dt, endpoint=True)
+
+
+LANE_MIXES = {
+    "every_width": ("int32", "float32", "int64", "uint64"),
+    "int64_only": ("int64",),
+    "uint64_then_int32": ("uint64", "int32"),
+    "four_byte_lanes": ("float32", "int32"),
+    "int64_between_int32": ("int32", "int64", "int32"),
+}
+
+
+@pytest.mark.parametrize("cap,n", [(1, 1), (32, 7), (32, 32)],
+                         ids=["capacity_1", "partial", "full"])
+@pytest.mark.parametrize("mix", sorted(LANE_MIXES))
+def test_pack_then_unpack_body_round_trip(mix, cap, n):
+    """``append`` writes the layout and ``unpack_body`` reads it: the
+    words are the planes the layout states, and the device's columns are
+    the host's bit for bit, int64 / uint64 values with their high words
+    (negative, beyond 2**32) included, rows past the fill zero."""
+    from windflow_tpu.batch import unpack_body
+    dtypes = LANE_MIXES[mix]
+    rng = np.random.default_rng(cap * 100 + n)
+    lanes = [_lane_of(d, n, rng) for d in dtypes]
+    tss = _lane_of("int64", n, rng)
+    b = PackedBatchBuilder(dtypes, cap, pool=StagingPool())
+    b.buf[:] = 0xFFFFFFFF
+    # two appends where there are two rows to split
+    for sl in (slice(0, n // 2), slice(n // 2, n)):
+        b.append([l[sl] for l in lanes], tss[sl])
+    buf = b.finish()
+    np.testing.assert_array_equal(buf, _layout_words(lanes, tss, cap))
+    cols, ts, valid, n_valid = jax.jit(unpack_body(dtypes, cap))(
+        jnp.asarray(buf))
+    assert int(n_valid) == n
+    np.testing.assert_array_equal(np.asarray(valid), np.arange(cap) < n)
+    for got, want in zip(list(cols) + [ts], lanes + [tss]):
+        got = np.asarray(got)
+        assert got.dtype == want.dtype
+        assert got[:n].tobytes() == want.tobytes()
+        assert not got[n:].view(np.uint8).any()
+
+
+@pytest.mark.parametrize("dt", ["int64", "uint64"])
+def test_rows_view_returns_the_values_written(dt):
+    """``rows_view`` shows rows an in-place writer wrote as columns: a
+    4-byte lane as a view of the buffer, an 8-byte lane (two planes, so no
+    one typed view) as the combined column, from any row on."""
+    cap, n = 16, 11
+    rng = np.random.default_rng(3)
+    wide, narrow = _lane_of(dt, n, rng), _lane_of("int32", n, rng)
+    b = PackedBatchBuilder((dt, "int32"), cap, pool=StagingPool())
+    b.append([wide[:4], narrow[:4]], np.zeros(4, np.int64))
+    b.append([wide[4:], narrow[4:]], np.zeros(n - 4, np.int64))
+    for lo, m in ((0, n), (4, n - 4), (10, 1), (3, 0)):
+        got_wide, got_narrow = b.rows_view(lo, m)
+        assert got_wide.dtype == wide.dtype
+        np.testing.assert_array_equal(got_wide, wide[lo:lo + m])
+        np.testing.assert_array_equal(got_narrow, narrow[lo:lo + m])
+    assert np.shares_memory(b.rows_view(0, n)[1], b.buf)
+
+
+def _strided_reads(fn, *shapes):
+    """What makes the chip gather in ``fn``'s program: the ``gather`` and
+    strided ``slice`` equations of its jaxpr (sub-jaxprs included), and
+    the same two in the HLO XLA compiles from it."""
+    import re
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            strides = eqn.params.get("strides")
+            if eqn.primitive.name == "gather" or (
+                    eqn.primitive.name == "slice" and strides is not None
+                    and any(s != 1 for s in strides)):
+                found.append(str(eqn))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    hlo = jax.jit(fn).lower(*shapes).compile().as_text()
+    found += re.findall(r"\bgather\(|slice=\{\[\d+:\d+:\d+\]", hlo)
+    return found
+
+
+@pytest.mark.parametrize("dtypes", [("int64",), ("int32", "uint64"),
+                                    ("float32", "int64", "int32")],
+                         ids=lambda d: "-".join(d))
+def test_staged_unpack_program_holds_no_gather(dtypes):
+    """An int64 lane is read back by contiguous slice: the
+    ``staging.unpack`` program of an int64-bearing batch (the ts lane
+    alone makes every batch one) holds no gather and no strided slice,
+    in its jaxpr or in compiled HLO.  On the chip the stride-2 read of
+    words interleaved a row was two gathers, ~4.2 ms a 262 144-row batch
+    (PERF.md, PR 47): a CPU check sees the form, not the time."""
+    from windflow_tpu.batch import _get_unpack
+    cap = 64
+    words = sum(staging.lane_words(d) for d in dtypes + ("int64",))
+    shape = jax.ShapeDtypeStruct((words * cap + 1,), jnp.uint32)
+    program = _get_unpack(None, dtypes, cap)
+    assert program.op_name == "staging.unpack"
+    assert _strided_reads(program._fn, shape) == []
+
+    # the detector sees the interleaved form it stands guard against
+    def interleaved(b):
+        seg = b[:2 * cap]
+        return seg[0::2], seg[1::2]
+    seen = _strided_reads(interleaved, shape)
+    assert len(seen) >= 2, seen
+
+
 @pytest.mark.parametrize("n", [7, 32])   # partial and full fill
 def test_packed_builder_round_trip(n):
     """PackedBatchBuilder + stage_packed must reproduce the lanes the
@@ -185,8 +324,10 @@ def test_in_place_writer_writes_what_append_writes(nv, key, val, ts_fixed):
     """A producer that writes the packed words itself — the native frame
     parse, given the builder's ``buf``, ``lane_layout`` and ``n``, and
     reporting its rows with ``advance`` — leaves the buffer ``append``
-    leaves for the same rows as columns, word for word, in three slices
-    that start mid-buffer, in a lane order that is not the wire's."""
+    leaves for the same rows as columns, word for word (an int64 lane's
+    low and high words each in their plane, every plane's tail zeroed),
+    in three slices that start mid-buffer, in a lane order that is not
+    the wire's."""
     from windflow_tpu import native
     if not native.is_available():
         pytest.skip("no native library")
@@ -217,7 +358,7 @@ def test_in_place_writer_writes_what_append_writes(nv, key, val, ts_fixed):
     at, extrema = 0, []
     for room in (7, 1, cap):
         m, lo, hi, k_lo, k_hi = native.parse_frames_packed(
-            blob, at * rec.dtype.itemsize, nv, got.buf, lane_off,
+            blob, at * rec.dtype.itemsize, nv, got.buf, lane_off, cap,
             2 if key == "int64" else 1,
             native.PACKED_VALUE_KINDS[np.dtype(val)], got.n,
             min(room, got.room), ts_fixed)
@@ -231,6 +372,9 @@ def test_in_place_writer_writes_what_append_writes(nv, key, val, ts_fixed):
         at += m
     assert got.n == n and got.room == cap - n
     np.testing.assert_array_equal(got.finish(), want.finish())
+    np.testing.assert_array_equal(got.buf, _layout_words(
+        [rec["v"][:, i].astype(val) for i in range(nv)]
+        + [rec["k"].astype(key)], tss, cap))
     with pytest.raises(AssertionError):
         got.advance(cap - n + 1)
     assert native.frames_key_range(blob, nv) == (int(rec["k"].min()),

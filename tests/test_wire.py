@@ -93,6 +93,65 @@ def test_codec_round_trip_bit_exact(name):
     assert np.array_equal(got_ts, np.arange(_CAP, dtype=np.int64) * 17)
 
 
+def _wide_lane(kind, cap):
+    """An int64 lane, high words in use and negative values among them,
+    that ``WireEncoder._choose`` gives the codec ``kind``."""
+    rng = np.random.default_rng(len(kind))
+    if kind == wire.RAW:
+        return rng.integers(-2**63, 2**63 - 1, cap)
+    if kind == wire.CONST:
+        return np.full(cap, -(1 << 40) + 3, np.int64)
+    if kind == wire.DELTA:
+        return -(1 << 45) + np.cumsum(rng.integers(0, 200, cap))
+    if kind == wire.DELTA2:
+        return np.arange(cap, dtype=np.int64) * 1_000 - (1 << 41)
+    return rng.choice(np.array([-(1 << 50), -1, 7, (1 << 33) + 5,
+                                (1 << 62) + 9], np.int64), cap)
+
+
+@pytest.mark.parametrize("dt", ["int64", "uint64"])
+@pytest.mark.parametrize("kind", [wire.RAW, wire.CONST, wire.DELTA,
+                                  wire.DELTA2, wire.DICT])
+@pytest.mark.parametrize("n", [512, 200], ids=["full", "partial"])
+def test_int64_lane_round_trips_under_each_codec(kind, dt, n):
+    """The wire plane reads an 8-byte lane of the logical buffer as its
+    two word planes (``_values``), ships a DICT table's entries as the
+    lane's words lie, and the decode writes what ``stage_packed`` gives
+    for the same buffer shipped raw: under every codec kind, and inlined
+    as the megastep inlines it (``unpack_body(..., wire=fmt)``)."""
+    from windflow_tpu.batch import stage_packed, unpack_body
+    cap = 512
+    lane = _wide_lane(kind, n).view(dt)
+    tss = np.arange(n, dtype=np.int64) * 17 + (1 << 35)
+    b = staging.PackedBatchBuilder((dt,), cap)
+    b.append([lane], tss)
+    buf = b.finish()
+    raw = stage_packed(buf.copy(), jax.tree.structure([0]), (dt,), cap, n)
+    enc = wire.WireEncoder((dt,), cap, reseed_every=4)
+    assert enc._values(buf, 0)[:n].tobytes() == lane.tobytes()
+    wbuf, fmt = enc.encode(buf.copy())
+    assert fmt is not None
+    if n == cap:        # a zeroed tail is data too: it changes the choice
+        assert fmt.codecs[0].kind == kind
+
+    def alone(w):
+        return jax.jit(wire.build_wire_decode(fmt, (dt,), cap))(w)
+
+    def inlined(w):
+        cols, ts, _, _ = jax.jit(unpack_body((dt,), cap, wire=fmt))(w)
+        return cols[0], ts
+
+    for decode in (alone, inlined):
+        got, got_ts = decode(jnp.asarray(wbuf))
+        assert np.asarray(got).dtype == lane.dtype
+        assert np.asarray(got).tobytes() == \
+            np.asarray(raw.payload[0]).tobytes()
+        assert np.asarray(got)[:n].tobytes() == lane.tobytes()
+        np.testing.assert_array_equal(np.asarray(got_ts),
+                                      np.asarray(raw.ts))
+        np.testing.assert_array_equal(np.asarray(got_ts)[:n], tss)
+
+
 def test_codec_round_trip_partial_batch_zero_tail():
     """finish() zero-pads the tail; the decode must reproduce those
     zeros exactly (downstream equality depends on it)."""

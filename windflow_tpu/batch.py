@@ -245,9 +245,12 @@ _UNPACK_CACHE: dict = {}
 # of batch_item_gpu_t for the same reason, forward_emitter_gpu.hpp:254-300),
 # so all lanes of a batch ride ONE uint32 buffer.  Only 32-bit bitcasts are
 # used on device — the TPU X64-rewrite pass implements no 64-bit bitcast —
-# int64 lanes travel as arithmetic lo/hi word pairs; float64 lanes make a
-# batch unpackable (TPU has no native f64 anyway: stage f32).  Packing,
-# layout, and the host-buffer recycling pool live in windflow_tpu/staging.
+# int64 lanes travel as arithmetic lo/hi word pairs, in two planes (a
+# lane's low words, then its high words: staging.join_planes), in the
+# staged buffer as in the egress buffer, so the device reads and writes
+# them by contiguous slice; float64 lanes make a batch unpackable (TPU has
+# no native f64 anyway: stage f32).  Packing, layout, and the host-buffer
+# recycling pool live in windflow_tpu/staging.
 
 _words = staging.lane_words
 _packable_dtype = staging.packable_dtype
@@ -277,10 +280,9 @@ def unpack_body(dtypes, capacity: int, wire=None):
             for dt in dtypes + ("int64",):
                 d = np.dtype(dt)
                 if d.itemsize == 8:
-                    seg = b[off:off + 2 * capacity]
-                    lo = seg[0::2].astype(jnp.int64)
-                    hi = seg[1::2].astype(jnp.int64)
-                    cols.append(((hi << 32) | lo).astype(d))
+                    cols.append(staging.join_planes(
+                        b[off:off + capacity],
+                        b[off + capacity:off + 2 * capacity]).astype(d))
                     off += 2 * capacity
                 else:
                     cols.append(jax.lax.bitcast_convert_type(
@@ -613,9 +615,8 @@ def _egress_unpack(raw, treedef, specs, lanes, n):
         if d == np.bool_:
             col = raw[off:off + w].astype(np.bool_)
         elif d.itemsize == 8:
-            lo = raw[off:off + w].astype(np.uint64)
-            hi = raw[off + w:off + 2 * w].astype(np.uint64)
-            col = ((hi << np.uint64(32)) | lo).view(np.int64) \
+            col = staging.join_planes(raw[off:off + w],
+                                      raw[off + w:off + 2 * w]) \
                 .astype(d, copy=False)
             off += w
         else:

@@ -139,9 +139,9 @@ class FrameSourceReplica(BaseSourceReplica):
                         sp.note(n=0, bytes=0)
                         continue
                 m, ts_lo, ts_hi, k_lo, k_hi = native.parse_frames_packed(
-                    buf, pos * rec, nv, bld.buf, lane_off, 2 if wide else 1,
-                    self._in_place_kind, bld.n, min(bld.room, n - pos),
-                    ts_fixed)
+                    buf, pos * rec, nv, bld.buf, lane_off, bld.capacity,
+                    2 if wide else 1, self._in_place_kind, bld.n,
+                    min(bld.room, n - pos), ts_fixed)
                 if not checked:
                     checked = True      # m == n: the whole chunk was read
                     if wide == _fits_int32(k_lo, k_hi):

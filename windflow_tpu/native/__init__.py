@@ -120,8 +120,8 @@ def lib() -> Optional[ctypes.CDLL]:
     L.wf_frames_key_range.restype = i8
     L.wf_frames_key_range.argtypes = [p, i8, i4, p]
     L.wf_parse_frames_packed.restype = i8
-    L.wf_parse_frames_packed.argtypes = [p, i8, i4, p, p, i4, i4, i8, i8,
-                                         p, p]
+    L.wf_parse_frames_packed.argtypes = [p, i8, i4, p, p, i8, i4, i4, i8,
+                                         i8, p, p]
     L.wf_parse_csv.restype = i8
     L.wf_parse_csv.argtypes = [p, i8, i4, p, p, p, i8, p]
     L.wf_min_watermark.restype = i8
@@ -242,12 +242,15 @@ def frames_key_range(buf, nv: int):
 
 
 def parse_frames_packed(buf, start: int, nv: int, dst: np.ndarray,
-                        lane_off: np.ndarray, key_words: int, val_kind: int,
-                        row: int, room: int, ts_fixed: Optional[int] = None):
+                        lane_off: np.ndarray, capacity: int, key_words: int,
+                        val_kind: int, row: int, room: int,
+                        ts_fixed: Optional[int] = None):
     """Parse up to ``room`` whole records of ``buf[start:]`` straight into the
-    packed staging buffer ``dst`` (``staging.PackedBatchBuilder`` layout)
-    from row ``row`` on; ``lane_off`` is int64[nv + 2]: the word offsets
-    of the key lane, the value lanes in wire order and the ts lane.
+    packed staging buffer ``dst`` (``staging.PackedBatchBuilder`` layout,
+    ``capacity`` rows: an int64 lane's high words lie ``capacity`` words
+    after its low words) from row ``row`` on; ``lane_off`` is
+    int64[nv + 2]: the word offsets of the key lane, the value lanes in
+    wire order and the ts lane.
     ``ts_fixed`` stamps every row with one timestamp instead of the
     record's own.  Returns (rows written, ts min, ts max, key min, key
     max).  Native library only: the numpy twins parse to columns."""
@@ -256,8 +259,8 @@ def parse_frames_packed(buf, start: int, nv: int, dst: np.ndarray,
     fixed = None if ts_fixed is None else \
         ctypes.byref(ctypes.c_int64(ts_fixed))
     m = lib().wf_parse_frames_packed(
-        _ptr(raw), len(raw), nv, _ptr(dst), _ptr(lane_off), key_words,
-        val_kind, row, room, fixed, _ptr(ranges))
+        _ptr(raw), len(raw), nv, _ptr(dst), _ptr(lane_off), capacity,
+        key_words, val_kind, row, room, fixed, _ptr(ranges))
     assert m >= 0, (key_words, val_kind)
     return (m, *ranges.tolist())
 

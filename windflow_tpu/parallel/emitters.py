@@ -771,7 +771,8 @@ class DeviceStageEmitter(Emitter):
         their data-ts extrema and ``w`` the row frontier after the last
         of them — the batch bookkeeping ``_emit_columns_packed`` reads
         off its columns, given by the writer instead.  A shard-plane key
-        probe reads the rows back as views of the staging buffer."""
+        probe reads the rows back from the staging buffer
+        (``rows_view``: views, an int64 lane's two planes combined)."""
         if self._shard_probe is not None:
             b = self._builder
             self._shard_probe.columns(jax.tree.unflatten(

@@ -29,7 +29,7 @@ H2D copies with kernel execution):
   and hands the count back with ``advance`` — the native frame parse
   (``native.parse_frames_packed``, ``io/frames.py``) does, so a frame is
   read once and no column stands between the bytes and the staged batch;
-  ``rows_view`` shows such rows as columns without a copy.
+  ``rows_view`` shows such rows as columns.
 
 * Double-buffered prefetch lives in the run loop
   (``graph/pipegraph.py``, ``Config.stage_prefetch_depth``): with a
@@ -45,11 +45,16 @@ H2D copies with kernel execution):
 
 Buffer layout (shared with ``batch.py``'s cached unpack programs)::
 
-    [lane0 words | lane1 words | ... | ts words (2/row) | n]
+    [lane0 words | lane1 words | ... | ts words (2 planes) | n]
 
-where a 4-byte lane contributes 1 word/row and an int64 lane 2 words/row
-(little-endian lo/hi interleaved — the TPU X64-rewrite implements no
-64-bit bitcast, so 64-bit lanes travel as arithmetic word pairs).
+where a 4-byte lane is ``capacity`` words, one a row, and an int64 lane
+``2 * capacity``: two PLANES, the rows' low words then their high words
+(:func:`split_planes` / :func:`join_planes`) — the layout
+``batch._egress_pack`` gives the buffer that leaves the chip, so the
+device reads and writes a 64-bit lane by contiguous slice in both
+directions (words interleaved a row are a stride-2 read, which the chip
+does as a gather).  The TPU X64-rewrite implements no 64-bit bitcast, so
+64-bit lanes travel as arithmetic word pairs.
 """
 
 from __future__ import annotations
@@ -79,6 +84,23 @@ DEFAULT_MAX_BYTES = 256 << 20
 def lane_words(dt) -> int:
     """uint32 words per row for one packed lane."""
     return 2 if np.dtype(dt).itemsize == 8 else 1
+
+
+def split_planes(col: np.ndarray):
+    """The two word planes of a contiguous 8-byte host column: its rows'
+    low words and their high words (little-endian views, as every host
+    pack here)."""
+    w = col.view(np.uint32)
+    return w[0::2], w[1::2]
+
+
+def join_planes(lo, hi):
+    """The int64 values of an 8-byte lane from its two uint32 word
+    planes, bit for bit (a uint64 lane re-types them); numpy arrays on
+    the host and traced arrays on the device alike — one statement of
+    the layout for the staging buffer, the egress buffer and the wire
+    plane's readers."""
+    return (hi.astype("int64") << 32) | lo.astype("int64")
 
 
 def packable_dtype(dt) -> bool:
@@ -402,18 +424,24 @@ class PackedBatchBuilder:
         for off, w, dt, lane in zip(self._offsets, self._words,
                                     self._lane_dtypes,
                                     itertools.chain(lanes, (tss,))):
-            src = np.ascontiguousarray(lane, dt).view(np.uint32)
-            lo = off + w * self.n
-            self.buf[lo:lo + w * m] = src
+            src = np.ascontiguousarray(lane, dt)
+            at = off + self.n
+            if w == 2:
+                hi = at + self.capacity
+                self.buf[at:at + m], self.buf[hi:hi + m] = split_planes(src)
+            else:
+                self.buf[at:at + m] = src.view(np.uint32)
         self.n += m
 
     @staticmethod
     def lane_layout(dtypes: Sequence, capacity: int) -> list:
         """Word offset of row 0 of each payload lane (``dtypes`` order),
         then of the ts lane, in a builder of ``capacity`` rows.  With
-        ``buf`` and ``n`` it is what an in-place writer needs: it writes
-        rows ``n .. n + m`` of every lane at ``offset + words * row``
-        itself (``native.parse_frames_packed``) and reports them with
+        ``buf``, ``capacity`` and ``n`` it is what an in-place writer
+        needs: it writes rows ``n .. n + m`` of every lane itself, a
+        4-byte lane's word at ``offset + row``, an int64 lane's low word
+        there and its high word at ``offset + capacity + row``
+        (``native.parse_frames_packed``), and reports them with
         :meth:`advance`."""
         offsets, off = [], 0
         for d in dtypes:
@@ -423,11 +451,19 @@ class PackedBatchBuilder:
 
     def rows_view(self, lo: int, m: int) -> list:
         """Rows ``lo .. lo + m`` of each payload lane (``dtypes`` order)
-        as typed views of the buffer: what an in-place writer's rows look
-        like as columns, without a copy."""
-        return [self.buf[off + w * lo:off + w * (lo + m)].view(dt)
-                for off, w, dt in zip(self._offsets, self._words,
-                                      self.dtypes)]
+        as columns: what an in-place writer's rows look like to a reader
+        on the host.  A 4-byte lane is a typed view of the buffer; an
+        int64 lane's rows lie in two planes, so its column is a copy."""
+        cols = []
+        for off, w, dt in zip(self._offsets, self._words, self.dtypes):
+            rows = self.buf[off + lo:off + lo + m]
+            if w == 2:
+                hi = off + self.capacity + lo
+                cols.append(join_planes(rows, self.buf[hi:hi + m])
+                            .astype(dt, copy=False))
+            else:
+                cols.append(rows.view(dt))
+        return cols
 
     @hot_path
     def advance(self, m: int) -> None:
@@ -452,8 +488,9 @@ class PackedBatchBuilder:
     @hot_path
     def _finish_impl(self) -> np.ndarray:
         if self.n < self.capacity:
-            for off, w in zip(self._offsets, self._words):
-                self.buf[off + w * self.n:off + w * self.capacity] = 0
+            # the buffer is planes of `capacity` words, a 4-byte lane
+            # one and an int64 lane two: the unwritten rows end each
+            self.buf[:-1].reshape(-1, self.capacity)[:, self.n:] = 0
         self.buf[-1] = self.n
         return self.buf
 

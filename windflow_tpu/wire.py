@@ -331,15 +331,13 @@ class WireEncoder:
     # -- lane value views ---------------------------------------------------
     def _values(self, buf: np.ndarray, i: int) -> np.ndarray:
         """Lane ``i`` of the logical buffer as int64 work values (signed
-        interpretation for 4-byte lanes; lo/hi recombined for 8-byte) —
-        the exact domain the device decode reconstructs."""
-        off, w = self._offsets[i], self._lane_words[i]
-        seg = buf[off:off + w * self.capacity]
+        interpretation for 4-byte lanes; the two word planes recombined
+        for 8-byte) — the exact domain the device decode reconstructs."""
+        off, w, cap = self._offsets[i], self._lane_words[i], self.capacity
         if w == 1:
-            return seg.view(np.int32).astype(np.int64)
-        lo = seg[0::2].astype(np.uint64)
-        hi = seg[1::2].astype(np.uint64)
-        return (lo | (hi << np.uint64(32))).view(np.int64)
+            return buf[off:off + cap].view(np.int32).astype(np.int64)
+        return staging.join_planes(buf[off:off + cap],
+                                   buf[off + cap:off + 2 * cap])
 
     def _raw_words(self, buf: np.ndarray, i: int) -> np.ndarray:
         off, w = self._offsets[i], self._lane_words[i]
@@ -446,13 +444,12 @@ class WireEncoder:
                 return None
             w = self._lane_words[i]
             if w == 1:
-                tw = (table & np.int64(0xFFFFFFFF)).astype(np.uint32)
+                tw = [(table & np.int64(0xFFFFFFFF)).astype(np.uint32)]
             else:
-                u = table.view(np.uint64)
-                tw = np.empty(2 * len(table), np.uint32)
-                tw[0::2] = (u & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-                tw[1::2] = (u >> np.uint64(32)).astype(np.uint32)
-            return [tw, _pack_width(idx.astype(np.uint32), c.width)]
+                # an 8-byte lane's table ships as the lane does: its
+                # entries' low words, then their high words
+                tw = list(staging.split_planes(table))
+            return tw + [_pack_width(idx.astype(np.uint32), c.width)]
         return None
 
     def encode(self, buf: np.ndarray,
@@ -557,8 +554,7 @@ def build_wire_decode(fmt: WireFormat, dtypes, capacity: int):
         sh = ((idx % per) * width).astype(jnp.uint32)
         return (w >> sh) & jnp.uint32((1 << width) - 1)
 
-    def _i64(lo, hi):
-        return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+    _i64 = staging.join_planes
 
     def _unzigzag(zz):
         z = zz.astype(jnp.int64)
@@ -597,13 +593,12 @@ def build_wire_decode(fmt: WireFormat, dtypes, capacity: int):
         for c, dt in zip(fmt.codecs, all_dts):
             w = staging.lane_words(dt)
             if c.kind == RAW:
-                seg = b[off:off + w * capacity]
                 if w == 2:
-                    lo = seg[0::2].astype(jnp.int64)
-                    hi = seg[1::2].astype(jnp.int64)
-                    cols.append(((hi << 32) | lo).astype(dt))
+                    cols.append(_i64(
+                        b[off:off + capacity],
+                        b[off + capacity:off + 2 * capacity]).astype(dt))
                 else:
-                    cols.append(_words_to_dtype(seg, dt))
+                    cols.append(_words_to_dtype(b[off:off + capacity], dt))
             elif c.kind == CONST:
                 v = _i64(b[off], b[off + 1])
                 cols.append(jnp.broadcast_to(_from_i64(v, dt),
@@ -632,10 +627,9 @@ def build_wire_decode(fmt: WireFormat, dtypes, capacity: int):
                     tw = b[off:off + c.extra]
                     cols.append(_words_to_dtype(tw[idx], dt))
                 else:
-                    seg = b[off:off + 2 * c.extra]
-                    lo = seg[0::2][idx].astype(jnp.int64)
-                    hi = seg[1::2][idx].astype(jnp.int64)
-                    cols.append(_from_i64((hi << 32) | lo, dt))
+                    cols.append(_from_i64(
+                        _i64(b[off:off + c.extra][idx],
+                             b[off + c.extra:off + 2 * c.extra][idx]), dt))
             else:
                 raise ValueError(f"unknown lane codec {c.kind!r}")
             off += lane_wire_words(c, dt, capacity)
