@@ -113,6 +113,27 @@ def chain(window="cb"):
     return w._build_step(B)._jit, args
 
 
+def rolling():
+    """The rolling aggregate: a sort a distinct group, the sets tested
+    and set, one row a touched group."""
+    from windflow_tpu.windows import rolling_kernels as rk
+    groups = [rk.DistinctGroup(("who", "low"), 40),
+              rk.DistinctGroup(("what",), 9)]
+    plain = {"n": "sum", "top": "max"}
+
+    def lift(e, ts):
+        who = e["v"].astype(jnp.int32)
+        return {"n": jnp.int32(1), "top": e["v"], "who": who,
+                "low": jnp.where(who < 4, who, -1),
+                "what": (ts % 9).astype(jnp.int32)}
+    step = rk.make_rolling_step(B, K, lift, plain, groups, lambda e: e["k"])
+    one = {"k": jax.ShapeDtypeStruct((), jnp.int32),
+           "v": jax.ShapeDtypeStruct((), jnp.float32)}
+    spec = jax.eval_shape(lift, one, jax.ShapeDtypeStruct((), jnp.int64))
+    state = rk.make_rolling_state(spec, plain, groups, K)
+    return jax.jit(step), (state, *_batch(), jnp.int64(2500))
+
+
 FAMILIES = {
     "cb": cb,
     "cb_sum": lambda: cb("sum"),
@@ -122,6 +143,7 @@ FAMILIES = {
     "tb_generic": lambda: tb(None),
     "session": session,
     "count_ordered": count_ordered,
+    "rolling": rolling,
     "mesh_cb": mesh_cb,
     "unpack": unpack,
     "chain_cb": chain,

@@ -57,6 +57,8 @@ EXPECT = {
     # the loop over the chunks of released rows holds place / fire / ring
     "count_ordered": ({"wf.order", "wf.place", "wf.fire", "wf.ring"},
                       set(), 1),
+    "rolling": ({"wf.agg.sort", "wf.agg.distinct", "wf.agg.fold",
+                 "wf.agg.rows"}, set(), 0),
     # the rounds' loop holds the owned-lane step and all its phases
     "mesh_cb": ({"wf.mesh.own", "wf.group", "wf.place", "wf.fire",
                  "wf.ring"}, {"mesh.ffat_step"}, 1),

@@ -121,6 +121,10 @@ KINDS = {
     "count_ordered": lambda: wf.Ffat_WindowsTPU_Builder(
         lambda t: t["v"], lambda a, b: a + b).withCBWindows(8, 4)
     .withKeyBy(key).withMaxKeys(KEYS).withEventTimeOrder().build(),
+    "rolling_aggregate": lambda: wf.Rolling_AggregateTPU_Builder(
+        lambda t, ts: {"v": t["v"], "who": t["key"]}).withSum("v")
+    .withDistinct("who", space=KEYS).withKeyBy(key).withMaxKeys(KEYS)
+    .build(),
 }
 
 #: kind -> the parent's answers, a column a question (``QUESTIONS``).
@@ -161,6 +165,10 @@ _ROWS = {
     # PR 44: the count window in event-time order, on the same shell
     "count_ordered": ("tail", True, "count windows in event-time order",
                       True, "count_ordered_tpu"),
+    # PR 49: the rolling aggregate with declared leaves, on the same
+    # shell; no window closes in it, so no freshness gauge
+    "rolling_aggregate": ("tail", True, "rolling aggregate", False,
+                          "rolling_aggregate_tpu"),
 }
 EXPECTED = {k: dict(zip(COLUMNS, row), unknown_state=False)
             for k, row in _ROWS.items()}
@@ -257,7 +265,8 @@ def test_the_answer_is_the_parents(kind, question):
 
 STATEFUL = sorted(k for k, e in EXPECTED.items() if e["snapshot"])
 SHAPELESS = {"reduce_tpu", "session_tpu", "interval_join_tpu",
-             "interval_join_pairs_tpu", "count_ordered_tpu"}
+             "interval_join_pairs_tpu", "count_ordered_tpu",
+             "rolling_aggregate_tpu"}
 
 
 @pytest.mark.parametrize("kind", STATEFUL)

@@ -38,6 +38,7 @@ from windflow_tpu.graph.builders import (Ffat_Windows_Builder,
                                          Paned_Windows_Builder,
                                          Parallel_Windows_Builder,
                                          Reduce_Builder, ReduceTPU_Builder,
+                                         Rolling_AggregateTPU_Builder,
                                          Session_WindowsTPU_Builder,
                                          Sink_Builder, Source_Builder)
 from windflow_tpu.graph.multipipe import MultiPipe
@@ -59,6 +60,7 @@ from windflow_tpu.windows.join_tpu import (IntervalJoinPairsTPU,
                                            IntervalJoinTPU)
 from windflow_tpu.windows.session_tpu import SessionWindowsTPU
 from windflow_tpu.windows.count_ordered_tpu import OrderedCountWindowsTPU
+from windflow_tpu.windows.rolling_tpu import RollingAggregateTPU
 from windflow_tpu.windows.ops import (KeyedWindows, MapReduceWindows,
                                       PanedWindows, ParallelWindows,
                                       WindowResult)
@@ -96,6 +98,7 @@ __all__ = [
     "Ffat_Windows_Builder", "Ffat_WindowsTPU_Builder",
     "SessionWindowsTPU", "Session_WindowsTPU_Builder",
     "OrderedCountWindowsTPU",
+    "RollingAggregateTPU", "Rolling_AggregateTPU_Builder",
     "IntervalJoinTPU", "IntervalJoinPairsTPU", "Interval_JoinTPU_Builder",
     "DBHandle", "LogKV", "PMap", "PFilter", "PFlatMap", "PReduce", "PSink",
     "PKeyedWindows", "P_Map_Builder", "P_Filter_Builder",

@@ -1218,6 +1218,7 @@ def propagate_specs(graph, ops=None, edges=None, upstreams=None,
     from windflow_tpu.ops.tpu_stateful import (StatefulFilterTPU,
                                                StatefulMapTPU)
     from windflow_tpu.windows.ffat_tpu import FfatWindowsTPU
+    from windflow_tpu.windows.rolling_tpu import RollingAggregateTPU
 
     in_spec: Dict[int, Any] = {}
 
@@ -1376,6 +1377,26 @@ def propagate_specs(graph, ops=None, edges=None, upstreams=None,
                 else:
                     _check_ffat_comb(op, agg, diags)
             return _UNKNOWN   # emits window results, not input records
+        if isinstance(op, RollingAggregateTPU):
+            if spec is _UNKNOWN:
+                return _UNKNOWN
+            _check_key_extractor(op, spec, diags)
+            try:
+                row, err = op.row_spec(spec), None
+            except Exception as e:  # noqa: BLE001 - lint: broad-except-ok
+                # (a user lift raises anything under abstract evaluation)
+                err = f"{type(e).__name__}: {e}"
+            if err is not None:
+                diags.append(Diagnostic(
+                    "WF101",
+                    f"operator '{op.name}': lift failed abstract "
+                    f"evaluation over the incoming record spec — {err}",
+                    node=op.name,
+                    hint="lift(record, ts) gives {leaf: value} for "
+                         "exactly the declared leaves (withSum / withMin "
+                         "/ withMax / withDistinct)"))
+                return _UNKNOWN
+            return row      # one upsert row: downstream maps are checked
         if isinstance(op, (StatefulMapTPU, StatefulFilterTPU)):
             if spec is not _UNKNOWN and op.assoc is None:
                 state = jax.tree.map(

@@ -974,3 +974,76 @@ class Interval_JoinTPU_Builder(_BuilderBase):
                 "pairs (built with a join function alone)")
         return IntervalJoinTPU(self._lift, self._comb, length=self._length,
                                build_capacity=self._capacity, **common)
+
+
+from windflow_tpu.windows.rolling_kernels import DistinctGroup  # noqa: E402
+from windflow_tpu.windows.rolling_tpu import RollingAggregateTPU  # noqa: E402
+
+
+class Rolling_AggregateTPU_Builder(_BuilderBase):
+    """A keyed aggregate that never closes, on the device
+    (:class:`~windflow_tpu.windows.rolling_tpu.RollingAggregateTPU`):
+    SQL's ``GROUP BY`` without a window, one upsert row a group a batch
+    touched.  ``lift(record, ts)`` maps a record and its event time
+    (int64 usec) to ``{leaf: value}`` and every leaf
+    is declared by one of ``withSum`` / ``withMin`` / ``withMax`` (a
+    monoid folds it) or ``withDistinct`` (an exact distinct count of the
+    member ids the lift gives)."""
+
+    _default_name = "rolling_aggregate_tpu"
+
+    def __init__(self, lift_fn):
+        super().__init__()
+        self._lift = lift_fn
+        self._plain = {}
+        self._distinct = []
+        self._max_keys = None
+        self._out_capacity = None
+
+    def withRebalancing(self):
+        raise WindFlowError(
+            "a rolling aggregate routes by key; REBALANCING does not apply")
+
+    def _fold(self, kind: str, leaves):
+        for leaf in leaves:
+            self._plain[leaf] = kind
+        return self
+
+    def withSum(self, *leaves: str):
+        """These leaves are sums (a count is a sum of ones, a filtered
+        count of a 0 / 1 lift); an integer sum is kept as int64."""
+        return self._fold("sum", leaves)
+
+    def withMin(self, *leaves: str):
+        return self._fold("min", leaves)
+
+    def withMax(self, *leaves: str):
+        return self._fold("max", leaves)
+
+    def withDistinct(self, *leaves: str, space: int):
+        """These leaves are exact distinct counts over member ids in
+        ``[0, space)`` (a negative id: the record adds none).  The leaves
+        of one call are filters of ONE member and share a bit table: a
+        record's ids among them must agree."""
+        self._distinct.append(DistinctGroup(tuple(leaves), int(space)))
+        return self
+
+    def withMaxKeys(self, n: int):
+        """Size of the dense device key space [0, n); keys outside it are
+        refused and counted."""
+        self._max_keys = int(n)
+        return self
+
+    def withOutputCapacity(self, n: int):
+        """Lanes of the batch a step hands on: the groups one batch can
+        touch (default ``min(max keys, capacity)`` rounded up to a power
+        of two).  A step that touched more stops the graph."""
+        self._out_capacity = int(n)
+        return self
+
+    def build(self) -> RollingAggregateTPU:
+        return RollingAggregateTPU(
+            self._lift, plain=self._plain, distinct=self._distinct,
+            max_keys=self._max_keys, key_extractor=self._key_extractor,
+            out_capacity=self._out_capacity, name=self._name,
+            parallelism=self._parallelism)

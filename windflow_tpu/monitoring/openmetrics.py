@@ -240,6 +240,20 @@ def _families(stats: dict,
     f_jm = fam("wf_operator_join_probes_pending_max", "gauge",
                "Most probes that ever waited at once (against "
                "withProbeCapacity)")
+    f_ar = fam("wf_operator_agg_rows_total", "counter",
+               "Upsert rows a rolling aggregate handed on: one a group a "
+               "step touched")
+    f_am = fam("wf_operator_agg_members_total", "counter",
+               "Member ids the distinct leaves of a rolling aggregate "
+               "were offered, by outcome: new (the group's set did not "
+               "hold it), seen (it did), refused (outside the leaf's "
+               "space, or not the member its group's other leaves gave)")
+    f_ak = fam("wf_operator_agg_keys_refused_total", "counter",
+               "Records whose key lay outside a rolling aggregate's "
+               "dense key space: refused, counted as dropped")
+    f_ao = fam("wf_operator_agg_output_overflow_total", "counter",
+               "Groups a rolling aggregate's step touched beyond its "
+               "output batch's lanes (the graph stops on the first)")
     f_sd = fam("wf_operator_sink_deliveries_total", "counter",
                "Batches a columnar sink delivered, by whether the device "
                "had reported the batch done (ready) or the driver waited "
@@ -291,6 +305,17 @@ def _families(stats: dict,
                          dict(lab, outcome=outcome))
             f_jo.add(op.get("Join_build_open", 0), lab)
             f_jh.add(op.get("Join_rows_held_back", 0), lab)
+        if "Agg_rows_out" in op:
+            lab = dict(base, operator=name)
+            f_ar.add(op["Agg_rows_out"], lab)
+            new = op.get("Agg_members_new", 0)
+            f_am.add(new, dict(lab, outcome="new"))
+            f_am.add(op.get("Agg_members_tested", 0) - new,
+                     dict(lab, outcome="seen"))
+            f_am.add(op.get("Agg_members_refused", 0),
+                     dict(lab, outcome="refused"))
+            f_ak.add(op.get("Agg_keys_refused", 0), lab)
+            f_ao.add(op.get("Agg_output_overflow", 0), lab)
         if "CB_rows_out_of_order" in op:
             lab = dict(base, operator=name)
             f_co.add(op["CB_rows_out_of_order"], lab)

@@ -367,6 +367,23 @@ PHASES: Dict[str, tuple] = {
         "a count window in event-time order: the sort of the rows that "
         "waited and the batch's by (released or waiting, key, event "
         "time, tie)"),
+    "wf.agg.sort": (
+        "fused operator program",
+        "a rolling aggregate: a batch's lanes sorted by the word of each "
+        "distinct group's table (so by key), the plain leaves riding"),
+    "wf.agg.distinct": (
+        "fused operator program",
+        "a rolling aggregate's sets: the words read, a run's bits OR-ed "
+        "down it, the new members counted, the changed words written"),
+    "wf.agg.fold": (
+        "fused operator program",
+        "a rolling aggregate's plain leaves folded a key and the new "
+        "members summed a key, the touched groups' state read, folded "
+        "and written"),
+    "wf.agg.rows": (
+        "fused operator program",
+        "a rolling aggregate's upsert rows: each touched group's last "
+        "lane compacted to the front of the output batch"),
     "wf.place": (
         "fused operator program",
         "folding a batch into pane cells and merging them into the "
