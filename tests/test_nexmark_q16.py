@@ -79,6 +79,11 @@ class Oracle:
                 g["what"].add(a_)
         return [self.row(k_) for k_ in sorted(touched)]
 
+    def members(self):
+        """Members over all sets: what ``Agg_members_new`` has counted."""
+        return sum(len(s) for g in self.groups.values()
+                   for s in g.values() if isinstance(s, set))
+
     def row(self, k_):
         g = self.groups[k_]
         return (k_,) + tuple(len(g[n]) if isinstance(g[n], set) else g[n]
@@ -101,6 +106,27 @@ def rows_of(out):
     p = {n: np.asarray(a)[ok] for n, a in out.payload.items()}
     return [tuple(int(p[n][i]) for n in ("key",) + LEAVES)
             for i in range(int(ok.sum()))]
+
+
+def groups_of(space=SPACE):
+    """``builder``'s two distinct groups."""
+    return (rk.DistinctGroup(("who", "low_who", "high_who"), space),
+            rk.DistinctGroup(("what",), 200))
+
+
+def tables_of(oracle):
+    """The bit tables the oracle's sets amount to: a bit a (group,
+    member, leaf), where ``rolling_kernels`` says it lies."""
+    tables = []
+    for g in groups_of(oracle.space):
+        t = np.zeros(oracle.keys * g.words_per_key, np.uint32)
+        for k_, leaves in oracle.groups.items():
+            for j, n in enumerate(g.leaves):
+                for m_ in leaves[n]:
+                    t[k_ * g.words_per_key + m_ // g.members_per_word] |= \
+                        np.uint32(1 << (m_ % g.members_per_word * g.bits + j))
+        tables.append(t)
+    return tables
 
 
 def a_stream(seed, n=1500, keys=KEYS, space=SPACE):
@@ -128,6 +154,9 @@ def drive(op, B, k, m, a, v, oracle, split=None, make=None):
         s = slice(lo, lo + B)
         got = rows_of(op._step(batch_of(B, k[s], m[s], a[s], v[s])))
         assert got == oracle.batch(k[s], m[s], a[s], v[s]), i
+        for mine, theirs in zip(op._state["sets"], tables_of(oracle)):
+            assert np.array_equal(np.asarray(mine), theirs), i
+        assert op.dump_stats()["Agg_members_new"] == oracle.members(), i
     assert op._flush() == []
     return op
 
@@ -136,20 +165,105 @@ def drive(op, B, k, m, a, v, oracle, split=None, make=None):
 # the operator a batch at a time
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B", [64, 256])
-@pytest.mark.parametrize("seed", [161, 162, 2**31 + 16])
-def test_every_step_against_the_sets(seed, B):
-    k, m, a, v = a_stream(seed)
+def _adversarial(name):
+    """Batches built against the way the sets are read and written: at
+    the sorted lanes' own addresses, every lane of a run writing the
+    run's whole word.  ``(B, [(k, m, a, v), ...])``."""
+    B = 32
+    full = lambda x: np.full(B, x, np.int32)      # noqa: E731
+    if name == "one_word":
+        # a whole batch in ONE word of either table: one run of B lanes;
+        # the second step's only new bits ride a middle lane
+        m, a = full(8), full(32)
+        m2, a2 = m.copy(), a.copy()
+        m2[B // 2], a2[B // 2] = 9, 33
+        return B, [(full(2), m, a, full(1)), (full(2), m2, a2, full(1)),
+                   (full(2), m2[::-1], a2[::-1], full(7))]
+    if name == "all_dead":
+        # no lane names a group: every index is the one past the table
+        k = np.where(np.arange(B) % 2 == 0, -1, KEYS).astype(np.int32)
+        live = (full(1), full(3), full(3), full(1))
+        return B, [live, (k, full(3), full(3), full(1)), live]
+    if name == "last_word":
+        # the tables' LAST words (index size - 1) beside dead lanes
+        # (index size, dropped), and the first word beside them
+        k = np.where(np.arange(B) % 3 == 0, KEYS,
+                     np.where(np.arange(B) % 3 == 1, KEYS - 1, 0))
+        m = np.where(k == 0, 0, SPACE - 1 - np.arange(B) % 2)
+        a = np.where(k == 0, 0, 199 - np.arange(B) % 5)
+        return B, [(k.astype(np.int32), m.astype(np.int32),
+                    a.astype(np.int32), np.arange(B, dtype=np.int32) % 10)] * 2
+    if name == "again":
+        # the second step re-tests exactly the first step's members: no
+        # bit is new, every word is written back as it was read
+        k, m, a, v = (x[:B] for x in a_stream(166))
+        return B, [(k, m, a, v), (k[::-1], m[::-1], a[::-1], v[::-1]),
+                   (k, m, a, v)]
+    if name == "two_groups":
+        # one group's runs break where the other's go on: members that
+        # change word every lane beside one auction, then the reverse
+        i = np.arange(B)
+        walk, stay = (i * 8 % 64).astype(np.int32), full(40)
+        return B, [(full(3), walk, stay, full(2)),
+                   (full(3), stay, (i * 32 % 192).astype(np.int32), full(8)),
+                   (np.where(i < B // 2, 3, 4).astype(np.int32),
+                    np.where(i % 4 < 2, 16, 17).astype(np.int32),
+                    np.where(i % 6 < 3, 64, 65).astype(np.int32), full(5))]
+    raise KeyError(name)
+
+
+ADVERSARIAL = ("one_word", "all_dead", "last_word", "again", "two_groups")
+
+
+@pytest.mark.parametrize("case", [
+    *((seed, B) for B in (64, 256) for seed in (161, 162, 2**31 + 16)),
+    *ADVERSARIAL], ids=str)
+def test_every_step_against_the_sets(case):
+    """Every step's rows, its tables bit for bit and its count of new
+    members against plain Python sets: a skewed stream, and batches made
+    to break the read and the write of the tables."""
+    stream = not isinstance(case, str)
+    if stream:
+        B, (k, m, a, v) = case[1], a_stream(case[0])
+    else:
+        B, batches = _adversarial(case)
+        k, m, a, v = (np.concatenate(x) for x in zip(*batches))
     oracle = Oracle()
     op = drive(builder().build(), B, k, m, a, v, oracle)
     st = op.dump_stats()
-    assert st["Agg_keys_refused"] == oracle.refused > 0
-    assert st["Agg_members_refused"] > 0        # ids past the space
+    assert st["Agg_keys_refused"] == oracle.refused
     assert st["Agg_output_overflow"] == 0
-    new = sum(len(s) for g in oracle.groups.values()
-              for s in g.values() if isinstance(s, set))
-    assert st["Agg_members_new"] == new < st["Agg_members_tested"]
     assert op.num_dropped_tuples() == oracle.refused
+    if stream:
+        assert oracle.refused > 0
+        assert st["Agg_members_refused"] > 0        # ids past the space
+        assert oracle.members() < st["Agg_members_tested"]
+
+
+@pytest.mark.parametrize("case", [(162, 64), *ADVERSARIAL], ids=str)
+def test_the_lanes_of_a_run_write_one_value_in_any_order(case, monkeypatch):
+    """A scatter applies the updates of one index in no promised order
+    (on the CPU backend the last lane wins; the chip need not agree).
+    Handed every table scatter's updates in a shuffled order, the steps
+    leave the same tables, rows and counts: every lane of a run writes
+    the run's whole word."""
+    from jax._src.lax import slicing
+    real, shuffled = slicing.scatter, []
+    tables = {(KEYS * g.words_per_key,) for g in groups_of()}
+    assert len(tables) == 2
+
+    def scatter(operand, indices, updates, dnums, **kw):
+        if operand.dtype == jnp.uint32 and operand.shape in tables:
+            order = np.random.default_rng(len(shuffled)).permutation(
+                updates.shape[0])
+            shuffled.append(operand.shape)
+            indices, updates = indices[order], updates[order]
+            kw["indices_are_sorted"] = False
+        return real(operand, indices, updates, dnums, **kw)
+
+    monkeypatch.setattr(slicing, "scatter", scatter)
+    test_every_step_against_the_sets(case)
+    assert set(shuffled) == tables and len(shuffled) == 2
 
 
 def one_group(members, values=None, B=16):
@@ -367,16 +481,19 @@ def test_plain_leaves_alone_and_their_widths():
 # the lowered step
 # ---------------------------------------------------------------------------
 
+def _lowered_step():
+    op = builder().build()
+    b = batch_of(64, [1], [1], [1], [1])
+    op._ensure(b)
+    return op._state, op._jit_step._jit.lower(
+        op._state, b.payload, b.ts, b.valid, jnp.int64(0))
+
+
 def test_the_step_updates_its_tables_in_place_and_moves_32_bit_words():
     """Every state leaf is donated and aliased to an output (the tables
     are updated where they lie), and no gather or scatter of the step
     moves a 64-bit element."""
-    op = builder().build()
-    b = batch_of(64, [1], [1], [1], [1])
-    op._ensure(b)
-    state = op._state
-    lowered = op._jit_step._jit.lower(state, b.payload, b.ts, b.valid,
-                                      jnp.int64(0))
+    state, lowered = _lowered_step()
     text = lowered.as_text()
     n_state = len(jax.tree.leaves(state))
     assert len(re.findall(r"tf\.aliasing_output", text)) == n_state
@@ -386,6 +503,35 @@ def test_the_step_updates_its_tables_in_place_and_moves_32_bit_words():
     names = set(re.findall(r"wf\.agg\.\w+", hlo))
     assert names == {"wf.agg.sort", "wf.agg.distinct", "wf.agg.fold",
                      "wf.agg.rows"}
+
+
+def test_the_step_reads_and_writes_its_tables_in_the_sorts_own_order():
+    """The sets are gathered and scattered at the sorted lanes' own
+    addresses and the step says so: every gather from and scatter into
+    a bit table carries ``indices_are_sorted = true`` (a compiler that
+    is not told sorts the updates itself, or scatters five times
+    slower: ``PERF.md`` section 6, PR 49); none claims
+    ``unique_indices`` (the lanes of a run write one word, the same
+    value); the step sorts once a distinct group and once for the rows
+    and no more; and all of its state is still updated in place."""
+    state, lowered = _lowered_step()
+    text = lowered.as_text()
+    sizes = {int(t.shape[0]) for t in state["sets"]}
+    assert len(sizes) == len(state["sets"]) == 2
+    flat = r"tensor<(\d+)xui32>, tensor<\d+x1xi32>"
+    gathers = re.findall(
+        r'"stablehlo\.gather"\([^)]*\) <\{([^\n]*?)\}> : \(' + flat, text)
+    scatters = re.findall(
+        r'"stablehlo\.scatter"\([^)]*\) <\{([^\n]*?)\}> \(\{.*?\n\s*\}\) : \('
+        + flat, text, re.S)
+    for found in (gathers, scatters):
+        assert sorted(int(n) for _, n in found) == sorted(sizes)
+        for attrs, _ in found:
+            assert "indices_are_sorted = true" in attrs
+    assert "unique_indices = true" not in text
+    assert len(re.findall(r'"stablehlo\.sort"', text)) == len(sizes) + 1
+    assert len(re.findall(r"tf\.aliasing_output", text)) \
+        == len(jax.tree.leaves(state))
 
 
 # ---------------------------------------------------------------------------

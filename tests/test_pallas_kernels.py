@@ -744,3 +744,60 @@ def test_ysb_tb_step_compiles_for_v5e_without_a_scatter(v5e_chip, in_scan):
     text = compiled.as_text()
     assert text.count(" convolution(") == 1 and " scatter(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.slow   # ~160 s: one real XLA:TPU compile at the cell's size
+def test_q16_rolling_step_compiles_for_v5e_in_the_sorts_own_order(v5e_chip):
+    """``tests/test_nexmark_q16.py`` reads in the lowered text what the
+    rolling aggregate's step ASKS for; this is what the chip's compiler
+    makes of it, at ``nexmark_q16.saturated``'s own sizes and from
+    shapes alone (no table is allocated): the program's only sorts are
+    the ones it wrote (one a distinct group, one for the rows: XLA:TPU
+    sorts an unmarked scatter's updates itself, and did inside
+    ``wf.agg.distinct`` before the step said its order), both table
+    gathers and both table scatters keep ``indices_are_sorted=true``,
+    and every byte of state is aliased, with no room beside it for a
+    copy of a table."""
+    from benchmark import harness
+    from windflow_tpu.windows.rolling_tpu import RollingAggregateTPU
+    q16 = harness.load_module("configs", "nexmark_q16")
+    cfg = harness.resolve_cell("nexmark_q16.saturated")["config"]
+    graph = q16.build_graph(cfg, None, lambda: iter(()), lambda c: None)
+    [op] = [o for o in graph._topo_operators()
+            if isinstance(o, RollingAggregateTPU)]
+    B = cfg["graph"]["batch"]
+    S = lambda shape, dt: jax.ShapeDtypeStruct(   # noqa: E731
+        shape, dt, sharding=v5e_chip)
+    payload = {"key": S((B,), jnp.int32),
+               **{f"v{i}": S((B,), jnp.float32)
+                  for i in range(q16.N_FIELDS)}}
+    state = jax.tree.map(lambda a: S(a.shape, a.dtype),
+                         jax.eval_shape(op._make_state, payload))
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:        # a described chip's entry could not be read back
+        compiled = jax.jit(op._make_step(B), donate_argnums=(0,)).lower(
+            state, payload, S((B,), jnp.int64), S((B,), jnp.bool_),
+            S((), jnp.int64)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    import re
+    lines = compiled.as_text().splitlines()
+
+    def phase(ln):      # XLA's own instructions may carry no name
+        m = re.search(r'op_name="[^"]*?(wf\.agg\.\w+)', ln)
+        return m.group(1) if m else None
+
+    sorts = [phase(ln) for ln in lines if " sort(" in ln]
+    assert sorted(sorts, key=str) \
+        == ["wf.agg.rows"] + ["wf.agg.sort"] * len(op.distinct)
+    for kind in (" gather(", " scatter("):
+        table = [ln for ln in lines
+                 if kind in ln and phase(ln) == "wf.agg.distinct"]
+        assert len(table) == len(op.distinct)
+        assert all("indices_are_sorted=true" in ln for ln in table)
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for a in jax.tree.leaves(state))
+    mem = compiled.memory_analysis()    # aliased: the leaves, padded to tiles
+    assert mem.alias_size_in_bytes >= held > 4 << 30
+    assert mem.temp_size_in_bytes < 1 << 30     # no copy of either table

@@ -38,8 +38,14 @@ One step, per fixed-capacity batch of ``B`` lanes:
    one member, and its members that share a word, fall into one run.
 3. ``wf.agg.distinct``: the words read once a lane, the bits of a run
    OR-ed down it (a segmented scan), the bits that are new counted a
-   leaf at the run's last lane, and only there, and only where a bit is
-   new, the word written: no two lanes write one word.
+   leaf at the run's last lane, the run's whole OR brought back up to
+   every lane (the same scan, reversed), and every lane of a run
+   writes the run's whole word at its own address.  The lanes of a
+   run write equal values, so the order of the writes does not
+   matter; and the read and the write go by the sort's own order,
+   which XLA is told (``indices_are_sorted``): it need not sort the
+   updates itself, and a scatter it does not know to be sorted costs
+   five times one it knows (``PERF.md`` section 6, PR 49).
 4. ``wf.agg.fold``: the plain leaves folded down each key's run, the new
    members summed a key; at the last lane of each key the group's state
    is read, folded and written (32-bit words: an 8-byte leaf as two).
@@ -249,13 +255,15 @@ def make_rolling_step(capacity: int, K: int, lift: Callable, plain: dict,
             n_key_refused = jnp.sum(valid & ~inside, dtype=jnp.int64)
         return addrs, bits, riders, live, tested, refused, n_key_refused
 
-    def or_down_runs(first, bits):
-        """Each lane: the OR of its run's bits up to it."""
+    def or_down_runs(first, bits, reverse=False):
+        """Each lane: the OR of its run's bits up to it; ``reverse``:
+        from it on, ``first`` then marking the lane a run ends at."""
         def op(a, b):
             fa, va = a
             fb, vb = b
             return fa | fb, jnp.where(fb, vb, va | vb)
-        return jax.lax.associative_scan(op, (first, bits))[1]
+        return jax.lax.associative_scan(op, (first, bits),
+                                        reverse=reverse)[1]
 
     def fold_down_runs(first, riders):
         """Each lane: its key's plain leaves folded up to it."""
@@ -272,16 +280,22 @@ def make_rolling_step(capacity: int, K: int, lift: Callable, plain: dict,
     def test_and_set(g: DistinctGroup, table, saddr, sbits):
         """One group's sorted lanes against its table.  Returns the
         table and, a leaf, each lane's count of new members (set at the
-        last lane of a word's run alone)."""
+        last lane of a word's run alone).  Every lane reads and writes
+        at its own address, a dead one (all at the back of the sort)
+        one past the table: the indices are the sort's own order.  The
+        lanes of a run all write the run's whole word (one with no new
+        bit what it read): duplicates, but of one value."""
         alive = saddr != DEAD
-        old = table.at[jnp.where(alive, saddr, 0)].get(
-            mode="promise_in_bounds")
+        at = jnp.minimum(saddr, table.shape[0])
+        old = table.at[at].get(mode="fill", fill_value=0,
+                               indices_are_sorted=True)
         first = saddr != _before(saddr, -1)
         last = saddr != _after(saddr, -1)
         run = or_down_runs(first, sbits)
         new = jnp.where(last & alive, run & ~old, jnp.uint32(0))
-        at = jnp.where(new != 0, saddr, table.shape[0])
-        table = table.at[at].set(old | run, mode="drop")
+        whole = or_down_runs(last, run, reverse=True)
+        table = table.at[at].set(old | whole, mode="drop",
+                                 indices_are_sorted=True)
         low = jnp.uint32(g.lowest_bits)
         return table, [jax.lax.population_count((new >> j) & low)
                        .astype(jnp.int32) for j in range(len(g.leaves))]
