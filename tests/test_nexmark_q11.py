@@ -706,7 +706,7 @@ def test_dispatch_span_says_out_cap(replayed, monkeypatch):
     assert not [kw for n, kw in seen if n == "wf.dispatch"
                 and kw.get("op") == "session_row" and "out_cap" in kw]
     d2h = [kw for n, kw in seen if n == "wf.sink.d2h"]
-    assert d2h and all(kw["lanes"] == 512 * kw["batches"] for kw in d2h)
+    assert d2h and all(kw["lanes"] == 512 for kw in d2h)
 
 
 @pytest.mark.parametrize("n_ready", [10, 64, 65, 700, 1500])

@@ -347,7 +347,7 @@ def test_counters_and_the_spans_waited_say_what_happened(monkeypatch, gated):
     # four whole-batch copies (a batch this small is never copied by its
     # front): ``lanes`` copied = the batch's ``cap``, ``bytes`` = its lanes
     # (key 4 + value 4 + ts 8 + valid 1 a lane), summed in the counter
-    assert all(c["batches"] == 1 and c["lanes"] == c["cap"] == 64
+    assert all("batches" not in c and c["lanes"] == c["cap"] == 64
                and c["bytes"] == 64 * 17 for c in d2h)
     assert st["Bytes_D2H_total"] == sum(c["bytes"] for c in d2h)
     assert row["Sink_front_copies"] == row["Sink_front_overflows"] == 0

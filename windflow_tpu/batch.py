@@ -693,7 +693,11 @@ class ColumnarEgress:
         if self._packed is None:
             return _columns_fallback(self.batch)
         buf, treedef, specs, cap = self._packed
-        raw, lanes, n = np.asarray(buf), cap, self.batch.known_size
+        # the wait for the chip and the link is a span of its own: what
+        # is left of the ``wf.sink.d2h`` around it is the re-typing
+        with flightrec.wait("d2h"):
+            raw = np.asarray(buf)
+        lanes, n = cap, self.batch.known_size
         if self.front is not None:
             n, extent = int(raw[-2]), int(raw[-1])
             if extent <= self.front:
@@ -702,7 +706,8 @@ class ColumnarEgress:
                 whole, _ = _egress_pack(
                     self.batch, jax.tree.leaves(self.batch.payload),
                     treedef, cap)
-                raw = np.asarray(whole)
+                with flightrec.wait("d2h"):
+                    raw = np.asarray(whole)
                 self.lanes_copied = self.front + cap
         cols, tss, self.extent = _egress_unpack(raw, treedef, specs, lanes,
                                                 n)
@@ -720,17 +725,24 @@ def device_to_columns_multi(batches):
     return [e.columns() for e in started]
 
 
+def _lanes_to_host(batch: DeviceBatch):
+    """``(payload, ts, valid)`` of a batch that takes no packed buffer as
+    numpy lanes: its blocking reads, one wait (the selection of the rows
+    is the caller's own work)."""
+    with flightrec.wait("d2h"):
+        return (jax.tree.map(_np_local, batch.payload),
+                _np_local(batch.ts), _np_local(batch.valid))
+
+
 def _columns_fallback(batch: DeviceBatch):
-    valid = _np_local(batch.valid)
+    payload, ts, valid = _lanes_to_host(batch)
     n = batch.known_size
     if n is not None and len(valid) == batch.capacity \
             and bool(valid[:n].all()):
         # staged batches carry prefix validity: slice, no gather
-        cols = jax.tree.map(lambda a: _np_local(a)[:n], batch.payload)
-        return cols, _np_local(batch.ts)[:n]
+        return jax.tree.map(lambda a: a[:n], payload), ts[:n]
     idx = np.nonzero(valid)[0]
-    cols = jax.tree.map(lambda a: _np_local(a)[idx], batch.payload)
-    return cols, _np_local(batch.ts)[idx]
+    return jax.tree.map(lambda a: a[idx], payload), ts[idx]
 
 
 def device_to_host(batch: DeviceBatch) -> HostBatch:
@@ -741,23 +753,23 @@ def device_to_host(batch: DeviceBatch) -> HostBatch:
     the reference's single pinned D2H copy — and record construction uses
     ``tolist()`` + ``dict(zip(...))`` on the common flat-dict payload shape
     rather than per-tuple pytree calls."""
-    valid = _np_local(batch.valid)
+    payload, ts, valid = _lanes_to_host(batch)
     idx = np.nonzero(valid)[0]
-    tss = _np_local(batch.ts)[idx].tolist()
-    if isinstance(batch.payload, dict) and all(
-            hasattr(a, "ndim") for a in batch.payload.values()):
+    tss = ts[idx].tolist()
+    if isinstance(payload, dict) and all(
+            hasattr(a, "ndim") for a in payload.values()):
         # flat dict of array lanes only: a nested pytree value (e.g. a
         # multi-leaf window aggregate) has no ndim and takes the generic
         # tree path below
-        cols = {n: _np_local(a)[idx] for n, a in batch.payload.items()}
+        cols = {n: a[idx] for n, a in payload.items()}
         if all(c.ndim == 1 for c in cols.values()):
             names = list(cols)
             items = [dict(zip(names, vals))
                      for vals in zip(*(cols[n].tolist() for n in names))]
             return HostBatch(items=items, tss=tss,
                              watermark=batch.watermark, trace=batch.trace)
-    treedef = jax.tree.structure(batch.payload)
-    cols = [_np_local(leaf)[idx] for leaf in jax.tree.leaves(batch.payload)]
+    treedef = jax.tree.structure(payload)
+    cols = [leaf[idx] for leaf in jax.tree.leaves(payload)]
     items = [jax.tree.unflatten(treedef, [c[i] for c in cols])
              for i in range(len(idx))]
     # Unwrap 0-d numpy scalars for ergonomic host-side records.

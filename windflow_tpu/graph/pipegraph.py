@@ -1307,8 +1307,9 @@ class PipeGraph:
                                 if self._recorder is not None
                                 else {"enabled": False}),
             # host time per layer span (count, total_ns, self_ns by span
-            # name): what a profiler capture shows per event, summed
-            # over the run; empty with the recorder off
+            # name; wait_ns on wf.sweep: blocked on the chip): what a
+            # profiler capture shows per event, summed over the run;
+            # empty with the recorder off
             "Layers": (self._recorder.layers()
                        if self._recorder is not None else {}),
             # pre-flight analysis (windflow_tpu/analysis): check() cost +

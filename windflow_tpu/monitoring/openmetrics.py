@@ -579,12 +579,13 @@ def _families(stats: dict,
         f_layer = fam("wf_layer_span_total", "counter",
                       "Host time per layer span of the sweep: stat=count "
                       "spans closed, total_ns their durations, self_ns "
-                      "those minus their child spans (docs/OBSERVABILITY.md "
+                      "those minus their child spans; on span=wf.sweep "
+                      "also wait_ns, the part of the sweeps the driver "
+                      "stood blocked on the chip (docs/OBSERVABILITY.md "
                       "span table)")
         for name, row in layers.items():
-            for stat in ("count", "total_ns", "self_ns"):
-                f_layer.add(row.get(stat, 0), dict(base, span=name,
-                                                   stat=stat))
+            for stat, value in row.items():
+                f_layer.add(value, dict(base, span=name, stat=stat))
 
     # -- latency histograms --------------------------------------------------
     lat = stats.get("Latency") or {}

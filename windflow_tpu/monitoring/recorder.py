@@ -51,7 +51,9 @@ time.  This module adds the missing batch-granular layer:
   no profiler runs.  Spans open per sweep, per chunk and per batch,
   never per tuple; the spans of one staged batch share ``batch=``, the
   recorder's batch sequence number (a sampled batch's trace id is that
-  number).
+  number).  Where the thread BLOCKS for the chip the wait is an
+  innermost span of its own, named in :data:`WAITS` (:func:`wait`), so
+  the self time of the span around it is the host's own work.
 
 * **Device phases.**  :func:`phase` and :func:`operator_scope` are to the
   device's ``XLA Ops`` line what :func:`span` is to the host plane: a
@@ -342,6 +344,83 @@ def span(name: str, **counts):
     return _Span(top.table, name, counts, top)
 
 
+#: the driver thread's root span (``PipeGraph.step``)
+ROOT_SPAN = "wf.sweep"
+WAIT_PREFIX = "wf.wait."
+
+#: the one vocabulary of the waits for the chip: span name -> (layer, as
+#: BENCHMARK.json names it; what the thread blocks for).  Every blocking
+#: read of a device value on the hot path is an innermost span named
+#: here, so the self time of the span around it (``wf.dispatch``,
+#: ``wf.sink.d2h``, ``wf.drain``, ``wf.pack``) is the host's own work,
+#: a thread's blocked time is the sum of these names' self times
+#: (``wait_ns`` of :meth:`FlightRecorder.layers`), and an idle gap of
+#: the chip under one of them is the link's, not the host's.  The reason
+#: is in the NAME: a reader of a capture keeps few spans' stats.
+#: :func:`wait` opens the ``wf.wait.*`` ones and refuses any other; the
+#: two that stood before the table keep their names and their
+#: :func:`span` sites.  docs/OBSERVABILITY.md "Span tracing" carries the
+#: same table.
+WAITS: Dict[str, tuple] = {
+    "wf.wait.held": (
+        "fused operator program",
+        "once a batch, the rows the step BEFORE held back or lost "
+        "(session window, joins, ordered count window, rolling "
+        "aggregate): the read that lets the watermark be handed on "
+        "blocks until that step has run, so it falls when the chip "
+        "gets faster"),
+    "wf.wait.flush": (
+        "fused operator program",
+        "at end of stream, what a flush pass of a window fired, "
+        "advanced or still holds (a pass a read: its length goes with "
+        "the windows left open, not with a batch's step)"),
+    "wf.wait.evicted": (
+        "fused operator program",
+        "a time window's eviction count under the ``error`` overflow "
+        "policy, read a step in 32"),
+    "wf.wait.keys": (
+        "fused operator program",
+        "a stateful map / filter without a declared key space: a "
+        "batch's keys and validity on the host, where its new keys are "
+        "interned (the wait for what fills the batch, then a small "
+        "copy)"),
+    "wf.wait.d2h": (
+        "egress / sink",
+        "an output batch's bytes on the host: the step that fills it, "
+        "the pack program and what is left of the copy started at "
+        "receipt (with ``waited=0`` on the ``wf.sink.d2h`` around it "
+        "the step had run: the rest is the link)"),
+    "wf.wait.sync": (
+        "driver sweep",
+        "the sampled ``block_until_ready`` behind a ``device_done`` "
+        "instant of the ring (1 traced batch in "
+        "``trace_device_sync_every``)"),
+    "wf.pool.wait": (
+        "staging: pack, wire encode, H2D",
+        "a pooled staging buffer whose transfer the device has not "
+        "taken yet (``StagingPool.acquire``, only when it blocks)"),
+    "wf.megastep.drain": (
+        "megastep (K=8 lax.scan)",
+        "the blocking copy of a K-group's outputs"),
+}
+
+
+#: ``wait``'s argument -> the span's name, built once
+_WAIT_NAMES = {name[len(WAIT_PREFIX):]: name for name in WAITS
+               if name.startswith(WAIT_PREFIX)}
+
+
+def wait(what: str, **counts):
+    """``span("wf.wait.<what>")``: the thread blocks for the chip here.
+    ``what`` is one of the ``wf.wait.*`` names of :data:`WAITS`."""
+    name = _WAIT_NAMES.get(what)
+    if name is None:
+        raise ValueError(
+            f"{WAIT_PREFIX + what!r} is not a wait for the chip: declare "
+            "it in recorder.WAITS (and docs/OBSERVABILITY.md)")
+    return span(name, **counts)
+
+
 # ---------------------------------------------------------------------------
 # Device phases: the program's own names on the device's ``XLA Ops`` line
 # ---------------------------------------------------------------------------
@@ -374,7 +453,8 @@ PHASES: Dict[str, tuple] = {
     "wf.agg.distinct": (
         "fused operator program",
         "a rolling aggregate's sets: the words read, a run's bits OR-ed "
-        "down it, the new members counted, the changed words written"),
+        "down it, the new members counted, every tested word written "
+        "back at its own sorted address"),
     "wf.agg.fold": (
         "fused operator program",
         "a rolling aggregate's plain leaves folded a key and the new "
@@ -563,17 +643,26 @@ class FlightRecorder:
     def layers(self, thread: Optional[int] = None) -> dict:
         """``{name: {"count", "total_ns", "self_ns"}}`` summed over the
         threads that recorded (``stats()["Layers"]``), or of one thread
-        (``threading.get_ident()`` of the driver, say)."""
+        (``threading.get_ident()`` of the driver, say).  The ``wf.sweep``
+        row also carries ``wait_ns``: the self time, on the threads that
+        own sweeps, of every span named in :data:`WAITS`, so that
+        ``wait_ns / total_ns`` of that row is the share of its sweeps
+        the driver stood blocked on the chip."""
         tables = list(self._layers.values()) if thread is None \
             else [self._layers.get(thread, {})]
         out: Dict[str, dict] = {}
         for table in tables:
-            for name, (count, total, self_ns) in list(table.items()):
+            rows = list(table.items())
+            for name, (count, total, self_ns) in rows:
                 row = out.setdefault(name, {"count": 0, "total_ns": 0,
                                             "self_ns": 0})
                 row["count"] += count
                 row["total_ns"] += total
                 row["self_ns"] += self_ns
+            if ROOT_SPAN in table:
+                root = out[ROOT_SPAN]
+                root["wait_ns"] = root.get("wait_ns", 0) + sum(
+                    r[2] for name, r in rows if name in WAITS)
         return out
 
     # -- ring registry -------------------------------------------------------

@@ -86,9 +86,8 @@ class SinkReplica(Replica):
             return
         nbytes = wfbatch.transfer_nbytes(batch)
         self.stats.d2h_bytes += nbytes
-        with flightrec.span("wf.sink.d2h", batch=batch.seq, batches=1,
-                            bytes=nbytes, lanes=batch.capacity,
-                            cap=batch.capacity):
+        with flightrec.span("wf.sink.d2h", batch=batch.seq, bytes=nbytes,
+                            lanes=batch.capacity, cap=batch.capacity):
             hb = wfbatch.device_to_host(batch)
         with flightrec.span("wf.sink.deliver", batch=batch.seq,
                             rows=len(hb.items)):
@@ -145,8 +144,8 @@ class SinkReplica(Replica):
         # here or found it done; ``lanes`` and ``bytes`` what crossed the
         # link of the batch's ``cap`` lanes: the front, the front and then
         # the whole batch (an overflow), or the whole batch
-        with flightrec.span("wf.sink.d2h", batch=b.seq, batches=1,
-                            cap=b.capacity, waited=waited) as sp:
+        with flightrec.span("wf.sink.d2h", batch=b.seq, cap=b.capacity,
+                            waited=waited) as sp:
             (cols, tss), = wfbatch.device_to_columns_multi([egress])
             lanes = egress.lanes_copied
             nbytes = wfbatch.transfer_nbytes(b) * lanes // b.capacity

@@ -91,7 +91,8 @@ class _TPUReplica(Replica):
             sync_every = self.config.trace_device_sync_every
             if out is not None and sync_every \
                     and self._traced_seen % sync_every == 0:
-                jax.block_until_ready(out.valid)
+                with flightrec.wait("sync", batch=batch.seq):
+                    jax.block_until_ready(out.valid)
                 now = current_time_usecs()
                 self.ring.record(batch.trace[0], flightrec.DEVICE_DONE,
                                  now)

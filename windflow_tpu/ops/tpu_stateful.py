@@ -509,8 +509,9 @@ class _StatefulTPUBase(Operator):
                                    op_name=f"{self.name}.key_extract")
         keys_dev = batch.keys if batch.keys is not None \
             else self._extract(batch.payload)
-        keys_np = np.asarray(keys_dev)
-        valid_np = np.asarray(batch.valid)
+        with flightrec.wait("keys", batch=batch.seq):
+            keys_np = np.asarray(keys_dev)
+            valid_np = np.asarray(batch.valid)
         uniq = np.unique(keys_np[valid_np])
         uniq_slots = self._intern(uniq)
         pad = cap - len(uniq)

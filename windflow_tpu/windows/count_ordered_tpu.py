@@ -192,7 +192,9 @@ class OrderedCountWindowsTPU(_RowsBoundedByDataTPU):
                         self.comb, agg_spec_for(self.lift, payload), one)),
                 op_name=f"{self.name}.flush")
         out, fired, ts = self._jit_flush(self._state)
-        if bool(np.asarray(fired).any()):
+        with flightrec.wait("flush"):
+            any_fired = bool(np.asarray(fired).any())
+        if any_fired:
             outs.append(DeviceBatch(out, ts, fired, watermark=0, size=None))
         return outs
 

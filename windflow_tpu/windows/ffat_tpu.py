@@ -649,12 +649,15 @@ class FfatWindowsTPU(Operator):
             out, fired, out_ts, n_adv = self._run_step(
                 sidx, self._payload_zero, ts0, invalid,
                 jnp.int64(1 << 60))
-            if bool(np.asarray(fired).any()):
+            with flightrec.wait("flush"):
+                any_fired = bool(np.asarray(fired).any())
+                n_adv = int(n_adv)
+            if any_fired:
                 outs.append(DeviceBatch(out, out_ts, fired, watermark=0,
                                         size=None))
             # loop on ADVANCE, not emission: windows beyond an empty gap
             # in the pane sequence would stall behind a no-emission pass
-            if int(n_adv) == 0:
+            if n_adv == 0:
                 break
         return outs
 
@@ -956,7 +959,8 @@ class FfatWindowsTPU(Operator):
         # are summed over every replica state
         if self._auto_np and self.NP < self._np_ceil:
             return   # still growing: regrow, don't error, on overflow
-        ev = self._tb_counter("n_evicted")
+        with flightrec.wait("evicted"):
+            ev = self._tb_counter("n_evicted")
         if self._auto_np and not self._error_armed:
             # the undersized phase leaves a window-firing backlog whose
             # drain still evicts briefly after growth; arm the error only

@@ -160,8 +160,9 @@ class _RowsBoundedByDataTPU(Operator):
         raise) and kept as the step gave it; None before the first."""
         if self._prev_held is None:
             return None
-        self._held(self._prev_held)
-        return np.asarray(self._prev_held)
+        with flightrec.wait("held"):
+            self._held(self._prev_held)
+            return np.asarray(self._prev_held)
 
     def build_replicas(self, mode, time_policy):
         if self.mesh is not None:
@@ -219,12 +220,17 @@ class _RowsBoundedByDataTPU(Operator):
         self._state, out, fired, out_ts, held = self._jit_step(
             self._state, batch.payload, batch.ts, batch.valid,
             jnp.int64(wm))
-        if self._prev_held is not None \
-                and self._held(self._prev_held) == 0 \
-                and self._prev_wm != TS_MIN:
-            # the previous step emitted everything its watermark closed
-            self._out_wm = max(self._out_wm, self._hand_on(
-                self._prev_wm, self._prev_held))
+        if self._prev_held is not None:
+            # the one blocking read of the step: the counts are there
+            # once the step BEFORE has run (a wait of its own, so that
+            # the dispatch span around it times the host's work alone)
+            with flightrec.wait("held", batch=batch.seq):
+                held_before = self._held(self._prev_held)
+            if held_before == 0 and self._prev_wm != TS_MIN:
+                # the previous step emitted everything its watermark
+                # closed
+                self._out_wm = max(self._out_wm, self._hand_on(
+                    self._prev_wm, self._prev_held))
         self._prev_wm, self._prev_held = wm, held
         return DeviceBatch(out, out_ts, fired, watermark=self._out_wm,
                            size=None, trace=batch.trace)
@@ -244,10 +250,13 @@ class _RowsBoundedByDataTPU(Operator):
             self._state, out, fired, out_ts, left = self._jit_step(
                 self._state, self._payload_zero, ts0, none,
                 jnp.int64(TS_MAX))
-            if bool(np.asarray(fired).any()):
+            with flightrec.wait("flush"):
+                any_fired = bool(np.asarray(fired).any())
+                left = self._held(left)
+            if any_fired:
                 outs.append(DeviceBatch(out, out_ts, fired, watermark=0,
                                         size=None))
-            if self._held(left) == 0:
+            if left == 0:
                 return outs
 
     # -- durable state (windflow_tpu/durability) -----------------------------
