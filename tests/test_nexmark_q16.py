@@ -53,7 +53,7 @@ class Oracle:
 
     def __init__(self, keys=KEYS, space=SPACE):
         self.groups, self.keys, self.space = {}, keys, space
-        self.refused = 0
+        self.refused = self.words = 0
 
     def fresh(self):
         return {"n": 0, "low_n": 0, "top": -2**31, "who": set(),
@@ -61,7 +61,8 @@ class Oracle:
 
     def batch(self, k, m, a, v):
         """Fold one batch; the rows it upserts, in key order."""
-        touched = set()
+        touched, words = set(), set()
+        who, what = groups_of(self.space)
         for k_, m_, a_, v_ in zip(k.tolist(), m.tolist(), a.tolist(),
                                   v.tolist()):
             if not 0 <= k_ < self.keys:
@@ -69,6 +70,12 @@ class Oracle:
                 continue
             g = self.groups.setdefault(k_, self.fresh())
             touched.add(k_)
+            # a record reads a word of either table: its member's, or
+            # its group's first where it gives none the table holds
+            words.add(("who", k_, m_ // who.members_per_word
+                       if 0 <= m_ < self.space else 0))
+            words.add(("what", k_, a_ // what.members_per_word
+                       if 0 <= a_ < 200 else 0))
             g["n"] += 1
             g["low_n"] += v_ < 5
             g["top"] = max(g["top"], v_)
@@ -77,6 +84,7 @@ class Oracle:
                 g["low_who" if v_ < 5 else "high_who"].add(m_)
             if 0 <= a_ < 200:
                 g["what"].add(a_)
+        self.words += len(words)
         return [self.row(k_) for k_ in sorted(touched)]
 
     def members(self):
@@ -156,7 +164,9 @@ def drive(op, B, k, m, a, v, oracle, split=None, make=None):
         assert got == oracle.batch(k[s], m[s], a[s], v[s]), i
         for mine, theirs in zip(op._state["sets"], tables_of(oracle)):
             assert np.array_equal(np.asarray(mine), theirs), i
-        assert op.dump_stats()["Agg_members_new"] == oracle.members(), i
+        st = op.dump_stats()
+        assert st["Agg_members_new"] == oracle.members(), i
+        assert st["Agg_words_touched"] == oracle.words, i
     assert op._flush() == []
     return op
 
@@ -167,8 +177,8 @@ def drive(op, B, k, m, a, v, oracle, split=None, make=None):
 
 def _adversarial(name):
     """Batches built against the way the sets are read and written: at
-    the sorted lanes' own addresses, every lane of a run writing the
-    run's whole word.  ``(B, [(k, m, a, v), ...])``."""
+    the addresses of the sorted runs' last lanes, a word read and
+    written once a run.  ``(B, [(k, m, a, v), ...])``."""
     B = 32
     full = lambda x: np.full(B, x, np.int32)      # noqa: E731
     if name == "one_word":
@@ -240,13 +250,61 @@ def test_every_step_against_the_sets(case):
         assert oracle.members() < st["Agg_members_tested"]
 
 
+def runs_batch(B, n_runs, keys, turn=0):
+    """``B`` live lanes that fall into exactly ``n_runs`` runs in either
+    table (``n_runs`` 0: none names a group): lane ``i`` names the
+    ``i % n_runs``-th (key, word) pair of both, and within the word a
+    member that ``turn`` and the lane's pass over the pairs choose."""
+    i = np.arange(B)
+    if n_runs == 0:
+        return (np.full(B, -1, np.int32), i.astype(np.int32) % 8,
+                i.astype(np.int32), i.astype(np.int32) % 10)
+    who, what = groups_of()
+    pair, within = i % n_runs, i // n_runs + turn
+    k, word = pair % keys, pair // keys
+    assert word.max() < min(SPACE // who.members_per_word,
+                            200 // what.members_per_word)
+    m = word * who.members_per_word + within % who.members_per_word
+    a = word * what.members_per_word + within % what.members_per_word
+    return (k.astype(np.int32), m.astype(np.int32), a.astype(np.int32),
+            (i % 10).astype(np.int32))
+
+
+@pytest.mark.parametrize("runs", ["none", "one", "a_chunk",
+                                  "a_chunk_and_one", "every_lane"])
+@pytest.mark.parametrize("B", [32, 51])
+def test_every_trip_count_of_the_walk_over_the_runs(B, runs):
+    """A step reads its runs a chunk of ``chunk_lanes`` at a time, as
+    many chunks as it has runs: none (no trip), one run, exactly a
+    chunk, a chunk and one, and every lane a word of its own (all the
+    chunks; at 51 lanes the last one runs past the batch).  Three steps
+    of each: new members, the same words with other members, and the
+    first again (no new bit)."""
+    C, keys = rk.chunk_lanes(B), 10
+    assert (C, -B % C) == {32: (4, 0), 51: (6, 3)}[B]
+    n_runs = {"none": 0, "one": 1, "a_chunk": C, "a_chunk_and_one": C + 1,
+              "every_lane": B}[runs]
+    batches = [runs_batch(B, n_runs, keys, turn) for turn in (0, 1, 0)]
+    k, m, a, v = (np.concatenate(x) for x in zip(*batches))
+    oracle = Oracle(keys=keys)
+    op = drive(builder(keys=keys).build(), B, k, m, a, v, oracle)
+    st = op.dump_stats()
+    assert st["Agg_words_touched"] == oracle.words == 3 * 2 * n_runs
+    assert st["Agg_keys_refused"] == oracle.refused == (0 if n_runs
+                                                        else 3 * B)
+    assert st["Agg_members_tested"] == (3 * 3 * B if n_runs else 0)
+    assert st["Agg_rows_out"] == 3 * min(n_runs, keys)
+    assert st["Agg_members_refused"] == st["Agg_output_overflow"] == 0
+
+
 @pytest.mark.parametrize("case", [(162, 64), *ADVERSARIAL], ids=str)
-def test_the_lanes_of_a_run_write_one_value_in_any_order(case, monkeypatch):
+def test_a_word_is_written_once_a_step_in_any_order(case, monkeypatch):
     """A scatter applies the updates of one index in no promised order
     (on the CPU backend the last lane wins; the chip need not agree).
-    Handed every table scatter's updates in a shuffled order, the steps
-    leave the same tables, rows and counts: every lane of a run writes
-    the run's whole word."""
+    A step writes a word once: a run's last lane alone carries the run's
+    address, the one value it writes the word it read and the run's
+    whole OR.  Handed every table scatter's updates in a shuffled order,
+    the steps leave the same tables, rows and counts."""
     from jax._src.lax import slicing
     real, shuffled = slicing.scatter, []
     tables = {(KEYS * g.words_per_key,) for g in groups_of()}
@@ -264,6 +322,26 @@ def test_the_lanes_of_a_run_write_one_value_in_any_order(case, monkeypatch):
     monkeypatch.setattr(slicing, "scatter", scatter)
     test_every_step_against_the_sets(case)
     assert set(shuffled) == tables and len(shuffled) == 2
+
+
+@pytest.mark.parametrize("seed,B", [(161, 64), (2**31 + 16, 256)])
+def test_the_words_touched_are_the_batchs_distinct_words(seed, B):
+    """``Agg_words_touched`` is the reference's count of the different
+    words a batch's records name, a table: under the source's skew far
+    fewer than the members tested; one a record and table where no two
+    records share a word."""
+    oracle = Oracle()
+    op = drive(builder().build(), B, *a_stream(seed), oracle)
+    st = op.dump_stats()
+    assert 0 < st["Agg_words_touched"] == oracle.words
+    assert st["Agg_words_touched"] < st["Agg_members_tested"] // 2
+    # every record a word of its own in either table: two a record
+    lone = builder(keys=8).build()
+    k, m, a, v = runs_batch(32, 32, 8)
+    lone._step(batch_of(32, k, m, a, v))
+    st = lone.dump_stats()
+    assert st["Agg_words_touched"] == 2 * 32
+    assert st["Agg_members_tested"] == 3 * 32
 
 
 def one_group(members, values=None, B=16):
@@ -506,30 +584,34 @@ def test_the_step_updates_its_tables_in_place_and_moves_32_bit_words():
 
 
 def test_the_step_reads_and_writes_its_tables_in_the_sorts_own_order():
-    """The sets are gathered and scattered at the sorted lanes' own
-    addresses and the step says so: every gather from and scatter into
+    """The sets are gathered and scattered at the sorted run-ends' own
+    addresses, and the step says so: a table is gathered from a chunk
+    of ``chunk_lanes`` at a time inside one loop, and scattered into
+    once, all the compacted lanes; every gather from and scatter into
     a bit table carries ``indices_are_sorted = true`` (a compiler that
-    is not told sorts the updates itself, or scatters five times
-    slower: ``PERF.md`` section 6, PR 49); none claims
-    ``unique_indices`` (the lanes of a run write one word, the same
-    value); the step sorts once a distinct group and once for the rows
+    is not told sorts the updates itself, or scatters twice to five
+    times slower: ``PERF.md`` section 6, PR 49 and PR 52); none claims
+    ``unique_indices``; the step sorts once a distinct group, once more
+    a group to bring the run-ends to the front, and once for the rows,
     and no more; and all of its state is still updated in place."""
     state, lowered = _lowered_step()
     text = lowered.as_text()
     sizes = {int(t.shape[0]) for t in state["sets"]}
     assert len(sizes) == len(state["sets"]) == 2
-    flat = r"tensor<(\d+)xui32>, tensor<\d+x1xi32>"
+    flat = r"tensor<(\d+)xui32>, tensor<%dx1xi32>"
     gathers = re.findall(
-        r'"stablehlo\.gather"\([^)]*\) <\{([^\n]*?)\}> : \(' + flat, text)
+        r'"stablehlo\.gather"\([^)]*\) <\{([^\n]*?)\}> : \('
+        + flat % rk.chunk_lanes(64), text)
     scatters = re.findall(
         r'"stablehlo\.scatter"\([^)]*\) <\{([^\n]*?)\}> \(\{.*?\n\s*\}\) : \('
-        + flat, text, re.S)
+        + flat % 64, text, re.S)
     for found in (gathers, scatters):
         assert sorted(int(n) for _, n in found) == sorted(sizes)
         for attrs, _ in found:
             assert "indices_are_sorted = true" in attrs
     assert "unique_indices = true" not in text
-    assert len(re.findall(r'"stablehlo\.sort"', text)) == len(sizes) + 1
+    assert len(re.findall(r'"stablehlo\.sort"', text)) == 2 * len(sizes) + 1
+    assert len(re.findall(r"stablehlo\.while", text)) == len(sizes)
     assert len(re.findall(r"tf\.aliasing_output", text)) \
         == len(jax.tree.leaves(state))
 
@@ -579,7 +661,10 @@ def test_the_graph_against_the_sets():
         "new": agg["Agg_members_new"],
         "seen": agg["Agg_members_tested"] - agg["Agg_members_new"],
         "refused": agg["Agg_members_refused"]}
+    assert 0 < agg["Agg_words_touched"] <= agg["Agg_members_tested"]
     for fam, stat in (("wf_operator_agg_rows_total", "Agg_rows_out"),
+                      ("wf_operator_agg_words_touched_total",
+                       "Agg_words_touched"),
                       ("wf_operator_agg_keys_refused_total",
                        "Agg_keys_refused"),
                       ("wf_operator_agg_output_overflow_total",

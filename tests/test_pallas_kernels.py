@@ -752,12 +752,15 @@ def test_q16_rolling_step_compiles_for_v5e_in_the_sorts_own_order(v5e_chip):
     rolling aggregate's step ASKS for; this is what the chip's compiler
     makes of it, at ``nexmark_q16.saturated``'s own sizes and from
     shapes alone (no table is allocated): the program's only sorts are
-    the ones it wrote (one a distinct group, one for the rows: XLA:TPU
-    sorts an unmarked scatter's updates itself, and did inside
-    ``wf.agg.distinct`` before the step said its order), both table
-    gathers and both table scatters keep ``indices_are_sorted=true``,
-    and every byte of state is aliased, with no room beside it for a
-    copy of a table."""
+    the ones it wrote (one a distinct group, one more a group that
+    brings the run-ends to the front, one for the rows: XLA:TPU sorts an
+    unmarked scatter's updates itself, and did inside
+    ``wf.agg.distinct`` before the step said its order); each table is
+    read in the body of one ``while``, a gather of ``chunk_lanes``
+    indices a trip, and written by one scatter outside it, both marked
+    ``indices_are_sorted=true``; and every byte of state is aliased,
+    with no room beside it for a copy of a table into or out of a
+    loop."""
     from benchmark import harness
     from windflow_tpu.windows.rolling_tpu import RollingAggregateTPU
     q16 = harness.load_module("configs", "nexmark_q16")
@@ -788,14 +791,28 @@ def test_q16_rolling_step_compiles_for_v5e_in_the_sorts_own_order(v5e_chip):
         m = re.search(r'op_name="[^"]*?(wf\.agg\.\w+)', ln)
         return m.group(1) if m else None
 
+    from windflow_tpu.windows.rolling_kernels import chunk_lanes
     sorts = [phase(ln) for ln in lines if " sort(" in ln]
     assert sorted(sorts, key=str) \
-        == ["wf.agg.rows"] + ["wf.agg.sort"] * len(op.distinct)
+        == ["wf.agg.distinct"] * len(op.distinct) + ["wf.agg.rows"] \
+        + ["wf.agg.sort"] * len(op.distinct)
     for kind in (" gather(", " scatter("):
         table = [ln for ln in lines
                  if kind in ln and phase(ln) == "wf.agg.distinct"]
         assert len(table) == len(op.distinct)
         assert all("indices_are_sorted=true" in ln for ln in table)
+        # a chunk of words a trip of a loop; ONE scatter a table, outside
+        assert all(("/while/body/" in ln) == (kind == " gather(")
+                   for ln in table)
+        if kind == " gather(":
+            assert all(f"= u32[{chunk_lanes(B)}]{{" in ln for ln in table)
+    loops = [ln for ln in lines if " while(" in ln]
+    assert len(loops) == len(op.distinct) \
+        and all(phase(ln) == "wf.agg.distinct" for ln in loops)
+    # ... each reads one table, which rides the loop as it is
+    assert sorted(int(n) for ln in loops for n in set(
+        re.findall(r"u32\[(\d{7,})\]", ln))) \
+        == sorted(int(t.shape[0]) for t in state["sets"])
     held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                for a in jax.tree.leaves(state))
     mem = compiled.memory_analysis()    # aliased: the leaves, padded to tiles

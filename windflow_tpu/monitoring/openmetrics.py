@@ -248,6 +248,10 @@ def _families(stats: dict,
                "were offered, by outcome: new (the group's set did not "
                "hold it), seen (it did), refused (outside the leaf's "
                "space, or not the member its group's other leaves gave)")
+    f_aw = fam("wf_operator_agg_words_touched_total", "counter",
+               "Words of a rolling aggregate's bit tables read and "
+               "written: one a run of a batch's records that name the "
+               "same word of a table, however many the run holds")
     f_ak = fam("wf_operator_agg_keys_refused_total", "counter",
                "Records whose key lay outside a rolling aggregate's "
                "dense key space: refused, counted as dropped")
@@ -314,6 +318,7 @@ def _families(stats: dict,
                      dict(lab, outcome="seen"))
             f_am.add(op.get("Agg_members_refused", 0),
                      dict(lab, outcome="refused"))
+            f_aw.add(op.get("Agg_words_touched", 0), lab)
             f_ak.add(op.get("Agg_keys_refused", 0), lab)
             f_ao.add(op.get("Agg_output_overflow", 0), lab)
         if "CB_rows_out_of_order" in op:

@@ -452,9 +452,10 @@ PHASES: Dict[str, tuple] = {
         "distinct group's table (so by key), the plain leaves riding"),
     "wf.agg.distinct": (
         "fused operator program",
-        "a rolling aggregate's sets: the words read, a run's bits OR-ed "
-        "down it, the new members counted, every tested word written "
-        "back at its own sorted address"),
+        "a rolling aggregate's sets: a run's bits OR-ed down it, the "
+        "run-ends sorted to the front, their words read a chunk of "
+        "lanes a trip of one loop a table and written back by one "
+        "scatter a table, the new members counted a run"),
     "wf.agg.fold": (
         "fused operator program",
         "a rolling aggregate's plain leaves folded a key and the new "

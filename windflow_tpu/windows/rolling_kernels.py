@@ -36,19 +36,26 @@ One step, per fixed-capacity batch of ``B`` lanes:
 2. ``wf.agg.sort``: one sort a group by word address (so by key), the
    bits riding; the plain leaves ride the first.  A batch's duplicates of
    one member, and its members that share a word, fall into one run.
-3. ``wf.agg.distinct``: the words read once a lane, the bits of a run
-   OR-ed down it (a segmented scan), the bits that are new counted a
-   leaf at the run's last lane, the run's whole OR brought back up to
-   every lane (the same scan, reversed), and every lane of a run
-   writes the run's whole word at its own address.  The lanes of a
-   run write equal values, so the order of the writes does not
-   matter; and the read and the write go by the sort's own order,
-   which XLA is told (``indices_are_sorted``): it need not sort the
-   updates itself, and a scatter it does not know to be sorted costs
-   five times one it knows (``PERF.md`` section 6, PR 49).
-4. ``wf.agg.fold``: the plain leaves folded down each key's run, the new
-   members summed a key; at the last lane of each key the group's state
-   is read, folded and written (32-bit words: an 8-byte leaf as two).
+3. ``wf.agg.distinct``: a word is read once and written once a RUN.
+   The bits of a run are OR-ed down it (a segmented scan), so a run's
+   last lane holds the run's whole OR; a second sort a group brings
+   those lanes to the front, still in address order (a run-end's
+   address is its own).  Their words are gathered a chunk of
+   :func:`chunk_lanes` at a time, as many chunks as the batch has runs
+   (a loop on the device): a gather costs by the index, so a skewed
+   batch pays for its words and not for its lanes.  The bits that are
+   new are kept a run, and the words go back, OR-ed with their runs'
+   bits, in ONE scatter a table over all the compacted lanes (those
+   past the runs one past the table, dropped): a scatter pays a pass
+   over its whole table each time it is called, and little by the
+   index (``PERF.md`` section 6, PR 52).  Both accesses go by the
+   sort's own order, which XLA is told (``indices_are_sorted``): a
+   scatter it does not know to be sorted costs twice one it knows.
+4. ``wf.agg.fold``: the plain leaves folded down each key's run; the new
+   members summed down the RUNS, and a key's read off at the rank its
+   last lane has among its group's run-ends; at the last lane of each
+   key the group's state is read, folded and written (32-bit words: an
+   8-byte leaf as two).
 5. ``wf.agg.rows``: those last lanes compacted to the front of the
    output batch (``OC`` lanes): one row a group.  A step that touched
    more groups than ``OC`` says so (the operator stops the graph).
@@ -71,7 +78,7 @@ from windflow_tpu.windows.ffat_kernels import (_MONOID_OPS, _monoid_identity,
 
 #: what a step counts, in the state as int64 scalars
 COUNTERS = ("n_rows", "n_tested", "n_new", "n_key_refused",
-            "n_member_refused", "n_overflow")
+            "n_member_refused", "n_overflow", "n_words")
 #: a dead lane's sort key: behind every word of every table
 DEAD = np.int32(np.iinfo(np.int32).max)
 
@@ -170,6 +177,14 @@ def out_capacity(capacity: int, K: int, declared: Optional[int]) -> int:
     return 1 << max(min(int(K), int(capacity)) - 1, 0).bit_length()
 
 
+def chunk_lanes(capacity: int) -> int:
+    """Lanes of one table gather: a step reads a group's runs this many
+    at a time, ``ceil(runs / chunk_lanes)`` times (a gather costs by the
+    index, ``PERF.md`` section 6, PR 52: a skewed batch pays for its
+    words)."""
+    return max(int(capacity) // 8, 1)
+
+
 def make_rolling_state(lift_spec: dict, plain: dict,
                        groups: Sequence[DistinctGroup], K: int):
     """``lift_spec``: one lifted record (shape/dtype of every leaf)."""
@@ -219,6 +234,9 @@ def make_rolling_step(capacity: int, K: int, lift: Callable, plain: dict,
     # the sort that brings the plain leaves into key order: the first
     # group's (a word address sorts by key too), else one by key alone
     wpk0 = groups[0].words_per_key if groups else 1
+    # the compacted run-ends are read C lanes at a time
+    C = chunk_lanes(B)
+    pad = -B % C
 
     def lanes(payload, ts, valid):
         with phase("wf.fn"):
@@ -255,15 +273,13 @@ def make_rolling_step(capacity: int, K: int, lift: Callable, plain: dict,
             n_key_refused = jnp.sum(valid & ~inside, dtype=jnp.int64)
         return addrs, bits, riders, live, tested, refused, n_key_refused
 
-    def or_down_runs(first, bits, reverse=False):
-        """Each lane: the OR of its run's bits up to it; ``reverse``:
-        from it on, ``first`` then marking the lane a run ends at."""
+    def or_down_runs(first, bits):
+        """Each lane: the OR of its run's bits up to it."""
         def op(a, b):
             fa, va = a
             fb, vb = b
             return fa | fb, jnp.where(fb, vb, va | vb)
-        return jax.lax.associative_scan(op, (first, bits),
-                                        reverse=reverse)[1]
+        return jax.lax.associative_scan(op, (first, bits))[1]
 
     def fold_down_runs(first, riders):
         """Each lane: its key's plain leaves folded up to it."""
@@ -278,27 +294,45 @@ def make_rolling_step(capacity: int, K: int, lift: Callable, plain: dict,
         return jax.lax.associative_scan(op, (first, riders))[1]
 
     def test_and_set(g: DistinctGroup, table, saddr, sbits):
-        """One group's sorted lanes against its table.  Returns the
-        table and, a leaf, each lane's count of new members (set at the
-        last lane of a word's run alone).  Every lane reads and writes
-        at its own address, a dead one (all at the back of the sort)
-        one past the table: the indices are the sort's own order.  The
-        lanes of a run all write the run's whole word (one with no new
-        bit what it read): duplicates, but of one value."""
-        alive = saddr != DEAD
-        at = jnp.minimum(saddr, table.shape[0])
-        old = table.at[at].get(mode="fill", fill_value=0,
-                               indices_are_sorted=True)
+        """One group's sorted lanes against its table, a read and a
+        write a RUN.  Returns the table; each lane's rank among the
+        run-ends (how many runs end at or before it); a leaf, each
+        run's count of new members, the runs at the front in address
+        order (a lane of rank ``r`` closes the ``r`` first of them).
+        A lane past the runs (all at the back of the compaction) reads
+        nothing and writes one past the table: the indices are the
+        sort's own order."""
+        size = table.shape[0]
         first = saddr != _before(saddr, -1)
-        last = saddr != _after(saddr, -1)
+        ends = (saddr != _after(saddr, -1)) & (saddr != DEAD)
         run = or_down_runs(first, sbits)
-        new = jnp.where(last & alive, run & ~old, jnp.uint32(0))
-        whole = or_down_runs(last, run, reverse=True)
-        table = table.at[at].set(old | whole, mode="drop",
+        rank = jnp.cumsum(ends, dtype=jnp.int32)
+        # a lane that ends no run goes to the back and carries no bits
+        caddr, cbits = jax.lax.sort(
+            (jnp.where(ends, saddr, DEAD),
+             jnp.where(ends, run, jnp.uint32(0))), num_keys=1)
+        at = jnp.minimum(jnp.pad(caddr, (0, pad), constant_values=DEAD),
+                         size)
+        cbits = jnp.pad(cbits, (0, pad))
+
+        def read(i, old):
+            words = table.at[jax.lax.dynamic_slice(at, (i * C,), (C,))].get(
+                mode="fill", fill_value=0, indices_are_sorted=True)
+            return jax.lax.dynamic_update_slice(old, words, (i * C,))
+
+        # a gather costs by the index: as many chunks as there are runs
+        old = jax.lax.fori_loop(0, (rank[-1] + (C - 1)) // C, read,
+                                jnp.zeros_like(cbits))
+        # a scatter pays a pass over its whole table however few its
+        # indices: ONE, and a run with no new bit writes back what it
+        # read (a hole punched into the index would hide its order)
+        table = table.at[at].set(old | cbits, mode="drop",
                                  indices_are_sorted=True)
+        new = cbits & ~old
         low = jnp.uint32(g.lowest_bits)
-        return table, [jax.lax.population_count((new >> j) & low)
-                       .astype(jnp.int32) for j in range(len(g.leaves))]
+        return table, rank, [
+            jax.lax.population_count((new >> j) & low).astype(jnp.int32)
+            for j in range(len(g.leaves))]
 
     def step(state, payload, ts, valid, wm_adj):
         del wm_adj      # nothing waits for a watermark
@@ -314,23 +348,26 @@ def make_rolling_step(capacity: int, K: int, lift: Callable, plain: dict,
             done += [jax.lax.sort((a, b), num_keys=1)
                      for a, b in zip(addrs[1:], bits[1:])]
         with phase("wf.agg.distinct"):
-            sets, fresh = [], []
+            sets, ranks, fresh = [], [], []
             for g, table, d in zip(groups, state["sets"], done):
-                table, new = test_and_set(g, table, d[0], d[1])
+                table, rank, new = test_and_set(g, table, d[0], d[1])
                 sets.append(table)
-                fresh += new
+                ranks.append(rank)
+                fresh.append(new)
         with phase("wf.agg.fold"):
             skey = done[0][0] // wpk0 if wpk0 > 1 else done[0][0]
             touched = skey < K                  # a dead lane's is not
             kfirst = skey != _before(skey, -1)
             klast = touched & (skey != _after(skey, -1))
             folded = fold_down_runs(kfirst, list(done[0][n_ahead:]))
-            # every sort holds a key's lanes in the same places: the new
-            # members of a key are a difference of running sums
-            running = [jnp.cumsum(c) for c in fresh]
+            # every sort holds a key's lanes in the same places: a key's
+            # last lane has, a group, the rank of the key's last run,
+            # and the new members up to that run are a sum down the runs
+            running = [jnp.stack([jnp.cumsum(c) for c in new])
+                       for new in fresh]
             words = [jax.lax.bitcast_convert_type(skey, jnp.uint32)]
             words += [jax.lax.bitcast_convert_type(r, jnp.uint32)
-                      for r in running]
+                      for r in ranks]
             n_fold = len(words)
             for a in folded:
                 words += _to_words(a)
@@ -348,13 +385,19 @@ def make_rolling_step(capacity: int, K: int, lift: Callable, plain: dict,
             read = jnp.where(fired, krow, 0)
             write = jnp.where(fired, krow, K)
         with phase("wf.agg.fold"):
-            sums = [jax.lax.bitcast_convert_type(w, jnp.int32)
-                    for w in at_row[1:n_fold]]
-            new_members = [s - _before(s, 0) for s in sums]
+            # the new members of a key: a difference of those sums, read
+            # where the key's last run and the key before's lie (a fired
+            # row's key has a run in every group)
+            new_members = []
+            for w, sums in zip(at_row[1:n_fold], running):
+                last_run = jnp.where(
+                    fired, jax.lax.bitcast_convert_type(w, jnp.int32) - 1, 0)
+                upto = sums.at[:, last_run].get(mode="promise_in_bounds")
+                new_members.append(jnp.diff(upto, axis=1, prepend=0))
             count = state["count"]
             if new_members:
                 seen = count.at[:, read].get(mode="promise_in_bounds") \
-                    + jnp.stack(new_members)
+                    + jnp.concatenate(new_members)
                 count = count.at[:, write].set(seen, mode="drop")
             table = state["plain"]
             values, w_part, w_state, put = [], n_fold, 0, []
@@ -386,11 +429,14 @@ def make_rolling_step(capacity: int, K: int, lift: Callable, plain: dict,
             counts = {
                 "n_rows": jnp.sum(fired, dtype=jnp.int64),
                 "n_tested": tested,
-                "n_new": sum((r[-1].astype(jnp.int64) for r in running),
+                "n_new": sum((jnp.sum(sums[:, -1], dtype=jnp.int64)
+                              for sums in running),
                              jnp.zeros((), jnp.int64)),
                 "n_key_refused": n_key_refused,
                 "n_member_refused": refused,
-                "n_overflow": over}
+                "n_overflow": over,
+                "n_words": sum((r[-1].astype(jnp.int64) for r in ranks),
+                               jnp.zeros((), jnp.int64))}
             new_state = {"plain": table, "count": count, "sets": sets}
             new_state.update({c: state[c] + counts[c] for c in COUNTERS})
         return new_state, out, fired, out_ts, jnp.stack(

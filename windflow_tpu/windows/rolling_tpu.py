@@ -90,6 +90,7 @@ class RollingAggregateTPU(_RowsBoundedByDataTPU):
     counters = (("Agg_rows_out", "n_rows"),
                 ("Agg_members_tested", "n_tested"),
                 ("Agg_members_new", "n_new"),
+                ("Agg_words_touched", "n_words"),
                 ("Agg_keys_refused", "n_key_refused"),
                 ("Agg_members_refused", "n_member_refused"),
                 ("Agg_output_overflow", "n_overflow"))
